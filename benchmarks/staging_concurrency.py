@@ -149,9 +149,8 @@ def main() -> None:
             f"{N_JOBS} concurrent jobs (single-flight: concurrent misses "
             "wait for the one maker). cache OFF re-stages per TrialData — "
             "the per-job upload tax this PR removes. Upload counts are "
-            "backend-independent; on the ~9 MB/s tunneled link each "
-            "avoided covertype upload is ~3.4 s of cold latency "
-            "(BASELINE.md r5 anatomy). wall_s is NOT the comparison "
+            "backend-independent; what an avoided upload is worth in "
+            "seconds is not measured on the current code. wall_s is NOT the comparison "
             "metric: the first mode to run (cache ON) pays the one-time "
             "XLA compile both modes then share."
         ),

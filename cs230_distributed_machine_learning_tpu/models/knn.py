@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..utils import backend as _backend
 from .base import ModelKernel
 
 _QUERY_BLOCK = 1024
@@ -43,11 +44,7 @@ _PALLAS_MIN_N = 150_000
 
 
 def _use_pallas(n: int) -> bool:
-    if n < _PALLAS_MIN_N:
-        return False
-    import jax
-
-    return jax.default_backend() not in ("cpu",)
+    return n >= _PALLAS_MIN_N and _backend.auto_pallas()
 
 
 class _KNNBase(ModelKernel):

@@ -42,6 +42,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ..utils import backend as _backend
 from .base import ModelKernel, add_intercept
 
 _NEWTON_STEPS = 25
@@ -254,9 +255,9 @@ class LogisticRegressionKernel(ModelKernel):
         dpp = _ceil_to(d + 2, 64)  # + intercept, rounded
         if dpp > 512:  # W block would blow the VMEM budget
             return False
-        if _interpret_mode():
+        if _backend.pallas_interpret():
             return True
-        return jax.default_backend() == "tpu" and n >= 4096
+        return _backend.auto_pallas() and n >= 4096
 
     def batched_staged_extras(self, static, n, d, n_classes, n_splits,
                               fold_signature=None):
@@ -338,7 +339,7 @@ class LogisticRegressionKernel(ModelKernel):
             packed_softmax_grad,
         )
 
-        interpret = _interpret_mode()
+        interpret = _backend.pallas_interpret()
         geo = _packed_geometry(static, n, d, n_classes, n_splits)
         c, S = geo["c"], geo["S"]
         fit_intercept = geo["fit_intercept"]
@@ -529,20 +530,14 @@ def _ceil_to(x: int, m: int) -> int:
     return pad_to_multiple(x, m)
 
 
-def _interpret_mode() -> bool:
-    """CS230_PALLAS_INTERPRET=1 forces the packed path with the interpreter
-    (CPU test coverage for the TPU kernel)."""
-    return os.environ.get("CS230_PALLAS_INTERPRET", "") == "1"
-
-
 def _masked_grad_mode() -> str:
     """Valve for the fused masked-gradient formulation (ISSUE 6 tentpole).
 
     - ``auto`` (default): fused-mask XLA formulation everywhere; the fused
       Pallas lane kernel for large-n nesterov fits on a real TPU backend.
     - ``xla``: fused-mask XLA formulation only (never the lane kernel).
-    - ``pallas``: force the Pallas lane kernel (uses the interpreter off
-      TPU — combine with CS230_PALLAS_INTERPRET=1 in tests). Applies to
+    - ``pallas``: force the Pallas lane kernel (compiled; interpreted only
+      under CS230_PALLAS_INTERPRET=1 on the CPU backend). Applies to
       the grad-descent driver only: the ``_newton`` driver needs the
       probabilities for its Hessian anyway, so it always runs the fused
       XLA form (any non-``legacy`` mode).
@@ -559,8 +554,7 @@ def _fused_step_mode() -> str:
     - ``auto`` (default): one ``packed_nesterov_step`` Pallas call per
       scan iteration — momentum extrapolation, masked softmax-Gram
       gradient, C/L2 scaling, the ``max|G|`` reduce, and the done-masked
-      W/Wp writeback all fused in VMEM with the weights aliased in place
-      — whenever the packed path runs (TPU, or interpret mode on CPU)
+      W/Wp writeback all fused in VMEM — whenever the packed path runs (TPU, or interpret mode on CPU)
       and the weight blocks pass the VMEM gate
       (``fused_step_applicable``); the legacy body otherwise.
     - ``pallas``: force the fused kernel, bypassing the VMEM gate (tests
@@ -639,10 +633,7 @@ def _make_masked_grad_fn(A, Y, y, w, C, lam, pen_mask, mm, mode):
     n, dp = A.shape
     c = Y.shape[1]
     use_pallas = mode == "pallas" or (
-        mode == "auto"
-        and not _interpret_mode()
-        and jax.default_backend() == "tpu"
-        and n >= 4096
+        mode == "auto" and _backend.auto_pallas() and n >= 4096
     )
     if use_pallas:
         from ..ops.pallas_logreg import masked_softmax_grad
@@ -657,7 +648,7 @@ def _make_masked_grad_fn(A, Y, y, w, C, lam, pen_mask, mm, mode):
         )
         y2 = jnp.pad(y.astype(jnp.int32), (0, n_pad - n))[:, None]
         wm = jnp.pad(w.astype(jnp.float32), (0, n_pad - n))[:, None]
-        interp = jax.default_backend() != "tpu"
+        interp = _backend.pallas_interpret()
 
         def grad_fn(W):
             Wp = jnp.pad(W, ((0, dpp - dp), (0, cp - c))).astype(jnp.bfloat16)

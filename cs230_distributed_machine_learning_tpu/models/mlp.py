@@ -25,13 +25,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..utils import backend as _backend
 from .base import ModelKernel
 
 _EPOCH_CAP = 100
-
-
-def _interpret_mode() -> bool:
-    return os.environ.get("CS230_PALLAS_INTERPRET", "") == "1"
 
 
 def _v_dtype_mode() -> str:
@@ -447,9 +444,9 @@ class _MLPBase(ModelKernel):
             return False
         # non-8-multiple batch sizes pad each batch block with zero-weight
         # slots (sublane rule); no eligibility cut needed
-        if _interpret_mode():
+        if _backend.pallas_interpret():
             return True
-        return jax.default_backend() == "tpu" and n >= 4096
+        return _backend.auto_pallas() and n >= 4096
 
     def build_batched_fn(self, static, n, d, n_classes, n_splits, chunk):
         """fn(X, y, TW, EW, hyper) -> {"score": [chunk, n_splits]} (+"mse"
@@ -460,7 +457,7 @@ class _MLPBase(ModelKernel):
 
         from ..ops.pallas_mlp import build_epoch_fn, pick_k
 
-        interpret = _interpret_mode()
+        interpret = _backend.pallas_interpret()
         classification = self.task == "classification"
         c = self._out_dim(static)
         dims = self._dims(d, static)

@@ -9,8 +9,8 @@ line-count in metadata collection (reference dataset_util.py:119-136).
 The shared library is compiled on first use with g++ into the storage root
 (keyed by source hash, so upgrades rebuild) and loaded with ctypes — no
 pybind11 dependency. Every caller must handle ``get_lib() is None`` and
-fall back to the pure-Python path: machines without a toolchain lose speed,
-not capability.
+take the pure-Python path: machines without a toolchain lose speed, not
+capability — and say so once in the log.
 """
 
 from __future__ import annotations
@@ -48,6 +48,14 @@ def _compile(src: str, out: str) -> bool:
         return False
 
 
+def _warn_unavailable(why: str) -> None:
+    from ..utils.logging import get_logger
+
+    get_logger("tpuml.native").warning(
+        "native CSV loader unavailable (%s); parsing with pandas", why
+    )
+
+
 def get_lib() -> Optional[ctypes.CDLL]:
     """The loaded native library, compiling it on first call; None if the
     source is missing, g++ is unavailable, or compilation fails."""
@@ -66,6 +74,7 @@ def get_lib() -> Optional[ctypes.CDLL]:
                 tmp = so_path + f".build{os.getpid()}"
                 if not _compile(_SRC, tmp):
                     _lib_failed = True
+                    _warn_unavailable("g++ missing or the build failed")
                     return None
                 os.replace(tmp, so_path)  # atomic vs concurrent builders
             lib = ctypes.CDLL(so_path)
@@ -84,8 +93,9 @@ def get_lib() -> Optional[ctypes.CDLL]:
             ]
             lib.csv_parse_f32.restype = ctypes.c_int64
             _lib = lib
-        except Exception:  # noqa: BLE001 — any failure degrades to Python
+        except Exception as e:  # noqa: BLE001 — any failure degrades to Python
             _lib_failed = True
+            _warn_unavailable(repr(e))
         return _lib
 
 

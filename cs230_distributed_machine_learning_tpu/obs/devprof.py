@@ -84,7 +84,7 @@ def record_batch_device_seconds(
 
 
 def phase_totals() -> Dict[str, float]:
-    """Current per-phase accumulations (tests / the cash-in report)."""
+    """Current per-phase accumulations (tests, chip_smoke.py)."""
     c = REGISTRY.counter(DEVICE_SECONDS)
     return {p: c.value(phase=p) for p in PHASES}
 

@@ -151,10 +151,34 @@ def level_histogram_pallas(local, xb, SC, n_nodes: int, n_bins: int, *,
     return H.transpose(0, 2, 3, 4, 1).reshape(NBk * Mb, d, n_bins, kk)[:n_nodes]
 
 
+#: Mosaic's default scoped-VMEM limit per kernel on v5e (the call sets no
+#: ``vmem_limit_bytes``); a kernel whose blocks and temporaries exceed it
+#: is refused at compile time ("exceeded scoped vmem limit")
+_SCOPED_VMEM_BYTES = 16 * 2**20
+
+
+def pallas_hist_vmem_bytes(d: int, n_bins: int, kk: int, *,
+                           bm: int = ROW_TILE, Mb: int = NODE_BLOCK) -> int:
+    """What one grid step of ``_hist_kernel`` allocates, at the widest
+    node block: the f32 accumulator page ``[kk*Mb, xpad]`` double-buffered
+    as the output block plus the dot result before it is added in, the
+    ``[bm, xpad]`` bin one-hot and its concatenation pieces, and the
+    ``[bm, kk*Mb]`` T1 tile (counted at 4 bytes: conservative for bf16).
+    Checked against deviceless v5e compiles of d=54 x {17..256} bins x
+    {3,7,8} stats: every refused shape counts over the limit."""
+    xpad = _ceil_to(d * n_bins, 128)
+    page = kk * Mb * xpad * 4
+    return 3 * page + 2 * bm * xpad * 4 + bm * kk * Mb * 4
+
+
 def pallas_hist_applicable(d: int, n_bins: int, kk: int) -> bool:
-    """Static shape gate: the accumulator page + one-hot tiles must fit
-    the VMEM budget (~6 MB at the defaults)."""
-    return d * n_bins <= 4096 and kk <= 16 and n_bins <= 256
+    """Static shape gate of the ``auto`` route: the kernel's VMEM
+    allocation must fit the compiler's scoped limit."""
+    return (
+        kk <= 16
+        and n_bins <= 256
+        and pallas_hist_vmem_bytes(d, n_bins, kk) <= _SCOPED_VMEM_BYTES
+    )
 
 
 def level_histogram_scatter(local, xb, SC, n_nodes: int, n_bins: int):

@@ -139,20 +139,25 @@ def packed_softmax_grad(
     )(Ab, W3, y2, WSP)
 
 
-#: conservative VMEM budget for the fused step's weight-resident blocks
-#: (W/Wp in + W/Wp out, all f32 — 16 bytes per (row, packed column)). The
-#: row-tile intermediates (logits, per-class exp tiles) match the plain
-#: gradient kernel's and are not re-counted here; this bounds only what
-#: the fused form ADDS over ``packed_softmax_grad``. Re-tune on real TPU
-#: (BENCH_r06 follow-up).
+#: gate on the fused step's weight-resident blocks (W/Wp in + W/Wp out,
+#: all f32 — 16 bytes per (row, packed column))
 _FUSED_STEP_VMEM_BYTES = 8 * 1024 * 1024
+
+#: scoped-VMEM limit the fused step asks of the compiler (v5e: 128 MiB
+#: physical, 16 MiB default). At the gate's edge the double-buffered weight
+#: blocks take 2 x 8 MiB and the row tile's [bm, NB] logits, exp tiles and
+#: residual at most ~25 MB more (NB <= 8192 at dpp = 64); the rest is
+#: headroom for operands XLA itself parks in VMEM — at small n it prefetches
+#: the whole design matrix there, which overflowed the default limit.
+_FUSED_STEP_VMEM_LIMIT = 64 * 1024 * 1024
 
 
 def fused_step_applicable(dpp: int, NB: int, bm: int = 256) -> bool:
     """VMEM gate for ``packed_nesterov_step``'s ``auto`` routing: the four
-    f32 weight blocks (W/Wp, in + aliased out) must fit the budget. Forced
-    modes (``CS230_FUSED_STEP=pallas``) bypass this — tests run tiny
-    shapes, and an operator forcing the kernel owns the consequences."""
+    f32 weight blocks (W/Wp in, W/Wp out) must fit the budget, which keeps
+    the whole call inside ``_FUSED_STEP_VMEM_LIMIT``. Forced modes
+    (``CS230_FUSED_STEP=pallas``) bypass this — tests run tiny shapes, and
+    an operator forcing the kernel owns the consequences."""
     return 16 * dpp * NB + 2 * bm * dpp <= _FUSED_STEP_VMEM_BYTES
 
 
@@ -169,22 +174,35 @@ def _fused_step_kernel(
     y_ref     [bm, 1]        i32   labels for the tile rows
     wsp_ref   [bm, S]        f32   per-split {0,1} sample weights
     t_ref     [1, 1]         f32   iteration index t (SMEM scalar)
-    done_ref  [1, B]         f32   1.0 where the trial already converged
-    step_ref  [1, B]         f32   per-(split, trial) step size
-    cb_ref    [1, B]         f32   per-trial C
-    maxit_ref [1, B]         f32   per-trial max_iter
+    done_ref  [1, 1, B]      f32   1.0 where the trial already converged
+    step_ref  [1, 1, B]      f32   per-(split, trial) step size
+    cb_ref    [1, 1, B]      f32   per-trial C
+    maxit_ref [1, 1, B]      f32   per-trial max_iter
     pen_ref   [dpp, 1]       f32   L2 penalty row mask (0 on intercept/pad)
-    wout_ref  [1, dpp, NB]   f32   OUT W_new — aliased onto w_ref's buffer;
-                                   doubles as the cross-tile Gram accumulator
-    wpout_ref [1, dpp, NB]   f32   OUT Wp_new — aliased onto wp_ref's buffer
-    gmax_ref  [1, B]         f32   OUT per-(split, trial) max|G|
+    wout_ref  [1, dpp, NB]   f32   OUT W_new; doubles as the cross-tile Gram
+                                   accumulator
+    wpout_ref [1, dpp, NB]   f32   OUT Wp_new
+    gmax_ref  [1, 1, B]      f32   OUT per-(split, trial) max|G|
+
+    The per-column vectors carry a unit middle axis so their block's last
+    two dims equal the array's (``(1, B)`` of ``[n_wb, 1, B]``): a
+    ``(1, B)`` block of an ``[n_wb, B]`` array is refused by the TPU
+    lowering whenever ``n_wb > 1`` (sublane dim must be 8-divisible or
+    full).
 
     The look-ahead iterate ``V = W + mom*(W - Wp)`` is formed in VMEM from
     the resident W/Wp blocks each row tile (VPU-cheap next to the tile's
     MXU work) — V never exists in HBM. The raw gradient accumulates across
     row tiles in the wout block; the LAST tile's epilogue applies the
     per-trial C scaling + L2 penalty, reduces ``max|G|``, and performs the
-    done/max_iter-masked W/Wp writeback in place.
+    done/max_iter-masked W/Wp writeback.
+
+    The outputs are NOT aliased onto the W/Wp inputs. An earlier form
+    (``input_output_aliases={1: 0, 2: 1}``) passed interpret-mode parity
+    bit for bit and computed wrong weights compiled on a v5e: the
+    accumulator block's write-backs land in the buffer the W input block
+    is read from. The scan carry ping-pongs two buffers instead; HBM
+    traffic on the weights is the same 4 passes.
     """
     i = pl.program_id(1)
     B = S * Tw
@@ -206,10 +224,10 @@ def _fused_step_kernel(
         W = w_ref[0]
         Wp = wp_ref[0]
         V = W + mom * (W - Wp)  # f32 this time: the writeback operand
-        cb = cb_ref[:]  # [1, B]
-        step = step_ref[:]
+        cb = cb_ref[0]  # [1, B]
+        step = step_ref[0]
         pen = pen_ref[:]  # [dpp, 1]
-        active = jnp.logical_and(t < maxit_ref[:], done_ref[:] == 0.0)  # [1, B]
+        active = jnp.logical_and(t < maxit_ref[0], done_ref[0] == 0.0)  # [1, B]
         gmax = None
         for a_i in range(c):
             sl = slice(a_i * B, (a_i + 1) * B)
@@ -219,7 +237,7 @@ def _fused_step_kernel(
             gmax = gm if gmax is None else jnp.maximum(gmax, gm)
             wout_ref[0, :, sl] = jnp.where(active, Vb_ - step * G, W[:, sl])
             wpout_ref[0, :, sl] = jnp.where(active, W[:, sl], Wp[:, sl])
-        gmax_ref[:] = gmax
+        gmax_ref[0] = gmax
 
 
 @functools.partial(
@@ -237,7 +255,7 @@ def packed_nesterov_step(
     gradient scaling, the ``max|G|`` reduce, the done-masked writeback)
     with in-VMEM epilogues around the streamed softmax-Gram gradient.
     Per-iteration HBM traffic on the weight tensors drops from ~10 full
-    f32 passes to 4 (W/Wp read + W/Wp write, aliased in place).
+    f32 passes to 4 (W/Wp read + W/Wp write).
 
     Ab      [n_pad, dpp]     bf16  (n_pad % bm == 0; pad rows carry w == 0)
     W3      [n_wb, dpp, NB]  f32   NB == c*S*Tw, column = (a*S + s)*Tw + t
@@ -253,10 +271,7 @@ def packed_nesterov_step(
     lam     static float           L2 strength (0 disables the penalty)
 
     Returns ``(W_new, Wp_new, gmax)`` with shapes/dtypes of
-    ``(W3, Wp3, [n_wb, B] f32)``. ALIASING CAVEAT: ``W3`` and ``Wp3`` are
-    donated to the outputs (``input_output_aliases``) — inside the solver
-    scan XLA updates them in place; a caller holding the input arrays
-    must treat them as consumed after the call.
+    ``(W3, Wp3, [n_wb, B] f32)``.
     """
     n_pad, dpp = Ab.shape
     n_wb, _, NB = W3.shape
@@ -269,7 +284,9 @@ def packed_nesterov_step(
     kernel = functools.partial(
         _fused_step_kernel, c=c, S=S, Tw=Tw, lam=float(lam), n_tiles=n_tiles
     )
-    return pl.pallas_call(
+    col_spec = pl.BlockSpec((1, 1, B), lambda wb, i: (wb, 0, 0))
+    cols = [v.reshape(n_wb, 1, B) for v in (done, step_b, Cb, maxit_b)]
+    W_new, Wp_new, gmax = pl.pallas_call(
         kernel,
         grid=(n_wb, n_tiles),
         in_specs=[
@@ -279,25 +296,27 @@ def packed_nesterov_step(
             pl.BlockSpec((bm, 1), lambda wb, i: (i, 0)),
             pl.BlockSpec((bm, S), lambda wb, i: (i, 0)),
             pl.BlockSpec((1, 1), lambda wb, i: (0, 0), memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, B), lambda wb, i: (wb, 0)),
-            pl.BlockSpec((1, B), lambda wb, i: (wb, 0)),
-            pl.BlockSpec((1, B), lambda wb, i: (wb, 0)),
-            pl.BlockSpec((1, B), lambda wb, i: (wb, 0)),
+            col_spec,
+            col_spec,
+            col_spec,
+            col_spec,
             pl.BlockSpec((dpp, 1), lambda wb, i: (0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, dpp, NB), lambda wb, i: (wb, 0, 0)),
             pl.BlockSpec((1, dpp, NB), lambda wb, i: (wb, 0, 0)),
-            pl.BlockSpec((1, B), lambda wb, i: (wb, 0)),
+            col_spec,
         ],
         out_shape=[
             jax.ShapeDtypeStruct((n_wb, dpp, NB), jnp.float32),
             jax.ShapeDtypeStruct((n_wb, dpp, NB), jnp.float32),
-            jax.ShapeDtypeStruct((n_wb, B), jnp.float32),
+            jax.ShapeDtypeStruct((n_wb, 1, B), jnp.float32),
         ],
-        input_output_aliases={1: 0, 2: 1},
         interpret=interpret,
-    )(Ab, W3, Wp3, y2, WSP, t2, done, step_b, Cb, maxit_b, pen_col)
+        **({} if interpret else {"compiler_params": pltpu.CompilerParams(
+            vmem_limit_bytes=_FUSED_STEP_VMEM_LIMIT)}),
+    )(Ab, W3, Wp3, y2, WSP, t2, *cols, pen_col)
+    return W_new, Wp_new, gmax.reshape(n_wb, B)
 
 
 def packed_nesterov_step_reference(

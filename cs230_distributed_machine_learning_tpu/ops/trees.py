@@ -35,6 +35,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..utils import backend as _backend
+
 _EPS = 1e-12
 
 
@@ -98,18 +100,16 @@ def _resolve_hist_kernel(integer_stats: bool, ds, n_binss, kk: int) -> str:
     mode = _hist_kernel_mode()
     if mode != "auto":
         return mode
-    backend = jax.default_backend()
-    if backend == "tpu":
+    if _backend.on_cpu():
+        return "scatter"
+    if _backend.auto_pallas():
         from .pallas_hist import pallas_hist_applicable
 
         if integer_stats and all(
             pallas_hist_applicable(d, nb, kk) for d, nb in zip(ds, n_binss)
         ):
             return "pallas"
-        return "matmul"  # float stats keep the HIGHEST-precision contraction
-    if backend == "cpu":
-        return "scatter"
-    return "matmul"
+    return "matmul"  # float stats keep the HIGHEST-precision contraction
 
 
 def _level_histogram_multi(local, xbs, SC, n_nodes: int, n_binss,
@@ -154,7 +154,7 @@ def _level_histogram_multi(local, xbs, SC, n_nodes: int, n_binss,
     if kern == "pallas":
         from .pallas_hist import level_histogram_pallas
 
-        interp = jax.default_backend() != "tpu"
+        interp = _backend.pallas_interpret()
         return tuple(
             level_histogram_pallas(
                 local, xb, SC, n_nodes, nb,

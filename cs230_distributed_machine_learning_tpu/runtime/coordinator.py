@@ -2258,7 +2258,7 @@ class Coordinator:
         )
         if sid is None:
             return None
-        from ..utils.flops import device_peak_flops
+        from ..utils.backend import device_peak_flops
 
         progress = self.store.job_progress(sid, job_id)
         groups: List[Dict[str, Any]] = []

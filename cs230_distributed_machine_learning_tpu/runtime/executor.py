@@ -50,7 +50,7 @@ def record_hbm_gauges() -> None:
     executed batch and at /metrics/prom scrape time."""
     if not obs_enabled():
         return
-    from ..utils.flops import device_memory_stats
+    from ..utils.backend import device_memory_stats
 
     stats = device_memory_stats()
     for kind, key in (
@@ -86,7 +86,7 @@ class ResourceSampler:
         # max over CURRENT bytes_in_use samples: this fit's observed peak.
         # (peak_bytes_in_use is monotonic over the backend's lifetime — it
         # would report the largest batch ever, not this one)
-        from ..utils.flops import device_memory_stats
+        from ..utils.backend import device_memory_stats
 
         used = device_memory_stats().get("bytes_in_use")
         if used is not None:
@@ -562,6 +562,7 @@ class LocalExecutor:
             "dataset_id": dataset_id,
             "n_subtasks": batch_size,
             "n_devices": n_devices,
+            "n_result_devices": run.n_result_devices,
             "device_seconds": run.run_time_s,
             "model_flops": run.model_flops,
             "xla_flops": run.xla_flops,
@@ -851,8 +852,8 @@ def _is_device_fatal(e: BaseException) -> bool:
     # (tests/test_chaos_spmd.py pins this path). The broad network markers
     # ("heartbeat", "Connection reset by peer") only escalate under a
     # multi-process slice: on a single-process executor a transient
-    # network hiccup on a tunneled device whose message happens to contain
-    # them fails ONE batch, not the whole agent (ADVICE r5 #3). The
+    # network hiccup on a remote device whose message happens to contain
+    # them fails ONE batch, not the whole agent. The
     # collective-specific prefixes stay unconditional — a gloo/coordination
     # error cannot occur outside a collective runtime.
     if "JaxRuntimeError" in msg or "XlaRuntimeError" in msg:

@@ -798,7 +798,7 @@ def create_app(coordinator: Optional[Coordinator] = None):
         try:
             import jax
 
-            from ..utils.flops import device_memory_stats
+            from ..utils.backend import device_memory_stats
 
             devices = jax.local_devices()
             dev = {

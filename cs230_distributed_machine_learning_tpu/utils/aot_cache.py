@@ -20,9 +20,10 @@ shape/dtype signature, so bf16/int8-staged and packed/per-leaf executables
 never collide with their f32/dict counterparts), the lowering platform, the
 jax version, and a content fingerprint of this package's compute-path
 sources — a code change invalidates every blob, so a stale cache can never
-resurrect old kernel behavior. Any failure to export/serialize/deserialize
-falls back silently to the traced path (CS230_AOT_CACHE=0 disables the
-cache outright).
+resurrect old kernel behavior. A blob that cannot be read back, or a
+program ``jax.export`` refuses, takes the traced path with a logged
+warning; a program that cannot LOWER raises — that is the job's error, not
+a cache miss (CS230_AOT_CACHE=0 disables the cache outright).
 """
 
 from __future__ import annotations
@@ -31,6 +32,11 @@ import hashlib
 import os
 import threading
 from typing import Any, Optional, Sequence, Tuple
+
+from . import backend as _backend
+from .logging import get_logger
+
+logger = get_logger("tpuml.aot_cache")
 
 _FINGERPRINT: Optional[str] = None
 _LOCK = threading.Lock()
@@ -49,20 +55,15 @@ def cache_dir() -> str:
 
 
 def enabled() -> bool:
-    """On by default on accelerator backends; OFF on CPU. Executing a
-    deserialized CPU export has been observed to SIGSEGV in this
-    environment (same machine, same context — jaxlib CPU AOT path), and the
-    cache's payoff is the TPU fleet anyway (tests use per-test cache dirs,
-    so CPU deserialize was never a tested path). ``CS230_AOT_CACHE=force``
-    overrides; ``0`` disables everywhere."""
+    """On by default on accelerator backends; OFF on CPU, where tracing is
+    the cheap part and the cache's payoff (the TPU fleet) is absent.
+    ``CS230_AOT_CACHE=force`` overrides; ``0`` disables everywhere."""
     flag = os.environ.get("CS230_AOT_CACHE", "1")
     if flag == "0":
         return False
     if flag == "force":
         return True
-    import jax
-
-    return jax.default_backend() != "cpu"
+    return not _backend.on_cpu()
 
 
 def _code_fingerprint() -> str:
@@ -99,7 +100,7 @@ def _generation() -> str:
     import jax
 
     host = ""
-    if jax.default_backend() == "cpu":
+    if _backend.on_cpu():
         # only CPU-lowered exports embed host machine features; TPU blobs
         # are device code and MUST stay shared across a heterogeneous-CPU
         # fleet (the whole payoff of a shared storage root)
@@ -147,10 +148,7 @@ def _prune_stale_generations(root: str, keep: str) -> None:
 
 
 def _blob_path(key_parts: Sequence[Any]) -> str:
-    import jax
-
-    platform = jax.default_backend()
-    ident = repr(tuple(key_parts)) + platform
+    ident = repr(tuple(key_parts)) + _backend.name()
     digest = hashlib.sha256(ident.encode()).hexdigest()
     return os.path.join(cache_dir(), _generation(), f"{digest}.jaxexport")
 
@@ -200,27 +198,36 @@ def aot_jit(fn, key_parts: Sequence[Any], example_args: Tuple[Any, ...]):
                 exp = jex.deserialize(f.read())
             counter_inc("tpuml_aot_cache_hits_total")
             return jax.jit(exp.call), "aot"
-        except Exception:  # noqa: BLE001 — stale/corrupt blob: re-trace
+        except Exception as e:  # noqa: BLE001 — stale/corrupt blob: re-trace
+            logger.warning("AOT blob %s unreadable (%r); re-tracing", path, e)
             try:
                 os.remove(path)
             except OSError:
                 pass
     counter_inc("tpuml_aot_cache_misses_total")
 
+    # Pallas kernels lower to Mosaic custom calls, which jax.export flags
+    # as non-stable across versions; the generation directory already keys
+    # on jax version + code content, so replay of a same-generation blob is
+    # safe — disable the stability check.
+    checks = [
+        jex.DisabledSafetyCheck.custom_call("tpu_custom_call"),
+        jex.DisabledSafetyCheck.custom_call("Mosaic"),
+    ]
+    refusal = None
     try:
-        # Pallas kernels lower to Mosaic custom calls, which jax.export
-        # flags as non-stable across versions; the generation directory
-        # already keys on jax version + code content, so replay of a
-        # same-generation blob is safe — disable the stability check.
-        kwargs = {}
-        try:
-            kwargs["disabled_checks"] = [
-                jex.DisabledSafetyCheck.custom_call("tpu_custom_call"),
-                jex.DisabledSafetyCheck.custom_call("Mosaic"),
-            ]
-        except AttributeError:
-            pass
-        exp = jex.export(jax.jit(fn), **kwargs)(*example_args)
+        exp = jex.export(jax.jit(fn), disabled_checks=checks)(*example_args)
+    except Exception as e:  # noqa: BLE001 — told apart just below
+        refusal = e
+    if refusal is not None:
+        # export traces AND lowers: a program the compiler refuses (a bad
+        # Pallas block shape, say) must surface as the job's one error,
+        # not be retried as a plain jit that fails again elsewhere. Only
+        # when the plain lowering passes was the refusal export's own.
+        jax.jit(fn).lower(*example_args)
+        logger.warning("AOT export refused (%r); tracing instead", refusal)
+        return jax.jit(fn), "traced"
+    try:
         blob = exp.serialize()
         os.makedirs(os.path.dirname(path), exist_ok=True)
         _prune_stale_generations(cache_dir(), _generation())
@@ -228,7 +235,6 @@ def aot_jit(fn, key_parts: Sequence[Any], example_args: Tuple[Any, ...]):
         with open(tmp, "wb") as f:
             f.write(blob)
         os.replace(tmp, path)  # atomic: concurrent executors race safely
-        return jax.jit(exp.call), "traced"
-    except Exception:  # noqa: BLE001 — unexportable (e.g. exotic custom
-        # calls) or read-only fs: plain traced jit
-        return jax.jit(fn), "traced"
+    except OSError as e:  # read-only or full storage root: run uncached
+        logger.warning("AOT blob not written (%r)", e)
+    return jax.jit(exp.call), "traced"

@@ -21,7 +21,10 @@ _ENV_PREFIX = "TPUML_"
 @dataclasses.dataclass
 class StorageConfig:
     """Filesystem layout. Mirrors the reference's /mnt/efs shared-volume layout
-    (``aws-prod/master/config.py:11-12``) but defaults to a repo-local root."""
+    (``aws-prod/master/config.py:11-12``). The default root is ``~/.tpuml``
+    — outside the checkout; place it with ``TPUML_STORAGE__ROOT`` (datasets,
+    journals, models, the native build and the AOT export cache all live
+    under it; ``CS230_AOT_DIR`` moves the export cache alone)."""
 
     root: str = os.path.expanduser("~/.tpuml")
 

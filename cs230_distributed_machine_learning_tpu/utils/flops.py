@@ -17,49 +17,7 @@ from __future__ import annotations
 
 from typing import Any, Dict, Optional
 
-#: peak dense bf16 FLOP/s by device kind substring (per published specs)
-_PEAKS = (
-    ("v5e", 197e12),
-    ("v5 lite", 197e12),
-    ("v5litepod", 197e12),
-    ("v5p", 459e12),
-    ("v4", 275e12),
-    ("v6e", 918e12),
-    ("v6 lite", 918e12),
-    ("v3", 123e12),
-    ("v2", 45e12),
-)
-
-
-def device_peak_flops() -> Optional[float]:
-    """Peak bf16 FLOP/s of device 0, or None when unknown/CPU (MFU is not a
-    meaningful metric for host execution)."""
-    import jax
-
-    try:
-        dev = jax.devices()[0]
-    except Exception:  # noqa: BLE001
-        return None
-    if dev.platform == "cpu":
-        return None
-    kind = str(getattr(dev, "device_kind", "")).lower()
-    for sub, peak in _PEAKS:
-        if sub in kind:
-            return peak
-    return 197e12 if dev.platform == "tpu" else None
-
-
-def device_memory_stats() -> Dict[str, Any]:
-    """``memory_stats()`` of local device 0, ``{}`` when the backend
-    exposes none (CPU) or no device is reachable. The one shared reader
-    behind the HBM gauge, ``TrialRunResult.hbm_peak_bytes``, and
-    ``GET /healthz`` — key names and the device-0 policy live here only."""
-    import jax
-
-    try:
-        return dict(jax.local_devices()[0].memory_stats() or {})
-    except Exception:  # noqa: BLE001 — stats are best-effort everywhere
-        return {}
+from . import backend as _backend
 
 
 def analytical_flops(
@@ -99,7 +57,7 @@ def mfu(
     analytical FLOPs figure. ``n_devices`` scales the peak for work that
     ran across a mesh — whole-mesh FLOPs over a single chip's peak would
     report N x reality."""
-    peak = device_peak_flops()
+    peak = _backend.device_peak_flops()
     if flops is None or peak is None or wall_s <= 0:
         return None
     return flops / wall_s / (peak * max(int(n_devices), 1))

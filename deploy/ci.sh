@@ -43,9 +43,9 @@
 #
 #   perf mode (manually-triggered + nightly in ci.yml, like chaos): the
 #   valve A/B regression harness (benchmarks/perf_observatory.py) in
-#   quick mode with the noise-aware gate against the committed
-#   benchmarks/PERF_OBSERVATORY.json baselines — a perf valve silently
-#   regressing (legacy fallback, lost cache keying) fails the job —
+#   quick mode with the noise-aware gate against a
+#   benchmarks/PERF_OBSERVATORY.json baseline (none is committed since
+#   PR 21: the comparator skips until one is measured) —
 #   followed by an injected-regression drill (PERF_OBS_INJECT) proving
 #   the gate itself still trips. Fresh measurements always land in
 #   bench-artifacts/PERF_OBSERVATORY.json for upload.
@@ -53,8 +53,7 @@
 #   multichip mode: the elastic-trial-fabric gate (docs/ARCHITECTURE.md
 #   "Elastic trial fabric"). The mesh cache-parity + resharding suites
 #   plus the scaling harness at 1/2 forced host devices (quick reps, no
-#   >1.0x gate — the smoke proves the harness end to end; the committed
-#   benchmarks/MULTICHIP_BENCH_r01.json proves the scaling). The nightly
+#   >1.0x gate — the smoke proves the harness end to end). The nightly
 #   ci.yml job additionally runs the FULL 1/2/4/8 curve and uploads the
 #   fresh MULTICHIP_BENCH JSON for trend-watching.
 #
@@ -137,8 +136,6 @@
 # diagnosable from the span journal, the flight-recorder event journal,
 # and a Prometheus snapshot instead of rerun archaeology; ci.yml uploads
 # the directory as a workflow artifact.
-# Wall time of the fast suite on the dev box is recorded in
-# docs/STATUS.md; keep the two in sync when it moves.
 set -euo pipefail
 cd "$(dirname "$0")/.."
 

@@ -20,8 +20,14 @@ PY="${PYTHON:-python}"
 up() {
   local n_agents="${1:-2}"
   mkdir -p "$STATE"
-  echo "starting coordinator on :$PORT ..."
-  (cd "$REPO" && PYTHONPATH="$REPO" nohup "$PY" -m \
+  # A chip belongs to ONE process (the second to initialize the backend
+  # dies). Same policy as `tpuml-coordinator --agent-executors`
+  # (runtime/server.py): the coordinator and every agent but the first pin
+  # themselves to the host CPU; agent 1 inherits the platform and owns the
+  # chip. On a multi-chip host run one fleet per chip, or one agent with
+  # --distributed over all of them.
+  echo "starting coordinator on :$PORT (TPUML_PLATFORM=cpu) ..."
+  (cd "$REPO" && TPUML_PLATFORM=cpu PYTHONPATH="$REPO" nohup "$PY" -m \
       cs230_distributed_machine_learning_tpu.runtime.server \
       --host 127.0.0.1 --port "$PORT" --journal \
       > "$STATE/coordinator.log" 2>&1 & echo $! > "$STATE/coordinator.pid")
@@ -32,8 +38,10 @@ up() {
   curl -fsS "$URL/health" > /dev/null || {
     echo "coordinator failed to come up; see $STATE/coordinator.log"; exit 1; }
   for i in $(seq 1 "$n_agents"); do
-    echo "starting agent $i ..."
-    (cd "$REPO" && PYTHONPATH="$REPO" nohup "$PY" -m \
+    local plat="${TPUML_PLATFORM:-}"
+    [ "$i" -gt 1 ] && plat="cpu"
+    echo "starting agent $i (TPUML_PLATFORM=${plat:-inherited}) ..."
+    (cd "$REPO" && TPUML_PLATFORM="$plat" PYTHONPATH="$REPO" nohup "$PY" -m \
         cs230_distributed_machine_learning_tpu.runtime.agent --url "$URL" \
         > "$STATE/agent$i.log" 2>&1 & echo $! > "$STATE/agent$i.pid")
   done

@@ -5,6 +5,7 @@ so these entries never collide with TPU blobs."""
 import os
 from pathlib import Path
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -19,9 +20,8 @@ def _blobs(root):
 @pytest.fixture()
 def tmp_aot_dir(tmp_path, monkeypatch):
     monkeypatch.setenv("CS230_AOT_DIR", str(tmp_path))
-    # the cache defaults OFF on the CPU test backend (deserialized CPU
-    # executables are unreliable in some environments); force it on so the
-    # round-trip machinery itself stays covered
+    # the cache defaults OFF on the CPU backend (tracing is the cheap part
+    # there); force it on so the round-trip machinery itself stays covered
     monkeypatch.setenv("CS230_AOT_CACHE", "force")
     return tmp_path
 
@@ -68,6 +68,26 @@ def test_corrupt_blob_falls_back_and_heals(tmp_aot_dir):
     # re-written: next load hits
     _, src2 = aot_cache.aot_jit(_fn, key, _example())
     assert src2 == "aot"
+
+
+def test_lowering_error_is_the_one_error(tmp_aot_dir):
+    """A program the compiler refuses must raise out of aot_jit with the
+    compiler's own message — not come back as a plain jit that fails again,
+    elsewhere, on first call (a bad Pallas block shape ended a chip job
+    with two unrelated tracebacks)."""
+    from jax.experimental import pallas as pl
+
+    def refused(x):  # a compiled Pallas call cannot lower on the CPU backend
+        def body(x_ref, o_ref):
+            o_ref[:] = x_ref[:] * 2.0
+
+        return pl.pallas_call(
+            body, out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype)
+        )(x)
+
+    with pytest.raises(ValueError, match="interpret mode"):
+        aot_cache.aot_jit(refused, ("refused",), (jnp.ones((8, 128)),))
+    assert len(_blobs(tmp_aot_dir)) == 0
 
 
 def test_disabled_by_env(tmp_aot_dir, monkeypatch):

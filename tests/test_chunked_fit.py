@@ -169,7 +169,7 @@ def test_split_axis_chunking_matches(monkeypatch):
     static["_n_classes"] = 2
     per = max(kernel.memory_estimate_mb(600, 8, static), 0.5)
     # budget = 0.5 * device_mb = 3 * per -> splits run in groups of 3 (6 total)
-    monkeypatch.setattr(trial_map, "_device_memory_mb", lambda: 6.0 * per)
+    monkeypatch.setattr(trial_map._backend, "device_memory_mb", lambda: 6.0 * per)
     trial_map._compiled_cache.clear()
     grouped = trial_map.run_trials(kernel, data, plan, params)
 

@@ -118,9 +118,9 @@ def test_mfu_populates_when_device_peak_is_known(monkeypatch):
     """On accelerators (device_peak_flops known) MFU must come out a real
     fraction — simulated here by pinning the peak-rate lookup, since the
     tier-1 box is CPU-only."""
-    from cs230_distributed_machine_learning_tpu.utils import flops as flops_mod
+    from cs230_distributed_machine_learning_tpu.utils import backend as backend_mod
 
-    monkeypatch.setattr(flops_mod, "device_peak_flops", lambda: 1e12)
+    monkeypatch.setattr(backend_mod, "device_peak_flops", lambda: 1e12)
     from cs230_distributed_machine_learning_tpu.runtime.executor import (
         LocalExecutor,
     )
@@ -144,7 +144,7 @@ def test_job_cost_mfu_populates_with_known_peak(monkeypatch):
     from cs230_distributed_machine_learning_tpu.runtime.coordinator import (
         Coordinator,
     )
-    from cs230_distributed_machine_learning_tpu.utils import flops as flops_mod
+    from cs230_distributed_machine_learning_tpu.utils import backend as backend_mod
 
     coord = Coordinator()
     sid = coord.create_session()
@@ -174,7 +174,7 @@ def test_job_cost_mfu_populates_with_known_peak(monkeypatch):
                                    r.get("status", "completed"), r)
     report_cpu = coord.job_cost("jc")
     assert report_cpu["mfu"] is None  # CPU: no peak rate
-    monkeypatch.setattr(flops_mod, "device_peak_flops", lambda: 1e12)
+    monkeypatch.setattr(backend_mod, "device_peak_flops", lambda: 1e12)
     report = coord.job_cost("jc")
     assert report["n_groups"] == 1
     assert report["mfu"] == pytest.approx(

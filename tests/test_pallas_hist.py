@@ -107,6 +107,7 @@ def test_hist_kernel_valve_routes_and_agrees(monkeypatch):
     xb = jnp.asarray(rng.randint(0, nb, (n, d)).astype(np.int32))
     SC = jnp.asarray(rng.randint(0, 3, (n, kk)).astype(np.float32))
     outs = {}
+    monkeypatch.setenv("CS230_PALLAS_INTERPRET", "1")
     for mode in ("matmul", "scatter", "pallas"):
         monkeypatch.setenv("CS230_HIST_KERNEL", mode)
         outs[mode] = np.asarray(
@@ -129,6 +130,7 @@ def test_hist_kernel_valve_full_tree_fit(monkeypatch):
     S = jnp.asarray(np.eye(k, dtype=np.float32)[y])
     C = jnp.asarray((rng.rand(n) > 0.2).astype(np.float32))
     trees = {}
+    monkeypatch.setenv("CS230_PALLAS_INTERPRET", "1")
     for mode in ("matmul", "scatter", "pallas"):
         monkeypatch.setenv("CS230_HIST_KERNEL", mode)
         jax.clear_caches()
@@ -150,5 +152,9 @@ def test_pallas_hist_applicability_gate():
     """The static shape gate keeps ineligible shapes off the kernel (the
     auto route must fall back rather than blow the VMEM budget)."""
     assert pallas_hist_applicable(54, 24, 8)  # covertype production shape
+    assert pallas_hist_applicable(54, 32, 7)
+    # covertype at 64 bins: 18.4 MB against the 16 MB scoped-VMEM limit,
+    # refused by the v5e compiler (tests/test_tpu_compile.py compiles it)
+    assert not pallas_hist_applicable(54, 64, 7)
     assert not pallas_hist_applicable(784, 64, 8)  # MNIST-wide: page too big
     assert not pallas_hist_applicable(10, 512, 8)  # bins over the lane cap

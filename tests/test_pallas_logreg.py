@@ -147,6 +147,10 @@ def test_fit_fused_masked_grad_matches_legacy(monkeypatch):
         "tol": jnp.float32(1e-5),
     }
 
+    # a forced Pallas kernel compiles; on the CPU backend only the
+    # interpreter valve makes it runnable
+    monkeypatch.setenv("CS230_PALLAS_INTERPRET", "1")
+
     def fit(mode, method):
         monkeypatch.setenv("CS230_MASKED_GRAD", mode)
         static = kernel.resolve_static(
@@ -246,11 +250,11 @@ def test_fused_step_freezes_done_and_past_max_iter_columns():
     assert np.abs(W_new[:, :, ~frozen_nb] - np.asarray(W)[:, :, ~frozen_nb]).max() > 0
 
 
-def test_fused_step_aliasing_is_invisible_at_the_api_boundary():
-    """The W/Wp buffers are aliased in place INSIDE the executable
-    (input_output_aliases); at the jit boundary the caller's arrays must
-    stay valid and un-mutated — two identical calls give identical
-    results and the inputs keep their original values."""
+def test_fused_step_leaves_its_inputs_untouched():
+    """W_new/Wp_new are fresh buffers (no input_output_aliases: the
+    aliased form was wrong compiled on a v5e); the caller's arrays stay
+    valid and un-mutated — two identical calls give identical results and
+    the inputs keep their original values."""
     c, S = 2, 2
     Ab, W, Wp, y2, WSP, done, step, Cb, maxit, pen, Tw = _fused_step_inputs(c, S)
     W0 = np.asarray(W).copy()

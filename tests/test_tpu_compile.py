@@ -1,0 +1,270 @@
+"""TPU compile gate, run on the CPU: every Pallas entry point an ``auto``
+valve can select on a TPU backend is lowered for ``platforms=("tpu",)`` at
+its default geometry and, where libtpu can describe a v5e topology with no
+chip attached, compiled by the real TPU compiler (Mosaic included).
+
+Interpret-mode parity tests cannot see what this file sees: block shapes
+the TPU lowering refuses, kernels over the scoped-VMEM limit, Mosaic calls
+under mesh shardings. A deviceless compile says the compiler accepts the
+program — not that it runs, is correct, or fits HBM; ``chip_smoke.py``
+covers that on the chip.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, SingleDeviceSharding
+
+from cs230_distributed_machine_learning_tpu.models.registry import get_kernel
+
+#: the flagship's geometry: covertype, cv=5 -> 6 splits
+N, D, C, S = 116_202, 54, 7, 6
+
+
+@functools.lru_cache(maxsize=1)
+def _v5e_devices():
+    """Four deviceless ``TPU v5 lite`` devices, or None with the reason."""
+    try:
+        from jax.experimental import topologies
+
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+        return tuple(topo.devices), None
+    except Exception as e:  # noqa: BLE001 — no libtpu: lowering-only run
+        return None, f"no deviceless TPU topology here: {e!r}"
+
+
+@pytest.fixture
+def tpu_backend(monkeypatch):
+    """Make the package's ``auto`` valves decide as they do on a TPU
+    backend (they ask ``jax.default_backend()`` through utils/backend)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.delenv("CS230_PALLAS_INTERPRET", raising=False)
+    for valve in ("CS230_FUSED_STEP", "CS230_MASKED_GRAD", "CS230_HIST_KERNEL"):
+        monkeypatch.delenv(valve, raising=False)
+
+
+def _sds(shape, dtype):
+    return jax.ShapeDtypeStruct(tuple(shape), dtype)
+
+
+def _lower_and_compile(fn, *args):
+    """Compile ``fn`` for one v5e device where a deviceless topology is
+    available (which lowers it on the way); lower it for the TPU platform
+    where not."""
+    devices, _ = _v5e_devices()
+    if devices is None:
+        jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",))
+        return None
+    sh = SingleDeviceSharding(devices[0])
+    placed = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh), args
+    )
+    return jax.jit(fn).lower(*placed).compile()
+
+
+def _logreg_static():
+    kernel = get_kernel("LogisticRegression")
+    static = kernel.resolve_static(
+        {"fit_intercept": True, "penalty": "l2"}, N, D, C
+    )
+    static["_n_classes"] = C
+    return kernel, kernel.bucket_static(static, [{"max_iter": 200}])
+
+
+def _trial_args(n, d, s, chunk, hyper_names):
+    return (
+        _sds((n, d), jnp.float32), _sds((n,), jnp.int32),
+        _sds((s, n), jnp.float32), _sds((s, n), jnp.float32),
+        {h: _sds((chunk,), jnp.float32) for h in hyper_names},
+    )
+
+
+@pytest.mark.parametrize("n,chunk", [(N, 128), (N, 1024), (12_000, 1024)])
+def test_flagship_packed_fit(tpu_backend, n, chunk):
+    """bench.py's search: 1000 trials -> one 1024-trial chunk (n_wb=8);
+    the REST slice and every test shape use 128 (n_wb=1). At small n XLA
+    parks the whole design matrix in VMEM next to the fused step, which
+    needs the call's raised scoped-VMEM limit to compile."""
+    kernel, static = _logreg_static()
+    assert kernel.batched_applicable(static, n, D)
+    fn = kernel.build_batched_fn(static, n, D, C, S, chunk)
+    _lower_and_compile(
+        fn, *_trial_args(n, D, S, chunk, ("C", "max_iter", "tol"))
+    )
+
+
+def test_fused_step_kernel(tpu_backend):
+    from cs230_distributed_machine_learning_tpu.ops.pallas_logreg import (
+        packed_nesterov_step,
+    )
+
+    n_wb, dpp, n_pad, Tw = 8, 64, 2048, 128
+    B = S * Tw
+    W = _sds((n_wb, dpp, C * B), jnp.float32)
+    col = _sds((n_wb, B), jnp.float32)
+    _lower_and_compile(
+        functools.partial(packed_nesterov_step, c=C, S=S, Tw=Tw, lam=1.0),
+        _sds((n_pad, dpp), jnp.bfloat16), W, W, _sds((n_pad, 1), jnp.int32),
+        _sds((n_pad, S), jnp.float32), _sds((), jnp.float32),
+        col, col, col, col, _sds((dpp, 1), jnp.float32),
+    )
+
+
+@pytest.mark.parametrize("vmapped", [False, True])
+def test_masked_softmax_grad(tpu_backend, vmapped):
+    """The generic LogReg fit's lane kernel, alone and under the engine's
+    trials x splits vmap (which adds grid dimensions)."""
+    from cs230_distributed_machine_learning_tpu.ops.pallas_logreg import (
+        masked_softmax_grad,
+    )
+
+    n_pad, dpp, cp = 116_224, 128, 128
+    Ab = _sds((n_pad, dpp), jnp.bfloat16)
+    y2 = _sds((n_pad, 1), jnp.int32)
+    one = functools.partial(masked_softmax_grad, c=C)
+    if not vmapped:
+        _lower_and_compile(
+            one, Ab, _sds((dpp, cp), jnp.bfloat16), y2,
+            _sds((n_pad, 1), jnp.float32),
+        )
+        return
+    over_splits = jax.vmap(one, in_axes=(None, 0, None, 0))
+    over_trials = jax.vmap(over_splits, in_axes=(None, 0, None, None))
+    _lower_and_compile(
+        over_trials, Ab, _sds((4, S, dpp, cp), jnp.bfloat16), y2,
+        _sds((S, n_pad, 1), jnp.float32),
+    )
+
+
+@pytest.mark.parametrize("n_nodes", [8, 512])
+@pytest.mark.parametrize("n_bins", [17, 32, 64])
+def test_hist_gate_admits_only_what_compiles(tpu_backend, n_bins, n_nodes):
+    """Covertype forests, 7 integer stat columns: whatever shape the
+    ``auto`` route's gate admits must get through the compiler. 64 bins
+    needs 18.4 MB of scoped VMEM at a 64-node block against a 16 MB limit."""
+    from cs230_distributed_machine_learning_tpu.ops.pallas_hist import (
+        level_histogram_pallas, pallas_hist_applicable,
+    )
+    from cs230_distributed_machine_learning_tpu.ops.trees import (
+        _resolve_hist_kernel,
+    )
+
+    kk, n = 7, 20_000
+    routed = _resolve_hist_kernel(True, (D,), (n_bins,), kk)
+    assert (routed == "pallas") == pallas_hist_applicable(D, n_bins, kk)
+    if routed != "pallas":
+        return
+
+    def hist(local, xb, SC):
+        return level_histogram_pallas(
+            local, xb, SC, n_nodes, n_bins, integer_stats=True
+        )
+
+    _lower_and_compile(
+        hist, _sds((n,), jnp.int32), _sds((n, D), jnp.int32),
+        _sds((n, kk), jnp.float32),
+    )
+
+
+def test_knn_topk(tpu_backend):
+    from cs230_distributed_machine_learning_tpu.models.knn import _use_pallas
+    from cs230_distributed_machine_learning_tpu.ops.pallas_knn import knn_topk
+
+    n = 160_000
+    assert _use_pallas(n)
+    _lower_and_compile(
+        functools.partial(knn_topk, k=5),
+        _sds((1024, D), jnp.float32), _sds((n, D), jnp.float32),
+        _sds((n,), jnp.float32),
+    )
+
+
+def test_mlp_epoch_kernel(tpu_backend):
+    """The fused MLP path at MNIST width (784-256-10), through the
+    kernel's own builder so lane packing and the VMEM limit are the
+    production ones."""
+    kernel = get_kernel("MLPClassifier")
+    n, d, c, chunk = 60_000, 784, 10, 4
+    _, hyper = kernel.canonicalize({"hidden_layer_sizes": (256,)})
+    static = kernel.resolve_static(
+        {**kernel.static_defaults, "hidden_layer_sizes": (256,),
+         "max_iter": 2}, n, d, c,
+    )
+    static["_n_classes"] = c
+    assert kernel.batched_applicable(static, n, d)
+    fn = kernel.build_batched_fn(static, n, d, c, S, chunk)
+    _lower_and_compile(fn, *_trial_args(n, d, S, chunk, sorted(hyper)))
+
+
+@pytest.mark.parametrize("data_parallel", [1, 2])
+def test_sharded_generic_logreg_four_devices(tpu_backend, data_parallel):
+    """The mesh executable of the flagship search (bench.py on a four-chip
+    host, chip_smoke.py's mesh leg): ``jit`` with mesh shardings cannot
+    partition a Mosaic kernel, so the engine must trace it on the XLA
+    formulations — on the 1-D trial mesh and the 2-D (trials, data) mesh."""
+    devices, why = _v5e_devices()
+    if devices is None:
+        pytest.skip(why)
+    from cs230_distributed_machine_learning_tpu.models.base import TrialData
+    from cs230_distributed_machine_learning_tpu.parallel import trial_map
+
+    shape = (4 // data_parallel, data_parallel)
+    names = ("trials", "data")
+    if data_parallel == 1:
+        shape, names = shape[:1], names[:1]
+    mesh = Mesh(np.array(devices).reshape(shape), names)
+    kernel, static = _logreg_static()
+    n = 116_224 if data_parallel > 1 else N  # rows divisible by the data axis
+    data = TrialData(
+        X=np.zeros((n, D), np.float32), y=np.zeros((n,), np.int32),
+        n_classes=C,
+    )
+
+    class Plan:
+        n_splits = S
+
+    chunk = 64
+    args = _trial_args(n, D, S, chunk, ("C", "max_iter", "tol"))
+    fn, _, _, fresh = trial_map._get_compiled(
+        kernel, ("tpu-compile-test", data_parallel), static, mesh, "trials",
+        data, Plan, chunk, ["C", "max_iter", "tol"], args[0],
+    )
+    assert fresh
+    compiled = fn.lower(*args).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" not in hlo  # no Mosaic kernel under the mesh
+    if data_parallel == 1:
+        # the trial axis needs no communication
+        assert "all-reduce" not in hlo and "all-gather" not in hlo
+
+
+def test_host_fast_path_traces_cpu_formulations(tpu_backend):
+    """A bucket under the host-exec MAC line runs on the host CPU of an
+    accelerator process. Its program must be traced for the CPU: on the
+    chip a tiny forest traced the Pallas histogram (the default backend
+    said "tpu") and died in the CPU lowering."""
+    from cs230_distributed_machine_learning_tpu.models.base import TrialData
+    from cs230_distributed_machine_learning_tpu.ops.folds import (
+        build_split_plan,
+    )
+    from cs230_distributed_machine_learning_tpu.parallel import trial_map
+
+    rng = np.random.RandomState(1)
+    X = rng.randn(96, 6).astype(np.float32)
+    y = (X[:, 0] + 0.3 * rng.randn(96) > 0).astype(np.int32)
+    data = TrialData(X=X, y=y, n_classes=2)
+    plan = build_split_plan(y, task="classification", n_folds=3)
+    trial_map._compiled_cache.clear()
+    out = trial_map.run_trials(
+        get_kernel("RandomForestClassifier"), data, plan,
+        [{"n_estimators": 8, "max_depth": 3, "random_state": 0}],
+    )
+    assert len(out.trial_metrics) == 1
+    assert np.isfinite(out.trial_metrics[0]["mean_cv_score"])
+    kinds = {k[0] for k in trial_map._compiled_cache if isinstance(k, tuple)}
+    assert "host" in kinds  # it did take the host fast path

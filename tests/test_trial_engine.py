@@ -101,7 +101,7 @@ def test_generic_split_group_chunking_matches_monolithic(monkeypatch):
     base = run_trials(kernel, data, plan, params)
 
     # tiny budget: per-split estimate x 6 splits >> budget -> fold groups
-    monkeypatch.setattr(trial_map, "_device_memory_mb", lambda: 4.0 * max(
+    monkeypatch.setattr(trial_map._backend, "device_memory_mb", lambda: 4.0 * max(
         kernel.memory_estimate_mb(len(data.X), data.X.shape[1], {"_n_classes": 3}),
         0.5))
     trial_map._compiled_cache.clear()
