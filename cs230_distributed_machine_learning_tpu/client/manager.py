@@ -186,13 +186,27 @@ class MLTaskManager:
         self.trace_id = new_trace_id()
         if self._coordinator is not None:
             # local mode: the job trace starts here — activate the id so
-            # submit_train (same process) adopts it, bracketed by a
-            # client-side span
-            with activate(self.trace_id):
-                with span("client.train", trace_id=self.trace_id,
-                          job_id=self.job_id, dataset_id=dataset_id):
+            # submit_train (same process) adopts it. ``client.train`` covers
+            # the whole call, what the user waits for: the submit and the
+            # wait through the terminal status and the result read
+            with activate(self.trace_id), span(
+                "client.train", trace_id=self.trace_id,
+                job_id=self.job_id, dataset_id=dataset_id,
+            ):
+                with span("client.submit", job_id=self.job_id):
                     submit = self._coordinator.submit_train(
                         self.session_id, payload
+                    )
+                self.job_id = submit.get("job_id") or self.job_id
+                if not wait_for_completion:
+                    return submit
+                with span("client.wait", job_id=self.job_id):
+                    if stream:
+                        return self._stream_local(
+                            timeout=timeout, show_progress=show_progress
+                        )
+                    return self._wait_for_completion(
+                        timeout=timeout, show_progress=show_progress
                     )
         else:
             scoring = (model_details.get("cv_params") or {}).get("scoring")
@@ -226,8 +240,6 @@ class MLTaskManager:
         self.job_id = submit.get("job_id") or self.job_id
         if not wait_for_completion:
             return submit
-        if stream and self._coordinator is not None:
-            return self._stream_local(timeout=timeout, show_progress=show_progress)
         return self._wait_for_completion(timeout=timeout, show_progress=show_progress)
 
     def _wait_for_completion(

@@ -382,143 +382,146 @@ class LogisticRegressionKernel(ModelKernel):
         pen_row_j = jnp.asarray(pen_row)
 
         def fn(X, y, TW, EW, hyper):
-            A = None
-            if "_logreg_ab" not in hyper or "_logreg_lam_max" not in hyper:
-                A = add_intercept(X, fit_intercept)  # [n, dp] f32
-                A = jnp.pad(A, ((0, n_pad - n), (0, dpp - dp)))
-            Ab = (
-                hyper["_logreg_ab"]
-                if "_logreg_ab" in hyper
-                else A.astype(jnp.bfloat16)
-            )
-            y_pad = jnp.pad(y.astype(jnp.int32), (0, n_pad - n))
-            y2 = y_pad[:, None]
-            TWp = jnp.pad(TW.astype(jnp.float32), ((0, 0), (0, n_pad - n)))
-            EWp = jnp.pad(EW.astype(jnp.float32), ((0, 0), (0, n_pad - n)))
-            WSP = TWp.T  # [n_pad, S]
+            with jax.named_scope("tpuml.fit"):
+                A = None
+                if "_logreg_ab" not in hyper or "_logreg_lam_max" not in hyper:
+                    A = add_intercept(X, fit_intercept)  # [n, dp] f32
+                    A = jnp.pad(A, ((0, n_pad - n), (0, dpp - dp)))
+                Ab = (
+                    hyper["_logreg_ab"]
+                    if "_logreg_ab" in hyper
+                    else A.astype(jnp.bfloat16)
+                )
+                y_pad = jnp.pad(y.astype(jnp.int32), (0, n_pad - n))
+                y2 = y_pad[:, None]
+                TWp = jnp.pad(TW.astype(jnp.float32), ((0, 0), (0, n_pad - n)))
+                EWp = jnp.pad(EW.astype(jnp.float32), ((0, 0), (0, n_pad - n)))
+                WSP = TWp.T  # [n_pad, S]
 
-            Cb = jnp.take(hyper["C"], trial_map_j)  # [n_wb, Bblk]
-            maxit_b = jnp.take(hyper["max_iter"], trial_map_j)
-            tol_b = jnp.take(hyper["tol"], trial_map_j)
+                Cb = jnp.take(hyper["C"], trial_map_j)  # [n_wb, Bblk]
+                maxit_b = jnp.take(hyper["max_iter"], trial_map_j)
+                tol_b = jnp.take(hyper["tol"], trial_map_j)
 
-            # Lipschitz bound per split: L <= 0.5*C*lam_max(A' diag(w) A)
-            # + lam — precomputed once per (dataset, folds) and staged by
-            # batched_staged_extras when available, else inline
-            lam_max_s = (
-                hyper["_logreg_lam_max"]
-                if "_logreg_lam_max" in hyper
-                else _packed_lam_max(A, TWp)
-            )  # [S]
-            lam_s = lam_max_s[split_of_j]  # [Bblk]
-            step_b = 1.0 / (0.5 * Cb * lam_s[None, :] + lam + 1e-6)
+                # Lipschitz bound per split: L <= 0.5*C*lam_max(A' diag(w) A)
+                # + lam — precomputed once per (dataset, folds) and staged by
+                # batched_staged_extras when available, else inline
+                lam_max_s = (
+                    hyper["_logreg_lam_max"]
+                    if "_logreg_lam_max" in hyper
+                    else _packed_lam_max(A, TWp)
+                )  # [S]
+                lam_s = lam_max_s[split_of_j]  # [Bblk]
+                step_b = 1.0 / (0.5 * Cb * lam_s[None, :] + lam + 1e-6)
 
-            W0 = jnp.zeros((n_wb, dpp, NB), jnp.float32)
-            done0 = jnp.zeros((n_wb, Bblk), bool)
+                W0 = jnp.zeros((n_wb, dpp, NB), jnp.float32)
+                done0 = jnp.zeros((n_wb, Bblk), bool)
 
-            # fixed-length scan (length already capped to the bucket's max
-            # max_iter by bucket_static's _iters). A while_loop with an
-            # all-converged early exit measures ~20% SLOWER here: the
-            # per-step cond reduce acts as a barrier, and slow-converging
-            # trials run to max_iter anyway.
-            tr0 = (
-                jnp.zeros((tr_used, n_wb, Bblk), jnp.float32)
-                if capture else None
-            )
+                # fixed-length scan (length already capped to the bucket's max
+                # max_iter by bucket_static's _iters). A while_loop with an
+                # all-converged early exit measures ~20% SLOWER here: the
+                # per-step cond reduce acts as a barrier, and slow-converging
+                # trials run to max_iter anyway.
+                tr0 = (
+                    jnp.zeros((tr_used, n_wb, Bblk), jnp.float32)
+                    if capture else None
+                )
 
-            if use_fused:
-                pen_col = pen_row_j[0]  # [dpp, 1]
+                if use_fused:
+                    pen_col = pen_row_j[0]  # [dpp, 1]
 
-                def body(carry, t):
-                    W, Wp, done, tr = carry
-                    W, Wp, gmax = packed_nesterov_step(
-                        Ab, W, Wp, y2, WSP, t, done.astype(jnp.float32),
-                        step_b, Cb, maxit_b, pen_col,
-                        c=c, S=S, Tw=Tw, bm=bm, lam=lam,
-                        interpret=interpret,
+                    def body(carry, t):
+                        W, Wp, done, tr = carry
+                        W, Wp, gmax = packed_nesterov_step(
+                            Ab, W, Wp, y2, WSP, t, done.astype(jnp.float32),
+                            step_b, Cb, maxit_b, pen_col,
+                            c=c, S=S, Tw=Tw, bm=bm, lam=lam,
+                            interpret=interpret,
+                        )
+                        done = jnp.logical_or(done, gmax < tol_b)
+                        if capture:
+                            tr = tr.at[jnp.asarray(t, jnp.int32) // tr_stride].set(gmax)
+                        return (W, Wp, done, tr), None
+
+                else:
+                    step_full = jnp.tile(step_b, (1, c))[:, None, :]  # [n_wb,1,NB]
+                    Cb_full = jnp.tile(Cb, (1, c))[:, None, :]
+
+                    def body(carry, t):  # legacy scan body — parity reference
+                        W, Wp, done, tr = carry
+                        mom = t / (t + 3.0)
+                        V = W + mom * (W - Wp)
+                        Graw = packed_softmax_grad(
+                            Ab, V.astype(jnp.bfloat16), y2, WSP,
+                            c=c, S=S, Tw=Tw, bm=bm, interpret=interpret,
+                        )
+                        G = Cb_full * Graw + lam * pen_row_j * V
+                        gmax = jnp.max(
+                            jnp.abs(G).reshape(n_wb, dpp, c, Bblk), axis=(1, 2)
+                        )  # [n_wb, Bblk]
+                        active = jnp.logical_and(
+                            t < maxit_b, jnp.logical_not(done)
+                        )
+                        act = jnp.tile(active, (1, c))[:, None, :]
+                        W_new = jnp.where(act, V - step_full * G, W)
+                        Wp_new = jnp.where(act, W, Wp)
+                        done = jnp.logical_or(done, gmax < tol_b)
+                        if capture:
+                            tr = tr.at[jnp.asarray(t, jnp.int32) // tr_stride].set(gmax)
+                        return (W_new, Wp_new, done, tr), None
+
+                (W, _, _, tr_out), _ = jax.lax.scan(
+                    body, (W0, W0, done0, tr0), jnp.arange(steps, dtype=jnp.float32)
+                )
+
+            with jax.named_scope("tpuml.eval"):
+                # ---- eval: streamed row chunks, argmax over the class axis ----
+                # (f32: eval runs once per dispatch, and argmax ties near fold
+                # boundaries are where bf16 noise could flip best_params_)
+                def eval_body(acc, start):
+                    a = jax.lax.dynamic_slice(Ab, (start, 0), (rc, dpp)).astype(
+                        jnp.float32
                     )
-                    done = jnp.logical_or(done, gmax < tol_b)
-                    if capture:
-                        tr = tr.at[jnp.asarray(t, jnp.int32) // tr_stride].set(gmax)
-                    return (W, Wp, done, tr), None
-
-            else:
-                step_full = jnp.tile(step_b, (1, c))[:, None, :]  # [n_wb,1,NB]
-                Cb_full = jnp.tile(Cb, (1, c))[:, None, :]
-
-                def body(carry, t):  # legacy scan body — parity reference
-                    W, Wp, done, tr = carry
-                    mom = t / (t + 3.0)
-                    V = W + mom * (W - Wp)
-                    Graw = packed_softmax_grad(
-                        Ab, V.astype(jnp.bfloat16), y2, WSP,
-                        c=c, S=S, Tw=Tw, bm=bm, interpret=interpret,
+                    logits = jnp.einsum(
+                        "rd,wdn->wrn", a, W, preferred_element_type=jnp.float32
                     )
-                    G = Cb_full * Graw + lam * pen_row_j * V
-                    gmax = jnp.max(
-                        jnp.abs(G).reshape(n_wb, dpp, c, Bblk), axis=(1, 2)
-                    )  # [n_wb, Bblk]
-                    active = jnp.logical_and(
-                        t < maxit_b, jnp.logical_not(done)
+                    pred = jnp.argmax(logits.reshape(n_wb, rc, c, Bblk), axis=2)
+                    yc = jax.lax.dynamic_slice(y_pad, (start,), (rc,))
+                    # slice the [S, n_pad] fold weights first, then expand to
+                    # trials: keeps the loop-invariant at [S, n_pad] instead of
+                    # materializing a [Bblk, n_pad] gather (~S*Tw/S x larger —
+                    # ~1.8 GB on the Covertype north-star config)
+                    wev = jax.lax.dynamic_slice(
+                        EWp, (0, start), (S, rc)
+                    )[split_of_j].T  # [rc, Bblk]
+                    hit = (pred == yc[None, :, None]).astype(jnp.float32)
+                    acc = acc + jnp.sum(hit * wev[None], axis=1)
+                    return acc, None
+
+                acc0 = jnp.zeros((n_wb, Bblk), jnp.float32)
+                acc, _ = jax.lax.scan(
+                    eval_body, acc0, jnp.arange(0, n_pad, rc, dtype=jnp.int32)
+                )
+                den = jnp.maximum(jnp.sum(EW.astype(jnp.float32), axis=1), 1e-12)  # [S]
+                score_b = acc / den[split_of_j][None, :]
+            with jax.named_scope("tpuml.pack"):
+                score = score_b.reshape(n_wb, S, Tw).transpose(0, 2, 1).reshape(chunk, S)
+                out = {"score": score}
+                if capture:
+                    # same lane->(trial, split) mapping as score, with the
+                    # trace-slot axis carried along as a trailing dim
+                    curve = (
+                        tr_out.transpose(1, 2, 0)
+                        .reshape(n_wb, S, Tw, tr_used)
+                        .transpose(0, 2, 1, 3)
+                        .reshape(chunk, S, tr_used)
                     )
-                    act = jnp.tile(active, (1, c))[:, None, :]
-                    W_new = jnp.where(act, V - step_full * G, W)
-                    Wp_new = jnp.where(act, W, Wp)
-                    done = jnp.logical_or(done, gmax < tol_b)
-                    if capture:
-                        tr = tr.at[jnp.asarray(t, jnp.int32) // tr_stride].set(gmax)
-                    return (W_new, Wp_new, done, tr), None
-
-            (W, _, _, tr_out), _ = jax.lax.scan(
-                body, (W0, W0, done0, tr0), jnp.arange(steps, dtype=jnp.float32)
-            )
-
-            # ---- eval: streamed row chunks, argmax over the class axis ----
-            # (f32: eval runs once per dispatch, and argmax ties near fold
-            # boundaries are where bf16 noise could flip best_params_)
-            def eval_body(acc, start):
-                a = jax.lax.dynamic_slice(Ab, (start, 0), (rc, dpp)).astype(
-                    jnp.float32
-                )
-                logits = jnp.einsum(
-                    "rd,wdn->wrn", a, W, preferred_element_type=jnp.float32
-                )
-                pred = jnp.argmax(logits.reshape(n_wb, rc, c, Bblk), axis=2)
-                yc = jax.lax.dynamic_slice(y_pad, (start,), (rc,))
-                # slice the [S, n_pad] fold weights first, then expand to
-                # trials: keeps the loop-invariant at [S, n_pad] instead of
-                # materializing a [Bblk, n_pad] gather (~S*Tw/S x larger —
-                # ~1.8 GB on the Covertype north-star config)
-                wev = jax.lax.dynamic_slice(
-                    EWp, (0, start), (S, rc)
-                )[split_of_j].T  # [rc, Bblk]
-                hit = (pred == yc[None, :, None]).astype(jnp.float32)
-                acc = acc + jnp.sum(hit * wev[None], axis=1)
-                return acc, None
-
-            acc0 = jnp.zeros((n_wb, Bblk), jnp.float32)
-            acc, _ = jax.lax.scan(
-                eval_body, acc0, jnp.arange(0, n_pad, rc, dtype=jnp.int32)
-            )
-            den = jnp.maximum(jnp.sum(EW.astype(jnp.float32), axis=1), 1e-12)  # [S]
-            score_b = acc / den[split_of_j][None, :]
-            score = score_b.reshape(n_wb, S, Tw).transpose(0, 2, 1).reshape(chunk, S)
-            out = {"score": score}
-            if capture:
-                # same lane->(trial, split) mapping as score, with the
-                # trace-slot axis carried along as a trailing dim
-                curve = (
-                    tr_out.transpose(1, 2, 0)
-                    .reshape(n_wb, S, Tw, tr_used)
-                    .transpose(0, 2, 1, 3)
-                    .reshape(chunk, S, tr_used)
-                )
-                out["curve_gmax"] = curve
-                out["curve_stride"] = jnp.full(
-                    (chunk, S), float(tr_stride), jnp.float32
-                )
-                out["curve_steps"] = jnp.full(
-                    (chunk, S), float(steps), jnp.float32
-                )
+                    out["curve_gmax"] = curve
+                    out["curve_stride"] = jnp.full(
+                        (chunk, S), float(tr_stride), jnp.float32
+                    )
+                    out["curve_steps"] = jnp.full(
+                        (chunk, S), float(steps), jnp.float32
+                    )
             return out
 
         return fn

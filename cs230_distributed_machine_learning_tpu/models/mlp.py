@@ -514,191 +514,193 @@ class _MLPBase(ModelKernel):
         mdt = jnp.float32 if interpret else jnp.bfloat16
 
         def fn(X, y, TW, EW, hyper):
-            Xb = X.astype(mdt)
-            if classification:
-                Y = jax.nn.one_hot(y, c, dtype=jnp.bfloat16)
-            else:
-                Y = y.astype(jnp.float32)[:, None]
-            TWf = TW.astype(jnp.float32)
-            lr = _lane_vec(hyper["learning_rate_init"])
-            alpha = _lane_vec(hyper["alpha"])
+            with jax.named_scope("tpuml.fit"):
+                Xb = X.astype(mdt)
+                if classification:
+                    Y = jax.nn.one_hot(y, c, dtype=jnp.bfloat16)
+                else:
+                    Y = y.astype(jnp.float32)[:, None]
+                TWf = TW.astype(jnp.float32)
+                lr = _lane_vec(hyper["learning_rate_init"])
+                alpha = _lane_vec(hyper["alpha"])
 
-            key = jax.random.PRNGKey(seed)
-            key, init_key = jax.random.split(key)
-            params = self._init(init_key, dims)
-            per_layer = 6 if solver == "adam" else 4
-            n_moments = 2 if solver == "adam" else 1
-            state = []
-            for layer in params:
-                # biases ride as [Lk, 8, out] row-identical slabs (see
-                # ops/pallas_mlp.py kernel docstring for the layout rule)
-                for leaf in (layer["W"], jnp.tile(layer["b"][None, :], (8, 1))):
-                    state.append(jnp.tile(leaf[None], (Lk,) + (1,) * leaf.ndim))
-                    for _ in range(n_moments):
-                        state.append(jnp.zeros((Lk,) + leaf.shape, jnp.float32))
-            # reorder to the kernel's per-layer layout: (pW, pB, mW, mB,
-            # vW, vB) for adam, (pW, pB, velW, velB) for sgd
-            half = 1 + n_moments
-            flat = []
-            for li in range(len(params)):
-                chunk6 = state[2 * half * li : 2 * half * (li + 1)]
-                Wslabs, Bslabs = chunk6[:half], chunk6[half:]
-                flat.extend([Wslabs[0], Bslabs[0]])
-                for j in range(1, half):
-                    flat.extend([Wslabs[j], Bslabs[j]])
-            state = flat
-            if adaptive:
-                state.append(jnp.zeros((Lk, 8, 128), jnp.float32))
+                key = jax.random.PRNGKey(seed)
+                key, init_key = jax.random.split(key)
+                params = self._init(init_key, dims)
+                per_layer = 6 if solver == "adam" else 4
+                n_moments = 2 if solver == "adam" else 1
+                state = []
+                for layer in params:
+                    # biases ride as [Lk, 8, out] row-identical slabs (see
+                    # ops/pallas_mlp.py kernel docstring for the layout rule)
+                    for leaf in (layer["W"], jnp.tile(layer["b"][None, :], (8, 1))):
+                        state.append(jnp.tile(leaf[None], (Lk,) + (1,) * leaf.ndim))
+                        for _ in range(n_moments):
+                            state.append(jnp.zeros((Lk,) + leaf.shape, jnp.float32))
+                # reorder to the kernel's per-layer layout: (pW, pB, mW, mB,
+                # vW, vB) for adam, (pW, pB, velW, velB) for sgd
+                half = 1 + n_moments
+                flat = []
+                for li in range(len(params)):
+                    chunk6 = state[2 * half * li : 2 * half * (li + 1)]
+                    Wslabs, Bslabs = chunk6[:half], chunk6[half:]
+                    flat.extend([Wslabs[0], Bslabs[0]])
+                    for j in range(1, half):
+                        flat.extend([Wslabs[j], Bslabs[j]])
+                state = flat
+                if adaptive:
+                    state.append(jnp.zeros((Lk, 8, 128), jnp.float32))
 
-            ekeys = jax.random.split(key, epochs)
-            t0s = jnp.arange(epochs, dtype=jnp.int32) * n_batches
+                ekeys = jax.random.split(key, epochs)
+                t0s = jnp.arange(epochs, dtype=jnp.int32) * n_batches
 
-            if bs_pad != bs:
-                pad_mask = jnp.asarray(
-                    np.concatenate(
-                        [np.ones((n_batches, bs), np.float32),
-                         np.zeros((n_batches, bs_pad - bs), np.float32)], 1
-                    ).reshape(-1)
-                )
-            else:
-                pad_mask = None
-
-            def _epoch_rows(perm):
-                if bs_pad == bs:
-                    return perm
-                idx = perm.reshape(n_batches, bs)
-                return jnp.concatenate(
-                    [idx, jnp.zeros((n_batches, bs_pad - bs), idx.dtype)], 1
-                ).reshape(-1)
-
-            def _run_epoch(st, key_e, t0, lr_col):
-                perm = jax.random.permutation(key_e, n)[:R]
-                idx = _epoch_rows(perm)
-                Wl = TWf[:, idx].T[:, lane_split]  # [Rp, Lk], lane-minor
-                if pad_mask is not None:
-                    Wl = Wl * pad_mask[:, None]
-                return epoch_fn(
-                    Xb[idx], Y[idx], Wl, lr_col, alpha,
-                    t0.reshape(1, 1), st,
-                ), Wl
-
-            if not adaptive:
-                def body(st, xs):
-                    key_e, t0 = xs
-                    if solver == "sgd" and schedule == "invscaling":
-                        # sklearn t_ advances by n samples per epoch
-                        e = (t0 // n_batches).astype(jnp.float32)
-                        lr_col = lr / (e * n + 1.0) ** power_t
-                    else:
-                        lr_col = lr
-                    st, _ = _run_epoch(st, key_e, t0, lr_col)
-                    return st, None
-
-                state, _ = jax.lax.scan(body, state, (ekeys, t0s))
-            else:
-                def body(carry, xs):
-                    st, lr_col, best, wait = carry
-                    key_e, t0 = xs
-                    st = st[:-1] + [jnp.zeros_like(st[-1])]  # reset loss acc
-                    st, Wl = _run_epoch(st, key_e, t0, lr_col)
-                    data_loss = st[-1][:, 0, 0] / n_batches  # [Lk]
-                    # L2 term added host-side from end-of-epoch weights
-                    # (sklearn accumulates it per batch; the improvement
-                    # signal only needs epoch resolution)
-                    l2 = jnp.zeros((Lk,), jnp.float32)
-                    for li in range(len(params)):
-                        Wli = st[per_layer * li]
-                        l2 = l2 + jnp.sum(
-                            Wli.astype(jnp.float32) ** 2,
-                            axis=tuple(range(1, Wli.ndim)),
-                        )
-                    bw_mean = jnp.maximum(jnp.sum(Wl, axis=0) / n_batches, 1e-12)
-                    epoch_loss = data_loss + 0.5 * alpha[:, 0] * l2 / bw_mean
-                    improved = epoch_loss < best - tol
-                    wait = jnp.where(improved, 0, wait + 1)
-                    cut = wait > no_change
-                    lr_col = jnp.where(
-                        cut[:, None], jnp.maximum(lr_col / 5.0, 1e-6), lr_col
+                if bs_pad != bs:
+                    pad_mask = jnp.asarray(
+                        np.concatenate(
+                            [np.ones((n_batches, bs), np.float32),
+                             np.zeros((n_batches, bs_pad - bs), np.float32)], 1
+                        ).reshape(-1)
                     )
-                    wait = jnp.where(cut, 0, wait)
-                    best = jnp.minimum(best, epoch_loss)
-                    return (st, lr_col, best, wait), None
+                else:
+                    pad_mask = None
 
-                carry0 = (
-                    state, lr,  # [Lk, 1] per-lane column (mutated by cuts)
-                    jnp.full((Lk,), jnp.inf, jnp.float32),
-                    jnp.zeros((Lk,), jnp.int32),
-                )
-                (state, _, _, _), _ = jax.lax.scan(body, carry0, (ekeys, t0s))
+                def _epoch_rows(perm):
+                    if bs_pad == bs:
+                        return perm
+                    idx = perm.reshape(n_batches, bs)
+                    return jnp.concatenate(
+                        [idx, jnp.zeros((n_batches, bs_pad - bs), idx.dtype)], 1
+                    ).reshape(-1)
 
-            # ---- eval (XLA): weighted score per lane over row chunks ----
-            pWs = [state[per_layer * li] for li in range(len(params))]
-            pBs = [state[per_layer * li + 1][:, 0:1, :] for li in range(len(params))]
-            act_f = _act(act)
-            Xe = jnp.pad(Xb, ((0, n_pad - n), (0, 0)))
-            EWp = jnp.pad(EW.astype(jnp.float32), ((0, 0), (0, n_pad - n)))
-            if classification:
-                ye = jnp.pad(y.astype(jnp.int32), (0, n_pad - n))
-            else:
-                ye = jnp.pad(y.astype(jnp.float32), (0, n_pad - n))
+                def _run_epoch(st, key_e, t0, lr_col):
+                    perm = jax.random.permutation(key_e, n)[:R]
+                    idx = _epoch_rows(perm)
+                    Wl = TWf[:, idx].T[:, lane_split]  # [Rp, Lk], lane-minor
+                    if pad_mask is not None:
+                        Wl = Wl * pad_mask[:, None]
+                    return epoch_fn(
+                        Xb[idx], Y[idx], Wl, lr_col, alpha,
+                        t0.reshape(1, 1), st,
+                    ), Wl
 
-            def forward_chunk(start):
-                h = jax.lax.dynamic_slice(Xe, (start, 0), (rc, d))
-                out = jnp.einsum(
-                    "rd,ldh->lrh", h, pWs[0].astype(mdt),
-                    preferred_element_type=jnp.float32,
-                ) + pBs[0]
-                for li in range(1, len(params)):
+                if not adaptive:
+                    def body(st, xs):
+                        key_e, t0 = xs
+                        if solver == "sgd" and schedule == "invscaling":
+                            # sklearn t_ advances by n samples per epoch
+                            e = (t0 // n_batches).astype(jnp.float32)
+                            lr_col = lr / (e * n + 1.0) ** power_t
+                        else:
+                            lr_col = lr
+                        st, _ = _run_epoch(st, key_e, t0, lr_col)
+                        return st, None
+
+                    state, _ = jax.lax.scan(body, state, (ekeys, t0s))
+                else:
+                    def body(carry, xs):
+                        st, lr_col, best, wait = carry
+                        key_e, t0 = xs
+                        st = st[:-1] + [jnp.zeros_like(st[-1])]  # reset loss acc
+                        st, Wl = _run_epoch(st, key_e, t0, lr_col)
+                        data_loss = st[-1][:, 0, 0] / n_batches  # [Lk]
+                        # L2 term added host-side from end-of-epoch weights
+                        # (sklearn accumulates it per batch; the improvement
+                        # signal only needs epoch resolution)
+                        l2 = jnp.zeros((Lk,), jnp.float32)
+                        for li in range(len(params)):
+                            Wli = st[per_layer * li]
+                            l2 = l2 + jnp.sum(
+                                Wli.astype(jnp.float32) ** 2,
+                                axis=tuple(range(1, Wli.ndim)),
+                            )
+                        bw_mean = jnp.maximum(jnp.sum(Wl, axis=0) / n_batches, 1e-12)
+                        epoch_loss = data_loss + 0.5 * alpha[:, 0] * l2 / bw_mean
+                        improved = epoch_loss < best - tol
+                        wait = jnp.where(improved, 0, wait + 1)
+                        cut = wait > no_change
+                        lr_col = jnp.where(
+                            cut[:, None], jnp.maximum(lr_col / 5.0, 1e-6), lr_col
+                        )
+                        wait = jnp.where(cut, 0, wait)
+                        best = jnp.minimum(best, epoch_loss)
+                        return (st, lr_col, best, wait), None
+
+                    carry0 = (
+                        state, lr,  # [Lk, 1] per-lane column (mutated by cuts)
+                        jnp.full((Lk,), jnp.inf, jnp.float32),
+                        jnp.zeros((Lk,), jnp.int32),
+                    )
+                    (state, _, _, _), _ = jax.lax.scan(body, carry0, (ekeys, t0s))
+
+            with jax.named_scope("tpuml.eval"):
+                # ---- eval (XLA): weighted score per lane over row chunks ----
+                pWs = [state[per_layer * li] for li in range(len(params))]
+                pBs = [state[per_layer * li + 1][:, 0:1, :] for li in range(len(params))]
+                act_f = _act(act)
+                Xe = jnp.pad(Xb, ((0, n_pad - n), (0, 0)))
+                EWp = jnp.pad(EW.astype(jnp.float32), ((0, 0), (0, n_pad - n)))
+                if classification:
+                    ye = jnp.pad(y.astype(jnp.int32), (0, n_pad - n))
+                else:
+                    ye = jnp.pad(y.astype(jnp.float32), (0, n_pad - n))
+
+                def forward_chunk(start):
+                    h = jax.lax.dynamic_slice(Xe, (start, 0), (rc, d))
                     out = jnp.einsum(
-                        "lrh,lhk->lrk",
-                        act_f(out).astype(mdt),
-                        pWs[li].astype(mdt),
+                        "rd,ldh->lrh", h, pWs[0].astype(mdt),
                         preferred_element_type=jnp.float32,
-                    ) + pBs[li]
-                ewc = jax.lax.dynamic_slice(
-                    EWp, (0, start), (S, rc)
-                )[lane_split]  # [Lk, rc]
-                return out, ewc
+                    ) + pBs[0]
+                    for li in range(1, len(params)):
+                        out = jnp.einsum(
+                            "lrh,lhk->lrk",
+                            act_f(out).astype(mdt),
+                            pWs[li].astype(mdt),
+                            preferred_element_type=jnp.float32,
+                        ) + pBs[li]
+                    ewc = jax.lax.dynamic_slice(
+                        EWp, (0, start), (S, rc)
+                    )[lane_split]  # [Lk, rc]
+                    return out, ewc
 
-            if classification:
-                def ebody(acc, start):
+                if classification:
+                    def ebody(acc, start):
+                        out, ewc = forward_chunk(start)
+                        pred = jnp.argmax(out, axis=-1)
+                        yc = jax.lax.dynamic_slice(ye, (start,), (rc,))
+                        hit = (pred == yc[None, :]).astype(jnp.float32)
+                        return acc + jnp.sum(hit * ewc, axis=1), None
+
+                    acc, _ = jax.lax.scan(
+                        ebody, jnp.zeros((Lk,), jnp.float32),
+                        jnp.arange(0, n_pad, rc),
+                    )
+                    den = jnp.sum(EWp, axis=1)[lane_split]
+                    score = acc / jnp.maximum(den, 1e-12)
+                    return {"score": score[:L0].reshape(chunk, S)}
+
+                def ebody(carry, start):
+                    sw, swy, swyy, ssr = carry
                     out, ewc = forward_chunk(start)
-                    pred = jnp.argmax(out, axis=-1)
-                    yc = jax.lax.dynamic_slice(ye, (start,), (rc,))
-                    hit = (pred == yc[None, :]).astype(jnp.float32)
-                    return acc + jnp.sum(hit * ewc, axis=1), None
+                    pred = out[:, :, 0]
+                    yc = jax.lax.dynamic_slice(ye, (start,), (rc,))[None, :]
+                    sw = sw + jnp.sum(ewc, axis=1)
+                    swy = swy + jnp.sum(ewc * yc, axis=1)
+                    swyy = swyy + jnp.sum(ewc * yc * yc, axis=1)
+                    ssr = ssr + jnp.sum(ewc * (yc - pred) ** 2, axis=1)
+                    return (sw, swy, swyy, ssr), None
 
-                acc, _ = jax.lax.scan(
-                    ebody, jnp.zeros((Lk,), jnp.float32),
-                    jnp.arange(0, n_pad, rc),
+                z = jnp.zeros((Lk,), jnp.float32)
+                (sw, swy, swyy, ssr), _ = jax.lax.scan(
+                    ebody, (z, z, z, z), jnp.arange(0, n_pad, rc)
                 )
-                den = jnp.sum(EWp, axis=1)[lane_split]
-                score = acc / jnp.maximum(den, 1e-12)
-                return {"score": score[:L0].reshape(chunk, S)}
-
-            def ebody(carry, start):
-                sw, swy, swyy, ssr = carry
-                out, ewc = forward_chunk(start)
-                pred = out[:, :, 0]
-                yc = jax.lax.dynamic_slice(ye, (start,), (rc,))[None, :]
-                sw = sw + jnp.sum(ewc, axis=1)
-                swy = swy + jnp.sum(ewc * yc, axis=1)
-                swyy = swyy + jnp.sum(ewc * yc * yc, axis=1)
-                ssr = ssr + jnp.sum(ewc * (yc - pred) ** 2, axis=1)
-                return (sw, swy, swyy, ssr), None
-
-            z = jnp.zeros((Lk,), jnp.float32)
-            (sw, swy, swyy, ssr), _ = jax.lax.scan(
-                ebody, (z, z, z, z), jnp.arange(0, n_pad, rc)
-            )
-            swc = jnp.maximum(sw, 1e-12)
-            ss_tot = jnp.maximum(swyy - swy * swy / swc, 1e-12)
-            r2 = 1.0 - ssr / ss_tot
-            mse = ssr / swc
-            return {
-                "score": r2[:L0].reshape(chunk, S),
-                "mse": mse[:L0].reshape(chunk, S),
-            }
+                swc = jnp.maximum(sw, 1e-12)
+                ss_tot = jnp.maximum(swyy - swy * swy / swc, 1e-12)
+                r2 = 1.0 - ssr / ss_tot
+                mse = ssr / swc
+                return {
+                    "score": r2[:L0].reshape(chunk, S),
+                    "mse": mse[:L0].reshape(chunk, S),
+                }
 
         return fn
 

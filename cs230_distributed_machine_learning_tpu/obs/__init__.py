@@ -87,11 +87,11 @@ from .tracing import (  # noqa: F401 — re-exported API
     Tracer,
     activate,
     active_tracer,
+    child_span,
     current_span_id,
     current_trace_id,
     new_trace_id,
     process_token,
-    record_phase,
     span,
     use_tracer,
 )
@@ -585,7 +585,7 @@ __all__ = [
     "TRACE_HEADER",
     "PARENT_HEADER",
     "span",
-    "record_phase",
+    "child_span",
     "activate",
     "use_tracer",
     "active_tracer",

@@ -8,7 +8,8 @@ joining a job's span tree with its flight-recorder timelines and tiling
 the measured wall [t0, t1] with labeled segments:
 
     frontend.proxy → submit → expand → queue.wait → place →
-    executor.{compile,stage,dispatch,fetch} → result.ingest → aggregate
+    executor.{load_data,split_plan,stage,compile,dispatch,fetch,emit} →
+    result.ingest → aggregate
 
 The tiling is EXACT by construction: candidate intervals (spans, plus
 intervals derived from recorder events — queue wait before the first
@@ -25,7 +26,9 @@ result the aggregate waited on last) and, within it, the *winning
 attempt* (the attempt stamped on the accepted result) — a speculative
 loser's executor spans and a superseded attempt's phases never enter the
 candidate set, while the reclaim wait that preceded a re-place does
-(it was real wall time the job spent hung).
+(it was real wall time the job spent hung). A direct (local) job has no
+placements and no worker to name: there every ``executor.batch`` span of
+the job's trace is admitted, with its children.
 
 ``compare(a, b)`` diffs two reports segment-by-segment and attributes
 the wall-clock delta — the interpretability layer for perf-observatory
@@ -46,15 +49,19 @@ from typing import Any, Dict, List, Optional, Tuple
 #: first — the earliest of these that exists anchors t0
 _ROOT_NAMES = ("frontend.proxy", "http.train", "http.train_status",
                "client.train", "job.submit")
-#: span names that can close the window — the latest end wins
-_TAIL_NAMES = ("job.aggregate", "job.execute", "job.submit")
+#: span names that can close the window — the latest end wins (a local
+#: client's ``client.train`` ends once it has read the result)
+_TAIL_NAMES = ("job.aggregate", "job.execute", "job.submit", "client.train")
 
 #: terminal result statuses (the event the aggregate waited on)
 _TERMINAL = {"completed", "failed", "pruned"}
 
-#: synthesized per-phase executor spans (children of executor.batch)
+#: the trial engine's phases and the executor's own steps: real spans,
+#: direct children of executor.batch, recorded where the work happens
 _PHASE_NAMES = ("executor.compile", "executor.stage",
                 "executor.dispatch", "executor.fetch")
+_BATCH_CHILD_NAMES = _PHASE_NAMES + (
+    "executor.load_data", "executor.split_plan", "executor.emit")
 
 
 def _f(v: Any, default: float = 0.0) -> float:
@@ -212,9 +219,9 @@ def critical_path(
             default=None,
         )
     batch_end = None
+    batch_windows: Dict[Any, Tuple[float, float]] = {}
     if win_worker and result_ts is not None:
         lo = win_place_ts if win_place_ts is not None else t0
-        batch_windows: Dict[Any, Tuple[float, float]] = {}
         for s in spans:
             if s["name"] != "executor.batch":
                 continue
@@ -232,20 +239,26 @@ def critical_path(
             add(b0, b1, "execute", 6, worker=win_worker)
             batch_windows[s.get("span_id")] = (b0, b1)
             batch_end = b1 if batch_end is None else max(batch_end, b1)
-        for s in spans:
-            win = batch_windows.get(s.get("parent_id"))
-            if s["name"] in _PHASE_NAMES and win is not None:
-                # synthesized phases carry exact DURATIONS but indicative
-                # offsets (laid sequentially from batch start while real
-                # phases overlap — executor._record_batch_phases): clamp
-                # to the parent batch envelope so an overrunning phase
-                # estimate can never eat into post-batch segments
-                # (result.ingest, aggregate)
-                add(max(_f(s.get("start")), win[0]),
-                    min(_f(s.get("end")), win[1]), s["name"], 7)
         if batch_end is not None and result_ts > batch_end:
             add(batch_end, result_ts, "result.ingest", 3,
                 subtask_id=crit_stid)
+    elif not any(e.get("kind") == "placement"
+                 for events in timelines.values() for e in events or []):
+        # direct job: nothing was placed, so no timeline names a winner;
+        # the trace is the job's own, and every batch in it ran for it
+        for s in spans:
+            if s["name"] == "executor.batch":
+                b0, b1 = _f(s.get("start")), _f(s.get("end"))
+                add(b0, b1, "execute", 6,
+                    worker=(s.get("attrs") or {}).get("worker"))
+                batch_windows[s.get("span_id")] = (b0, b1)
+    for s in spans:
+        win = batch_windows.get(s.get("parent_id"))
+        if s["name"] in _BATCH_CHILD_NAMES and win is not None:
+            # a child is a real interval inside its batch; the clamp only
+            # cuts the part of a winner's batch that outlived its result
+            add(max(_f(s.get("start")), win[0]),
+                min(_f(s.get("end")), win[1]), s["name"], 7)
 
     # ---- sweep: most-specific candidate wins each elementary slice ----
     bounds = sorted({t0, t1, *(c.start for c in cands),

@@ -19,8 +19,8 @@ LIVE system, where device time actually goes. Two instruments:
 - **Programmatic ``jax.profiler`` capture**: ``POST /profile/start`` /
   ``POST /profile/stop`` (runtime/server.py) bracket a live workload with
   a real XLA trace dumped under ``<journal_dir>/profile/<tag>`` — the
-  deep-inspection path that previously required restarting the
-  coordinator with ``execution.enable_profiler``. One capture at a time;
+  one deep-inspection path; the program's spans land in it as
+  ``tpuml.<span name>`` host rows (obs/tracing.py). One capture at a time;
   start/stop land in the flight recorder (``profile.start`` /
   ``profile.stop``) so the capture window is visible next to the
   scheduling decisions it brackets.
@@ -73,8 +73,7 @@ def record_batch_device_seconds(
 ) -> None:
     """Attribute one executed batch's phase totals (TrialRunResult's
     timers). ``dispatch`` = the device window minus the blocking fetches
-    inside it, clamped at zero — the same decomposition the synthesized
-    trace phases use (executor._record_batch_phases)."""
+    inside it, clamped at zero."""
     if not _enabled():
         return
     device_seconds("compile", compile_s)

@@ -30,6 +30,7 @@ import contextlib
 import contextvars
 import json
 import os
+import sys
 import threading
 import time
 import uuid
@@ -103,26 +104,70 @@ def journal_dir() -> str:
     return d
 
 
+#: open journal files, least recently written first: path -> [file, bytes
+#: in it, perf_counter() of the last look at the path]. A handful of
+#: handles at most (one process writes spans.jsonl and events.jsonl; tests
+#: re-root the journal dir per test)
+_JOURNAL_OPEN: "collections.OrderedDict[str, list]" = collections.OrderedDict()
+_JOURNAL_MAX_OPEN = 4
+_JOURNAL_LOCK = threading.Lock()
+#: seconds an open handle is trusted before the path is looked at again
+#: (another process may have rotated or removed the file)
+_JOURNAL_RECHECK_S = 1.0
+
+
+def _journal_file(path: str) -> list:
+    """The open append handle of ``path`` (caller holds the lock). A line
+    used to cost an ``os.makedirs``, an ``os.path.getsize``, an open and a
+    close: 0.63 ms on the benchmark machine's 9p filesystem, 0.25 s a
+    128-trial search over its 274 lines, 20 us with the handle kept
+    (PERF.md section 6, PR 26). The handle is checked against the path
+    once a second: rotated or removed by someone else, it is reopened."""
+    now = time.perf_counter()
+    entry = _JOURNAL_OPEN.get(path)
+    if entry is not None and now - entry[2] > _JOURNAL_RECHECK_S:
+        try:
+            st = os.fstat(entry[0].fileno())
+            if os.stat(path).st_ino != st.st_ino:
+                raise FileNotFoundError(path)
+            entry[1], entry[2] = st.st_size, now  # other writers' lines count
+        except OSError:
+            entry[0].close()
+            del _JOURNAL_OPEN[path]
+            entry = None
+    if entry is None:
+        os.makedirs(os.path.dirname(path), exist_ok=True)
+        f = open(path, "a")
+        entry = _JOURNAL_OPEN[path] = [f, os.fstat(f.fileno()).st_size, now]
+        while len(_JOURNAL_OPEN) > _JOURNAL_MAX_OPEN:
+            _JOURNAL_OPEN.popitem(last=False)[1][0].close()
+    else:
+        _JOURNAL_OPEN.move_to_end(path)
+    return entry
+
+
 def journal_append(basename: str, obj: Dict[str, Any]) -> None:
     """Best-effort size-rotated JSONL append under the journal dir — the
     shared writer behind the span journal (``spans.jsonl``) and the flight
-    recorder's event journal (``events.jsonl``). Volume is low (dozens of
-    lines per job), so open-append-close per line is acceptable; any
-    filesystem failure silently drops the line (the in-process rings stay
-    authoritative)."""
+    recorder's event journal (``events.jsonl``). Every line is flushed as
+    it is written, through a handle that stays open (:func:`_journal_file`);
+    any filesystem failure silently drops the line (the in-process rings
+    stay authoritative)."""
     if not _journal_enabled():
         return
     try:
-        d = journal_dir()
-        os.makedirs(d, exist_ok=True)
-        path = os.path.join(d, basename)
-        try:
-            if os.path.getsize(path) > _journal_max_bytes():
+        path = os.path.join(journal_dir(), basename)
+        line = json.dumps(obj, default=str) + "\n"
+        with _JOURNAL_LOCK:
+            entry = _journal_file(path)
+            if entry[1] > _journal_max_bytes():
+                entry[0].close()
+                del _JOURNAL_OPEN[path]
                 os.replace(path, path + ".1")
-        except OSError:
-            pass  # first write: no file to rotate yet
-        with open(path, "a") as f:
-            f.write(json.dumps(obj, default=str) + "\n")
+                entry = _journal_file(path)
+            entry[0].write(line)
+            entry[0].flush()
+            entry[1] += len(line)
     except Exception:  # noqa: BLE001 — observability must never fail a job
         pass
 
@@ -345,6 +390,19 @@ class _NoopSpan:
 _NOOP = _NoopSpan()
 
 
+def _annotation(name: str):
+    """The profiler-side sink of :func:`span`: a
+    ``jax.profiler.TraceAnnotation("tpuml.<name>")``, so a profiler session
+    stamps the program's spans on the clock it stamps device ops with and
+    an idle gap of the chip gets the name of what the host was doing.
+    ``jax`` is looked up, never imported: a process that has not loaded it
+    (a REST-only client) has no profiler session to write to."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return contextlib.nullcontext()
+    return jax.profiler.TraceAnnotation("tpuml." + name)
+
+
 @contextlib.contextmanager
 def span(
     name: str,
@@ -359,7 +417,13 @@ def span(
     context unless given explicitly; with no ambient trace and no explicit
     id a fresh trace starts. Yields a :class:`SpanHandle` whose ``attrs``
     can be extended mid-span; the span records on exit (errors are noted
-    in ``attrs['error']`` and re-raised)."""
+    in ``attrs['error']`` and re-raised).
+
+    One interval, two sinks: the tracer (ring + journal) and, for the same
+    interval, a profiler annotation (:func:`_annotation`). ``start`` is the
+    wall-clock anchor REST consumers stitch processes on; the duration
+    comes from ``time.perf_counter()`` (``end = start + elapsed``), so a
+    stepping wall clock cannot stretch or invert a span."""
     if not _enabled():
         _NOOP.attrs.clear()
         yield _NOOP
@@ -372,12 +436,15 @@ def span(
     sid = new_span_id()
     handle = SpanHandle(tid, sid, pid, name, time.time(), dict(attrs))
     token = _CTX.set((tid, sid))
+    t0 = time.perf_counter()
     try:
-        yield handle
+        with _annotation(name):
+            yield handle
     except BaseException as e:
         handle.attrs["error"] = f"{type(e).__name__}: {e}"
         raise
     finally:
+        elapsed = time.perf_counter() - t0
         _CTX.reset(token)
         t = tracer or active_tracer()
         t.record(
@@ -387,46 +454,25 @@ def span(
                 "parent_id": pid,
                 "name": name,
                 "start": handle.start,
-                "end": time.time(),
+                "end": handle.start + elapsed,
                 "attrs": handle.attrs,
                 "process": process or _process_tag(),
             }
         )
 
 
-def record_phase(
-    parent: Any,
-    name: str,
-    duration_s: float,
-    *,
-    start: Optional[float] = None,
-    tracer: Optional[Tracer] = None,
-    **attrs: Any,
-) -> Optional[float]:
-    """Record a synthesized child span from a measured duration — the
-    vehicle for surfacing the trial engine's phase timers (compile /
-    stage / dispatch / fetch) as timeline entries. ``parent`` is the
-    enclosing SpanHandle; phases lay out sequentially from ``start``
-    (default: parent start). Returns the phase's end time so callers can
-    chain phases; no-op (returns None) when disabled or parent is a
-    no-op span."""
-    if not _enabled() or getattr(parent, "span_id", None) is None:
-        return None
-    t0 = parent.start if start is None else start
-    t = tracer or active_tracer()
-    t.record(
-        {
-            "trace_id": parent.trace_id,
-            "span_id": new_span_id(),
-            "parent_id": parent.span_id,
-            "name": name,
-            "start": t0,
-            "end": t0 + max(float(duration_s), 0.0),
-            "attrs": {"synthesized": True, **attrs},
-            "process": _process_tag(),
-        }
-    )
-    return t0 + max(float(duration_s), 0.0)
+@contextlib.contextmanager
+def child_span(name: str, **attrs: Any):
+    """:func:`span` for code below the executor (the trial engine, the
+    stage cache): records only as the child of an ambient trace. A direct
+    ``run_trials`` caller (a benchmark, a test) has none, and minting a
+    fresh trace per call there would churn the bounded trace ring."""
+    if _CTX.get() is None:
+        _NOOP.attrs.clear()
+        yield _NOOP
+        return
+    with span(name, **attrs) as handle:
+        yield handle
 
 
 def _process_tag() -> str:

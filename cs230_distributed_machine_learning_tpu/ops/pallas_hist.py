@@ -143,6 +143,7 @@ def level_histogram_pallas(local, xb, SC, n_nodes: int, n_bins: int, *,
         out_specs=pl.BlockSpec((1, kk * Mb, xpad), lambda nb, i: (nb, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((NBk, kk * Mb, xpad), jnp.float32),
         interpret=interpret,
+        name="level_histogram",
     )(local[:, None].astype(jnp.int32), xb.astype(jnp.int32),
       SC.astype(jnp.float32))
 

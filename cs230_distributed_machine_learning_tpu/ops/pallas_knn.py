@@ -151,5 +151,6 @@ def knn_topk(Q, Xt, w, k: int, interpret: bool = False):
             pltpu.VMEM((_BQ, k), jnp.int32),
         ],
         interpret=interpret,
+        name="knn_topk",
     )(Qp, qsq, Xp, tsq, wp[None, :])
     return d2_out[:nq], idx_out[:nq]

@@ -136,6 +136,7 @@ def packed_softmax_grad(
         out_specs=pl.BlockSpec((1, dpp, NB), lambda wb, i: (wb, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((n_wb, dpp, NB), jnp.float32),
         interpret=interpret,
+        name="packed_softmax_grad",
     )(Ab, W3, y2, WSP)
 
 
@@ -313,6 +314,7 @@ def packed_nesterov_step(
             jax.ShapeDtypeStruct((n_wb, 1, B), jnp.float32),
         ],
         interpret=interpret,
+        name="packed_nesterov_step",
         **({} if interpret else {"compiler_params": pltpu.CompilerParams(
             vmem_limit_bytes=_FUSED_STEP_VMEM_LIMIT)}),
     )(Ab, W3, Wp3, y2, WSP, t2, *cols, pen_col)
@@ -417,6 +419,7 @@ def masked_softmax_grad(Ab, W, y2, wm, *, c: int, bm: int = 256, interpret: bool
         out_specs=pl.BlockSpec((dpp, cp), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((dpp, cp), jnp.float32),
         interpret=interpret,
+        name="masked_softmax_grad",
     )(Ab, W, y2, wm)
 
 

@@ -306,6 +306,7 @@ def build_epoch_fn(
                 out_specs=out_specs,
                 out_shape=out_shape,
                 interpret=interpret,
+                name="mlp_fused_epoch",
                 **kwargs,
             )(Xs, Ys, Wlane, lr, alpha, t0, *state)
         )

@@ -32,7 +32,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models.base import ModelKernel, TrialData
-from ..obs import counter_inc, obs_enabled, observe
+from ..obs import child_span, counter_inc, obs_enabled, observe
 from ..ops.folds import SplitPlan
 from ..utils import backend as _backend
 from ..utils.aot_cache import aot_jit
@@ -188,15 +188,18 @@ def _pack_wrap(fn):
 
     def packed(*args):
         leaves = jax.tree_util.tree_leaves(fn(*args))
-        parts = []
-        for leaf in leaves:
-            leaf = jnp.asarray(leaf)
-            if leaf.dtype == jnp.bool_:
-                leaf = leaf.astype(jnp.uint8)
-            parts.append(jax.lax.bitcast_convert_type(leaf, jnp.uint8).reshape(-1))
-        if not parts:
-            return jnp.zeros((0,), jnp.uint8)
-        return jnp.concatenate(parts) if len(parts) > 1 else parts[0]
+        with jax.named_scope("tpuml.pack"):
+            parts = []
+            for leaf in leaves:
+                leaf = jnp.asarray(leaf)
+                if leaf.dtype == jnp.bool_:
+                    leaf = leaf.astype(jnp.uint8)
+                parts.append(
+                    jax.lax.bitcast_convert_type(leaf, jnp.uint8).reshape(-1)
+                )
+            if not parts:
+                return jnp.zeros((0,), jnp.uint8)
+            return jnp.concatenate(parts) if len(parts) > 1 else parts[0]
 
     return packed
 
@@ -225,17 +228,19 @@ def _fetch_result(out, spec: Optional[_PackSpec]):
     collective fetch. Each blocking fetch feeds the
     ``tpuml_executor_fetch_seconds`` histogram and the per-run phase
     accumulator (TrialRunResult.fetch_time_s)."""
-    t0 = time.perf_counter()
-    if isinstance(out, _Packed):
-        out, spec = out.buf, out.spec
-    if spec is not None:
-        buf = np.asarray(jax.device_get(out))
-        result = _unpack(buf, spec), 1, buf.nbytes
-    else:
-        host = _fetch(out)
-        leaves = jax.tree_util.tree_leaves(host)
-        result = host, len(leaves), sum(int(l.nbytes) for l in leaves)
-    dt = time.perf_counter() - t0
+    with child_span("executor.fetch") as sp:
+        t0 = time.perf_counter()
+        if isinstance(out, _Packed):
+            out, spec = out.buf, out.spec
+        if spec is not None:
+            buf = np.asarray(jax.device_get(out))
+            result = _unpack(buf, spec), 1, buf.nbytes
+        else:
+            host = _fetch(out)
+            leaves = jax.tree_util.tree_leaves(host)
+            result = host, len(leaves), sum(int(l.nbytes) for l in leaves)
+        dt = time.perf_counter() - t0
+        sp.attrs["bytes"] = result[2]
     observe("tpuml_executor_fetch_seconds", dt)
     _PHASE.fetch += dt
     return result
@@ -524,18 +529,34 @@ def _staged_mesh(data, x_key, X_np, mesh, trial_axis, replicate_only=False):
     # replication traffic: every device beyond the source gets a full
     # copy; a row reshard moves ~one full pass of the data in total
     ici_est = nbytes * (n_dev - 1) if form == "repl" else nbytes
-    t0 = time.perf_counter()
-    stage_before = _PHASE.stage
-    val, outcome = _sc.STAGE_CACHE.get_or_stage(
-        mesh_key, make_mesh, transport="ici", ici_bytes=ici_est
-    )
+    with child_span("executor.stage", what="mesh." + form) as sp:
+        t0 = time.perf_counter()
+        stage_before = _PHASE.stage
+        val, outcome = _sc.STAGE_CACHE.get_or_stage(
+            mesh_key, make_mesh, transport="ici", ici_bytes=ici_est
+        )
+        wall = time.perf_counter() - t0
+        # the host upload is the inner span's; this one moved bytes over ICI
+        sp.attrs.update(outcome=outcome, bytes=0)
     if outcome != "hit":
         # the inner host upload already added its own wall to the phase
         # accumulator; add only the replicate remainder so the run's
         # staging time covers both layers without double-counting
         inner = _PHASE.stage - stage_before
-        _PHASE.stage += max(0.0, (time.perf_counter() - t0) - inner)
+        _PHASE.stage += max(0.0, wall - inner)
     return val
+
+
+def _stage_what(key) -> str:
+    """The ``what`` of an ``executor.stage`` span, from the entry's subkey:
+    ``data`` (the design matrix, any staged form), ``folds`` (labels and
+    fold masks), or the name a kernel gave a staged extra."""
+    head = str(key[0])
+    if head == "X":
+        return "data"
+    if head == "batched_extra":
+        return str(key[2])
+    return "folds" if head.endswith("folds") else head
 
 
 def _staged_device(data, key, make):
@@ -553,11 +574,16 @@ def _staged_device(data, key, make):
     from ..data import stage_cache as _sc
 
     if _sc.enabled():
-        gkey = (_sc.dataset_fingerprint(data), _device_sig()) + tuple(key)
-        t0 = time.perf_counter()
-        val, outcome = _sc.STAGE_CACHE.get_or_stage(gkey, make)
-        if outcome != "hit":
+        with child_span("executor.stage", what=_stage_what(key)) as sp:
+            gkey = (_sc.dataset_fingerprint(data), _device_sig()) + tuple(key)
+            t0 = time.perf_counter()
+            val, outcome = _sc.STAGE_CACHE.get_or_stage(gkey, make)
             dt = time.perf_counter() - t0
+            sp.attrs.update(
+                outcome=outcome,
+                bytes=_sc._tree_nbytes(val) if outcome == "miss" else 0,
+            )
+        if outcome != "hit":
             if outcome == "miss":
                 # only real uploads feed the histogram (hit contract);
                 # "wait" time still counts as this run's staging wall
@@ -580,9 +606,12 @@ def _staged_device(data, key, make):
     # unlike a concurrent LRU eviction between insert and a re-read, which
     # would KeyError. The local `val` is returned directly so eviction of
     # this key by another thread can never fail THIS call.
-    t0 = time.perf_counter()
-    val = make()
-    dt = time.perf_counter() - t0
+    with child_span("executor.stage", what=_stage_what(key),
+                    outcome="miss") as sp:
+        t0 = time.perf_counter()
+        val = make()
+        dt = time.perf_counter() - t0
+        sp.attrs["bytes"] = _sc._tree_nbytes(val)
     # only misses are observed: a cache hit is not a staging upload
     observe("tpuml_executor_stage_seconds", dt)
     _PHASE.stage += dt
@@ -633,15 +662,21 @@ def _make_batched(kernel, static, has_hyper):
         if not has_hyper:
             hyper = {}
 
+        # the scopes label every device op of the executable in a profiler
+        # trace: the fit half, the scoring half (docs/OBSERVABILITY.md)
         def one_split(tw, ew):
             if capture:
-                fitted, curve = kernel.fit_curve(X, y, tw, hyper, static)
-                out = dict(kernel.evaluate(fitted, X, y, ew, static))
+                with jax.named_scope("tpuml.fit"):
+                    fitted, curve = kernel.fit_curve(X, y, tw, hyper, static)
+                with jax.named_scope("tpuml.eval"):
+                    out = dict(kernel.evaluate(fitted, X, y, ew, static))
                 for k, v in curve.items():
                     out["curve_" + k] = v
                 return out
-            fitted = kernel.fit(X, y, tw, hyper, static)
-            return kernel.evaluate(fitted, X, y, ew, static)
+            with jax.named_scope("tpuml.fit"):
+                fitted = kernel.fit(X, y, tw, hyper, static)
+            with jax.named_scope("tpuml.eval"):
+                return kernel.evaluate(fitted, X, y, ew, static)
 
         return jax.vmap(one_split)(TW, EW)
 
@@ -859,12 +894,14 @@ def _run_trials_impl(
                 _prefetch_async(out.buf)
             else:
                 _prefetch_async(out)
-        for bi, bs, batch_idx in pending_best:
-            pos, score = int(bi), float(bs)
-            n_fetches += 2  # two replicated scalars from the collective argmax
-            if pos < len(batch_idx) and np.isfinite(score):
-                _merge_best(batch_idx[pos], score)
-        pending_best.clear()
+        if pending_best:
+            with child_span("executor.fetch", what="argmax", bytes=0):
+                for bi, bs, batch_idx in pending_best:
+                    pos, score = int(bi), float(bs)
+                    n_fetches += 2  # two replicated scalars from the collective argmax
+                    if pos < len(batch_idx) and np.isfinite(score):
+                        _merge_best(batch_idx[pos], score)
+            pending_best.clear()
         for out, batch_idx in pending:
             if isinstance(out, list):  # split-group dispatches: concat folds
                 fetched = [(_to_host(og), size) for og, size in out]
@@ -1076,26 +1113,28 @@ def _run_trials_impl(
             cache_key = ("host",) + _aot_key(
                 kernel, static, X, data.n_classes, plan.n_splits, chunk, hyper_names
             )
-            fresh_compile = cache_key not in _compiled_cache
-            _cache_count(not fresh_compile)
-            if fresh_compile:
-                # traced (the scope wraps every call of the Python body)
-                # for the platform it runs on, not the default one
-                raw = _backend.host_execution()(
-                    _make_batched(kernel, static, bool(hyper_names))
-                )
-                example = _example_args(
-                    X, y_np, plan.train_w, plan.eval_w, hyper_names, chunk
-                )
-                # cost captured on the pre-pack form: the executable's
-                # priced work must not vary with the transport knob
-                cost = _capture_cost(raw, example)
-                spec = None
-                if _packed_enabled():
-                    spec = _pack_spec_of(raw, example)
-                    raw = _pack_wrap(raw)
-                _compiled_cache[cache_key] = (jax.jit(raw), spec, cost)
-            fn, out_spec, exec_cost = _compiled_cache[cache_key]
+            with child_span("executor.compile", cache="hit") as csp:
+                fresh_compile = cache_key not in _compiled_cache
+                _cache_count(not fresh_compile)
+                if fresh_compile:
+                    # traced (the scope wraps every call of the Python body)
+                    # for the platform it runs on, not the default one
+                    raw = _backend.host_execution()(
+                        _make_batched(kernel, static, bool(hyper_names))
+                    )
+                    example = _example_args(
+                        X, y_np, plan.train_w, plan.eval_w, hyper_names, chunk
+                    )
+                    # cost captured on the pre-pack form: the executable's
+                    # priced work must not vary with the transport knob
+                    cost = _capture_cost(raw, example)
+                    spec = None
+                    if _packed_enabled():
+                        spec = _pack_spec_of(raw, example)
+                        raw = _pack_wrap(raw)
+                    _compiled_cache[cache_key] = (jax.jit(raw), spec, cost)
+                    csp.attrs["cache"] = "traced"
+                fn, out_spec, exec_cost = _compiled_cache[cache_key]
 
         # Kernels with a fused batched path (e.g. the Pallas packed
         # LogisticRegression fit, models/logistic.py) take over the whole
@@ -1170,28 +1209,31 @@ def _run_trials_impl(
                         for k, v in sorted(extra_args.items())
                     ),
                 )
-            fresh_compile = cache_key not in _compiled_cache
-            _cache_count(not fresh_compile)
-            if fresh_compile:
-                raw = batched_fn
-                if stage_mode != "f32":
-                    # widen the compressed staged matrix before the fused
-                    # kernel sees it (it expects the f32 design matrix)
-                    raw = _decode_wrap(batched_fn)
-                example = _example_args(X, y_np, plan.train_w, plan.eval_w,
-                                        hyper_names, chunk)
-                if extra_args:
-                    example[4].update(
-                        {k: _sds(v) for k, v in extra_args.items()}
+            with child_span("executor.compile", cache="hit") as csp:
+                fresh_compile = cache_key not in _compiled_cache
+                _cache_count(not fresh_compile)
+                if fresh_compile:
+                    raw = batched_fn
+                    if stage_mode != "f32":
+                        # widen the compressed staged matrix before the fused
+                        # kernel sees it (it expects the f32 design matrix)
+                        raw = _decode_wrap(batched_fn)
+                    example = _example_args(X, y_np, plan.train_w, plan.eval_w,
+                                            hyper_names, chunk)
+                    if extra_args:
+                        example[4].update(
+                            {k: _sds(v) for k, v in extra_args.items()}
+                        )
+                    cost = _capture_cost(raw, example)
+                    spec = None
+                    if _packed_enabled():
+                        spec = _pack_spec_of(raw, example)
+                        raw = _pack_wrap(raw)
+                    compiled, csp.attrs["cache"] = aot_jit(
+                        raw, cache_key, example
                     )
-                cost = _capture_cost(raw, example)
-                spec = None
-                if _packed_enabled():
-                    spec = _pack_spec_of(raw, example)
-                    raw = _pack_wrap(raw)
-                compiled, _ = aot_jit(raw, cache_key, example)
-                _compiled_cache[cache_key] = (compiled, spec, cost)
-            fn, out_spec, exec_cost = _compiled_cache[cache_key]
+                    _compiled_cache[cache_key] = (compiled, spec, cost)
+                fn, out_spec, exec_cost = _compiled_cache[cache_key]
         elif not host_exec:
             y_d, TW_d, EW_d = _dev_args()
             X_d = X
@@ -1261,68 +1303,70 @@ def _run_trials_impl(
                 jax.device_put, device=NamedSharding(mesh, P(trial_axis))
             )
         for start in range(0, len(idxs), chunk):
-            batch_idx = idxs[start : start + chunk]
-            T = len(batch_idx)
-            if hyper_names:
-                hyper_batch = {
-                    k: np.full((chunk,), hypers[batch_idx[-1]][k], np.float32)
-                    for k in hyper_names
-                }
-                for j, gi in enumerate(batch_idx):
-                    for k in hyper_names:
-                        hyper_batch[k][j] = hypers[gi][k]
-            else:
-                hyper_batch = {"_pad": np.zeros((chunk,), np.float32)}
-            hyper_arg = {k: to_dev(v) for k, v in hyper_batch.items()}
-            if extra_args:
-                hyper_arg = {**hyper_arg, **extra_args}
+            with child_span("executor.dispatch", chunk=start // chunk,
+                            n_trials=min(chunk, len(idxs) - start)):
+                batch_idx = idxs[start : start + chunk]
+                T = len(batch_idx)
+                if hyper_names:
+                    hyper_batch = {
+                        k: np.full((chunk,), hypers[batch_idx[-1]][k], np.float32)
+                        for k in hyper_names
+                    }
+                    for j, gi in enumerate(batch_idx):
+                        for k in hyper_names:
+                            hyper_batch[k][j] = hypers[gi][k]
+                else:
+                    hyper_batch = {"_pad": np.zeros((chunk,), np.float32)}
+                hyper_arg = {k: to_dev(v) for k, v in hyper_batch.items()}
+                if extra_args:
+                    hyper_arg = {**hyper_arg, **extra_args}
 
-            t0 = time.perf_counter()
-            if t_first_dispatch is None:
-                t_first_dispatch = t0
-            if split_groups is not None:
-                group_outs = []
-                for gi_, (twg, ewg, size) in enumerate(split_groups):
-                    out_g = fn(X_d, y_d, twg, ewg, hyper_arg)
-                    dispatches += 1
-                    _acc_cost(exec_cost)
-                    if fresh_compile and start == 0 and gi_ == 0:
-                        # attribute the XLA compile to the FIRST group only;
-                        # later groups reuse the executable and their device
-                        # time is steady run time, not compile
-                        out_g = jax.block_until_ready(out_g)
-                        compile_time += time.perf_counter() - t0
-                        observe("tpuml_executor_compile_seconds",
-                                time.perf_counter() - t0)
-                    if out_spec is not None:
-                        out_g = _Packed(out_g, out_spec)
-                    group_outs.append((out_g, size))
-                pending.append((group_outs, batch_idx))
-                continue
-            out = fn(X_d, y_d, TW_d, EW_d, hyper_arg)
-            if fresh_compile and start == 0:
-                # block only on a fresh executable's first dispatch so its
-                # XLA compile is attributed; steady-state dispatches queue
-                out = jax.block_until_ready(out)
-                compile_time += time.perf_counter() - t0
-                observe("tpuml_executor_compile_seconds",
-                        time.perf_counter() - t0)
-            if out_spec is not None:
-                out = _Packed(out, out_spec)
-            if mesh is not None and n_dev > 1:
-                # collective argmax over the trial-sharded score vector: XLA
-                # inserts the ICI all-gather/reduce; only two replicated
-                # scalars come back to host per chunk
-                bi, bs = _chunk_best(
-                    mesh, trial_axis, chunk, int(plan.n_splits), plan.n_folds
-                )(out["score"], jnp.int32(T))
-                pending_best.append((bi, bs, batch_idx))
-                n_result_devices = max(
-                    n_result_devices, len(out["score"].sharding.device_set)
-                )
-            pending.append((out, batch_idx))
-            dispatches += 1
-            _acc_cost(exec_cost)
+                t0 = time.perf_counter()
+                if t_first_dispatch is None:
+                    t_first_dispatch = t0
+                if split_groups is not None:
+                    group_outs = []
+                    for gi_, (twg, ewg, size) in enumerate(split_groups):
+                        out_g = fn(X_d, y_d, twg, ewg, hyper_arg)
+                        dispatches += 1
+                        _acc_cost(exec_cost)
+                        if fresh_compile and start == 0 and gi_ == 0:
+                            # attribute the XLA compile to the FIRST group only;
+                            # later groups reuse the executable and their device
+                            # time is steady run time, not compile
+                            out_g = jax.block_until_ready(out_g)
+                            compile_time += time.perf_counter() - t0
+                            observe("tpuml_executor_compile_seconds",
+                                    time.perf_counter() - t0)
+                        if out_spec is not None:
+                            out_g = _Packed(out_g, out_spec)
+                        group_outs.append((out_g, size))
+                    pending.append((group_outs, batch_idx))
+                    continue
+                out = fn(X_d, y_d, TW_d, EW_d, hyper_arg)
+                if fresh_compile and start == 0:
+                    # block only on a fresh executable's first dispatch so its
+                    # XLA compile is attributed; steady-state dispatches queue
+                    out = jax.block_until_ready(out)
+                    compile_time += time.perf_counter() - t0
+                    observe("tpuml_executor_compile_seconds",
+                            time.perf_counter() - t0)
+                if out_spec is not None:
+                    out = _Packed(out, out_spec)
+                if mesh is not None and n_dev > 1:
+                    # collective argmax over the trial-sharded score vector: XLA
+                    # inserts the ICI all-gather/reduce; only two replicated
+                    # scalars come back to host per chunk
+                    bi, bs = _chunk_best(
+                        mesh, trial_axis, chunk, int(plan.n_splits), plan.n_folds
+                    )(out["score"], jnp.int32(T))
+                    pending_best.append((bi, bs, batch_idx))
+                    n_result_devices = max(
+                        n_result_devices, len(out["score"].sharding.device_set)
+                    )
+                pending.append((out, batch_idx))
+                dispatches += 1
+                _acc_cost(exec_cost)
 
     _drain()
 
@@ -1542,10 +1586,21 @@ def _mesh_signature(mesh):
     )
 
 
-def _get_compiled(kernel, static_key, static, mesh, trial_axis, data, plan, chunk,
-                  hyper_names, X_proto=None, y=None, TW=None, EW=None,
-                  n_splits_override=None, stage_mode="f32"):
-    """Returns (fn, pack_spec_or_None, cost_or_None, fresh). Single-device
+def _get_compiled(*args, **kwargs):
+    """:func:`_lookup_compiled` under an ``executor.compile`` span whose
+    ``cache`` attribute says where the executable came from (``hit``: this
+    process's cache; ``aot``: a disk blob; ``traced``: built here). Returns
+    (fn, pack_spec_or_None, cost_or_None, fresh)."""
+    with child_span("executor.compile") as sp:
+        fn, spec, cost, source = _lookup_compiled(*args, **kwargs)
+        sp.attrs["cache"] = source
+    return fn, spec, cost, source != "hit"
+
+
+def _lookup_compiled(kernel, static_key, static, mesh, trial_axis, data, plan, chunk,
+                     hyper_names, X_proto=None, y=None, TW=None, EW=None,
+                     n_splits_override=None, stage_mode="f32"):
+    """Returns (fn, pack_spec_or_None, cost_or_None, source). Single-device
     executables take the packed-output form (one uint8 result buffer, see
     _pack_wrap) and carry their XLA cost analysis (captured once, at
     construction); mesh executables keep the per-leaf dict — their score
@@ -1588,7 +1643,7 @@ def _get_compiled(kernel, static_key, static, mesh, trial_axis, data, plan, chun
     if cache_key in _compiled_cache:
         _cache_count(True)
         fn, spec, cost = _compiled_cache[cache_key]
-        return fn, spec, cost, False
+        return fn, spec, cost, "hit"
     _cache_count(False)
 
     batched = _make_batched(kernel, static, has_hyper)
@@ -1627,6 +1682,7 @@ def _get_compiled(kernel, static_key, static, mesh, trial_axis, data, plan, chun
             )
         spec = None
         cost = None
+        source = "traced"
     else:
         X_ex = X_proto if X_proto is not None else jax.ShapeDtypeStruct(
             data.X.shape, jnp.float32
@@ -1641,9 +1697,9 @@ def _get_compiled(kernel, static_key, static, mesh, trial_axis, data, plan, chun
         if _packed_enabled():
             spec = _pack_spec_of(batched, example)
             batched = _pack_wrap(batched)
-        fn, _ = aot_jit(batched, disk_key, example)
+        fn, source = aot_jit(batched, disk_key, example)
     _compiled_cache[cache_key] = (fn, spec, cost)
-    return fn, spec, cost, True
+    return fn, spec, cost, source
 
 
 def _run_chunked(
@@ -1684,21 +1740,24 @@ def _run_chunked(
         return hyper if hyper_names else {}
 
     def init_b(X, y, TW, EW, hyper):
-        return jax.vmap(
-            lambda tw: kernel.chunk_init(X, y, tw, _h(hyper), static)
-        )(TW)
+        with jax.named_scope("tpuml.fit"):
+            return jax.vmap(
+                lambda tw: kernel.chunk_init(X, y, tw, _h(hyper), static)
+            )(TW)
 
     def step_b(X, y, TW, EW, hyper, ci, state):
-        return jax.vmap(
-            lambda tw, st: kernel.chunk_step(
-                X, y, tw, _h(hyper), static, ci, st, chunk_plan
-            )
-        )(TW, state)
+        with jax.named_scope("tpuml.fit"):
+            return jax.vmap(
+                lambda tw, st: kernel.chunk_step(
+                    X, y, tw, _h(hyper), static, ci, st, chunk_plan
+                )
+            )(TW, state)
 
     def eval_b(X, y, TW, EW, hyper, state):
-        return jax.vmap(
-            lambda ew, st: kernel.chunk_eval(X, y, ew, _h(hyper), static, st)
-        )(EW, state)
+        with jax.named_scope("tpuml.eval"):
+            return jax.vmap(
+                lambda ew, st: kernel.chunk_eval(X, y, ew, _h(hyper), static, st)
+            )(EW, state)
 
     vinit = jax.vmap(init_b, in_axes=(None, None, None, None, 0))
     vstep = jax.vmap(step_b, in_axes=(None, None, None, None, 0, None, 0))
@@ -1756,74 +1815,76 @@ def _run_chunked(
     result_bytes = 0
     device_best = None
     n_result_devices = 1
-    fresh = cache_tag not in _compiled_cache
-    _cache_count(not fresh)
-    if fresh:
-        # compile_time counts executable construction (trace or AOT
-        # deserialize) only — the first batch's wall time is real chunked
-        # compute and is NOT compile (an earlier version attributed it,
-        # inflating the metric even on full AOT-cache hits). XLA compiles of
-        # freshly traced executables still land in the first batch's
-        # run_time; the persistent compile cache keeps that small.
-        t_build = time.perf_counter()
-        hyper_ex = {
-            k: jax.ShapeDtypeStruct((chunk,), jnp.float32)
-            for k in (hyper_names or ["_pad"])
-        }
-        if mesh is not None:
-            # sharded chunked protocol: trial axis (hypers, state, outputs)
-            # split across the mesh, dataset/fold masks replicated. Mesh
-            # executables are process-local — no AOT export.
-            repl = NamedSharding(mesh, P())
-            tsh = NamedSharding(mesh, P(trial_axis))
-            X_sh = jax.tree_util.tree_map(lambda _: repl, X)
-            h_sh = {k: tsh for k in hyper_ex}
-            state_ex = jax.eval_shape(vinit, X, y, TW_ex, EW_ex, hyper_ex)
-            st_sh = jax.tree_util.tree_map(lambda _: tsh, state_ex)
-            out_ex = jax.eval_shape(veval, X, y, TW_ex, EW_ex, hyper_ex, state_ex)
-            fi = jax.jit(
-                vinit,
-                in_shardings=(X_sh, repl, repl, repl, h_sh),
-                out_shardings=st_sh,
-            )
-            fs = jax.jit(
-                vstep,
-                in_shardings=(X_sh, repl, repl, repl, h_sh, repl, st_sh),
-                out_shardings=st_sh,
-            )
-            fe = jax.jit(
-                veval,
-                in_shardings=(X_sh, repl, repl, repl, h_sh, st_sh),
-                out_shardings=jax.tree_util.tree_map(lambda _: tsh, out_ex),
-            )
-            fe_spec = None
-        else:
-            Xe = jax.tree_util.tree_map(_sds, X)
-            args_ie = (Xe, _sds(y), _sds(TW_ex), _sds(EW_ex), hyper_ex)
-            fi, _ = aot_jit(vinit, ("chunk_init",) + base_key_parts, args_ie)
-            state_ex = jax.eval_shape(vinit, X, y, TW_ex, EW_ex, hyper_ex)
-            args_e = args_ie + (jax.tree_util.tree_map(_sds, state_ex),)
-            fs, _ = aot_jit(
-                vstep,
-                ("chunk_step",) + base_key_parts,
-                args_ie + (jax.ShapeDtypeStruct((), jnp.int32),)
-                + (jax.tree_util.tree_map(_sds, state_ex),),
-            )
-            # only eval's output crosses to host: pack it (init/step state
-            # stays device-resident across the pipelined dispatches)
-            ev = veval
-            fe_spec = None
-            if _packed_enabled():
-                fe_spec = _pack_spec_of(veval, args_e)
-                ev = _pack_wrap(veval)
-            fe, _ = aot_jit(
-                ev,
-                ("chunk_eval",) + base_key_parts + (_packed_enabled(),),
-                args_e,
-            )
-        _compiled_cache[cache_tag] = (fi, fs, fe, fe_spec)
-        compile_time += time.perf_counter() - t_build
-        observe("tpuml_executor_compile_seconds", compile_time)
+    with child_span("executor.compile", cache="hit") as csp:
+        fresh = cache_tag not in _compiled_cache
+        _cache_count(not fresh)
+        if fresh:
+            # compile_time counts executable construction (trace or AOT
+            # deserialize) only — the first batch's wall time is real chunked
+            # compute and is NOT compile (an earlier version attributed it,
+            # inflating the metric even on full AOT-cache hits). XLA compiles of
+            # freshly traced executables still land in the first batch's
+            # run_time; the persistent compile cache keeps that small.
+            t_build = time.perf_counter()
+            hyper_ex = {
+                k: jax.ShapeDtypeStruct((chunk,), jnp.float32)
+                for k in (hyper_names or ["_pad"])
+            }
+            if mesh is not None:
+                # sharded chunked protocol: trial axis (hypers, state, outputs)
+                # split across the mesh, dataset/fold masks replicated. Mesh
+                # executables are process-local — no AOT export.
+                repl = NamedSharding(mesh, P())
+                tsh = NamedSharding(mesh, P(trial_axis))
+                X_sh = jax.tree_util.tree_map(lambda _: repl, X)
+                h_sh = {k: tsh for k in hyper_ex}
+                state_ex = jax.eval_shape(vinit, X, y, TW_ex, EW_ex, hyper_ex)
+                st_sh = jax.tree_util.tree_map(lambda _: tsh, state_ex)
+                out_ex = jax.eval_shape(veval, X, y, TW_ex, EW_ex, hyper_ex, state_ex)
+                fi = jax.jit(
+                    vinit,
+                    in_shardings=(X_sh, repl, repl, repl, h_sh),
+                    out_shardings=st_sh,
+                )
+                fs = jax.jit(
+                    vstep,
+                    in_shardings=(X_sh, repl, repl, repl, h_sh, repl, st_sh),
+                    out_shardings=st_sh,
+                )
+                fe = jax.jit(
+                    veval,
+                    in_shardings=(X_sh, repl, repl, repl, h_sh, st_sh),
+                    out_shardings=jax.tree_util.tree_map(lambda _: tsh, out_ex),
+                )
+                fe_spec = None
+            else:
+                Xe = jax.tree_util.tree_map(_sds, X)
+                args_ie = (Xe, _sds(y), _sds(TW_ex), _sds(EW_ex), hyper_ex)
+                fi, _ = aot_jit(vinit, ("chunk_init",) + base_key_parts, args_ie)
+                state_ex = jax.eval_shape(vinit, X, y, TW_ex, EW_ex, hyper_ex)
+                args_e = args_ie + (jax.tree_util.tree_map(_sds, state_ex),)
+                fs, _ = aot_jit(
+                    vstep,
+                    ("chunk_step",) + base_key_parts,
+                    args_ie + (jax.ShapeDtypeStruct((), jnp.int32),)
+                    + (jax.tree_util.tree_map(_sds, state_ex),),
+                )
+                # only eval's output crosses to host: pack it (init/step state
+                # stays device-resident across the pipelined dispatches)
+                ev = veval
+                fe_spec = None
+                if _packed_enabled():
+                    fe_spec = _pack_spec_of(veval, args_e)
+                    ev = _pack_wrap(veval)
+                fe, src = aot_jit(
+                    ev,
+                    ("chunk_eval",) + base_key_parts + (_packed_enabled(),),
+                    args_e,
+                )
+            _compiled_cache[cache_tag] = (fi, fs, fe, fe_spec)
+            csp.attrs["cache"] = "traced" if mesh is not None else src
+            compile_time += time.perf_counter() - t_build
+            observe("tpuml_executor_compile_seconds", compile_time)
     fi, fs, fe, fe_spec = _compiled_cache[cache_tag]
 
     if warm_only:
@@ -1847,29 +1908,31 @@ def _run_chunked(
             hyper_arg = {"_pad": jnp.zeros((chunk,), jnp.float32)}
 
         t0 = time.perf_counter()
-        group_outs = []
-        group_curves = []
-        for twg, ewg, size in split_groups:
-            state = fi(X, y, twg, ewg, hyper_arg)
-            mids = []
-            for ci in range(n_chunks):
-                state = fs(X, y, twg, ewg, hyper_arg, jnp.int32(ci), state)
-                if (
-                    curve_stride
-                    and (ci + 1) % curve_stride == 0
-                    and ci < n_chunks - 1
-                ):
-                    # trial telemetry plane: score-vs-chunk curve via
-                    # strided extra eval dispatches on the existing fe
-                    # executable (the accumulator protocol makes every
-                    # prefix a valid model) — the tree kernels themselves
-                    # are untouched. eval is O(n*k) against the chunk's
-                    # O(n*k*trees) build, so the sampled extra evals stay
-                    # inside the curve overhead gate.
-                    mids.append(fe(X, y, twg, ewg, hyper_arg, state))
-            group_outs.append((fe(X, y, twg, ewg, hyper_arg, state), size))
-            group_curves.append(mids)
-            dispatches += len(mids)
+        with child_span("executor.dispatch", chunk=start // chunk,
+                        n_trials=len(batch_idx)):
+            group_outs = []
+            group_curves = []
+            for twg, ewg, size in split_groups:
+                state = fi(X, y, twg, ewg, hyper_arg)
+                mids = []
+                for ci in range(n_chunks):
+                    state = fs(X, y, twg, ewg, hyper_arg, jnp.int32(ci), state)
+                    if (
+                        curve_stride
+                        and (ci + 1) % curve_stride == 0
+                        and ci < n_chunks - 1
+                    ):
+                        # trial telemetry plane: score-vs-chunk curve via
+                        # strided extra eval dispatches on the existing fe
+                        # executable (the accumulator protocol makes every
+                        # prefix a valid model) — the tree kernels themselves
+                        # are untouched. eval is O(n*k) against the chunk's
+                        # O(n*k*trees) build, so the sampled extra evals stay
+                        # inside the curve overhead gate.
+                        mids.append(fe(X, y, twg, ewg, hyper_arg, state))
+                group_outs.append((fe(X, y, twg, ewg, hyper_arg, state), size))
+                group_curves.append(mids)
+                dispatches += len(mids)
         if mesh is not None:
             n_result_devices = max(
                 n_result_devices,
@@ -2038,11 +2101,13 @@ def _run_streamed(
         t0 = time.perf_counter()
         wait0 = streamer.stats["wait_s"]
         blocks0 = streamer.stats["blocks"]
-        score = np.asarray(
-            kernel.stream_scores(
-                streamer, y_d, TW_d, EW_d, hyper_batch, static, n
+        with child_span("executor.dispatch", chunk=start // chunk,
+                        n_trials=len(batch_idx), streamed=True):
+            score = np.asarray(
+                kernel.stream_scores(
+                    streamer, y_d, TW_d, EW_d, hyper_batch, static, n
+                )
             )
-        )
         wall = time.perf_counter() - t0
         wait = streamer.stats["wait_s"] - wait0
         _PHASE.stage += wait

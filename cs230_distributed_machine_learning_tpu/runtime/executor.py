@@ -14,7 +14,6 @@ engine's runtime predictor) are drop-in.
 from __future__ import annotations
 
 import contextlib
-import os
 import threading
 import time
 from typing import Any, Callable, Dict, List, Optional
@@ -22,13 +21,13 @@ from typing import Any, Callable, Dict, List, Optional
 from ..data.datasets import DatasetCache
 from ..models.registry import get_kernel
 from ..obs import (
+    child_span,
     counter_inc,
     gauge_set,
     obs_enabled,
     observe,
     process_token,
     record_batch_device_seconds,
-    record_phase,
     span,
 )
 from ..ops.folds import build_split_plan
@@ -162,8 +161,6 @@ class LocalExecutor:
         self.max_trials_per_batch = max_trials_per_batch or cfg.execution.max_trials_per_batch
         self.trial_axis = cfg.execution.trial_axis
         self.fault_injector = fault_injector
-        self.enable_profiler = cfg.execution.enable_profiler
-        self.profiler_dir = cfg.execution.profiler_dir
         #: live run_subtasks calls — the prewarm worker's yield signal
         self._inflight = 0
         self._inflight_lock = threading.Lock()
@@ -379,19 +376,20 @@ class LocalExecutor:
     ) -> None:
         """Execute one (dataset, model_type) group on the trial engine and
         emit per-subtask results/metrics. ``batch_sp`` is the enclosing
-        ``executor.batch`` span handle (or None): the engine's phase timers
-        — compile / stage-upload / dispatch / packed fetch, the numbers
-        PR 1 measured ad-hoc — are attached to it as synthesized child
-        spans laid out sequentially from batch start."""
+        ``executor.batch`` span handle (or None); every step below — data
+        load, split plan, the engine's stage / compile / dispatch / fetch,
+        result emission — records a real child span of it
+        (docs/OBSERVABILITY.md "Reading GET /trace")."""
         if self.fault_injector is not None:
             self.fault_injector.before_batch(self.executor_id, model_type)
         kernel = get_kernel(model_type)
-        data = self.cache.get(dataset_id, kernel.task)
+        with child_span("executor.load_data", dataset_id=dataset_id):
+            data = self.cache.get(dataset_id, kernel.task)
         tp = subtasks[idxs[0]].get("train_params", {}) or {}
         scoring = _normalize_scoring(
             tp.get("scoring"), kernel.task, data.n_classes, kernel
         )
-        plan = build_split_plan(
+        plan = _split_plan(
             data.y if kernel.task == "regression" else _np(data.y),
             task=kernel.task,
             n_folds=_coerce_cv(tp.get("cv")),
@@ -399,8 +397,7 @@ class LocalExecutor:
             random_state=tp.get("random_state", 42),
         )
         started_at = time.time()
-        profiler_cm = self._profiler_cm(model_type)
-        with profiler_cm, ResourceSampler() as sampler:
+        with ResourceSampler() as sampler:
             if callable(scoring) and not isinstance(scoring, str):
                 # host-side fallback: device fits per fold, sklearn
                 # export, user scorer on host (trial_map docstring)
@@ -445,8 +442,8 @@ class LocalExecutor:
             )
             return
         observe("tpuml_executor_dispatch_seconds", run.run_time_s)
-        # device-time attribution (obs/devprof.py): the same phase totals
-        # the synthesized trace children carry, accumulated into the
+        # device-time attribution (obs/devprof.py): the engine's phase
+        # totals, accumulated into the
         # tpuml_executor_device_seconds_total{phase=} counter
         record_batch_device_seconds(
             run.compile_time_s, run.stage_time_s,
@@ -456,7 +453,7 @@ class LocalExecutor:
         batch_cost = self._record_batch_cost(
             run, model_type, dataset_id, len(idxs), resources
         )
-        self._record_batch_phases(batch_sp, run, started_at, batch_cost)
+        self._record_batch_summary(batch_sp, run, batch_cost)
         per_trial_time = run.run_time_s / max(len(idxs), 1)
         # winner-by-ICI-collective: run_trials' on-device argmax over
         # the mesh-sharded scores (multi-device only). The marked
@@ -465,49 +462,50 @@ class LocalExecutor:
         device_best_pos = (
             run.device_best[0] if run.device_best is not None else None
         )
-        for j, gi in enumerate(idxs):
-            st = subtasks[gi]
-            result = {
-                "subtask_id": st["subtask_id"],
-                "job_id": st.get("job_id"),
-                "model_type": model_type,
-                "parameters": st["parameters"],
-                "search_params": st.get("search_params"),
-                "training_time": per_trial_time,
-                "status": "completed",
-                # attempt-id stamp for result-ingest dedup under retries
-                # and speculative duplicates (docs/ROBUSTNESS.md)
-                "attempt": int(st.get("attempt") or 0),
-                **run.trial_metrics[j],
-            }
-            if st.get("speculative"):
-                result["speculative"] = True
-            if st.get("asha"):
-                # rung stamp echoed so the coordinator's rung controller
-                # can attribute the score without a spec lookup race
-                result["asha"] = dict(st["asha"])
-            if device_best_pos == j:
-                result["device_argmax"] = True
-            if j == 0 and batch_cost is not None:
-                # the batch's cost record rides exactly ONE result (the
-                # primary) into the job store, where GET /cost/<job_id>
-                # aggregates it — stamping every result would overcount
-                result["batch_cost"] = batch_cost
-            results[gi] = result
-            counter_inc("tpuml_subtasks_completed_total")
-            if on_result:
-                on_result(st["subtask_id"], "completed", result)
-            if on_metrics:
-                on_metrics(
-                    self._metrics_message(
-                        st, received_at, started_at, finished_at,
-                        model_type, resources, run=run,
-                        batch_size=len(idxs), primary=(j == 0),
-                        batch_cost=batch_cost,
-                        score=run.trial_metrics[j].get("mean_cv_score"),
-                        curve=run.trial_metrics[j].get("curve"),
+        with child_span("executor.emit", n_subtasks=len(idxs)):
+            for j, gi in enumerate(idxs):
+                st = subtasks[gi]
+                result = {
+                    "subtask_id": st["subtask_id"],
+                    "job_id": st.get("job_id"),
+                    "model_type": model_type,
+                    "parameters": st["parameters"],
+                    "search_params": st.get("search_params"),
+                    "training_time": per_trial_time,
+                    "status": "completed",
+                    # attempt-id stamp for result-ingest dedup under retries
+                    # and speculative duplicates (docs/ROBUSTNESS.md)
+                    "attempt": int(st.get("attempt") or 0),
+                    **run.trial_metrics[j],
+                }
+                if st.get("speculative"):
+                    result["speculative"] = True
+                if st.get("asha"):
+                    # rung stamp echoed so the coordinator's rung controller
+                    # can attribute the score without a spec lookup race
+                    result["asha"] = dict(st["asha"])
+                if device_best_pos == j:
+                    result["device_argmax"] = True
+                if j == 0 and batch_cost is not None:
+                    # the batch's cost record rides exactly ONE result (the
+                    # primary) into the job store, where GET /cost/<job_id>
+                    # aggregates it — stamping every result would overcount
+                    result["batch_cost"] = batch_cost
+                results[gi] = result
+                counter_inc("tpuml_subtasks_completed_total")
+                if on_result:
+                    on_result(st["subtask_id"], "completed", result)
+                if on_metrics:
+                    on_metrics(
+                        self._metrics_message(
+                            st, received_at, started_at, finished_at,
+                            model_type, resources, run=run,
+                            batch_size=len(idxs), primary=(j == 0),
+                            batch_cost=batch_cost,
+                            score=run.trial_metrics[j].get("mean_cv_score"),
+                            curve=run.trial_metrics[j].get("curve"),
+                        )
                     )
-                )
 
     def _record_batch_cost(
         self, run, model_type: str, dataset_id: str, batch_size: int,
@@ -573,15 +571,13 @@ class LocalExecutor:
         }
 
     @staticmethod
-    def _record_batch_phases(
-        batch_sp, run, started_at: float,
-        batch_cost: Optional[Dict[str, Any]] = None,
+    def _record_batch_summary(
+        batch_sp, run, batch_cost: Optional[Dict[str, Any]] = None,
     ) -> None:
-        """Attach the trial engine's measured phase totals to the batch
-        span as synthesized children. Phases are laid out sequentially from
-        batch start (real execution overlaps stage/dispatch/fetch — the
-        durations are exact, the offsets indicative; attrs carry
-        ``synthesized: true``)."""
+        """Summary attributes of the ``executor.batch`` span: the engine's
+        transfer and timer totals, and the batch's cost record so trace
+        timelines price themselves. (The phases themselves are the span's
+        real children, recorded where they happen.)"""
         if batch_sp is None or getattr(batch_sp, "span_id", None) is None:
             return
         batch_sp.attrs.update(
@@ -592,7 +588,6 @@ class LocalExecutor:
             run_time_s=round(run.run_time_s, 6),
         )
         if batch_cost is not None:
-            # cost attrs join the span so trace timelines price themselves
             batch_sp.attrs.update(
                 {
                     k: batch_cost[k]
@@ -601,16 +596,6 @@ class LocalExecutor:
                     if batch_cost.get(k) is not None
                 }
             )
-        t = record_phase(
-            batch_sp, "executor.compile", run.compile_time_s, start=started_at
-        )
-        t = record_phase(batch_sp, "executor.stage", run.stage_time_s, start=t)
-        dispatch_s = max(run.run_time_s - run.fetch_time_s, 0.0)
-        t = record_phase(batch_sp, "executor.dispatch", dispatch_s, start=t,
-                         n_dispatches=run.n_dispatches)
-        record_phase(batch_sp, "executor.fetch", run.fetch_time_s, start=t,
-                     n_host_fetches=run.n_host_fetches,
-                     result_bytes=run.result_bytes)
 
     def prewarm_hint(
         self, hint: Dict[str, Any], mode: str = "construct"
@@ -644,7 +629,7 @@ class LocalExecutor:
             scoring if isinstance(scoring, str) else None,
             kernel.task, data.n_classes, kernel,
         )
-        plan = build_split_plan(
+        plan = _split_plan(
             data.y if kernel.task == "regression" else _np(data.y),
             task=kernel.task,
             n_folds=_coerce_cv(tp.get("cv")),
@@ -685,7 +670,7 @@ class LocalExecutor:
         kernel = get_kernel(subtask["model_type"])
         data = self.cache.get(subtask["dataset_id"], kernel.task)
         tp = subtask.get("train_params", {}) or {}
-        plan = build_split_plan(
+        plan = _split_plan(
             _np(data.y),
             task=kernel.task,
             n_folds=0,
@@ -782,21 +767,6 @@ class LocalExecutor:
             msg["curve"] = curve
             msg["attempt"] = int(st.get("attempt") or 0)
         return msg
-
-
-    def _profiler_cm(self, tag: str):
-        """jax.profiler trace around a trial batch (replaces the reference's
-        psutil sampler as the deep-inspection path, SURVEY.md §5.1)."""
-        import contextlib
-
-        if not self.enable_profiler:
-            return contextlib.nullcontext()
-        import os
-
-        import jax
-
-        trace_dir = os.path.join(self.profiler_dir, f"{self.executor_id}-{tag}")
-        return jax.profiler.trace(trace_dir)
 
 
 class DeviceLostError(RuntimeError):
@@ -933,6 +903,15 @@ def _np(y):
     import numpy as np
 
     return np.asarray(y)
+
+
+def _split_plan(y, **kw):
+    """``build_split_plan`` under an ``executor.split_plan`` span: the
+    splitters walk every row on the host, once per batch."""
+    with child_span("executor.split_plan", n_rows=len(y)) as sp:
+        plan = build_split_plan(y, **kw)
+        sp.attrs.update(n_splits=plan.n_splits, signature=str(plan.signature))
+    return plan
 
 
 def _normalize_scoring(scoring, task: str, n_classes: int = 0, kernel=None):

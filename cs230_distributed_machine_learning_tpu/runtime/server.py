@@ -136,7 +136,7 @@ function renderTrace(el, data){
         `white-space:nowrap" title="${esc(JSON.stringify(n.attrs))}">${esc(n.name)}</span>` +
         `<span style="flex:1;position:relative;height:10px;background:#f4f4f4">` +
         `<span style="position:absolute;left:${off}%;width:${w}%;height:10px;` +
-        `background:${n.attrs && n.attrs.synthesized ? "#9bb8d3" : "#4a7fb5"}"></span></span>` +
+        `background:#4a7fb5"></span></span>` +
         `<span style="width:80px;text-align:right">${((n.end - n.start) * 1000).toFixed(1)} ms</span></div>`;
     }).join("");
 }

@@ -128,9 +128,6 @@ class ExecutionConfig:
     # cv defaults matching sklearn cross_val_score(cv=5)
     default_cv_folds: int = 5
     default_test_size: float = 0.2
-    # donate buffers / profiler toggles
-    enable_profiler: bool = False
-    profiler_dir: str = "/tmp/tpuml_traces"
 
 
 @dataclasses.dataclass

@@ -227,34 +227,71 @@ def test_speculative_win_charges_only_winner():
     assert r["untraced_s"] > 2.0
 
 
-def test_overrunning_phase_estimates_clamped_to_batch_envelope():
-    """The executor lays synthesized phases sequentially with exact
-    durations but indicative offsets — when real phases overlap, the
-    last phase overruns the batch end. The engine must clamp them to the
-    measured envelope so the overrun never eats into aggregate."""
+def _direct_scenario():
+    """A local job: the client's span brackets everything, nothing is
+    placed, and the result events name no worker."""
     spans = [
-        _span("job.submit", 0.0, 0.2),
-        _span("job.execute", 0.2, 2.0),
-        _span("executor.batch", 0.4, 2.0, sid="bb000001",
-              attrs={"worker": "w1"}),
-        # compile measured 1.6 s + dispatch measured 1.6 s laid
-        # sequentially -> dispatch "ends" at 3.6, past batch end 2.0 and
-        # deep into aggregate [2.0, 4.0]
-        _span("executor.compile", 0.4, 2.0, parent="bb000001"),
-        _span("executor.dispatch", 2.0, 3.6, parent="bb000001"),
-        _span("job.aggregate", 2.0, 4.0),
+        _span("client.train", 0.0, 10.0, sid="c1000000"),
+        _span("client.submit", 0.0, 0.3, parent="c1000000"),
+        _span("job.submit", 0.05, 0.25),
+        _span("job.expand", 0.1, 0.2),
+        _span("client.wait", 0.3, 10.0, parent="c1000000"),
+        _span("job.execute", 0.4, 9.5),
+        _span("executor.batch", 0.5, 9.4, sid="bd000001",
+              attrs={"worker": "exec-0"}),
+        _span("executor.split_plan", 0.5, 2.5, parent="bd000001"),
+        _span("executor.stage", 2.5, 2.6, sid="sg000001", parent="bd000001"),
+        # the upload inside a mesh stage: a grandchild, covered by its parent
+        _span("executor.stage", 2.52, 2.58, parent="sg000001"),
+        _span("executor.dispatch", 2.7, 2.8, parent="bd000001"),
+        _span("executor.fetch", 2.8, 9.0, parent="bd000001"),
+        _span("executor.emit", 9.0, 9.4, parent="bd000001"),
+        _span("job.aggregate", 9.5, 9.9),
     ]
     timelines = {"st1": [
-        _ev("placement", 0.4, worker="w1"),
-        _ev("result", 2.0, worker="w1", data={"status": "completed"}),
+        _ev("result", 9.2, data={"status": "completed"}),
     ]}
+    return spans, timelines
+
+
+@pytest.mark.parametrize("with_timelines", [True, False])
+def test_direct_job_admits_its_batches_by_trace(with_timelines):
+    """No placement, no winner to name: every ``executor.batch`` of the
+    job's trace is on its path, tiled by its real children; what no span
+    names stays ``untraced``, and the wall is the client's."""
+    spans, timelines = _direct_scenario()
+    r = critical_path("job-1", trace_id="aaaabbbbccccdddd", spans=spans,
+                      timelines=timelines if with_timelines else None)
+    _assert_tiles(r)
+    assert r["wall_s"] == pytest.approx(10.0)  # client.train, start to end
+    assert r["winning_worker"] is None
+    assert r["totals"]["executor.split_plan"] == pytest.approx(2.0)
+    assert r["totals"]["executor.stage"] == pytest.approx(0.1)
+    assert r["totals"]["executor.fetch"] == pytest.approx(6.2)
+    assert r["totals"]["executor.emit"] == pytest.approx(0.4)
+    assert r["totals"]["execute"] == pytest.approx(0.1, abs=1e-5)  # 2.6-2.7, in no child
+    assert r["totals"]["aggregate"] == pytest.approx(0.4)
+    # 0.25-0.5 (thread start), 9.4-9.5, 9.9-10.0 (wake-up and result read)
+    assert r["untraced_s"] == pytest.approx(0.05 + 0.25 + 0.1 + 0.1, abs=1e-5)
+    assert r["dominant"][0] == "executor.fetch"
+
+
+def test_scheduled_job_keeps_the_winner_only_rule():
+    """A placed job whose result has not named a worker yet admits no
+    batch: the direct rule is for jobs that were never placed."""
+    spans = [
+        _span("job.submit", 0.0, 0.2),
+        _span("job.execute", 0.2, 5.0),
+        _span("executor.batch", 0.6, 4.0, sid="bs000001",
+              attrs={"worker": "w0"}),
+        _span("executor.dispatch", 0.7, 3.9, parent="bs000001"),
+    ]
+    timelines = {"st1": [_ev("placement", 0.5, worker="w0")]}
     r = critical_path("job-1", trace_id="aaaabbbbccccdddd", spans=spans,
                       timelines=timelines)
     _assert_tiles(r)
-    # aggregate keeps its full 2 s — the phase overrun was clamped out
-    assert r["totals"]["aggregate"] == pytest.approx(2.0)
-    assert "executor.dispatch" not in r["totals"]  # zero width after clamp
-    assert r["totals"]["executor.compile"] == pytest.approx(1.6)
+    assert "execute" not in r["totals"]
+    assert "executor.dispatch" not in r["totals"]
 
 
 def test_compare_attributes_injected_slowdown():

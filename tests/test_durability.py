@@ -72,20 +72,6 @@ def test_fault_injection_fails_batch_then_recovers():
     assert status2["job_result"]["best_result"] is not None
 
 
-def test_profiler_traces_written(tmp_path):
-    cfg = get_config()
-    cfg.execution.enable_profiler = True
-    cfg.execution.profiler_dir = str(tmp_path / "traces")
-    try:
-        coord = Coordinator()
-        m = MLTaskManager(coordinator=coord)
-        m.train(LogisticRegression(max_iter=300), "iris", show_progress=False)
-        assert os.path.isdir(cfg.execution.profiler_dir)
-        assert any(os.scandir(cfg.execution.profiler_dir))
-    finally:
-        cfg.execution.enable_profiler = False
-
-
 def test_wait_job_is_event_driven():
     """wait_job blocks until finalize_job fires the event, with no polling,
     and returns immediately for already-finalized jobs."""

@@ -13,7 +13,6 @@ from cs230_distributed_machine_learning_tpu.obs import (
     counter_inc,
     current_trace_id,
     observe,
-    record_phase,
     span,
     use_tracer,
 )
@@ -257,19 +256,6 @@ def test_error_span_records_and_reraises():
     assert "RuntimeError" in s["attrs"]["error"]
 
 
-def test_record_phase_synthesizes_child(monkeypatch):
-    t = Tracer(journal=False)
-    with use_tracer(t):
-        with span("parent", trace_id="feed000000000000") as sp:
-            end = record_phase(sp, "phase.compile", 0.25, n_dispatches=3)
-            assert end == pytest.approx(sp.start + 0.25)
-    spans = {s["name"]: s for s in t.spans_for("feed000000000000")}
-    ph = spans["phase.compile"]
-    assert ph["parent_id"] == spans["parent"]["span_id"]
-    assert ph["attrs"]["synthesized"] is True
-    assert ph["end"] - ph["start"] == pytest.approx(0.25)
-
-
 # ---------------- disabled valve ----------------
 
 
@@ -282,7 +268,6 @@ def test_disabled_valve_is_a_noop(monkeypatch):
             sp.attrs["x"] = 1
             sp.start = 123.0
             assert sp.span_id is None
-            assert record_phase(sp, "phase", 1.0) is None
     assert t.traces() == []
 
     from cs230_distributed_machine_learning_tpu.obs import REGISTRY

@@ -553,9 +553,12 @@ def test_frontend_streams_large_bodies_zero_copy():
                     break
                 h.update(chunk)
                 remaining -= len(chunk)
-            received["sha1"] = h.hexdigest()
-            received["length"] = length
-            received["te"] = self.headers.get("Transfer-Encoding")
+            if not self.path.startswith("/trace_spans"):
+                # the front end ships its frontend.proxy span to the shard
+                # after the relay: that POST may land before the asserts
+                received["sha1"] = h.hexdigest()
+                received["length"] = length
+                received["te"] = self.headers.get("Transfer-Encoding")
             body = json.dumps({"status": "ok"}).encode()
             self.send_response(200)
             self.send_header("Content-Type", "application/json")

@@ -11,6 +11,7 @@ covers that on the chip.
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -184,12 +185,9 @@ def test_knn_topk(tpu_backend):
     )
 
 
-def test_mlp_epoch_kernel(tpu_backend):
-    """The fused MLP path at MNIST width (784-256-10), through the
-    kernel's own builder so lane packing and the VMEM limit are the
-    production ones."""
+def _mlp_batched(chunk=4):
     kernel = get_kernel("MLPClassifier")
-    n, d, c, chunk = 60_000, 784, 10, 4
+    n, d, c = 60_000, 784, 10
     _, hyper = kernel.canonicalize({"hidden_layer_sizes": (256,)})
     static = kernel.resolve_static(
         {**kernel.static_defaults, "hidden_layer_sizes": (256,),
@@ -198,7 +196,15 @@ def test_mlp_epoch_kernel(tpu_backend):
     static["_n_classes"] = c
     assert kernel.batched_applicable(static, n, d)
     fn = kernel.build_batched_fn(static, n, d, c, S, chunk)
-    _lower_and_compile(fn, *_trial_args(n, d, S, chunk, sorted(hyper)))
+    return fn, _trial_args(n, d, S, chunk, sorted(hyper))
+
+
+def test_mlp_epoch_kernel(tpu_backend):
+    """The fused MLP path at MNIST width (784-256-10), through the
+    kernel's own builder so lane packing and the VMEM limit are the
+    production ones."""
+    fn, args = _mlp_batched()
+    _lower_and_compile(fn, *args)
 
 
 @pytest.mark.parametrize("data_parallel", [1, 2])
@@ -268,3 +274,74 @@ def test_host_fast_path_traces_cpu_formulations(tpu_backend):
     assert np.isfinite(out.trial_metrics[0]["mean_cv_score"])
     kinds = {k[0] for k in trial_map._compiled_cache if isinstance(k, tuple)}
     assert "host" in kinds  # it did take the host fast path
+
+
+def _logreg_batched(chunk=128):
+    kernel, static = _logreg_static()
+    fn = kernel.build_batched_fn(static, N, D, C, S, chunk)
+    return fn, _trial_args(N, D, S, chunk, ("C", "max_iter", "tol"))
+
+
+@pytest.mark.parametrize("family,build,kernel_name", [
+    ("LogisticRegression", _logreg_batched, "packed_nesterov_step"),
+    ("MLPClassifier", _mlp_batched, "mlp_fused_epoch"),
+])
+def test_batched_functions_carry_scopes_and_kernel_names(
+    tpu_backend, family, build, kernel_name
+):
+    """What a profiler trace shows of a family's executable: every op under
+    ``tpuml.fit``, ``tpuml.eval`` or ``tpuml.pack`` (the op's label), the
+    Mosaic kernel under its own name (the op's name). Lowering only: names
+    are metadata, and the CPU lowering would drop the kernel's."""
+    from cs230_distributed_machine_learning_tpu.parallel import trial_map
+
+    fn, args = build()
+    text = (
+        jax.jit(trial_map._pack_wrap(fn)).trace(*args)
+        .lower(lowering_platforms=("tpu",)).as_text(debug_info=True)
+    )
+    names = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, flags=re.M))
+    scoped = [n for n in names.values() if n.startswith("jit(packed)/")]
+    for scope in ("tpuml.fit", "tpuml.eval", "tpuml.pack"):
+        assert any(f"/{scope}/" in n for n in scoped), (family, scope)
+    # nothing the executable computes is left outside the three scopes
+    assert not [n for n in scoped if "/tpuml." not in n]
+    (call,) = [ln for ln in text.splitlines() if "@tpu_custom_call" in ln]
+    assert f'kernel_name = "{kernel_name}"' in call
+    # compiled for the chip, the kernel's instruction has the kernel's name
+    # (a trace event's name) and the fit's scope in its op_name (its label)
+    compiled = _lower_and_compile(trial_map._pack_wrap(fn), *args)
+    if compiled is not None:
+        (instr,) = {m.group(0) for m in re.finditer(
+            rf'%{kernel_name}[\w.]* = [^\n]*custom_call_target="tpu_custom_call"[^\n]*',
+            compiled.as_text())}
+        assert "/tpuml.fit/" in re.search(r'op_name="([^"]*)"', instr).group(1)
+
+
+@pytest.mark.parametrize("name", [
+    "packed_softmax_grad", "masked_softmax_grad", "knn_topk", "level_histogram",
+])
+def test_every_other_pallas_call_has_its_name(tpu_backend, name):
+    from cs230_distributed_machine_learning_tpu.ops import (
+        pallas_hist, pallas_knn, pallas_logreg,
+    )
+
+    n_pad, dpp, Tw = 2048, 64, 128
+    lowered = {
+        "packed_softmax_grad": lambda: jax.jit(functools.partial(
+            pallas_logreg.packed_softmax_grad, c=C, S=S, Tw=Tw)).trace(
+            _sds((n_pad, dpp), jnp.bfloat16), _sds((1, dpp, C * S * Tw), jnp.bfloat16),
+            _sds((n_pad, 1), jnp.int32), _sds((n_pad, S), jnp.float32)),
+        "masked_softmax_grad": lambda: jax.jit(functools.partial(
+            pallas_logreg.masked_softmax_grad, c=C)).trace(
+            _sds((n_pad, 128), jnp.bfloat16), _sds((128, 128), jnp.bfloat16),
+            _sds((n_pad, 1), jnp.int32), _sds((n_pad, 1), jnp.float32)),
+        "knn_topk": lambda: jax.jit(functools.partial(pallas_knn.knn_topk, k=5)).trace(
+            _sds((1024, D), jnp.float32), _sds((4096, D), jnp.float32),
+            _sds((4096,), jnp.float32)),
+        "level_histogram": lambda: jax.jit(lambda a, b, c_: pallas_hist.level_histogram_pallas(
+            a, b, c_, 8, 17, integer_stats=True)).trace(
+            _sds((4096,), jnp.int32), _sds((4096, D), jnp.int32), _sds((4096, 7), jnp.float32)),
+    }[name]()
+    text = lowered.lower(lowering_platforms=("tpu",)).as_text()
+    assert f'kernel_name = "{name}"' in text

@@ -101,7 +101,7 @@ def test_trace_stitches_across_agent_round_trip(http_coordinator):
         ]
         assert shipped, "agent batch span missing"
 
-        # tree shape: the executor batch nests its synthesized phases
+        # tree shape: the executor batch nests its phases (real spans)
         def find(nodes, name):
             for n in nodes:
                 if n["name"] == name:
