@@ -336,6 +336,12 @@ def register_catalog() -> None:
         "cache is committed beyond its budget (reason=pinned), or "
         "CS230_STAGE_STRICT refused an oversize upload (reason=strict)",
     )
+    c(
+        "tpuml_split_plan_cache_total",
+        "Fold-plan lookups by the executor, labeled by outcome: hit (the "
+        "memoised plan), miss (sklearn's splitters ran over every row), "
+        "bypass (no integer random_state: a fresh draw, never memoised)",
+    )
     # ---- out-of-core row-block streaming (docs/ARCHITECTURE.md
     # "Out-of-core streaming") ----
     c(

@@ -30,7 +30,8 @@ from ..obs import (
     record_batch_device_seconds,
     span,
 )
-from ..ops.folds import build_split_plan
+from ..data.stage_cache import dataset_fingerprint
+from ..ops.folds import SPLIT_PLAN_CACHE
 from ..parallel.trial_map import fit_single, run_trials
 from ..utils.config import get_config
 from ..utils.flops import mfu as _mfu
@@ -390,6 +391,7 @@ class LocalExecutor:
             tp.get("scoring"), kernel.task, data.n_classes, kernel
         )
         plan = _split_plan(
+            data,
             data.y if kernel.task == "regression" else _np(data.y),
             task=kernel.task,
             n_folds=_coerce_cv(tp.get("cv")),
@@ -630,6 +632,7 @@ class LocalExecutor:
             kernel.task, data.n_classes, kernel,
         )
         plan = _split_plan(
+            data,
             data.y if kernel.task == "regression" else _np(data.y),
             task=kernel.task,
             n_folds=_coerce_cv(tp.get("cv")),
@@ -671,6 +674,7 @@ class LocalExecutor:
         data = self.cache.get(subtask["dataset_id"], kernel.task)
         tp = subtask.get("train_params", {}) or {}
         plan = _split_plan(
+            data,
             _np(data.y),
             task=kernel.task,
             n_folds=0,
@@ -905,12 +909,21 @@ def _np(y):
     return np.asarray(y)
 
 
-def _split_plan(y, **kw):
-    """``build_split_plan`` under an ``executor.split_plan`` span: the
-    splitters walk every row on the host, once per batch."""
+def _split_plan(data, y, **kw):
+    """The batch's fold plan under an ``executor.split_plan`` span, from
+    the process-wide memo (``ops/folds.py::SplitPlanCache``): the splitters
+    walk every row on the host once per (dataset, split arguments), not
+    once per batch. The key's first half is the fingerprint the stage
+    cache prefixes to the same masks' device copy, memoised on ``data``."""
     with child_span("executor.split_plan", n_rows=len(y)) as sp:
-        plan = build_split_plan(y, **kw)
-        sp.attrs.update(n_splits=plan.n_splits, signature=str(plan.signature))
+        plan, outcome = SPLIT_PLAN_CACHE.get_or_build(
+            dataset_fingerprint(data), y, **kw
+        )
+        sp.attrs.update(
+            n_splits=plan.n_splits, signature=str(plan.signature),
+            outcome=outcome,
+        )
+    counter_inc("tpuml_split_plan_cache_total", outcome=outcome)
     return plan
 
 
