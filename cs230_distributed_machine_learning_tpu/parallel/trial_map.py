@@ -38,7 +38,7 @@ from ..utils import backend as _backend
 from ..utils.aot_cache import aot_jit
 from .distributed import fetch as _fetch
 from .distributed import prefetch_async
-from .mesh import pad_to_multiple
+from .mesh import mesh_info, pad_to_multiple
 
 _compiled_cache: Dict[Any, Any] = {}
 
@@ -68,6 +68,22 @@ _PHASE = _PhaseAcc()
 
 def _sds(a):
     return jax.ShapeDtypeStruct(a.shape, a.dtype)
+
+
+def _dispatch_span(mesh, chunk_no: int, lanes: int, n_trials: int, **attrs):
+    """The ``executor.dispatch`` span of one chunk. ``lanes`` is the chunk
+    size the executable was compiled for, so ``lanes_padding`` trial lanes
+    run on a repeated hyperparameter row and are dropped after the fetch; on
+    a mesh the chunk is a multiple of its devices and the lanes are counted
+    (``tpuml_mesh_lanes_total{kind}``)."""
+    n_devices = mesh_info(mesh)[0]
+    padding = lanes - n_trials
+    if n_devices > 1:
+        counter_inc("tpuml_mesh_lanes_total", n_trials, kind="real")
+        counter_inc("tpuml_mesh_lanes_total", padding, kind="padding")
+    return child_span("executor.dispatch", chunk=chunk_no, n_trials=n_trials,
+                      n_devices=n_devices, lanes=lanes, lanes_padding=padding,
+                      **attrs)
 
 
 def _xla_only(fn):
@@ -232,6 +248,12 @@ def _fetch_result(out, spec: Optional[_PackSpec]):
         t0 = time.perf_counter()
         if isinstance(out, _Packed):
             out, spec = out.buf, out.spec
+        # devices the result's shards are read from (a mesh result is
+        # trial-sharded: one transfer a device and leaf)
+        sp.attrs["n_devices"] = len({
+            d for leaf in jax.tree_util.tree_leaves(out)
+            if isinstance(leaf, jax.Array) for d in leaf.sharding.device_set
+        }) or 1
         if spec is not None:
             buf = np.asarray(jax.device_get(out))
             result = _unpack(buf, spec), 1, buf.nbytes
@@ -467,29 +489,30 @@ def _mesh_axes_subkey(mesh) -> tuple:
     )
 
 
-def _staged_mesh(data, x_key, X_np, mesh, trial_axis, replicate_only=False):
-    """Mesh-shaped staged dataset (docs/ARCHITECTURE.md "Elastic trial
-    fabric"): ONE host->device upload per (dataset, host) — the
-    plain single-device entry, shared with single-device jobs over the
-    same content — then an on-device ``jax.device_put`` broadcast (1-D
-    trial mesh: replicated) or reshard (2-D mesh: rows split over the
-    data axis) that moves bytes over ICI instead of N independent host
-    uploads. Both layers ride the multi-tenant stage cache:
-    single-flight (8 concurrent mesh jobs build one copy), refcount
-    pinning, and LRU eviction all apply, and the mesh entry's subkey
-    carries the mesh axis spec so differently-shaped meshes coexist.
+def _staged_mesh(data, key, dev_key, make_dev, nbytes, mesh, trial_axis,
+                 replicate_only=False):
+    """Mesh-shaped staged form of a job-invariant pytree: the dataset, or
+    the labels and fold masks (docs/ARCHITECTURE.md "Elastic trial
+    fabric"): ONE host->device upload per (dataset, host) — the plain
+    single-device entry ``dev_key`` built by ``make_dev``, shared with
+    single-device jobs over the same content — then an on-device
+    ``jax.device_put`` broadcast (1-D trial mesh: replicated) or reshard
+    (2-D mesh: rows split over the data axis) that moves ``nbytes`` over
+    ICI instead of N independent host uploads. Both layers ride the
+    multi-tenant stage cache: single-flight (8 concurrent mesh jobs build
+    one copy), refcount pinning, and LRU eviction all apply, and the mesh
+    entry's subkey carries the mesh axis spec so differently-shaped meshes
+    coexist.
 
     ``replicate_only=True`` forces full replication even on a 2-D mesh —
     the chunked-fit protocol's executables expect replicated data
     (its in_shardings, _run_chunked). Falls back to the legacy
-    per-dispatch ``jnp.asarray`` when the cache valve is off."""
+    per-dispatch placement by jit when the cache valve is off."""
     from ..data import stage_cache as _sc
 
     if not _sc.enabled():
         # legacy: leave staging/placement to jit's sharding machinery
-        return jax.tree_util.tree_map(jnp.asarray, X_np)
-
-    from .mesh import mesh_info
+        return make_dev()
 
     n_dev, _ = mesh_info(mesh)
     data_axis = (
@@ -504,17 +527,14 @@ def _staged_mesh(data, x_key, X_np, mesh, trial_axis, replicate_only=False):
     form = "rows" if data_axis is not None else "repl"
     mesh_key = (
         (_sc.dataset_fingerprint(data), _sc.host_signature())
-        + tuple(x_key) + ("mesh", _mesh_axes_subkey(mesh), form)
+        + tuple(key) + ("mesh", _mesh_axes_subkey(mesh), form)
     )
 
     def make_mesh():
         # layer 1 — the host upload: the ordinary single-device staged entry
         # (key-identical to the single-device f32 path, so a mesh job and
         # a single-device job over one dataset share ONE upload)
-        host_val = _staged_device(
-            data, tuple(x_key) + ("dev",),
-            lambda: jax.tree_util.tree_map(jnp.asarray, X_np),
-        )
+        host_val = _staged_device(data, tuple(dev_key), make_dev)
         # layer 2 — ICI: broadcast/reshard the resident copy across the
         # local mesh; device-to-device, never through the host again
         return jax.tree_util.tree_map(
@@ -522,14 +542,11 @@ def _staged_mesh(data, x_key, X_np, mesh, trial_axis, replicate_only=False):
             host_val,
         )
 
-    nbytes = sum(
-        int(getattr(leaf, "nbytes", 0))
-        for leaf in jax.tree_util.tree_leaves(X_np)
-    )
     # replication traffic: every device beyond the source gets a full
     # copy; a row reshard moves ~one full pass of the data in total
     ici_est = nbytes * (n_dev - 1) if form == "repl" else nbytes
-    with child_span("executor.stage", what="mesh." + form) as sp:
+    with child_span("executor.stage", what="mesh." + form, of=_stage_what(key),
+                    transport="ici") as sp:
         t0 = time.perf_counter()
         stage_before = _PHASE.stage
         val, outcome = _sc.STAGE_CACHE.get_or_stage(
@@ -537,7 +554,10 @@ def _staged_mesh(data, x_key, X_np, mesh, trial_axis, replicate_only=False):
         )
         wall = time.perf_counter() - t0
         # the host upload is the inner span's; this one moved bytes over ICI
-        sp.attrs.update(outcome=outcome, bytes=0)
+        moved = ici_est if outcome == "miss" else 0
+        sp.attrs.update(outcome=outcome, bytes=0, ici_bytes=moved)
+    if moved:
+        counter_inc("tpuml_mesh_replicated_bytes_total", float(moved))
     if outcome != "hit":
         # the inner host upload already added its own wall to the phase
         # accumulator; add only the replicate remainder so the run's
@@ -865,12 +885,21 @@ def _run_trials_impl(
                     jnp.asarray(plan.eval_w),
                 )
 
-            if plan.signature is not None:
-                _dev_cache.append(
-                    _staged_device(data, ("folds", plan.signature), make)
-                )
-            else:
+            key = ("folds", plan.signature)
+            if plan.signature is None:
                 _dev_cache.append(make())
+            elif n_dev > 1 and len(mesh.shape) == 1:
+                # 1-D trial mesh: every executable takes the labels and
+                # fold masks replicated, so they are replicated ONCE like
+                # the dataset; left on device 0, each dispatch's jit copied
+                # them to every chip again
+                _dev_cache.append(_staged_mesh(
+                    data, key, key, make,
+                    int(y_np.nbytes + plan.train_w.nbytes + plan.eval_w.nbytes),
+                    mesh, trial_axis,
+                ))
+            else:
+                _dev_cache.append(_staged_device(data, key, make))
         return _dev_cache[0]
 
     def _to_host(out):
@@ -1077,8 +1106,11 @@ def _run_trials_impl(
             # and broadcast/reshard over ICI (the mesh-aware stage cache;
             # legacy jit-placed staging when the cache valve is off)
             X = _staged_mesh(
-                data, x_key, X_np, mesh, trial_axis,
-                replicate_only=bool(chunk_plan),
+                data, x_key, x_key + ("dev",),
+                lambda: jax.tree_util.tree_map(jnp.asarray, X_np),
+                sum(int(getattr(leaf, "nbytes", 0))
+                    for leaf in jax.tree_util.tree_leaves(X_np)),
+                mesh, trial_axis, replicate_only=bool(chunk_plan),
             )
             stage_mode = "f32"
         if chunk_plan:
@@ -1303,8 +1335,8 @@ def _run_trials_impl(
                 jax.device_put, device=NamedSharding(mesh, P(trial_axis))
             )
         for start in range(0, len(idxs), chunk):
-            with child_span("executor.dispatch", chunk=start // chunk,
-                            n_trials=min(chunk, len(idxs) - start)):
+            with _dispatch_span(mesh, start // chunk, chunk,
+                                min(chunk, len(idxs) - start)):
                 batch_idx = idxs[start : start + chunk]
                 T = len(batch_idx)
                 if hyper_names:
@@ -1546,6 +1578,7 @@ def _chunk_best(mesh, trial_axis: str, chunk: int, n_splits: int, n_folds: int):
     if key in _compiled_cache:
         return _compiled_cache[key]
 
+    @jax.named_scope("tpuml.collective")
     def reduce(score, n_valid):
         if n_folds >= 2:
             mean_cv = jnp.mean(score[:, 1:], axis=1)
@@ -1908,8 +1941,7 @@ def _run_chunked(
             hyper_arg = {"_pad": jnp.zeros((chunk,), jnp.float32)}
 
         t0 = time.perf_counter()
-        with child_span("executor.dispatch", chunk=start // chunk,
-                        n_trials=len(batch_idx)):
+        with _dispatch_span(mesh, start // chunk, chunk, len(batch_idx)):
             group_outs = []
             group_curves = []
             for twg, ewg, size in split_groups:
@@ -2101,8 +2133,8 @@ def _run_streamed(
         t0 = time.perf_counter()
         wait0 = streamer.stats["wait_s"]
         blocks0 = streamer.stats["blocks"]
-        with child_span("executor.dispatch", chunk=start // chunk,
-                        n_trials=len(batch_idx), streamed=True):
+        with _dispatch_span(None, start // chunk, chunk, len(batch_idx),
+                            streamed=True):
             score = np.asarray(
                 kernel.stream_scores(
                     streamer, y_d, TW_d, EW_d, hyper_batch, static, n

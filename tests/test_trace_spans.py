@@ -89,8 +89,10 @@ def test_engine_spans_are_real_children_of_the_batch(local_search):
     assert {a["what"] for a in stages} >= {"data", "folds"}
     assert all(a["outcome"] == "hit" and a["bytes"] == 0 for a in stages)  # warm
     assert _one(spans, "executor.compile")["attrs"]["cache"] == "hit"
-    assert _one(spans, "executor.dispatch")["attrs"] == {"chunk": 0, "n_trials": 4}
+    assert _one(spans, "executor.dispatch")["attrs"] == {
+        "chunk": 0, "n_trials": 4, "n_devices": 1, "lanes": 4, "lanes_padding": 0}
     assert _one(spans, "executor.fetch")["attrs"]["bytes"] > 0
+    assert _one(spans, "executor.fetch")["attrs"]["n_devices"] == 1
     assert _one(spans, "executor.emit")["attrs"]["n_subtasks"] == 4
     assert batch["attrs"]["n_dispatches"] == 1  # the summary stays on the batch
 
