@@ -10,8 +10,9 @@ worker.py:289-349 semantics) on a subsample of trials for the denominator.
     python bench.py             # ONE chip: the packed single-device path
     python bench.py --chips 4   # trial_mesh over 4 chips: the sharded path
 
-The two are different executables (trial_map: the packed Pallas fit is
-single-device only), so the chip count is asked for, never taken from
+The two are different executables (trial_map: on a mesh the packed Pallas
+fit runs per chip under shard_map, at a block as wide as a chip's share
+of the trials), so the chip count is asked for, never taken from
 whatever the host happens to hold; the output names the device and the
 path it measured.
 

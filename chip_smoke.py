@@ -354,8 +354,9 @@ def check_mesh_results(obs: Dict[str, Any], one_chip: Dict[str, Any],
     best = obs["best"]
     check(best.get("winner_via") == "ici_argmax",
           f"winner_via={best.get('winner_via')!r}")
-    # the mesh runs the XLA formulation, one chip the packed Pallas fit:
-    # same math, different bf16 rounding order — compare trial by trial
+    # both legs run the packed Pallas fit (the mesh per chip, under
+    # shard_map); the tolerance dates from the mesh's XLA formulation —
+    # compare trial by trial
     ours = {r["parameters"]["C"]: r["mean_cv_score"] for r in obs["results"]}
     theirs = {r["parameters"]["C"]: r["mean_cv_score"]
               for r in one_chip["results"]}

@@ -245,9 +245,20 @@ class LogisticRegressionKernel(ModelKernel):
     # host round-trip (measured ~7x per-iteration over the vmap path on
     # v5e for the Covertype north-star config).
 
-    #: trials per packed weight block; engine rounds chunks to this multiple
+    #: trials in the widest packed weight block (``ops/pallas_logreg.py::
+    #: TRIAL_BLOCK``); a chunk beyond it is a whole number of such blocks
     batched_trial_multiple = 128
     batched_chunk_cap = 1024
+
+    def batched_trial_block(self, trials_per_device: int, n_splits: int) -> int:
+        """Trials a packed weight block (``Tw``) for a device's share of a
+        bucket: the narrowest width the kernel admits that holds the share,
+        ``batched_trial_multiple`` at most. The engine rounds a device's
+        chunk up to it and ``build_batched_fn`` reads the same width back
+        from that chunk."""
+        from ..ops.pallas_logreg import packed_trial_block
+
+        return packed_trial_block(trials_per_device, n_splits)
 
     def batched_applicable(self, static: Dict[str, Any], n: int, d: int) -> bool:
         if static.get("_method") != "nesterov":
@@ -329,7 +340,7 @@ class LogisticRegressionKernel(ModelKernel):
         derived inline, bit-identically."""
         if not self.batched_applicable(static, n, d):
             return None
-        Tw = self.batched_trial_multiple
+        Tw = self.batched_trial_block(chunk, n_splits)
         if chunk % Tw:
             return None
 

@@ -431,6 +431,10 @@ class _MLPBase(ModelKernel):
     batched_trial_multiple = 1
     batched_chunk_cap = 64
 
+    def batched_trial_block(self, trials_per_device: int, n_splits: int) -> int:
+        """Lanes pack per (trial, split), so any trial count is a block."""
+        return self.batched_trial_multiple
+
     def batched_applicable(self, static: Dict[str, Any], n: int, d: int) -> bool:
         solver = static.get("solver", "adam")
         if solver not in ("adam", "sgd"):

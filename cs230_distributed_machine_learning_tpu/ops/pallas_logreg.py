@@ -34,8 +34,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-#: trials per weight block; the packed block width is ``c * S * TRIAL_BLOCK``
+#: trials in the widest weight block; the packed block width is ``c * S * Tw``
 TRIAL_BLOCK = 128
+#: the widths ``packed_trial_block`` chooses among, narrowest first
+TRIAL_BLOCKS = (16, 32, 64, TRIAL_BLOCK)
 
 
 def _tile_softmax_gram(a, W, yv, wsp_ref, acc_ref, *, c: int, S: int, Tw: int):
@@ -151,6 +153,29 @@ _FUSED_STEP_VMEM_BYTES = 8 * 1024 * 1024
 #: headroom for operands XLA itself parks in VMEM — at small n it prefetches
 #: the whole design matrix there, which overflowed the default limit.
 _FUSED_STEP_VMEM_LIMIT = 64 * 1024 * 1024
+
+
+def trial_block_admissible(S: int, Tw: int) -> bool:
+    """Whether the packed fit may run ``Tw`` trials a weight block at ``S``
+    splits. The widest block always may; a narrower one only where its
+    class slabs ``[bm, S*Tw]`` fill whole 128-lane vregs. Found on a v5e
+    (5M x 54, 7 classes, 6 splits, 100 steps, one block a fit): 9.53 s at
+    128, 5.24 s at 64, and then *slower*: 5.98 s at 32 and 7.23 s at 16,
+    whose slabs of 192 and 96 lanes start off a vreg boundary. All four
+    gave the same scores and curves to the last bit."""
+    return Tw == TRIAL_BLOCK or (Tw in TRIAL_BLOCKS and (S * Tw) % 128 == 0)
+
+
+def packed_trial_block(trials: int, S: int) -> int:
+    """The narrowest admissible weight block that holds ``trials`` trials
+    at ``S`` splits, ``TRIAL_BLOCK`` at most. Kernel time follows the
+    packed width ``c*S*Tw``, so a share of 16 trials in a block of 128
+    spends seven eighths of the fit on padding lanes."""
+    return next(
+        (Tw for Tw in TRIAL_BLOCKS
+         if Tw >= trials and trial_block_admissible(S, Tw)),
+        TRIAL_BLOCK,
+    )
 
 
 def fused_step_applicable(dpp: int, NB: int, bm: int = 256) -> bool:

@@ -10,7 +10,10 @@ Kafka->scheduler->worker dispatch of per-trial sklearn fits
         sharded over mesh axis 'trials' (NamedSharding) — one slice per chip
 
 XLA compiles the bucket once (static shapes, traced hypers) and partitions
-the trial axis across chips; cross-trial aggregation (argmax of
+the trial axis across chips (a kernel that publishes a fused
+``build_batched_fn`` runs it whole on every chip of a 1-D trial mesh under
+``shard_map`` instead, on that chip's share of the trials); cross-trial
+aggregation (argmax of
 mean_cv_score) happens on-device, so the only host traffic is the final
 scalar results — replacing the reference's per-trial Kafka round trips.
 """
@@ -70,29 +73,113 @@ def _sds(a):
     return jax.ShapeDtypeStruct(a.shape, a.dtype)
 
 
-def _dispatch_span(mesh, chunk_no: int, lanes: int, n_trials: int, **attrs):
-    """The ``executor.dispatch`` span of one chunk. ``lanes`` is the chunk
+def _dispatch_span(mesh, engine: str, chunk_no: int, lanes: int,
+                   n_trials: int, **attrs):
+    """The ``executor.dispatch`` span of one chunk. ``engine`` says which
+    of the four forms ran it (``packed``: a kernel's ``build_batched_fn``,
+    with ``block`` trials a weight block and ``blocks`` a device;
+    ``generic``: the vmapped fit; ``chunked``; ``streamed``), counted in
+    ``tpuml_engine_dispatch_total{engine, mesh}``. ``lanes`` is the chunk
     size the executable was compiled for, so ``lanes_padding`` trial lanes
     run on a repeated hyperparameter row and are dropped after the fetch; on
     a mesh the chunk is a multiple of its devices and the lanes are counted
     (``tpuml_mesh_lanes_total{kind}``)."""
-    n_devices = mesh_info(mesh)[0]
+    n_devices, shape = mesh_info(mesh)
     padding = lanes - n_trials
+    counter_inc(
+        "tpuml_engine_dispatch_total", engine=engine,
+        mesh="none" if n_devices == 1 else f"{len(shape)}d",
+    )
     if n_devices > 1:
         counter_inc("tpuml_mesh_lanes_total", n_trials, kind="real")
         counter_inc("tpuml_mesh_lanes_total", padding, kind="padding")
-    return child_span("executor.dispatch", chunk=chunk_no, n_trials=n_trials,
-                      n_devices=n_devices, lanes=lanes, lanes_padding=padding,
-                      **attrs)
+    return child_span("executor.dispatch", engine=engine, chunk=chunk_no,
+                      n_trials=n_trials, n_devices=n_devices, lanes=lanes,
+                      lanes_padding=padding, **attrs)
 
 
 def _xla_only(fn):
-    """The form of every mesh executable: ``auto`` kernel valves on their
-    XLA formulation. ``jit`` with mesh shardings cannot partition a Mosaic
-    kernel, and the trial axis needs no kernel-level help (each device
-    runs its own trial shard). The scope decorates the function, so it is
-    entered on every call of the Python body — which is every trace."""
+    """The form of a mesh executable that XLA partitions: ``auto`` kernel
+    valves on their XLA formulation, because ``jit`` with mesh shardings
+    cannot partition a Mosaic kernel. That is every family's generic
+    vmapped fit and the chunked protocol on any mesh, and every family on
+    a (trials, data) mesh; a kernel's ``build_batched_fn`` on a 1-D trial
+    mesh is not partitioned but run whole on every device
+    (:func:`_shard_batched`) and keeps its kernel. The scope decorates the
+    function, so it is entered on every call of the Python body — which is
+    every trace."""
     return _backend.xla_formulations()(fn)
+
+
+def _deal_lanes(fn, n_dev: int, dev_chunk: int, trial_keys):
+    """Deal a chunk's lanes round-robin over the devices of a trial mesh.
+
+    ``P(trials)`` hands device ``k`` the lanes ``[k * dev_chunk, (k + 1) *
+    dev_chunk)``, and the host fills the first lanes of a chunk with its
+    real trials: undealt, they would pile on the first devices. Host lane
+    ``j`` runs on device ``j % n_dev`` in slot ``j // n_dev``, so the
+    devices' real counts differ by at most one and each device's padding
+    lanes are its own. Both gathers are static permutations inside the
+    executable; the host still sees "the first lanes are real"."""
+    chunk = n_dev * dev_chunk
+    # position dev * dev_chunk + slot of the dealt order <- host lane
+    dealt_from = np.arange(chunk).reshape(dev_chunk, n_dev).T.reshape(-1)
+    host_from = np.argsort(dealt_from)
+
+    def dealt(X, y, TW, EW, hyper):
+        with jax.named_scope("tpuml.pack"):
+            hyper = {
+                k: jnp.take(v, dealt_from, axis=0) if k in trial_keys else v
+                for k, v in hyper.items()
+            }
+        out = fn(X, y, TW, EW, hyper)
+        with jax.named_scope("tpuml.pack"):
+            return jax.tree_util.tree_map(
+                lambda a: jnp.take(a, host_from, axis=0), out
+            )
+
+    return dealt
+
+
+def _shard_map_trials(fn, mesh, trial_axis: str, trial_keys):
+    """``fn`` (a kernel's batched function for ONE device's chunk) run on
+    every device of a 1-D trial mesh on that device's run of lanes:
+    dataset, folds and staged extras replicated, per-trial hypers and
+    every result leaf split over the trial axis. Nothing is partitioned
+    and nothing crosses chips inside, so a Mosaic kernel stays in."""
+
+    def sharded(X, y, TW, EW, hyper):
+        hyper_specs = {
+            k: P(trial_axis) if k in trial_keys else P() for k in hyper
+        }
+        return jax.shard_map(
+            fn, mesh=mesh,
+            in_specs=(P(), P(), P(), P(), hyper_specs),
+            out_specs=P(trial_axis), check_vma=False,
+        )(X, y, TW, EW, hyper)
+
+    return sharded
+
+
+def _shard_batched(fn, mesh, trial_axis: str, dev_chunk: int, trial_keys,
+                   extra_keys):
+    """The packed engine's mesh executable: ``fn`` under
+    :func:`_shard_map_trials`, lanes dealt by :func:`_deal_lanes`, jitted
+    with the shardings every mesh executable has (replicated data,
+    trial-sharded hypers and results)."""
+    n_dev = int(mesh.shape[trial_axis])
+    trial_keys = frozenset(trial_keys)
+    repl = NamedSharding(mesh, P())
+    tsh = NamedSharding(mesh, P(trial_axis))
+    hyper_sh = {**{k: tsh for k in trial_keys}, **{k: repl for k in extra_keys}}
+    return jax.jit(
+        _deal_lanes(
+            _shard_map_trials(fn, mesh, trial_axis, trial_keys),
+            n_dev, dev_chunk, trial_keys,
+        ),
+        in_shardings=(repl, repl, repl, repl, hyper_sh),
+        out_shardings=tsh,
+    )
 
 
 # ---- device cost accounting -----------------------------------------------
@@ -491,9 +578,10 @@ def _mesh_axes_subkey(mesh) -> tuple:
 
 def _staged_mesh(data, key, dev_key, make_dev, nbytes, mesh, trial_axis,
                  replicate_only=False):
-    """Mesh-shaped staged form of a job-invariant pytree: the dataset, or
-    the labels and fold masks (docs/ARCHITECTURE.md "Elastic trial
-    fabric"): ONE host->device upload per (dataset, host) — the plain
+    """Mesh-shaped staged form of a job-invariant pytree: the dataset, the
+    labels and fold masks, or a staged extra of a kernel's packed path
+    (docs/ARCHITECTURE.md "Elastic trial fabric"): ONE host->device
+    upload or build per (dataset, host) — the plain
     single-device entry ``dev_key`` built by ``make_dev``, shared with
     single-device jobs over the same content — then an on-device
     ``jax.device_put`` broadcast (1-D trial mesh: replicated) or reshard
@@ -503,6 +591,10 @@ def _staged_mesh(data, key, dev_key, make_dev, nbytes, mesh, trial_axis,
     one copy), refcount pinning, and LRU eviction all apply, and the mesh
     entry's subkey carries the mesh axis spec so differently-shaped meshes
     coexist.
+
+    ``nbytes`` is the form's size on one device, for the ICI estimate;
+    ``None`` reads it off the single-device entry when the mesh form has
+    to be built.
 
     ``replicate_only=True`` forces full replication even on a 2-D mesh —
     the chunked-fit protocol's executables expect replicated data
@@ -542,9 +634,13 @@ def _staged_mesh(data, key, dev_key, make_dev, nbytes, mesh, trial_axis,
             host_val,
         )
 
+    if nbytes is None and not _sc.STAGE_CACHE.contains(mesh_key):
+        # a form whose size only its maker knows (a kernel's staged extra):
+        # build the single-device entry first and read it there
+        nbytes = _sc._tree_nbytes(_staged_device(data, tuple(dev_key), make_dev))
     # replication traffic: every device beyond the source gets a full
     # copy; a row reshard moves ~one full pass of the data in total
-    ici_est = nbytes * (n_dev - 1) if form == "repl" else nbytes
+    ici_est = (nbytes or 0) * (n_dev - 1 if form == "repl" else 1)
     with child_span("executor.stage", what="mesh." + form, of=_stage_what(key),
                     transport="ici") as sp:
         t0 = time.perf_counter()
@@ -876,30 +972,32 @@ def _run_trials_impl(
     y_np = np.asarray(data.y)
     _dev_cache: List[Any] = []
 
+    folds_key = ("folds", plan.signature)
+
+    def _make_folds():
+        return (
+            jnp.asarray(data.y),
+            jnp.asarray(plan.train_w),
+            jnp.asarray(plan.eval_w),
+        )
+
     def _dev_args():
         if not _dev_cache:
-            def make():
-                return (
-                    jnp.asarray(data.y),
-                    jnp.asarray(plan.train_w),
-                    jnp.asarray(plan.eval_w),
-                )
-
-            key = ("folds", plan.signature)
             if plan.signature is None:
-                _dev_cache.append(make())
+                _dev_cache.append(_make_folds())
             elif n_dev > 1 and len(mesh.shape) == 1:
                 # 1-D trial mesh: every executable takes the labels and
                 # fold masks replicated, so they are replicated ONCE like
                 # the dataset; left on device 0, each dispatch's jit copied
                 # them to every chip again
                 _dev_cache.append(_staged_mesh(
-                    data, key, key, make,
+                    data, folds_key, folds_key, _make_folds,
                     int(y_np.nbytes + plan.train_w.nbytes + plan.eval_w.nbytes),
                     mesh, trial_axis,
                 ))
             else:
-                _dev_cache.append(_staged_device(data, key, make))
+                _dev_cache.append(
+                    _staged_device(data, folds_key, _make_folds))
         return _dev_cache[0]
 
     def _to_host(out):
@@ -1171,26 +1269,42 @@ def _run_trials_impl(
         # Kernels with a fused batched path (e.g. the Pallas packed
         # LogisticRegression fit, models/logistic.py) take over the whole
         # chunk: one jitted call = fit scan + eval, with its own (larger)
-        # chunk geometry. Single-device only — the trial mesh axis is
-        # handled by the generic sharded path.
+        # chunk geometry. On one device the call is the executable; on a
+        # mesh whose only axis is the trial axis every device runs the
+        # call on its share of the chunk under ``shard_map``
+        # (_shard_batched). A (trials, data) mesh, a custom scorer and a
+        # kernel that publishes no such path stay on the generic one.
         batched_fn = None
         extra_args = None
-        if (hasattr(kernel, "build_batched_fn") and single_device and not host_exec
+        packed_mesh = (
+            mesh if not single_device and tuple(mesh.shape) == (trial_axis,)
+            else None
+        )
+        if (hasattr(kernel, "build_batched_fn")
+                and (single_device or packed_mesh is not None)
+                and not host_exec
                 and scoring is None):  # fused paths score by the default metric
-            Tw = getattr(kernel, "batched_trial_multiple", 128)
-            cap = getattr(kernel, "batched_chunk_cap", 1024)
-            bchunk = max(Tw, min(cap, pad_to_multiple(len(idxs), Tw)))
+            # geometry from a device's share: the kernel names the weight
+            # block that holds it, the chunk is whole blocks a device
+            n_shard = n_dev if packed_mesh is not None else 1
+            share = -(-len(idxs) // n_shard)
+            Tw = kernel.batched_trial_block(share, plan.n_splits)
+            dev_chunk = max(Tw, min(kernel.batched_chunk_cap,
+                                    pad_to_multiple(share, Tw)))
             batched_fn = kernel.build_batched_fn(
                 static=static,
                 n=n,
                 d=d,
                 n_classes=data.n_classes,
                 n_splits=plan.n_splits,
-                chunk=bchunk,
+                chunk=dev_chunk,
             )
 
+        engine, dispatch_attrs = "generic", {}
         if batched_fn is not None:
-            chunk = bchunk
+            engine = "packed"
+            chunk = dev_chunk * n_shard
+            dispatch_attrs = {"block": Tw, "blocks": dev_chunk // Tw}
             y_d, TW_d, EW_d = _dev_args()
             X_d = X
             # dispatch-invariant staged forms the kernel wants precomputed
@@ -1207,22 +1321,45 @@ def _run_trials_impl(
                     n_splits=plan.n_splits, fold_signature=plan.signature,
                 )
                 if specs:
-                    ctx = {"X": X_d, "y": y_d, "TW": TW_d, "EW": EW_d,
-                           "decode": _stage_decode}
+                    def extra_ctx():
+                        ctx = {"X": X_d, "y": y_d, "TW": TW_d, "EW": EW_d,
+                               "decode": _stage_decode}
+                        if packed_mesh is not None:
+                            # built on ONE chip, from the single-device
+                            # entries the mesh forms were replicated from
+                            ctx["X"] = _staged_device(
+                                data, x_key + ("dev",),
+                                lambda: jax.tree_util.tree_map(
+                                    jnp.asarray, X_np),
+                            )
+                            if plan.signature is not None:
+                                ctx["y"], ctx["TW"], ctx["EW"] = (
+                                    _staged_device(
+                                        data, folds_key, _make_folds)
+                                )
+                        return ctx
+
                     extra_args = {}
                     for name in sorted(specs):
                         subkey, make = specs[name]
+                        build = lambda m=make: m(extra_ctx())  # noqa: E731
                         if subkey is None:
                             # nothing stable to key on (e.g. an unsigned
                             # fold plan): still hoisted out of the
                             # per-dispatch jit, just not cached across runs
-                            extra_args[name] = make(ctx)
-                        else:
+                            extra_args[name] = build()
+                            continue
+                        ekey = ("batched_extra", kernel.name, name,
+                                stage_mode) + tuple(subkey)
+                        if packed_mesh is None:
                             extra_args[name] = _staged_device(
-                                data,
-                                ("batched_extra", kernel.name, name,
-                                 stage_mode) + tuple(subkey),
-                                lambda m=make: m(ctx),
+                                data, ekey, build)
+                        else:
+                            # like the data and the folds: one build, then
+                            # a copy to every chip over ICI
+                            extra_args[name] = _staged_mesh(
+                                data, ekey, ekey, build, None, mesh,
+                                trial_axis,
                             )
             # one key for both layers: _aot_key carries everything that
             # determines the executable (incl. the interpret-mode env var,
@@ -1231,7 +1368,11 @@ def _run_trials_impl(
             cache_key = ("batched",) + _aot_key(
                 kernel, static, X, data.n_classes, plan.n_splits, chunk,
                 hyper_names, stage_mode=stage_mode,
+                # a mesh executable hands back the per-leaf dict (below)
+                packed=None if packed_mesh is None else False,
             )
+            if packed_mesh is not None:
+                cache_key = cache_key + (_mesh_signature(packed_mesh),)
             if extra_args:
                 # the staged extras join the executable's input signature
                 cache_key = cache_key + (
@@ -1250,20 +1391,31 @@ def _run_trials_impl(
                         # widen the compressed staged matrix before the fused
                         # kernel sees it (it expects the f32 design matrix)
                         raw = _decode_wrap(batched_fn)
-                    example = _example_args(X, y_np, plan.train_w, plan.eval_w,
-                                            hyper_names, chunk)
-                    if extra_args:
-                        example[4].update(
-                            {k: _sds(v) for k, v in extra_args.items()}
+                    if packed_mesh is not None:
+                        # like every mesh executable (_lookup_compiled):
+                        # the per-leaf dict, no cost capture, no AOT blob
+                        compiled = _shard_batched(
+                            raw, packed_mesh, trial_axis, dev_chunk,
+                            hyper_names or ["_pad"], sorted(extra_args or ()),
                         )
-                    cost = _capture_cost(raw, example)
-                    spec = None
-                    if _packed_enabled():
-                        spec = _pack_spec_of(raw, example)
-                        raw = _pack_wrap(raw)
-                    compiled, csp.attrs["cache"] = aot_jit(
-                        raw, cache_key, example
-                    )
+                        spec = cost = None
+                        csp.attrs["cache"] = "traced"
+                    else:
+                        example = _example_args(
+                            X, y_np, plan.train_w, plan.eval_w, hyper_names,
+                            chunk)
+                        if extra_args:
+                            example[4].update(
+                                {k: _sds(v) for k, v in extra_args.items()}
+                            )
+                        cost = _capture_cost(raw, example)
+                        spec = None
+                        if _packed_enabled():
+                            spec = _pack_spec_of(raw, example)
+                            raw = _pack_wrap(raw)
+                        compiled, csp.attrs["cache"] = aot_jit(
+                            raw, cache_key, example
+                        )
                     _compiled_cache[cache_key] = (compiled, spec, cost)
                 fn, out_spec, exec_cost = _compiled_cache[cache_key]
         elif not host_exec:
@@ -1335,8 +1487,9 @@ def _run_trials_impl(
                 jax.device_put, device=NamedSharding(mesh, P(trial_axis))
             )
         for start in range(0, len(idxs), chunk):
-            with _dispatch_span(mesh, start // chunk, chunk,
-                                min(chunk, len(idxs) - start)):
+            with _dispatch_span(mesh, engine, start // chunk, chunk,
+                                min(chunk, len(idxs) - start),
+                                **dispatch_attrs):
                 batch_idx = idxs[start : start + chunk]
                 T = len(batch_idx)
                 if hyper_names:
@@ -1633,14 +1786,19 @@ def _get_compiled(*args, **kwargs):
 def _lookup_compiled(kernel, static_key, static, mesh, trial_axis, data, plan, chunk,
                      hyper_names, X_proto=None, y=None, TW=None, EW=None,
                      n_splits_override=None, stage_mode="f32"):
-    """Returns (fn, pack_spec_or_None, cost_or_None, source). Single-device
+    """The generic (vmapped) executable of a bucket. Returns (fn,
+    pack_spec_or_None, cost_or_None, source). Single-device
     executables take the packed-output form (one uint8 result buffer, see
     _pack_wrap) and carry their XLA cost analysis (captured once, at
     construction); mesh executables keep the per-leaf dict — their score
     vector feeds the on-device collective argmax and the cross-process
     collective fetch — and skip cost capture (sharded lowering would pay a
     second full trace; the analytical bucket accounting still prices
-    them)."""
+    them). A mesh executable built here is partitioned by XLA and so
+    traced on the XLA formulations (:func:`_xla_only`); the one mesh
+    executable that keeps a Pallas kernel is the packed engine's
+    (:func:`_shard_batched`, built in ``_run_trials_impl``), with the same
+    output form."""
     has_hyper = bool(hyper_names)
     n_splits_key = n_splits_override or plan.n_splits
     # a 1-device mesh is compilation-equivalent to no mesh: drop the
@@ -1941,7 +2099,8 @@ def _run_chunked(
             hyper_arg = {"_pad": jnp.zeros((chunk,), jnp.float32)}
 
         t0 = time.perf_counter()
-        with _dispatch_span(mesh, start // chunk, chunk, len(batch_idx)):
+        with _dispatch_span(mesh, "chunked", start // chunk, chunk,
+                            len(batch_idx)):
             group_outs = []
             group_curves = []
             for twg, ewg, size in split_groups:
@@ -2133,8 +2292,8 @@ def _run_streamed(
         t0 = time.perf_counter()
         wait0 = streamer.stats["wait_s"]
         blocks0 = streamer.stats["blocks"]
-        with _dispatch_span(None, start // chunk, chunk, len(batch_idx),
-                            streamed=True):
+        with _dispatch_span(None, "streamed", start // chunk, chunk,
+                            len(batch_idx)):
             score = np.asarray(
                 kernel.stream_scores(
                     streamer, y_d, TW_d, EW_d, hyper_batch, static, n

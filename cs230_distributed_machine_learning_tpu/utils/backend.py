@@ -64,9 +64,14 @@ def pallas_interpret() -> bool:
 @contextlib.contextmanager
 def xla_formulations():
     """Trace-time scope in which no ``auto`` valve selects a Pallas
-    kernel. The trial engine traces its mesh executables inside it: a
-    Mosaic kernel under ``jit`` with mesh shardings cannot be partitioned
-    ("wrap the call in a shard_map"), the XLA formulations can."""
+    kernel. The trial engine traces inside it every mesh executable that
+    XLA has to partition: a Mosaic kernel under ``jit`` with mesh
+    shardings cannot be partitioned ("wrap the call in a shard_map"), the
+    XLA formulations can. Those are the generic vmapped fit and the
+    chunked protocol of every family, and everything on a (trials, data)
+    mesh. A kernel's ``build_batched_fn`` on a 1-D trial mesh is wrapped
+    in that ``shard_map`` instead (``parallel/trial_map.py::
+    _shard_batched``) and is traced outside this scope."""
     prev = getattr(_scope, "xla_only", False)
     _scope.xla_only = True
     try:

@@ -176,9 +176,8 @@ def test_fit_fused_masked_grad_matches_legacy(monkeypatch):
 # ---------------- fused packed Nesterov step (ISSUE 10) ----------------
 
 
-def _fused_step_inputs(c, S, n_wb=2, n_pad=512, dpp=64, seed=0):
+def _fused_step_inputs(c, S, n_wb=2, n_pad=512, dpp=64, seed=0, Tw=128):
     rng = np.random.RandomState(seed)
-    Tw = 128
     B = S * Tw
     NB = c * B
     Ab = jnp.asarray(rng.randn(n_pad, dpp).astype(np.float32)).astype(
@@ -222,6 +221,49 @@ def test_fused_step_kernel_matches_reference_interpret(c, S, lam):
         g, r = np.asarray(g), np.asarray(r)
         scale = np.abs(r).max() + 1e-9
         assert np.abs(g - r).max() / scale < 5e-3, name
+
+
+@pytest.mark.parametrize("Tw", [16, 32, 64, 128])
+def test_fused_step_kernel_matches_reference_at_every_trial_block(Tw):
+    """The widths ``packed_trial_block`` chooses among, at the benchmark's
+    7 classes x 6 splits: below 128 the class slabs ``[bm, S*Tw]`` are
+    narrower than at the widest block (at 16 and 32 not a whole number of
+    128-lane vregs), and the kernel's column arithmetic must not care."""
+    c, S, lam = 7, 6, 1.0
+    Ab, W, Wp, y2, WSP, done, step, Cb, maxit, pen, _ = _fused_step_inputs(
+        c, S, Tw=Tw
+    )
+    got = packed_nesterov_step(
+        Ab, W, Wp, y2, WSP, 3.0, done, step, Cb, maxit, pen,
+        c=c, S=S, Tw=Tw, bm=256, lam=lam, interpret=True,
+    )
+    ref = packed_nesterov_step_reference(
+        Ab, W, Wp, y2, WSP, 3.0, done, step, Cb, maxit, pen,
+        c=c, S=S, Tw=Tw, lam=lam,
+    )
+    for name, g, r in zip(("W_new", "Wp_new", "gmax"), got, ref):
+        g, r = np.asarray(g), np.asarray(r)
+        assert np.abs(g - r).max() / (np.abs(r).max() + 1e-9) < 5e-3, name
+
+
+def test_trial_block_is_the_narrowest_admissible_width_that_holds_the_share():
+    from cs230_distributed_machine_learning_tpu.ops.pallas_logreg import (
+        TRIAL_BLOCK, TRIAL_BLOCKS, packed_trial_block, trial_block_admissible,
+    )
+
+    for S in (1, 2, 4, 6):
+        for trials in (1, 3, 16, 17, 64, 65, 128, 130, 1000):
+            Tw = packed_trial_block(trials, S)
+            assert trial_block_admissible(S, Tw) and Tw <= TRIAL_BLOCK
+            assert Tw >= min(trials, TRIAL_BLOCK)  # it holds the share
+            assert not any(  # and nothing narrower would
+                trial_block_admissible(S, w) for w in TRIAL_BLOCKS
+                if trials <= w < Tw
+            )
+    # the one-chip benchmark cell's geometry is what it was: 128 trials, one block
+    assert packed_trial_block(128, 6) == TRIAL_BLOCK == 128
+    kernel = get_kernel("LogisticRegression")
+    assert kernel.batched_trial_block(128, 6) == kernel.batched_trial_multiple
 
 
 def test_fused_step_freezes_done_and_past_max_iter_columns():

@@ -99,12 +99,23 @@ def test_flagship_packed_fit(tpu_backend, n, chunk):
     )
 
 
-def test_fused_step_kernel(tpu_backend):
+def _admissible_blocks(n_splits=S):
+    """The trial-block widths the packed fit may run at ``n_splits``, by the
+    kernel's own rule (pure Python: safe while a test file is imported)."""
+    from cs230_distributed_machine_learning_tpu.ops.pallas_logreg import (
+        TRIAL_BLOCKS, trial_block_admissible,
+    )
+
+    return [Tw for Tw in TRIAL_BLOCKS if trial_block_admissible(n_splits, Tw)]
+
+
+@pytest.mark.parametrize("Tw", _admissible_blocks())
+def test_fused_step_kernel(tpu_backend, Tw):
     from cs230_distributed_machine_learning_tpu.ops.pallas_logreg import (
         packed_nesterov_step,
     )
 
-    n_wb, dpp, n_pad, Tw = 8, 64, 2048, 128
+    n_wb, dpp, n_pad = 8, 64, 2048
     B = S * Tw
     W = _sds((n_wb, dpp, C * B), jnp.float32)
     col = _sds((n_wb, B), jnp.float32)
@@ -247,6 +258,63 @@ def test_sharded_generic_logreg_four_devices(tpu_backend, data_parallel):
     if data_parallel == 1:
         # the trial axis needs no communication
         assert "all-reduce" not in hlo and "all-gather" not in hlo
+
+
+@pytest.mark.parametrize("Tw", _admissible_blocks())
+def test_sharded_packed_logreg_four_devices(tpu_backend, Tw):
+    """The four-chip benchmark cell's executable (``logreg_rows5m_mesh4``:
+    5M x 54, 7 classes, 6 splits, 100 steps, staged extras handed in) at
+    every block a device's share may get: the packed fit under
+    ``shard_map`` keeps its one Mosaic call and puts nothing across chips
+    inside the fit; dealing the lanes adds only the two gathers outside it."""
+    devices, why = _v5e_devices()
+    if devices is None:
+        pytest.skip(why)
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from cs230_distributed_machine_learning_tpu.parallel import trial_map
+
+    n, steps = 5_000_000, 100
+    kernel = get_kernel("LogisticRegression")
+    static = kernel.resolve_static({"fit_intercept": True, "penalty": "l2"}, n, D, C)
+    static["_n_classes"] = C
+    static = kernel.bucket_static(static, [{"max_iter": steps}])
+    assert kernel.batched_trial_block(Tw, S) == Tw
+    fn = kernel.build_batched_fn(static, n, D, C, S, Tw)
+    mesh = Mesh(np.array(devices), ("trials",))
+    repl, sharded = NamedSharding(mesh, P()), NamedSharding(mesh, P("trials"))
+    trial_keys = ("C", "max_iter", "tol")
+    n_pad = -(-n // 2048) * 2048
+    sds = lambda shape, dt, sh: jax.ShapeDtypeStruct(tuple(shape), dt, sharding=sh)  # noqa: E731
+    args = (
+        sds((n, D), jnp.float32, repl), sds((n,), jnp.int32, repl),
+        sds((S, n), jnp.float32, repl), sds((S, n), jnp.float32, repl),
+        {**{h: sds((4 * Tw,), jnp.float32, sharded) for h in trial_keys},
+         "_logreg_ab": sds((n_pad, 64), jnp.bfloat16, repl),
+         "_logreg_lam_max": sds((S,), jnp.float32, repl)},
+    )
+    collectives = ("all-reduce", "all-gather", "reduce-scatter",
+                   "collective-permute", "all-to-all")
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        fit = jax.jit(
+            trial_map._shard_map_trials(fn, mesh, "trials", frozenset(trial_keys)),
+            out_shardings=sharded,
+        ).lower(*args).compile()
+        dealt = trial_map._shard_batched(
+            fn, mesh, "trials", Tw, trial_keys, ("_logreg_ab", "_logreg_lam_max"),
+        ).lower(*args).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+    text = fit.as_text()
+    assert len(re.findall(r'custom_call_target="tpu_custom_call"', text)) == 1
+    assert "packed_nesterov_step" in text
+    assert not any(op in text for op in collectives)
+    mem = fit.memory_analysis()  # of one chip
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 15.5e9
+    # the executable the engine runs: the same one kernel, and what crosses
+    # chips is the dealing of [chunk] hypers and [chunk, S(, slots)] results
+    assert len(re.findall(r'custom_call_target="tpu_custom_call"', dealt.as_text())) == 1
 
 
 def test_host_fast_path_traces_cpu_formulations(tpu_backend):

@@ -131,6 +131,82 @@ def test_mesh_fit_matches_one_device_and_a_plain_float32_fit(covertype_like, cas
             assert np.abs(c_mesh[j, s] - gmax).max() < 0.03 * gmax[0], (j, s)
 
 
+PACKED_CASES = {  # trials -> trials a weight block, blocks a device, lanes of the chunk
+    "sixteen_a_chip": (64, 32, 1, 128),
+    "ten_dealt_3_3_2_2": (10, 32, 1, 128),
+    "two_blocks_a_chip": (520, 128, 2, 1024),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PACKED_CASES))
+def test_packed_fit_on_every_chip_matches_one_device_and_a_plain_float32_fit(
+        covertype_like, monkeypatch, case):
+    """The kernel's packed fit (``build_batched_fn``, the Pallas step
+    interpreted) per device under ``shard_map``: the same trials on one
+    device give the same scores and curves, whatever block the share got
+    and whichever device and slot a trial was dealt to."""
+    monkeypatch.setenv("CS230_PALLAS_INTERPRET", "1")
+    data, plan = covertype_like
+    n_trials, block, blocks, lanes = PACKED_CASES[case]
+    params = _trials(n_trials)
+    kernel = get_kernel("LogisticRegression")
+    mesh = trial_mesh(jax.devices()[:4])
+    engines = REGISTRY.counter("tpuml_engine_dispatch_total")
+    packed_before = engines.value(engine="packed", mesh="1d")
+    with span("test.batch") as root:
+        on_mesh = trial_map.run_trials(kernel, data, plan, params, mesh=mesh)
+    solo = trial_map.run_trials(kernel, data, plan, params)
+    assert on_mesh.n_dispatches == 1 and on_mesh.n_result_devices == 4
+    assert engines.value(engine="packed", mesh="1d") - packed_before == 1
+    (dispatch,) = [s["attrs"] for s in TRACER.spans_for(root.trace_id)
+                   if s["name"] == "executor.dispatch"]
+    assert dispatch == {"engine": "packed", "block": block, "blocks": blocks, "chunk": 0,
+                        "n_devices": 4, "n_trials": n_trials, "lanes": lanes,
+                        "lanes_padding": lanes - n_trials}
+    assert lanes == 4 * blocks * block  # every device a whole number of blocks
+    assert len(on_mesh.trial_metrics) == n_trials  # padding lanes are dropped
+    s_mesh, c_mesh = _scores_and_curves(on_mesh)
+    s_solo, c_solo = _scores_and_curves(solo)
+    # a lane's arithmetic does not depend on its block's width or its neighbours
+    np.testing.assert_allclose(s_mesh, s_solo, atol=1e-6)
+    np.testing.assert_allclose(c_mesh, c_solo, rtol=1e-6, atol=1e-9)
+    # the best entry is a real trial, the one the host's arithmetic names
+    means = s_mesh[:, 1:].mean(axis=1)
+    best, best_score = on_mesh.device_best
+    assert 0 <= best < n_trials and best_score == pytest.approx(means.max(), abs=1e-6)
+    assert means[best] == pytest.approx(means.max(), abs=1e-6)
+    # against the plain fit as above: the first trial and the last, which is
+    # dealt to another device and a later slot
+    for j in (0, n_trials - 1):
+        for s in range(plan.n_splits):
+            score, gmax = _plain_fit(data.X, data.y, plan.train_w[s], plan.eval_w[s],
+                                     params[j]["C"], params[j]["tol"], params[j]["max_iter"])
+            assert abs(s_mesh[j, s] - score) < 0.01, (j, s, s_mesh[j, s], score)
+            assert np.abs(c_mesh[j, s] - gmax).max() < 0.03 * gmax[0], (j, s)
+
+
+def test_lanes_are_dealt_round_robin_and_come_back_in_host_order():
+    """Ten real trials in a chunk of 128 on four devices: 3, 3, 2, 2 of them
+    a device, each device's padding its own, and the result in host order."""
+    import jax.numpy as jnp
+
+    n_dev, dev_chunk, n_real = 4, 32, 10
+    seen = {}
+
+    def per_device_view(X, y, TW, EW, hyper):
+        seen["lanes"] = hyper["lane"]  # in the order P("trials") would split it
+        return {"score": jnp.stack([hyper["lane"], hyper["lane"] + 0.5], axis=1)}
+
+    lane = jnp.arange(n_dev * dev_chunk, dtype=jnp.float32)
+    out = trial_map._deal_lanes(per_device_view, n_dev, dev_chunk, {"lane"})(
+        None, None, None, None, {"lane": lane, "_extra": jnp.zeros(3)})
+    per_device = np.asarray(seen["lanes"]).reshape(n_dev, dev_chunk)
+    assert [(row < n_real).sum() for row in per_device] == [3, 3, 2, 2]
+    for k, row in enumerate(per_device):
+        assert list(row) == list(range(k, n_dev * dev_chunk, n_dev))  # slot j // n_dev
+    np.testing.assert_array_equal(np.asarray(out["score"])[:, 0], np.asarray(lane))
+
+
 def test_dispatch_spans_and_lane_counters_read_what_the_dispatches_did(covertype_like):
     data, plan = covertype_like
     kernel = get_kernel("LogisticRegression")
@@ -146,8 +222,8 @@ def test_dispatch_spans_and_lane_counters_read_what_the_dispatches_did(covertype
         trial_map.run_trials(kernel, data, plan, _trials(6), mesh=mesh, max_trials_per_batch=4)
     spans = TRACER.spans_for(root.trace_id)
     dispatch = [s["attrs"] for s in spans if s["name"] == "executor.dispatch"]
-    assert [(a["chunk"], a["n_trials"], a["lanes"], a["lanes_padding"], a["n_devices"])
-            for a in dispatch] == [(0, 4, 4, 0, 4), (1, 2, 4, 2, 4)]
+    assert [(a["engine"], a["chunk"], a["n_trials"], a["lanes"], a["lanes_padding"], a["n_devices"])
+            for a in dispatch] == [("generic", 0, 4, 4, 0, 4), ("generic", 1, 2, 4, 2, 4)]
     real = lanes.value(kind="real") - before["real"]
     padding = lanes.value(kind="padding") - before["padding"]
     assert (real, padding) == (12, 4)  # two runs of 6 trials in chunks of 4

@@ -90,7 +90,8 @@ def test_engine_spans_are_real_children_of_the_batch(local_search):
     assert all(a["outcome"] == "hit" and a["bytes"] == 0 for a in stages)  # warm
     assert _one(spans, "executor.compile")["attrs"]["cache"] == "hit"
     assert _one(spans, "executor.dispatch")["attrs"] == {
-        "chunk": 0, "n_trials": 4, "n_devices": 1, "lanes": 4, "lanes_padding": 0}
+        "engine": "generic", "chunk": 0, "n_trials": 4, "n_devices": 1, "lanes": 4,
+        "lanes_padding": 0}
     assert _one(spans, "executor.fetch")["attrs"]["bytes"] > 0
     assert _one(spans, "executor.fetch")["attrs"]["n_devices"] == 1
     assert _one(spans, "executor.emit")["attrs"]["n_subtasks"] == 4
