@@ -6,8 +6,8 @@ to legacy, lost its cache keying, grew an extra copy). This harness makes
 each perf valve's cost measurable and gateable:
 
 - **Valve A/B**: for every perf valve (``CS230_FUSED_STEP``,
-  ``CS230_MASKED_GRAD``, ``CS230_HIST_KERNEL``, ``CS230_STAGE_CACHE``,
-  ``CS230_PACKED_FETCH``, ``CS230_STAGE_DTYPE``) run a small workload
+  ``CS230_MASKED_GRAD``, ``CS230_HIST_KERNEL``, ``CS230_STAGE_CACHE``)
+  run a small workload
   that exercises the valve's real code path — through ``run_trials`` where
   possible, so the executable caches' ``trace_salt`` keying is part of
   what's measured — with the valve ON and OFF in **interleaved pairs**
@@ -417,32 +417,6 @@ def _components() -> Dict[str, Dict[str, Any]]:
             ),
             "what": "multi-tenant staged-dataset cache: content-fingerprint "
                     "hit across jobs vs per-object restaging (PR 8)",
-        },
-        "packed_fetch": {
-            "valve": "CS230_PACKED_FETCH",
-            "on_value": "1",
-            "off_value": "0",
-            "build": lambda env: _build_executor_workload(
-                "LogisticRegression", env,
-                n=512, d=8, c=3, n_trials=64,
-                params={"C": 1.0, "max_iter": 5.0, "tol": 0.0},
-            ),
-            "what": "device->host results: one packed buffer fetch vs "
-                    "per-leaf conversions (PR 1); 64 trials keep the "
-                    "result pytree wide so the fetch layer is a real term",
-        },
-        "stage_dtype": {
-            "valve": "CS230_STAGE_DTYPE",
-            "on_value": "bf16",
-            "off_value": "f32",
-            "build": lambda env: _build_executor_workload(
-                "LogisticRegression", env,
-                n=65536, d=32, c=4, n_trials=2,
-                params={"C": 1.0, "max_iter": 3.0, "tol": 0.0},
-                cv=2, fresh_data=True,
-            ),
-            "what": "staging upload dtype: bf16-compressed vs f32 uploads "
-                    "(PR 1/8; the win scales with link slowness)",
         },
     }
 

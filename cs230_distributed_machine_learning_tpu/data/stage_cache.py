@@ -12,7 +12,7 @@ This module is the process-global replacement:
 - **content-fingerprint keys**: every staged entry is keyed by a sha1 over
   the dataset's actual bytes + shape/dtype + ``n_classes`` + an optional
   ``preprocess_salt`` attribute, plus the default device identity and the
-  caller's entry subkey (placement, staging dtype, prepared-form salt).
+  caller's entry subkey (placement, prepared-form salt).
   Two TrialData objects with identical content share one device copy; a
   dtype or preprocessing difference can never collide. Beyond raw
   dataset tensors, the same keying carries *solver precomputes*: the

@@ -23,9 +23,9 @@ HBM<->host:
   (single-flight), repeat passes are cache hits while the budget allows,
   and LRU eviction reclaims blocks the pass has already consumed.
 - **double-buffered upload** (``RowBlockStreamer``): a one-worker
-  prefetch thread stages block ``i+1`` (host fetch -> optional
-  ``CS230_STAGE_DTYPE`` compression -> ``device_put``) while the caller
-  computes on block ``i``, hiding the transfer wall behind compute.
+  prefetch thread stages block ``i+1`` (host fetch -> ``device_put``)
+  while the caller computes on block ``i``, hiding the transfer wall
+  behind compute.
   In-flight and prefetched blocks hold an explicit cache ref
   (``StagedDatasetCache.acquire``/``release``) so LRU pressure from
   other tenants can never drop them mid-pass.
@@ -174,15 +174,6 @@ def host_block_set(n_blocks: int, n_shards: int, shard_idx: int) -> range:
     return range(start, stop)
 
 
-def decode_block(blk):
-    """Widen a compressed staged block (bf16 / int8 dict forms) back to
-    the f32 matrix kernels expect — the same traced decode the
-    single-shot staging path uses."""
-    from ..parallel.trial_map import _stage_decode
-
-    return _stage_decode(blk)
-
-
 def pad_rows(blk: np.ndarray, rows: int) -> np.ndarray:
     """Zero-pad a partial tail block up to the uniform block height."""
     short = rows - blk.shape[0]
@@ -218,9 +209,8 @@ class RowBlockStreamer:
     single-flight path.
 
     ``fetch_host(i)`` produces the host-side block (already padded to
-    ``plan.rows``); ``to_device`` uploads it (optionally compressing via
-    the CS230_STAGE_DTYPE path first). Both run on the prefetch worker
-    thread when double-buffering is on.
+    ``plan.rows``); ``to_device`` uploads it. Both run on the prefetch
+    worker thread when double-buffering is on.
     """
 
     def __init__(

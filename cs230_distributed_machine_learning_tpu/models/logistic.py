@@ -287,7 +287,7 @@ class LogisticRegressionKernel(ModelKernel):
           dispatch after the first is a cache hit.
 
         Returns ``{name: (subkey | None, make)}``; ``make(ctx)`` receives
-        ``{"X", "y", "TW", "EW", "decode"}`` device args. A ``None``
+        ``{"X", "y", "TW", "EW"}`` device args. A ``None``
         subkey means compute once per bucket, don't cache (no fold
         signature to key on). Empty in ``legacy`` mode: the rollback path
         must keep deriving everything inline, bit-for-bit."""
@@ -304,14 +304,11 @@ class LogisticRegressionKernel(ModelKernel):
             return jnp.pad(A, ((0, n_pad - n), (0, dpp - dp)))
 
         def make_ab(ctx):
-            f = jax.jit(
-                lambda X: pad_a(ctx["decode"](X)).astype(jnp.bfloat16)
-            )
-            return f(ctx["X"])
+            return jax.jit(lambda X: pad_a(X).astype(jnp.bfloat16))(ctx["X"])
 
         def make_lam_max(ctx):
             def compute(X, TW):
-                A = pad_a(ctx["decode"](X))
+                A = pad_a(X)
                 TWp = jnp.pad(
                     TW.astype(jnp.float32), ((0, 0), (0, n_pad - n))
                 )
@@ -844,8 +841,6 @@ def _stream_fns(rows, d, c, S, T, fit_intercept, lam):
     if fns is not None:
         return fns
 
-    from ..data.streaming import decode_block
-
     dp = d + (1 if fit_intercept else 0)
     pen = np.ones((dp, c), np.float32)
     if fit_intercept:
@@ -853,7 +848,7 @@ def _stream_fns(rows, d, c, S, T, fit_intercept, lam):
     pen_mask = jnp.asarray(pen)
 
     def design(blk):
-        return add_intercept(decode_block(blk), bool(fit_intercept))
+        return add_intercept(blk, bool(fit_intercept))
 
     @jax.jit
     def power_block(blk, u, v, TW, start):

@@ -829,7 +829,6 @@ class _RandomForestBase(_TreeBase):
         row range so the multinomial stream matches exactly. Prediction
         for the fitting rows reuses the builder's final node ids — a
         resident leaf lookup, no extra pass."""
-        from ..data.streaming import decode_block
         from ..ops.trees import _LOOKUP_M, _leaf_select, build_tree_streamed
 
         c = max(int(static["_n_classes"]), 2)
@@ -847,8 +846,7 @@ class _RandomForestBase(_TreeBase):
         def stream_pass(fn, carry, *consts):
             for _i, start, blk in streamer.iter_blocks():
                 carry = fn(
-                    carry, *consts, decode_block(blk),
-                    jnp.asarray(start, jnp.int32),
+                    carry, *consts, blk, jnp.asarray(start, jnp.int32),
                 )
             return carry
 

@@ -774,8 +774,8 @@ def build_tree_streamed(
 
     ``stream_pass(fn, carry, *consts)`` must run one ascending pass over
     the bin-code blocks, folding ``carry = fn(carry, *consts, xb_b,
-    start)`` per block (the kernel drivers wrap a RowBlockStreamer plus
-    the staged-form decode). ``S``/``C`` are the full padded per-sample
+    start)`` per block (the kernel drivers wrap a RowBlockStreamer).
+    ``S``/``C`` are the full padded per-sample
     stats/counts — zero on pad rows, so pads land in node 0's histograms
     with zero weight and contribute nothing anywhere, exactly like a
     zero-count sample in build_tree.

@@ -1,21 +1,35 @@
-"""Trial execution engine: vmapped fits, sharded over the mesh trial axis.
+"""Trial execution engine: a bucket of trials in one dispatch.
 
 This is the TPU-native replacement for the reference's entire
 Kafka->scheduler->worker dispatch of per-trial sklearn fits
 (``task_handler.py:185-236`` fan-out; ``worker.py:289-363`` per-trial fit +
-5-fold CV). One dispatch here runs a whole *bucket* of trials:
+5-fold CV). ``run_trials`` buckets the trials by static (shape-determining)
+config and runs each bucket as
 
-    vmap over (K+1) split masks        — holdout fit + K CV folds
-      x vmap over T trials             — hyperparameters as arrays
-        sharded over mesh axis 'trials' (NamedSharding) — one slice per chip
+    plan -> stage -> build -> dispatch, then one drain of all buckets
 
-XLA compiles the bucket once (static shapes, traced hypers) and partitions
-the trial axis across chips (a kernel that publishes a fused
-``build_batched_fn`` runs it whole on every chip of a 1-D trial mesh under
-``shard_map`` instead, on that chip's share of the trials); cross-trial
-aggregation (argmax of
-mean_cv_score) happens on-device, so the only host traffic is the final
-scalar results — replacing the reference's per-trial Kafka round trips.
+- :func:`plan_bucket` chooses the engine, the placement and the chunk
+  geometry, once, from host-side facts. Five engines: ``generic`` (vmap
+  over the K+1 split masks x vmap over T trials with hyperparameters as
+  arrays; on a mesh XLA partitions the trial axis), ``packed`` (a kernel's
+  fused ``build_batched_fn``, whole on one device or under ``shard_map``
+  on every chip of a 1-D trial mesh, each on its share of the trials),
+  ``chunked`` (a kernel's init / step / eval protocol: one long fit over
+  several bounded dispatches), ``streamed`` (row blocks of a matrix that
+  does not fit the stage budget) and ``host`` (the generic program on the
+  host's CPU, for a bucket not worth one device round trip).
+- ``_stage_X`` / ``_stage_folds`` / ``_stage_extras`` put the job-invariant
+  tensors where the plan's placement says, through the stage cache.
+- :func:`_build_executable` is the one constructor of executables (cache,
+  counters, ``executor.compile`` span, cost capture, packing, AOT export).
+- :func:`_dispatch` enqueues a bucket chunk by chunk without blocking;
+  ``_run_chunked`` and ``_run_streamed`` run theirs blocking. All add to
+  one :class:`_Run`, whose drain fetches the results.
+
+XLA compiles a bucket once (static shapes, traced hypers); cross-trial
+aggregation (argmax of mean_cv_score) happens on-device, so the only host
+traffic is the final scalar results, replacing the reference's per-trial
+Kafka round trips.
 """
 
 from __future__ import annotations
@@ -26,7 +40,7 @@ import functools
 import os
 import threading
 import time
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -34,6 +48,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..data import stage_cache as _sc
 from ..models.base import ModelKernel, TrialData
 from ..obs import child_span, counter_inc, obs_enabled, observe
 from ..ops.folds import SplitPlan
@@ -42,6 +57,7 @@ from ..utils.aot_cache import aot_jit
 from .distributed import fetch as _fetch
 from .distributed import prefetch_async
 from .mesh import mesh_info, pad_to_multiple
+from .packing import Packed, PackSpec, pack_spec_of, pack_wrap, unpack
 
 _compiled_cache: Dict[Any, Any] = {}
 
@@ -184,34 +200,22 @@ def _shard_batched(fn, mesh, trial_axis: str, dev_chunk: int, trial_keys,
 
 # ---- device cost accounting -----------------------------------------------
 #
-# Promotes the offline bench helpers (utils/flops.py) to runtime telemetry:
-# each cached executable carries its XLA cost analysis (flops, bytes
-# accessed), captured ONCE at construction, and every dispatch accumulates
-# it into the TrialRunResult so the executor can derive achieved-FLOP/s and
-# MFU per batch. The analytical model-FLOP estimate (kernel.macs_estimate)
-# is accumulated per bucket alongside — it is the MFU numerator (model
-# FLOPs, comparable across implementations; see utils/flops docstring)
-# while the XLA figure prices what the hardware actually did.
-
-
-def _cost_capture_enabled() -> bool:
-    """Capturing an executable's cost analysis costs one extra trace+lower
-    at construction time (never on the dispatch hot path). Rides the master
-    CS230_OBS valve; CS230_COST_ANALYSIS=0 turns just the XLA capture off
-    (the free analytical accounting stays)."""
-    return (
-        obs_enabled()
-        and os.environ.get("CS230_COST_ANALYSIS", "1") != "0"
-    )
+# Each cached executable carries its XLA cost analysis (flops, bytes
+# accessed), captured ONCE at construction; every dispatch adds it to the
+# run, so the executor can derive achieved FLOP/s per batch: what the
+# hardware executed. The analytical estimate (kernel.macs_estimate) is
+# added per bucket alongside: model FLOPs, comparable across
+# implementations, the MFU numerator.
 
 
 def _capture_cost(fn, example_args) -> Optional[Dict[str, float]]:
     """XLA cost analysis of ``fn`` lowered at ``example_args``:
     {"flops": ..., "bytes": ...} (either value may be absent), or None when
-    capture is disabled or the backend/lowering offers no analysis. Runs at
-    executable-construction time only — results are cached in
-    ``_compiled_cache`` beside the executable."""
-    if not _cost_capture_enabled():
+    ``CS230_OBS=0`` or the backend/lowering offers no analysis. One extra
+    trace + lower at executable-construction time, never on the dispatch
+    path: the result is cached in ``_compiled_cache`` beside the
+    executable."""
+    if not obs_enabled():
         return None
     try:
         analysis = jax.jit(fn).lower(*example_args).cost_analysis()
@@ -229,103 +233,10 @@ def _capture_cost(fn, example_args) -> Optional[Dict[str, float]]:
         return None
 
 
-# ---- packed single-fetch result transport ---------------------------------
-#
-# Every blocking device->host conversion is its own round trip, paid PER
-# LEAF of the result pytree — the cost floor of tiny jobs (iris-sized
-# grids, GaussianNB). The trial executables
-# therefore concatenate all result leaves into ONE flat byte buffer inside
-# the jitted computation (bitcast, so f32/int leaves stay bit-identical)
-# and the host fetches that single buffer with one jax.device_get, then
-# reassembles the pytree with zero-copy numpy views.
-
-
-def _packed_enabled() -> bool:
-    """CS230_PACKED_FETCH=0 restores the per-leaf fetch path (debug/parity
-    valve). The flag changes the executable's OUTPUT signature, so it joins
-    every executable cache key via _aot_key."""
-    return os.environ.get("CS230_PACKED_FETCH", "1") != "0"
-
-
-@dataclasses.dataclass(frozen=True)
-class _PackSpec:
-    """Host-side recipe to reassemble a result pytree from one byte buffer."""
-
-    treedef: Any
-    shapes: tuple
-    dtypes: tuple
-    offsets: tuple
-    nbytes: int
-
-
-class _Packed:
-    """A packed device buffer awaiting its single-transfer host fetch."""
-
-    __slots__ = ("buf", "spec")
-
-    def __init__(self, buf, spec: _PackSpec):
-        self.buf = buf
-        self.spec = spec
-
-
-def _pack_spec_of(fn, example_args) -> _PackSpec:
-    """Abstract-trace ``fn`` to learn its output tree; no device work."""
-    out = jax.eval_shape(fn, *example_args)
-    leaves, treedef = jax.tree_util.tree_flatten(out)
-    shapes = tuple(tuple(int(s) for s in l.shape) for l in leaves)
-    dtypes = tuple(np.dtype(l.dtype) for l in leaves)
-    sizes = [
-        int(np.prod(s, dtype=np.int64)) * dt.itemsize
-        for s, dt in zip(shapes, dtypes)
-    ]
-    offs = np.concatenate([[0], np.cumsum(sizes, dtype=np.int64)])
-    return _PackSpec(
-        treedef, shapes, dtypes, tuple(int(o) for o in offs[:-1]), int(offs[-1])
-    )
-
-
-def _pack_wrap(fn):
-    """Wrap a to-be-jitted trial function so its result leaves the device
-    as one flat uint8 buffer (bitcast + concat traced into the executable).
-    Pair with the _PackSpec from ``_pack_spec_of`` on the same example args."""
-
-    def packed(*args):
-        leaves = jax.tree_util.tree_leaves(fn(*args))
-        with jax.named_scope("tpuml.pack"):
-            parts = []
-            for leaf in leaves:
-                leaf = jnp.asarray(leaf)
-                if leaf.dtype == jnp.bool_:
-                    leaf = leaf.astype(jnp.uint8)
-                parts.append(
-                    jax.lax.bitcast_convert_type(leaf, jnp.uint8).reshape(-1)
-                )
-            if not parts:
-                return jnp.zeros((0,), jnp.uint8)
-            return jnp.concatenate(parts) if len(parts) > 1 else parts[0]
-
-    return packed
-
-
-def _unpack(buf_np: np.ndarray, spec: _PackSpec):
-    """Reassemble the result pytree from one fetched byte buffer (views,
-    not copies — and bitwise identical to the per-leaf path)."""
-    buf_np = np.ascontiguousarray(buf_np)
-    leaves = []
-    for off, shape, dt in zip(spec.offsets, spec.shapes, spec.dtypes):
-        size = int(np.prod(shape, dtype=np.int64)) * dt.itemsize
-        raw = buf_np[off : off + size]
-        if dt == np.dtype(bool):
-            leaves.append(raw.view(np.uint8).astype(bool).reshape(shape))
-        else:
-            leaves.append(raw.view(dt).reshape(shape))
-    return jax.tree_util.tree_unflatten(spec.treedef, leaves)
-
-
-def _fetch_result(out, spec: Optional[_PackSpec]):
+def _fetch_result(out, spec: Optional[PackSpec]):
     """One dispatch result -> (host pytree, n_blocking_fetches, bytes).
 
-    Packed results (``spec`` given, or ``out`` already a ``_Packed``) cross
+    Packed results (``spec`` given, or ``out`` already a ``Packed``) cross
     the link as ONE buffer via a single device_get; unpacked dicts pay one
     conversion per leaf — and under a multi-process mesh go through the
     collective fetch. Each blocking fetch feeds the
@@ -333,7 +244,7 @@ def _fetch_result(out, spec: Optional[_PackSpec]):
     accumulator (TrialRunResult.fetch_time_s)."""
     with child_span("executor.fetch") as sp:
         t0 = time.perf_counter()
-        if isinstance(out, _Packed):
+        if isinstance(out, Packed):
             out, spec = out.buf, out.spec
         # devices the result's shards are read from (a mesh result is
         # trial-sharded: one transfer a device and leaf)
@@ -343,7 +254,7 @@ def _fetch_result(out, spec: Optional[_PackSpec]):
         }) or 1
         if spec is not None:
             buf = np.asarray(jax.device_get(out))
-            result = _unpack(buf, spec), 1, buf.nbytes
+            result = unpack(buf, spec), 1, buf.nbytes
         else:
             host = _fetch(out)
             leaves = jax.tree_util.tree_leaves(host)
@@ -355,99 +266,6 @@ def _fetch_result(out, spec: Optional[_PackSpec]):
     return result
 
 
-# ---- compressed staging uploads -------------------------------------------
-#
-# Cold start uploads the f32 design matrix host->device.
-# CS230_STAGE_DTYPE=bf16 halves those bytes (int8 quarters
-# them, with a per-column scale); the executable widens back to f32 on
-# device as its first traced op. Off (f32) by default: bf16 staging moves
-# scores by O(1e-3) (documented tolerance, tests/test_packed_parity.py).
-
-
-def _staging_dtype() -> str:
-    mode = os.environ.get("CS230_STAGE_DTYPE", "f32").lower()
-    return mode if mode in ("bf16", "int8", "auto") else "f32"
-
-
-#: probed host->device upload bandwidth (MB/s), measured once per process
-_LINK_MBPS: Optional[float] = None
-
-
-def _measured_link_mbps() -> float:
-    """Host->device upload bandwidth in MB/s: ``CS230_STAGE_LINK_MBPS``
-    pins it (tests, operators who know their link); otherwise one 4 MiB
-    ``device_put`` probe measures it (the second put — the first warms the
-    transfer path so backend init doesn't read as a slow link). This is
-    the ``auto`` staging policy's input: a local PCIe/host link measures
-    GB/s, a remote device far less."""
-    global _LINK_MBPS
-    env = os.environ.get("CS230_STAGE_LINK_MBPS")
-    if env:
-        try:
-            return float(env)
-        except ValueError:
-            pass
-    if _LINK_MBPS is None:
-        try:
-            probe = np.zeros((4 << 20,), np.uint8)
-            jax.block_until_ready(jax.device_put(probe))
-            t0 = time.perf_counter()
-            jax.block_until_ready(jax.device_put(probe))
-            dt = max(time.perf_counter() - t0, 1e-9)
-            _LINK_MBPS = probe.nbytes / dt / 1e6
-        except Exception:  # noqa: BLE001 — no backend: treat as fast/local
-            _LINK_MBPS = float("inf")
-    return _LINK_MBPS
-
-
-def _resolve_stage_mode(mode: str) -> str:
-    """Resolve the staging dtype, including the ``auto`` policy: bf16 for
-    float features when the measured upload link is slower than
-    ``CS230_STAGE_AUTO_MBPS`` (default 100 MB/s — an order of magnitude
-    below any local link), f32 otherwise.
-    int8 stays opt-in: its per-column quantization moves scores by ~2e-2,
-    too coarse for a default."""
-    if mode == "auto":
-        threshold = float(os.environ.get("CS230_STAGE_AUTO_MBPS", 100.0))
-        return "bf16" if _measured_link_mbps() < threshold else "f32"
-    return mode
-
-
-def _stage_compress(X_np: np.ndarray, mode: str):
-    """HOST-side compression right before the upload — the point is fewer
-    bytes on the link, so the narrow form must exist before device_put."""
-    X_np = np.asarray(X_np, np.float32)
-    if mode == "bf16":
-        import ml_dtypes
-
-        return {"bf16": X_np.astype(ml_dtypes.bfloat16)}
-    if mode == "int8":
-        scale = np.maximum(np.abs(X_np).max(axis=0), 1e-30) / 127.0
-        q = np.clip(np.rint(X_np / scale), -127, 127).astype(np.int8)
-        return {"q8": q, "scale": scale.astype(np.float32)}
-    return X_np
-
-
-def _stage_decode(X):
-    """Inverse of ``_stage_compress``, traced into the executable: widen
-    bf16 / dequantize int8 back to the f32 matrix every kernel expects."""
-    if isinstance(X, dict) and "bf16" in X:
-        return X["bf16"].astype(jnp.float32)
-    if isinstance(X, dict) and "q8" in X:
-        return X["q8"].astype(jnp.float32) * X["scale"][None, :]
-    return X
-
-
-def _decode_wrap(fn):
-    """Prepend the staged-X decode to a trial function's X argument (the
-    one shared wrapper for the generic and fused-batched paths)."""
-
-    def wrapped(X, y, TW, EW, hyper):
-        return fn(_stage_decode(X), y, TW, EW, hyper)
-
-    return wrapped
-
-
 def _example_args(X, y, TW, EW, hyper_names, chunk):
     """Shape/dtype skeleton of one dispatch — drives the AOT export trace."""
     hyper = {
@@ -457,8 +275,10 @@ def _example_args(X, y, TW, EW, hyper_names, chunk):
     return (jax.tree_util.tree_map(_sds, X), _sds(y), _sds(TW), _sds(EW), hyper)
 
 
-def _aot_key(kernel, static, X, n_classes, n_splits, chunk, hyper_names,
-             stage_mode="f32", packed=None):
+def _aot_key(kernel, static, X, n_classes, n_splits, chunk, hyper_names):
+    """Everything that determines a bucket's traced program: the identity
+    of an executable in ``_compiled_cache`` (behind its engine's tag) and
+    of its AOT blob on disk."""
     leaves, treedef = jax.tree_util.tree_flatten(X)
     x_sig = (
         str(treedef),
@@ -472,20 +292,10 @@ def _aot_key(kernel, static, X, n_classes, n_splits, chunk, hyper_names,
         n_splits,
         chunk,
         tuple(hyper_names),
+        # env knobs read at trace time change the program without landing
+        # in static: a mid-process flip must miss, on disk and in memory
         kernel.trace_salt(),
         os.environ.get("CS230_PALLAS_INTERPRET", ""),
-        # transfer-layer knobs that change the executable's I/O signature:
-        # packed output buffer vs per-leaf dict, and the EFFECTIVE staged-X
-        # dtype of this executable (bf16/int8 stagings must never collide
-        # with f32 blobs; the x_sig above carries the staged leaves' actual
-        # dtype, this entry keys the decode wrapper itself). Callers pass
-        # the effective mode, NOT the raw env knob — paths that force f32
-        # (prepare_data/chunked/host/mesh) keep their blobs valid across
-        # knob flips. ``packed`` can likewise be pinned False by callers
-        # whose executable does not pack (chunk_init/chunk_step), keeping
-        # their blobs valid across CS230_PACKED_FETCH flips.
-        _packed_enabled() if packed is None else bool(packed),
-        stage_mode,
     )
 
 
@@ -564,7 +374,8 @@ def _data_row_count(data) -> int:
 
 
 def _mesh_axes_subkey(mesh) -> tuple:
-    """Mesh axis spec + device identity for mesh-shaped cache subkeys:
+    """Mesh axis spec + device identity for mesh-shaped cache keys (staged
+    forms and executables):
     (((axis, size), ...), (device ids...)). The axis spec keeps the 1-D
     trial-replicated and 2-D data-sharded staged forms of one dataset
     distinct; the device ids keep two same-shaped meshes over DIFFERENT
@@ -600,8 +411,6 @@ def _staged_mesh(data, key, dev_key, make_dev, nbytes, mesh, trial_axis,
     the chunked-fit protocol's executables expect replicated data
     (its in_shardings, _run_chunked). Falls back to the legacy
     per-dispatch placement by jit when the cache valve is off."""
-    from ..data import stage_cache as _sc
-
     if not _sc.enabled():
         # legacy: leave staging/placement to jit's sharding machinery
         return make_dev()
@@ -687,8 +496,6 @@ def _staged_device(data, key, make):
 
     ``CS230_STAGE_CACHE=0`` falls back to the legacy per-TrialData-object
     cache below (bit-for-bit identical staging, no cross-job sharing)."""
-    from ..data import stage_cache as _sc
-
     if _sc.enabled():
         with child_span("executor.stage", what=_stage_what(key)) as sp:
             gkey = (_sc.dataset_fingerprint(data), _device_sig()) + tuple(key)
@@ -737,12 +544,6 @@ def _staged_device(data, key, make):
             while len(cache) > _STAGED_CACHE_MAX:
                 cache.popitem(last=False)
     return val
-
-
-# overlapped device->host transfers (each converted leaf is otherwise a
-# serial round trip — the cost floor of tiny jobs): start every pending
-# copy before the first blocking conversion
-_prefetch_async = prefetch_async
 
 
 def _call_with_prepared(fn, prepared, *args):
@@ -806,17 +607,15 @@ class TrialRunResult:
     ``device_best`` is the (submission-order index, mean_cv_score) winner as
     computed ON DEVICE by the collective argmax over the mesh-sharded score
     vector — present whenever the run executed sharded dispatches on a
-    multi-device mesh (the BASELINE.json "argmax over ICI" path, running
-    inside the production job flow, not just tests)."""
+    multi-device mesh."""
 
     trial_metrics: List[Dict[str, Any]]
     compile_time_s: float
     run_time_s: float
     n_dispatches: int
     device_best: Optional[tuple] = None
-    #: blocking device->host result transfers performed (packed path: ONE
-    #: per dispatched result buffer; per-leaf path: one per pytree leaf) —
-    #: the observable the transfer-layer micro-benchmark pins
+    #: blocking device->host result transfers performed (a packed result:
+    #: ONE per dispatched buffer; a per-leaf one: one per pytree leaf)
     n_host_fetches: int = 0
     #: bytes crossing the device->host boundary in those fetches
     result_bytes: int = 0
@@ -838,14 +637,801 @@ class TrialRunResult:
     #: model_flops prices the whole run; consumers must not read a partial
     #: sum as a total)
     flops_coverage: Optional[float] = None
-    #: HBM high-water over the run's devices (peak_bytes_in_use — MONOTONIC
-    #: over the process lifetime, not per-run; the executor's in-fit
-    #: sampler supplies the per-batch figure and uses this as fallback);
-    #: None on CPU
+    #: largest ``peak_bytes_in_use`` of the run's devices, the process's
+    #: high-water so far and not this run's; None on the CPU
     hbm_peak_bytes: Optional[int] = None
     #: distinct devices that held shards of a dispatched result — read off
     #: the output arrays' shardings, not assumed from the mesh shape
     n_result_devices: int = 1
+
+
+# ---- the bucket plan ------------------------------------------------------
+#
+# Which engine runs a bucket, where, and at what chunk geometry is decided
+# here and nowhere else, from host-side facts only (shapes, the kernel's
+# hooks, the mesh, the memory budgets): nothing below asks the kernel or
+# the mesh which path it is on again.
+
+
+def _host_put(a):
+    """``a`` on the host's XLA CPU backend, whatever the default backend."""
+    return jax.device_put(np.asarray(a), jax.local_devices(backend="cpu")[0])
+
+
+@dataclasses.dataclass(frozen=True)
+class Placement:
+    """Where a bucket's executable runs and its staged forms live:
+    ``host`` (the XLA CPU backend of an accelerator process), ``device``
+    (one accelerator device: no mesh, or a mesh of one), ``mesh_1d`` (a
+    mesh whose only axis is the trial axis) or ``mesh_2d`` ((trials,
+    data)). ``mesh`` is None off a multi-device mesh."""
+
+    kind: str
+    mesh: Optional[Mesh] = None
+    trial_axis: str = "trials"
+
+    @classmethod
+    def of(cls, mesh, trial_axis: str = "trials") -> "Placement":
+        """The accelerator placement a caller's mesh asks for (the host
+        CPU is the plan's choice, never the caller's)."""
+        if mesh_info(mesh)[0] == 1:
+            return cls("device", None, trial_axis)
+        kind = "mesh_1d" if tuple(mesh.shape) == (trial_axis,) else "mesh_2d"
+        return cls(kind, mesh, trial_axis)
+
+    @property
+    def n_dev(self) -> int:
+        """Devices along the trial axis."""
+        return 1 if self.mesh is None else int(self.mesh.shape[self.trial_axis])
+
+    def trial_put(self):
+        """How a per-trial host vector reaches its lanes."""
+        if self.kind == "host":
+            return _host_put
+        if self.mesh is None:
+            return jnp.asarray
+        # placed trial-sharded straight from the host: jnp.asarray would
+        # land on device 0 and be resharded every dispatch
+        return functools.partial(
+            jax.device_put,
+            device=NamedSharding(self.mesh, P(self.trial_axis)),
+        )
+
+
+@dataclasses.dataclass(frozen=True)
+class BucketPlan:
+    """How one bucket (the trials of one static config) runs.
+
+    ``engine``: ``host`` (the generic program on the host CPU: a bucket
+    whose whole work is trivial next to one device round trip),
+    ``streamed`` (row blocks through ``kernel.stream_scores``: a matrix
+    that crowds the stage budget), ``chunked`` (the kernel's init / step /
+    eval protocol: one fit over several bounded dispatches), ``packed``
+    (the kernel's fused ``build_batched_fn``, whole on one device or on
+    every device of a 1-D trial mesh) or ``generic`` (the vmapped fit,
+    partitioned by XLA on a mesh).
+
+    ``chunk`` is the trial lanes of one dispatch, all devices together.
+    Packed: ``block`` trials a weight block, ``blocks`` blocks and
+    ``dev_chunk`` lanes a device. Generic and chunked: ``mem_cap`` trials
+    the memory budget admits a dispatch, and ``split_width`` folds a
+    dispatch when one trial's whole fold stack passes half a device's
+    memory (None: all folds in one)."""
+
+    engine: str
+    placement: Placement
+    static: Dict[str, Any]
+    hyper_names: Tuple[str, ...]
+    chunk: int
+    block: Optional[int] = None
+    blocks: Optional[int] = None
+    dev_chunk: Optional[int] = None
+    mem_cap: Optional[int] = None
+    split_width: Optional[int] = None
+    chunk_plan: Optional[Dict[str, Any]] = None
+    batched_fn: Optional[Callable] = dataclasses.field(
+        default=None, compare=False, repr=False
+    )
+
+
+def _resolved_static(kernel, static_key, n, d, n_classes, scoring=None):
+    """A bucket's static config before its hypers are known."""
+    static = kernel.static_from_key(static_key)
+    if hasattr(kernel, "resolve_static"):
+        static = kernel.resolve_static(static, n, d, n_classes)
+    static["_n_classes"] = n_classes
+    if scoring is not None:
+        # only non-default scorers join the key: default jobs keep their
+        # (already disk-cached) executables byte-identical
+        static["_scoring"] = scoring
+    return static
+
+
+def _memory_chunk_cap(kernel, n, d, static, n_splits, n_dev) -> int:
+    """Trials per dispatch bounded by per-device HBM: each in-flight trial
+    holds ~memory_estimate_mb per split concurrently under the split vmap."""
+    per_trial_mb = max(kernel.memory_estimate_mb(n, d, static), 0.5) * max(n_splits, 1)
+    budget_mb = 0.5 * _backend.device_memory_mb() * max(n_dev, 1)
+    return max(n_dev, int(budget_mb / per_trial_mb))
+
+
+def _split_width(kernel, n, d, static, n_splits) -> Optional[int]:
+    """Folds one dispatch may hold when a single trial's fold stack (the
+    per-split working set times ``n_splits`` under the split vmap) passes
+    half a device's memory: Nyström SVC's [n, m] features per split lane,
+    deep or wide trees at large n. None when the stack fits. The budget is
+    PER DEVICE: at one trial a device, fold memory does not divide by the
+    device count."""
+    per_split_mb = max(kernel.memory_estimate_mb(n, d, static), 0.5)
+    budget_mb = 0.5 * _backend.device_memory_mb()
+    if per_split_mb * n_splits <= budget_mb:
+        return None
+    width = max(1, min(n_splits, int(budget_mb / per_split_mb)))
+    return width if width < n_splits else None
+
+
+def plan_bucket(kernel, static, bucket_hypers, host_X, *, n, d, n_classes,
+                n_splits, mesh=None, trial_axis="trials", scoring=None,
+                max_trials_per_batch=256) -> BucketPlan:
+    """Choose a bucket's engine, placement and chunk geometry. Host-only:
+    no upload, no trace, no dispatch. ``static`` is
+    :func:`_resolved_static`'s, ``bucket_hypers`` the traced hypers of the
+    bucket's trials and ``host_X`` the host form that would be staged (the
+    float32 matrix, or the kernel's ``prepare_data`` dict). The predicates
+    run in one order, the first that holds names the engine: chunked,
+    host, streamed, packed, generic."""
+    if hasattr(kernel, "bucket_static"):
+        static = kernel.bucket_static(static, bucket_hypers)
+    n_trials = len(bucket_hypers)
+    n_splits = int(n_splits)
+    place = Placement.of(mesh, trial_axis)
+    plan = functools.partial(
+        BucketPlan, static=static,
+        hyper_names=tuple(sorted(bucket_hypers[0])),
+    )
+
+    # A kernel with a chunked-fit protocol (tree ensembles) splits one
+    # trial's fit across several bounded-time dispatches whenever its own
+    # plan says the fit is long enough, on one device or with the trial
+    # axis sharded over all of a mesh's devices (data replicated). Chunked
+    # buckets always take the device path: their executables are
+    # device-platform AOT blobs.
+    chunk_plan = None
+    if hasattr(kernel, "chunked_plan"):
+        chunk_plan = _call_with_prepared(
+            kernel.chunked_plan, host_X, static, n, d, n_classes, n_splits
+        )
+    if chunk_plan:
+        n_all = mesh_info(place.mesh)[0]
+        # bounded by BOTH the cross-dispatch state and the kernel's
+        # per-trial working set (histogram buffers etc.)
+        state_mb = 4.0 * n * max(n_classes, 1) * n_splits / 1e6
+        mem_cap = _memory_chunk_cap(kernel, n, d, static, n_splits, n_all)
+        chunk = max(1, min(
+            n_trials, mem_cap,
+            int(0.25 * n_all * _backend.device_memory_mb() / max(state_mb, 1.0)),
+            64 * n_all,
+        ))
+        chunk = max(n_all, pad_to_multiple(chunk, n_all))
+        return plan(
+            "chunked", place, chunk=chunk, mem_cap=mem_cap,
+            chunk_plan=chunk_plan,
+            split_width=(
+                _split_width(kernel, n, d, static, n_splits)
+                if chunk == 1 else None
+            ),
+        )
+
+    # Host fast path, decided before any accelerator transfer: dispatching
+    # an iris-sized fit to an accelerator costs more in round trips than
+    # the whole computation. Only kernels publishing an analytical cost
+    # opt in.
+    if (
+        place.kind == "device"
+        and not _backend.on_cpu()
+        and hasattr(kernel, "macs_estimate")
+        and _call_with_prepared(kernel.macs_estimate, host_X, n, d, static)
+        * max(n_splits, 1) * n_trials <= _HOST_EXEC_MACS
+    ):
+        return plan("host", dataclasses.replace(place, kind="host"),
+                    chunk=min(max_trials_per_batch, n_trials))
+
+    # Out-of-core row-block streaming (data/streaming.py), decided BEFORE
+    # any X staging so the oversized single-shot upload (the thing
+    # CS230_STAGE_STRICT turns into a hard error) never happens.
+    # CS230_STREAM=force/off overrides the auto threshold.
+    if (
+        place.kind == "device"
+        and scoring is None
+        and hasattr(kernel, "stream_scores")
+    ):
+        from ..data.streaming import should_stream, stream_mode
+
+        if (
+            stream_mode() != "off"
+            and kernel.stream_applicable(static, n, d)
+            and should_stream(_sc._tree_nbytes(host_X))
+        ):
+            return plan("streamed", place,
+                        chunk=min(max_trials_per_batch, n_trials))
+
+    # A kernel's fused batched path (the Pallas packed LogisticRegression
+    # fit, the MLP epoch kernel) takes over the whole chunk: one jitted
+    # call = fit + eval, with its own chunk geometry from a device's share
+    # of the bucket: the kernel names the weight block that holds the
+    # share, the chunk is whole blocks a device. A (trials, data) mesh and
+    # a custom scorer (fused paths score by the default metric) stay on
+    # the generic path, as does a bucket the kernel declines (None).
+    if (
+        hasattr(kernel, "build_batched_fn")
+        and place.kind in ("device", "mesh_1d")
+        and scoring is None
+    ):
+        share = -(-n_trials // place.n_dev)
+        block = kernel.batched_trial_block(share, n_splits)
+        dev_chunk = max(block, min(kernel.batched_chunk_cap,
+                                   pad_to_multiple(share, block)))
+        batched_fn = kernel.build_batched_fn(
+            static=static, n=n, d=d, n_classes=n_classes, n_splits=n_splits,
+            chunk=dev_chunk,
+        )
+        if batched_fn is not None:
+            return plan(
+                "packed", place, chunk=dev_chunk * place.n_dev, block=block,
+                blocks=dev_chunk // block, dev_chunk=dev_chunk,
+                batched_fn=batched_fn,
+            )
+
+    mem_cap = _memory_chunk_cap(kernel, n, d, static, n_splits, place.n_dev)
+    chunk = min(max_trials_per_batch, mem_cap,
+                pad_to_multiple(n_trials, place.n_dev))
+    chunk = max(place.n_dev, pad_to_multiple(chunk, place.n_dev))
+    return plan(
+        "generic", place, chunk=chunk, mem_cap=mem_cap,
+        split_width=(
+            _split_width(kernel, n, d, static, n_splits)
+            if chunk == place.n_dev else None
+        ),
+    )
+
+
+# ---- the run accumulator --------------------------------------------------
+
+
+class _Run:
+    """What one ``run_trials`` call adds up, shared by every engine: the
+    results by submission index, the timings and counts that become the
+    ``TrialRunResult``, and the dispatches enqueued but not yet fetched.
+    Dispatches queue without blocking and are drained at the end: each
+    blocking round trip is pure latency, so a multi-bucket job (a grid
+    over a static param) overlaps its transfers instead of paying them
+    serially."""
+
+    def __init__(self, n_trials: int, split_plan: SplitPlan, task: str,
+                 scoring: Optional[str]):
+        self.split_plan, self.task, self.scoring = split_plan, task, scoring
+        self.results: List[Optional[Dict[str, Any]]] = [None] * n_trials
+        self.compile_time = 0.0
+        self.run_time = 0.0
+        self.dispatches = 0
+        self.n_fetches = 0
+        self.result_bytes = 0
+        # cost accounting (valve read once: a mid-run flip must not
+        # produce a half-priced result)
+        self.acct = obs_enabled()
+        self.model_flops = 0.0
+        self.n_buckets = 0
+        self.buckets_priced = 0
+        self.xla_flops = 0.0
+        self.xla_bytes = 0.0
+        #: (out | [(group out, n folds)], batch_idx) awaiting the drain
+        self.pending: List[Any] = []
+        #: (lane, score, batch_idx) of each chunk's collective argmax
+        #: (multi-device mesh only), read at the drain
+        self.pending_best: List[Any] = []
+        self.device_best: Optional[tuple] = None
+        self.n_result_devices = 1
+        self.t_first_dispatch: Optional[float] = None
+        #: the labels and fold masks on the accelerator, staged at most
+        #: once a run and not at all by an all-host job
+        self.device_folds: Optional[tuple] = None
+
+    def folds(self, data, place: Placement):
+        if place.kind == "host":
+            return _stage_folds(data, self.split_plan, place)
+        if self.device_folds is None:
+            self.device_folds = _stage_folds(data, self.split_plan, place)
+        return self.device_folds
+
+    def price_bucket(self, kernel, host_X, n, d, static, n_trials) -> None:
+        """Analytical model FLOPs of a whole bucket (2 * per-(trial,
+        split) MACs * splits * trials): free to compute, and it covers
+        every engine the bucket may take."""
+        self.n_buckets += 1
+        if not (self.acct and hasattr(kernel, "macs_estimate")):
+            return
+        try:
+            macs = _call_with_prepared(kernel.macs_estimate, host_X, n, d, static)
+            self.model_flops += (
+                2.0 * float(macs) * max(self.split_plan.n_splits, 1) * n_trials
+            )
+            self.buckets_priced += 1
+        except Exception:  # noqa: BLE001 — estimator bug: unpriced bucket
+            pass
+
+    def dispatched(self, cost: Optional[Dict[str, float]]) -> None:
+        """One dispatch of an executable executes its cost analysis once."""
+        self.dispatches += 1
+        if cost:
+            self.xla_flops += cost.get("flops", 0.0)
+            self.xla_bytes += cost.get("bytes", 0.0)
+
+    def await_compile(self, out, t0: float):
+        """Block on a fresh executable's first dispatch, so that its XLA
+        compile is attributed; steady-state dispatches queue."""
+        out = jax.block_until_ready(out)
+        dt = time.perf_counter() - t0
+        self.compile_time += dt
+        observe("tpuml_executor_compile_seconds", dt)
+        return out
+
+    def merge_best(self, idx: int, score: float) -> None:
+        """sklearn's first-max rule GLOBALLY: on equal scores keep the
+        smaller submission index (chunks and buckets arrive out of global
+        submission order, so "first seen" is not enough)."""
+        cur = self.device_best
+        if cur is None or score > cur[1] or (score == cur[1] and idx < cur[0]):
+            self.device_best = (idx, score)
+
+    def chunk_best(self, place: Placement, score, batch_idx, lanes: int,
+                   n_splits: int):
+        """Enqueue the collective argmax over a chunk's trial-sharded
+        score vector: XLA inserts the ICI all-gather / reduce, and only
+        two replicated scalars come back to the host."""
+        bi, bs = _chunk_best(
+            place.mesh, place.trial_axis, lanes, n_splits,
+            self.split_plan.n_folds,
+        )(score, jnp.int32(len(batch_idx)))
+        self.pending_best.append((bi, bs, batch_idx))
+        self.n_result_devices = max(
+            self.n_result_devices, len(score.sharding.device_set)
+        )
+
+    def fetch(self, out, spec: Optional[PackSpec] = None):
+        host, n_fetches, n_bytes = _fetch_result(out, spec)
+        self.n_fetches += n_fetches
+        self.result_bytes += n_bytes
+        return host
+
+    def record(self, out: Dict[str, np.ndarray], batch_idx) -> None:
+        for j, gi in enumerate(batch_idx):
+            self.results[gi] = _postprocess(
+                out, j, self.split_plan, self.task, self.scoring
+            )
+
+    def drain(self) -> None:
+        """Fetch everything enqueued and close the dispatch window."""
+        # start every pending device->host copy before the first blocking
+        # conversion (serial round trips otherwise)
+        for bi, bs, _ in self.pending_best:
+            prefetch_async((bi, bs))
+        for out, _ in self.pending:
+            for og, _size in out if isinstance(out, list) else [(out, None)]:
+                prefetch_async(og.buf if isinstance(og, Packed) else og)
+        if self.pending_best:
+            with child_span("executor.fetch", what="argmax", bytes=0):
+                for bi, bs, batch_idx in self.pending_best:
+                    pos, score = int(bi), float(bs)
+                    self.n_fetches += 2  # the argmax's two replicated scalars
+                    if pos < len(batch_idx) and np.isfinite(score):
+                        self.merge_best(batch_idx[pos], score)
+            self.pending_best.clear()
+        for out, batch_idx in self.pending:
+            if isinstance(out, list):
+                out = _join_split_groups(
+                    [(self.fetch(og), size) for og, size in out]
+                )
+            else:
+                out = self.fetch(out)
+            self.record(out, batch_idx)
+        self.pending.clear()
+        if self.t_first_dispatch is not None:
+            self.run_time += time.perf_counter() - self.t_first_dispatch
+            self.t_first_dispatch = None
+
+    def result(self, mesh) -> TrialRunResult:
+        acct = self.acct
+        return TrialRunResult(
+            trial_metrics=[r for r in self.results if r is not None],
+            compile_time_s=self.compile_time,
+            run_time_s=self.run_time,
+            n_dispatches=self.dispatches,
+            device_best=self.device_best,
+            n_host_fetches=self.n_fetches,
+            result_bytes=self.result_bytes,
+            stage_time_s=_PHASE.stage,
+            fetch_time_s=_PHASE.fetch,
+            model_flops=(
+                self.model_flops if acct and self.buckets_priced else None
+            ),
+            xla_flops=self.xla_flops if acct and self.xla_flops > 0 else None,
+            bytes_accessed=(
+                self.xla_bytes if acct and self.xla_bytes > 0 else None
+            ),
+            flops_coverage=(
+                self.buckets_priced / self.n_buckets
+                if acct and self.n_buckets else None
+            ),
+            hbm_peak_bytes=(
+                _backend.hbm_peak_bytes(
+                    list(mesh.devices.flat) if mesh is not None else None
+                )
+                if acct else None
+            ),
+            n_result_devices=self.n_result_devices,
+        )
+
+
+# ---- the executable builder -----------------------------------------------
+
+
+@dataclasses.dataclass(frozen=True)
+class _Part:
+    """One program of an executable-cache entry: ``fn`` at the shapes of
+    ``example``. ``disk_key`` names its AOT blob (None: jitted in this
+    process only); ``mesh_jit`` jits it with a mesh's shardings instead.
+    ``to_host``: its result crosses to the host (False: it is state that
+    stays on the device); ``priced``: its dispatches are priced."""
+
+    fn: Callable
+    example: tuple
+    disk_key: Optional[tuple] = None
+    mesh_jit: Optional[Callable] = None
+    to_host: bool = True
+    priced: bool = True
+
+
+def _build_executable(key, make_parts):
+    """THE executable constructor of all four tags (``host``, ``batched``,
+    ``generic``, ``chunked``: the first element of ``key``). Returns
+    ``(((fn, pack_spec, cost), ...), fresh)``, one triple for each
+    :class:`_Part` that ``make_parts()`` names, from ``_compiled_cache``
+    where the key is there (``fresh`` False). The ``executor.compile``
+    span's ``cache`` says where the entry came from: ``hit`` (this
+    process's cache), ``aot`` (a disk blob), ``traced`` (built here).
+
+    The one rule of the result's form: a ONE-DEVICE program whose result
+    crosses to the host packs it (one uint8 buffer, one transfer:
+    :func:`pack_wrap`), carries its XLA cost analysis (captured once, on
+    the pre-pack form) and is exported to disk. A MESH program hands back
+    the per-leaf dict: its score vector feeds the on-device collective
+    argmax and the per-device fetch; it takes no cost capture (a sharded
+    lowering would pay a second full trace; the analytical bucket
+    accounting still prices it) and has no blob (process-local; the
+    persistent compile cache holds its compile). A program that keeps
+    state on the device packs nothing either."""
+    with child_span("executor.compile", cache="hit") as sp:
+        fresh = key not in _compiled_cache
+        _cache_count(not fresh)
+        if fresh:
+            built = []
+            for part in make_parts():
+                fn, spec, cost, source = part.fn, None, None, "traced"
+                if part.mesh_jit is not None:
+                    fn = part.mesh_jit(fn)
+                else:
+                    if part.priced:
+                        cost = _capture_cost(fn, part.example)
+                    if part.to_host:
+                        spec = pack_spec_of(fn, part.example)
+                        fn = pack_wrap(fn)
+                    if part.disk_key is None:
+                        fn = jax.jit(fn)
+                    else:
+                        fn, source = aot_jit(fn, part.disk_key, part.example)
+                built.append((fn, spec, cost))
+            _compiled_cache[key] = tuple(built)
+            sp.attrs["cache"] = source
+    return _compiled_cache[key], fresh
+
+
+def _mesh_key(mesh) -> tuple:
+    """What a mesh adds to an executable's cache key: nothing off a mesh,
+    else its axes and device ids (not ``id(mesh)``: a collected Mesh's
+    address can be recycled by another)."""
+    return () if mesh is None else (_mesh_axes_subkey(mesh),)
+
+
+def _get_compiled(kernel, static_key, static, mesh, trial_axis, data, plan, chunk,
+                  hyper_names, X_proto=None, y=None, TW=None, EW=None,
+                  n_splits_override=None):
+    """The generic (vmapped) executable of a bucket: (fn,
+    pack_spec_or_None, cost_or_None, fresh). On a mesh XLA partitions it,
+    so it is traced on the XLA formulations (:func:`_xla_only`)."""
+    n_splits = n_splits_override or plan.n_splits
+    # a 1-device mesh is compilation-equivalent to no mesh: drop the
+    # NamedShardings so the executable is AOT-exportable and its disk key
+    # is mesh-independent
+    mesh = Placement.of(mesh, trial_axis).mesh
+    X_ex = X_proto if X_proto is not None else jax.ShapeDtypeStruct(
+        data.X.shape, jnp.float32
+    )
+    key = ("generic",) + _aot_key(
+        kernel, static, X_ex, data.n_classes, n_splits, chunk, hyper_names
+    )
+
+    def parts():
+        batched = _make_batched(kernel, static, bool(hyper_names))
+        if mesh is None:
+            example = _example_args(X_ex, y, TW, EW, hyper_names, chunk)
+            return [_Part(batched, example, disk_key=key)]
+        replicated = NamedSharding(mesh, P())
+        trial_sharded = NamedSharding(mesh, P(trial_axis))
+        ins = (replicated,) * 4
+        # 2-D mesh (trials, data): additionally shard the sample dimension
+        # of the dataset arrays across the data axis: XLA inserts the
+        # psum / all-gather collectives inside each trial's fit
+        data_axis = next((a for a in mesh.shape if a != trial_axis), None)
+        if data_axis is not None and X_proto is not None:
+            # shared with _staged_mesh: the staged placement and these
+            # in_shardings must agree or every dispatch re-shards
+            leaf_sharding = _mesh_leaf_sharding_fn(
+                mesh, data_axis, _data_row_count(data)
+            )
+            w_sh = NamedSharding(mesh, P(None, data_axis))
+            ins = (jax.tree_util.tree_map(leaf_sharding, X_proto),
+                   NamedSharding(mesh, P(data_axis)), w_sh, w_sh)
+        return [_Part(
+            _xla_only(batched), (),
+            mesh_jit=lambda fn: jax.jit(
+                fn, in_shardings=ins + (trial_sharded,),
+                out_shardings=trial_sharded,
+            ),
+        )]
+
+    ((fn, spec, cost),), fresh = _build_executable(key + _mesh_key(mesh), parts)
+    return fn, spec, cost, fresh
+
+
+def _bucket_executable(kernel, bp: BucketPlan, static_key, data, split_plan,
+                       X, extras):
+    """The executable of a host, packed or generic bucket and whether it
+    was built by this call: ((fn, pack_spec, cost), fresh)."""
+    place, names = bp.placement, bp.hyper_names
+    y_np, TW, EW = np.asarray(data.y), split_plan.train_w, split_plan.eval_w
+    if bp.engine == "generic":
+        width = bp.split_width or int(split_plan.n_splits)
+        fn, spec, cost, fresh = _get_compiled(
+            kernel, static_key, bp.static, place.mesh, place.trial_axis, data,
+            split_plan, bp.chunk, names, X, y_np, TW[:width], EW[:width],
+            n_splits_override=bp.split_width,
+        )
+        return (fn, spec, cost), fresh
+
+    key = _aot_key(kernel, bp.static, X, data.n_classes, split_plan.n_splits,
+                   bp.chunk, names)
+    example = _example_args(X, y_np, TW, EW, names, bp.chunk)
+    if bp.engine == "host":
+        key = ("host",) + key
+
+        def parts():
+            # traced (the scope wraps every call of the Python body) for
+            # the platform it runs on, not the default one; no blob: it
+            # would be the CPU's program under the accelerator's name
+            fn = _backend.host_execution()(
+                _make_batched(kernel, bp.static, bool(names))
+            )
+            return [_Part(fn, example)]
+    else:
+        key = ("batched",) + key + _mesh_key(place.mesh)
+        if extras:
+            # the staged extras join the executable's input signature
+            example[4].update({k: _sds(v) for k, v in extras.items()})
+            key += ("extras", tuple(
+                (k, tuple(v.shape), str(v.dtype))
+                for k, v in sorted(extras.items())
+            ))
+
+        def parts():
+            if place.mesh is None:
+                return [_Part(bp.batched_fn, example, disk_key=key)]
+            return [_Part(
+                bp.batched_fn, example,
+                mesh_jit=lambda fn: _shard_batched(
+                    fn, place.mesh, place.trial_axis, bp.dev_chunk,
+                    names or ["_pad"], sorted(extras or ()),
+                ),
+            )]
+
+    (exe,), fresh = _build_executable(key, parts)
+    return exe, fresh
+
+
+# ---- staging by placement -------------------------------------------------
+
+
+def _stage_X(data, x_key, host_X, place: Placement, replicate_only=False):
+    """The bucket's design matrix (or prepared forms) where the plan
+    places it, float32 as the kernels take it. On a mesh: uploaded from
+    the host ONCE per (dataset, host) and broadcast / resharded over ICI
+    (:func:`_staged_mesh`)."""
+    if place.kind == "host":
+        return _staged_device(
+            data, x_key + ("host",),
+            lambda: jax.tree_util.tree_map(_host_put, host_X),
+        )
+
+    def make():
+        return jax.tree_util.tree_map(jnp.asarray, host_X)
+
+    if place.mesh is None:
+        return _staged_device(data, x_key + ("dev",), make)
+    return _staged_mesh(
+        data, x_key, x_key + ("dev",), make, _sc._tree_nbytes(host_X),
+        place.mesh, place.trial_axis, replicate_only=replicate_only,
+    )
+
+
+def _stage_folds(data, split_plan: SplitPlan, place: Placement):
+    """(y, train masks, eval masks) where the plan places the bucket. The
+    host CPU takes them as they are and an unsigned plan has nothing to
+    key a cache entry on. On a 1-D trial mesh every executable takes them
+    replicated, so they are replicated ONCE like the dataset (left on
+    device 0, each dispatch's jit copied them to every chip again)."""
+    parts = (np.asarray(data.y), split_plan.train_w, split_plan.eval_w)
+    if place.kind == "host":
+        return tuple(_host_put(a) for a in parts)
+
+    def make():
+        return tuple(jnp.asarray(a) for a in parts)
+
+    key = ("folds", split_plan.signature)
+    if split_plan.signature is None:
+        return make()
+    if place.kind == "mesh_1d":
+        return _staged_mesh(
+            data, key, key, make, _sc._tree_nbytes(parts),
+            place.mesh, place.trial_axis,
+        )
+    return _staged_device(data, key, make)
+
+
+def _stage_extras(kernel, bp: BucketPlan, data, split_plan: SplitPlan, x_key,
+                  host_X, X, folds) -> Optional[Dict[str, Any]]:
+    """Dispatch-invariant forms a packed kernel wants precomputed (the
+    LogReg padded bf16 design matrix and per-split Lipschitz bound):
+    built ONCE per (dataset, device, subkey) in the stage cache and merged
+    into every dispatch's hyper dict, so the per-dispatch jit stops paying
+    for them. On a mesh like the data and the folds: one build on ONE
+    chip, from the single-device entries the mesh forms were replicated
+    from, then a copy to every chip over ICI."""
+    if not hasattr(kernel, "batched_staged_extras"):
+        return None
+    n, d = data.X.shape
+    place, signature = bp.placement, split_plan.signature
+    specs = kernel.batched_staged_extras(
+        static=bp.static, n=n, d=d, n_classes=data.n_classes,
+        n_splits=split_plan.n_splits, fold_signature=signature,
+    )
+    if not specs:
+        return None
+
+    def ctx():
+        Xc, (y, TW, EW) = X, folds
+        if place.mesh is not None:
+            one = Placement("device", None, place.trial_axis)
+            Xc = _stage_X(data, x_key, host_X, one)
+            if signature is not None:
+                y, TW, EW = _stage_folds(data, split_plan, one)
+        return {"X": Xc, "y": y, "TW": TW, "EW": EW}
+
+    extras = {}
+    for name in sorted(specs):
+        subkey, make = specs[name]
+        build = lambda m=make: m(ctx())  # noqa: E731
+        if subkey is None:
+            # nothing stable to key on (an unsigned fold plan): still
+            # hoisted out of the per-dispatch jit, not cached across runs
+            extras[name] = build()
+            continue
+        ekey = ("batched_extra", kernel.name, name) + tuple(subkey)
+        if place.mesh is None:
+            extras[name] = _staged_device(data, ekey, build)
+        else:
+            extras[name] = _staged_mesh(
+                data, ekey, ekey, build, None, place.mesh, place.trial_axis
+            )
+    return extras
+
+
+# ---- dispatch ---------------------------------------------------------------
+
+
+def _hyper_batch(hypers, batch_idx, hyper_names, chunk) -> Dict[str, np.ndarray]:
+    """The per-trial hyper vectors of one chunk, float32 ``[chunk]``:
+    padding lanes repeat the last real trial's row and are dropped after
+    the fetch."""
+    if not hyper_names:
+        return {"_pad": np.zeros((chunk,), np.float32)}
+    pad = [batch_idx[-1]] * (chunk - len(batch_idx))
+    return {
+        k: np.asarray([hypers[gi][k] for gi in list(batch_idx) + pad], np.float32)
+        for k in hyper_names
+    }
+
+
+def _split_groups(TW, EW, width: int):
+    """The device fold masks cut into groups of ``width`` folds, as
+    ``(train masks, eval masks, n real folds)``; the last group is padded
+    by repeating a fold, and its padded columns are dropped by
+    :func:`_join_split_groups`."""
+    groups = []
+    n_splits = int(TW.shape[0])
+    for s0 in range(0, n_splits, width):
+        size = min(width, n_splits - s0)
+        twg, ewg = TW[s0 : s0 + size], EW[s0 : s0 + size]
+        if size < width:
+            twg = jnp.concatenate([twg, jnp.repeat(twg[-1:], width - size, 0)])
+            ewg = jnp.concatenate([ewg, jnp.repeat(ewg[-1:], width - size, 0)])
+        groups.append((twg, ewg, size))
+    return groups
+
+
+def _join_split_groups(fetched) -> Dict[str, np.ndarray]:
+    """[(host result of a fold group, n real folds)] -> one result over
+    all folds."""
+    return {
+        k: np.concatenate([og[k][:, :size] for og, size in fetched], axis=1)
+        for k in fetched[0][0]
+    }
+
+
+def _dispatch(run: _Run, bp: BucketPlan, exe, fresh: bool, X, folds, extras,
+              hypers, idxs) -> None:
+    """Enqueue a host, packed or generic bucket, chunk by chunk, without
+    blocking (but for a fresh executable's first dispatch); results wait
+    in ``run.pending`` for the drain."""
+    fn, spec, cost = exe
+    place, chunk = bp.placement, bp.chunk
+    y, TW, EW = folds
+    to_dev = place.trial_put()
+    groups = [(TW, EW, None)]
+    if bp.split_width:
+        groups = _split_groups(TW, EW, bp.split_width)
+    # the host engine runs the generic program: its span says so
+    engine, attrs = "generic", {}
+    if bp.engine == "packed":
+        engine, attrs = "packed", {"block": bp.block, "blocks": bp.blocks}
+    for start in range(0, len(idxs), chunk):
+        batch_idx = idxs[start : start + chunk]
+        with _dispatch_span(place.mesh, engine, start // chunk, chunk,
+                            len(batch_idx), **attrs):
+            hyper_arg = {
+                k: to_dev(v) for k, v in
+                _hyper_batch(hypers, batch_idx, bp.hyper_names, chunk).items()
+            }
+            if extras:
+                hyper_arg = {**hyper_arg, **extras}
+            t0 = time.perf_counter()
+            if run.t_first_dispatch is None:
+                run.t_first_dispatch = t0
+            outs = []
+            for g, (twg, ewg, size) in enumerate(groups):
+                out = fn(X, y, twg, ewg, hyper_arg)
+                if fresh and start == 0 and g == 0:
+                    # the FIRST group only: later ones reuse the
+                    # executable, their device time is run time
+                    out = run.await_compile(out, t0)
+                run.dispatched(cost)
+                outs.append((Packed(out, spec) if spec is not None else out, size))
+            if bp.split_width:
+                run.pending.append((outs, batch_idx))
+                continue
+            out = outs[0][0]
+            if place.n_dev > 1:
+                run.chunk_best(place, out["score"], batch_idx, chunk,
+                               int(run.split_plan.n_splits))
+            run.pending.append((out, batch_idx))
 
 
 def run_trials(
@@ -868,8 +1454,7 @@ def run_trials(
     executable cache key.
 
     ``warm_only=True`` is the prewarm path (runtime/prewarm.py): every
-    bucket's executable is constructed (AOT blob deserialize or trace —
-    the 2.2 s the r5 cold breakdown charges to inline AOT loading) and
+    bucket's executable is constructed (AOT blob deserialize or trace) and
     its staged tensors uploaded, but nothing is dispatched — the returned
     result carries the construction/staging timings and no metrics.
 
@@ -877,33 +1462,6 @@ def run_trials(
     (refcounted) for its duration so concurrent jobs' memory-pressure
     evictions can never drop a tensor out from under a dispatch.
     """
-    from ..data import stage_cache as _sc
-
-    token = _sc.STAGE_CACHE.pin_begin() if _sc.enabled() else None
-    try:
-        return _run_trials_impl(
-            kernel, data, plan, param_dicts, mesh=mesh,
-            trial_axis=trial_axis,
-            max_trials_per_batch=max_trials_per_batch, scoring=scoring,
-            warm_only=warm_only,
-        )
-    finally:
-        if token is not None:
-            _sc.STAGE_CACHE.pin_end(token)
-
-
-def _run_trials_impl(
-    kernel: ModelKernel,
-    data: TrialData,
-    plan: SplitPlan,
-    param_dicts: Sequence[Dict[str, Any]],
-    *,
-    mesh: Optional[Mesh] = None,
-    trial_axis: str = "trials",
-    max_trials_per_batch: int = 256,
-    scoring: Optional[str] = None,
-    warm_only: bool = False,
-) -> TrialRunResult:
     if scoring is not None:
         # fail loudly at the engine boundary, not inside a trace: every
         # entry point (executor, benchmarks, direct callers) inherits the
@@ -911,54 +1469,28 @@ def _run_trials_impl(
         from ..ops.metrics import validate_scoring
 
         validate_scoring(scoring, kernel.task, data.n_classes, kernel)
-    n, d = data.X.shape
-    results: List[Optional[Dict[str, Any]]] = [None] * len(param_dicts)
-    compile_time = 0.0
-    run_time = 0.0
-    dispatches = 0
-    n_fetches = 0
-    result_bytes = 0
-    # cost accounting for THIS run (valve read once: a mid-run flip must
-    # not produce a half-priced result)
-    acct = obs_enabled()
-    model_flops = 0.0
-    n_buckets = 0
-    buckets_priced = 0
-    xla_flops = 0.0
-    xla_bytes = 0.0
+    token = _sc.STAGE_CACHE.pin_begin() if _sc.enabled() else None
+    try:
+        return _run_buckets(
+            kernel, data, plan, param_dicts, mesh, trial_axis,
+            max_trials_per_batch, scoring, warm_only,
+        )
+    finally:
+        if token is not None:
+            _sc.STAGE_CACHE.pin_end(token)
 
-    def _acc_cost(cost: Optional[Dict[str, float]]) -> None:
-        # one dispatch of an executable executes its cost analysis once
-        nonlocal xla_flops, xla_bytes
-        if cost:
-            xla_flops += cost.get("flops", 0.0)
-            xla_bytes += cost.get("bytes", 0.0)
+
+def _run_buckets(kernel, data, split_plan, param_dicts, mesh, trial_axis,
+                 max_trials_per_batch, scoring, warm_only) -> TrialRunResult:
+    """Bucket the trials by static (shape-determining) config; for each
+    bucket: plan -> stage -> build -> dispatch (or hand it to the chunked
+    or the streamed engine); drain; result."""
+    n, d = data.X.shape
+    run = _Run(len(param_dicts), split_plan, kernel.task, scoring)
     # phase accumulators for THIS call (thread-local: concurrent jobs in
-    # other threads keep their own) — read back into the TrialRunResult
+    # other threads keep their own), read back into the TrialRunResult
     _PHASE.stage = 0.0
     _PHASE.fetch = 0.0
-    # dispatches are queued without blocking and drained at the end: each
-    # blocking round trip is pure latency, so a
-    # multi-bucket job (e.g. a grid over a static param) overlaps its RPCs
-    # instead of paying them serially
-    pending: List[Any] = []
-    # per-chunk on-device collective argmax results (multi-device mesh only):
-    # (idx_scalar, score_scalar, batch_idx) — combined at drain
-    pending_best: List[Any] = []
-    device_best: Optional[tuple] = None
-    n_result_devices = 1
-    t_first_dispatch: Optional[float] = None
-
-    def _merge_best(idx: int, score: float):
-        # sklearn's first-max rule GLOBALLY: on equal scores keep the
-        # smaller submission index (chunks/buckets arrive out of global
-        # submission order, so "first seen" is not enough)
-        nonlocal device_best
-        cur = device_best
-        if cur is None or score > cur[1] or (score == cur[1] and idx < cur[0]):
-            device_best = (idx, score)
-
-    # ---- bucket trials by static (shape-determining) config ----
     buckets: Dict[Any, List[int]] = {}
     hypers: List[Dict[str, float]] = []
     for i, params in enumerate(param_dicts):
@@ -966,619 +1498,58 @@ def _run_trials_impl(
         hypers.append(hyper)
         buckets.setdefault(static_key, []).append(i)
 
-    # device copies of the fold tensors are made lazily: an all-host job
-    # (tiny buckets on an accelerator-default backend) must not pay any
-    # accelerator transfer at all
-    y_np = np.asarray(data.y)
-    _dev_cache: List[Any] = []
-
-    folds_key = ("folds", plan.signature)
-
-    def _make_folds():
-        return (
-            jnp.asarray(data.y),
-            jnp.asarray(plan.train_w),
-            jnp.asarray(plan.eval_w),
-        )
-
-    def _dev_args():
-        if not _dev_cache:
-            if plan.signature is None:
-                _dev_cache.append(_make_folds())
-            elif n_dev > 1 and len(mesh.shape) == 1:
-                # 1-D trial mesh: every executable takes the labels and
-                # fold masks replicated, so they are replicated ONCE like
-                # the dataset; left on device 0, each dispatch's jit copied
-                # them to every chip again
-                _dev_cache.append(_staged_mesh(
-                    data, folds_key, folds_key, _make_folds,
-                    int(y_np.nbytes + plan.train_w.nbytes + plan.eval_w.nbytes),
-                    mesh, trial_axis,
-                ))
-            else:
-                _dev_cache.append(
-                    _staged_device(data, folds_key, _make_folds))
-        return _dev_cache[0]
-
-    def _to_host(out):
-        nonlocal n_fetches, result_bytes
-        host, nf, nb = _fetch_result(out, None)
-        n_fetches += nf
-        result_bytes += nb
-        return host
-
-    def _drain():
-        nonlocal run_time, t_first_dispatch, n_fetches
-        # overlap every pending device->host transfer before the first
-        # blocking conversion (serial ~100 ms round trips otherwise)
-        for bi, bs, _ in pending_best:
-            _prefetch_async((bi, bs))
-        for out, _ in pending:
-            if isinstance(out, list):
-                for og, _size in out:
-                    _prefetch_async(og.buf if isinstance(og, _Packed) else og)
-            elif isinstance(out, _Packed):
-                _prefetch_async(out.buf)
-            else:
-                _prefetch_async(out)
-        if pending_best:
-            with child_span("executor.fetch", what="argmax", bytes=0):
-                for bi, bs, batch_idx in pending_best:
-                    pos, score = int(bi), float(bs)
-                    n_fetches += 2  # two replicated scalars from the collective argmax
-                    if pos < len(batch_idx) and np.isfinite(score):
-                        _merge_best(batch_idx[pos], score)
-            pending_best.clear()
-        for out, batch_idx in pending:
-            if isinstance(out, list):  # split-group dispatches: concat folds
-                fetched = [(_to_host(og), size) for og, size in out]
-                out = {
-                    k: np.concatenate(
-                        [og[k][:, :size] for og, size in fetched], axis=1
-                    )
-                    for k in fetched[0][0]
-                }
-            else:
-                out = _to_host(out)
-            for j, gi in enumerate(batch_idx):
-                results[gi] = _postprocess(out, j, plan, kernel.task, scoring)
-        pending.clear()
-        if t_first_dispatch is not None:
-            run_time += time.perf_counter() - t_first_dispatch
-            t_first_dispatch = None
-
-    n_dev = int(mesh.shape[trial_axis]) if mesh is not None else 1
     for static_key, idxs in buckets.items():
-        static = kernel.static_from_key(static_key)
-        if hasattr(kernel, "resolve_static"):
-            static = kernel.resolve_static(static, n, d, data.n_classes)
-        static["_n_classes"] = data.n_classes
-        if scoring is not None:
-            # only non-default scorers join the key: default jobs keep their
-            # (already disk-cached) executables byte-identical
-            static["_scoring"] = scoring
-
-        # bucket-level data prep (e.g. feature binning for trees): computed
-        # once, shared by every trial and split in the bucket — and cached
-        # across jobs on the TrialData object
+        static = _resolved_static(kernel, static_key, n, d, data.n_classes,
+                                  scoring)
+        # bucket-level data prep (feature binning for trees): computed
+        # once, shared by every trial and split, cached across jobs on the
+        # TrialData. Without it every bucket stages the same [n, d] matrix:
+        # keyed by placement alone, an 8-bucket MLP grid uploads X once
         if hasattr(kernel, "prepare_data"):
-            X_np = _prepared_data(kernel, data, static_key, static)
+            host_X = _prepared_data(kernel, data, static_key, static)
+            x_key = ("X", kernel.name, static_key, kernel.trace_salt())
         else:
-            X_np = np.asarray(data.X, np.float32)
-
-        if hasattr(kernel, "bucket_static"):
-            static = kernel.bucket_static(static, [hypers[i] for i in idxs])
-
-        # analytical model FLOPs of the whole bucket (2 * per-(trial,split)
-        # MACs * splits * trials) — free to compute, covers every dispatch
-        # path (generic/host/batched/chunked) the bucket takes below
-        n_buckets += 1
-        if acct and hasattr(kernel, "macs_estimate"):
-            try:
-                macs = _call_with_prepared(
-                    kernel.macs_estimate, X_np, n, d, static
-                )
-                model_flops += (
-                    2.0 * float(macs) * max(plan.n_splits, 1) * len(idxs)
-                )
-                buckets_priced += 1
-            except Exception:  # noqa: BLE001 — estimator bug: unpriced bucket
-                pass
-
-        hyper_names = sorted(hypers[idxs[0]].keys())
-        single_device = mesh is None or int(np.prod(list(mesh.shape.values()))) == 1
-
-        # Kernels with a chunked-fit protocol (tree ensembles) split one
-        # trial's fit across several bounded-time dispatches — full-depth
-        # forests at any dataset size without multi-minute single RPCs. On a
-        # multi-device mesh the same protocol runs with the trial axis
-        # sharded across chips (state/hypers NamedSharded, data replicated),
-        # so large forests keep bounded dispatches there too.
-        chunk_plan = None
-        if hasattr(kernel, "chunked_plan"):
-            chunk_plan = _call_with_prepared(
-                kernel.chunked_plan, X_np,
-                static, n, d, data.n_classes, plan.n_splits,
-            )
-
-        # Host fast path decision (before any accelerator transfer): a bucket
-        # whose entire work is trivial next to one device round trip runs on
-        # the XLA CPU backend instead. Only kernels publishing an analytical
-        # cost opt in; chunked buckets always take the device path (their
-        # executables are device-platform AOT blobs).
-        host_exec = (
-            not chunk_plan
-            and single_device
-            and not _backend.on_cpu()
-            and hasattr(kernel, "macs_estimate")
-            and _call_with_prepared(kernel.macs_estimate, X_np, n, d, static)
-            * max(plan.n_splits, 1) * len(idxs) <= _HOST_EXEC_MACS
+            host_X = np.asarray(data.X, np.float32)
+            x_key = ("X",)
+        bp = plan_bucket(
+            kernel, static, [hypers[i] for i in idxs], host_X, n=n, d=d,
+            n_classes=data.n_classes, n_splits=split_plan.n_splits,
+            mesh=mesh, trial_axis=trial_axis, scoring=scoring,
+            max_trials_per_batch=max_trials_per_batch,
         )
-        # Out-of-core row-block streaming (data/streaming.py): a bucket
-        # whose staged footprint crowds the stage budget never uploads
-        # the full matrix — kernels publishing a stream_scores driver
-        # accumulate across double-buffered row blocks instead. Decided
-        # BEFORE any X staging so the oversized single-shot upload (the
-        # thing CS230_STAGE_STRICT turns into a hard error) never
-        # happens. CS230_STREAM=force/off overrides the auto threshold.
-        if (
-            not chunk_plan
-            and single_device
-            and not host_exec
-            and scoring is None
-            and hasattr(kernel, "stream_scores")
-        ):
-            from ..data.streaming import should_stream, stream_mode
-
-            x_bytes = sum(
-                int(np.asarray(a).nbytes)
-                for a in jax.tree_util.tree_leaves(X_np)
-            )
-            if (
-                stream_mode() != "off"
-                and kernel.stream_applicable(static, n, d)
-                and should_stream(x_bytes)
-            ):
-                if warm_only:
-                    # streamed buckets have nothing to prewarm that is
-                    # worth a full block pass: their executables build
-                    # lazily on the first real pass
-                    continue
-                # flush queued generic dispatches first — the streamed
-                # bucket runs blocking and its wall must not be counted
-                # inside the generic dispatch window
-                _drain()
-                rt, nd = _run_streamed(
-                    kernel, static, X_np, y_np, hypers, idxs, results,
-                    plan, hyper_names, data, max_trials_per_batch,
-                )
-                run_time += rt
-                dispatches += nd
-                continue
-
-        # without prepare_data every bucket stages the same [n, d] matrix —
-        # key by placement alone so an 8-bucket MLP grid uploads X once,
-        # not 8 times
-        x_key = (
-            ("X", kernel.name, static_key, kernel.trace_salt())
-            if hasattr(kernel, "prepare_data") else ("X",)
-        )
-        # compressed staging (CS230_STAGE_DTYPE=bf16|int8): the single-device
-        # raw-matrix upload is the cold-start bill (~3.4 s of 7.4 s measured,
-        # BASELINE.md r5 anatomy) — halve/quarter the bytes on the link and
-        # widen back to f32 as the executable's first traced op. Kernels with
-        # prepare_data stage already-compact prepared forms (binned int8)
-        # and are left alone; the host fast path has no link to save.
-        stage_mode = (
-            _resolve_stage_mode(_staging_dtype())
-            if single_device
-            and not hasattr(kernel, "prepare_data")
-            # chunked-protocol executables never decode (their kernels all
-            # prepare_data today; this guards any future exception)
-            and not chunk_plan
-            else "f32"
-        )
-        if host_exec:
-            cpu_dev = jax.local_devices(backend="cpu")[0]
-            put = lambda a: jax.device_put(np.asarray(a), cpu_dev)  # noqa: E731
-            X = _staged_device(
-                data, x_key + ("host",),
-                lambda: jax.tree_util.tree_map(put, X_np),
-            )
-            stage_mode = "f32"
-        elif single_device:
-            if stage_mode != "f32":
-                X = _staged_device(
-                    data, x_key + ("dev", stage_mode),
-                    lambda: jax.tree_util.tree_map(
-                        jnp.asarray, _stage_compress(X_np, stage_mode)
-                    ),
-                )
-            else:
-                X = _staged_device(
-                    data, x_key + ("dev",),
-                    lambda: jax.tree_util.tree_map(jnp.asarray, X_np),
-                )
-        else:
-            # mesh path: upload from the host ONCE per (dataset, host)
-            # and broadcast/reshard over ICI (the mesh-aware stage cache;
-            # legacy jit-placed staging when the cache valve is off)
-            X = _staged_mesh(
-                data, x_key, x_key + ("dev",),
-                lambda: jax.tree_util.tree_map(jnp.asarray, X_np),
-                sum(int(getattr(leaf, "nbytes", 0))
-                    for leaf in jax.tree_util.tree_leaves(X_np)),
-                mesh, trial_axis, replicate_only=bool(chunk_plan),
-            )
-            stage_mode = "f32"
-        if chunk_plan:
-            # flush queued generic dispatches first: the chunked bucket runs
-            # blocking, and its wall time must not be double-counted inside
-            # the generic dispatch window
-            _drain()
-            y, TW, EW = _dev_args()
-            ct, rt, nd, db, nf, nb, nrd = _run_chunked(
-                kernel, static, X, y, TW, EW, hypers, idxs, results,
-                plan, chunk_plan, hyper_names, data,
-                mesh=None if single_device else mesh, trial_axis=trial_axis,
-                warm_only=warm_only,
-            )
-            compile_time += ct
-            run_time += rt
-            dispatches += nd
-            n_fetches += nf
-            result_bytes += nb
-            n_result_devices = max(n_result_devices, nrd)
-            if db is not None:
-                _merge_best(db[0], db[1])
+        run.price_bucket(kernel, host_X, n, d, bp.static, len(idxs))
+        place = bp.placement
+        if bp.engine == "streamed":
+            # nothing to prewarm that is worth a full block pass: the
+            # streamed executables build lazily on the first real pass
+            if not warm_only:
+                run.drain()  # as for the chunked engine below
+                _run_streamed(run, kernel, bp, host_X, data, hypers, idxs)
             continue
-
-        out_spec: Optional[_PackSpec] = None
-        exec_cost: Optional[Dict[str, float]] = None
-        if host_exec:
-            X_d = X
-            y_d = put(y_np)
-            TW_d, EW_d = put(plan.train_w), put(plan.eval_w)
-            chunk = min(max_trials_per_batch, len(idxs))
-            cache_key = ("host",) + _aot_key(
-                kernel, static, X, data.n_classes, plan.n_splits, chunk, hyper_names
-            )
-            with child_span("executor.compile", cache="hit") as csp:
-                fresh_compile = cache_key not in _compiled_cache
-                _cache_count(not fresh_compile)
-                if fresh_compile:
-                    # traced (the scope wraps every call of the Python body)
-                    # for the platform it runs on, not the default one
-                    raw = _backend.host_execution()(
-                        _make_batched(kernel, static, bool(hyper_names))
-                    )
-                    example = _example_args(
-                        X, y_np, plan.train_w, plan.eval_w, hyper_names, chunk
-                    )
-                    # cost captured on the pre-pack form: the executable's
-                    # priced work must not vary with the transport knob
-                    cost = _capture_cost(raw, example)
-                    spec = None
-                    if _packed_enabled():
-                        spec = _pack_spec_of(raw, example)
-                        raw = _pack_wrap(raw)
-                    _compiled_cache[cache_key] = (jax.jit(raw), spec, cost)
-                    csp.attrs["cache"] = "traced"
-                fn, out_spec, exec_cost = _compiled_cache[cache_key]
-
-        # Kernels with a fused batched path (e.g. the Pallas packed
-        # LogisticRegression fit, models/logistic.py) take over the whole
-        # chunk: one jitted call = fit scan + eval, with its own (larger)
-        # chunk geometry. On one device the call is the executable; on a
-        # mesh whose only axis is the trial axis every device runs the
-        # call on its share of the chunk under ``shard_map``
-        # (_shard_batched). A (trials, data) mesh, a custom scorer and a
-        # kernel that publishes no such path stay on the generic one.
-        batched_fn = None
-        extra_args = None
-        packed_mesh = (
-            mesh if not single_device and tuple(mesh.shape) == (trial_axis,)
-            else None
-        )
-        if (hasattr(kernel, "build_batched_fn")
-                and (single_device or packed_mesh is not None)
-                and not host_exec
-                and scoring is None):  # fused paths score by the default metric
-            # geometry from a device's share: the kernel names the weight
-            # block that holds it, the chunk is whole blocks a device
-            n_shard = n_dev if packed_mesh is not None else 1
-            share = -(-len(idxs) // n_shard)
-            Tw = kernel.batched_trial_block(share, plan.n_splits)
-            dev_chunk = max(Tw, min(kernel.batched_chunk_cap,
-                                    pad_to_multiple(share, Tw)))
-            batched_fn = kernel.build_batched_fn(
-                static=static,
-                n=n,
-                d=d,
-                n_classes=data.n_classes,
-                n_splits=plan.n_splits,
-                chunk=dev_chunk,
-            )
-
-        engine, dispatch_attrs = "generic", {}
-        if batched_fn is not None:
-            engine = "packed"
-            chunk = dev_chunk * n_shard
-            dispatch_attrs = {"block": Tw, "blocks": dev_chunk // Tw}
-            y_d, TW_d, EW_d = _dev_args()
-            X_d = X
-            # dispatch-invariant staged forms the kernel wants precomputed
-            # (e.g. the LogReg padded bf16 design matrix and the per-split
-            # Lipschitz bound): staged ONCE per (dataset, device, subkey)
-            # in the multi-tenant stage cache and merged into every
-            # dispatch's hyper dict — the per-dispatch jit stops paying
-            # for them. Keys ride the content fingerprint + the effective
-            # staged-X dtype (a bf16-staged matrix derives different
-            # values than f32).
-            if hasattr(kernel, "batched_staged_extras"):
-                specs = kernel.batched_staged_extras(
-                    static=static, n=n, d=d, n_classes=data.n_classes,
-                    n_splits=plan.n_splits, fold_signature=plan.signature,
-                )
-                if specs:
-                    def extra_ctx():
-                        ctx = {"X": X_d, "y": y_d, "TW": TW_d, "EW": EW_d,
-                               "decode": _stage_decode}
-                        if packed_mesh is not None:
-                            # built on ONE chip, from the single-device
-                            # entries the mesh forms were replicated from
-                            ctx["X"] = _staged_device(
-                                data, x_key + ("dev",),
-                                lambda: jax.tree_util.tree_map(
-                                    jnp.asarray, X_np),
-                            )
-                            if plan.signature is not None:
-                                ctx["y"], ctx["TW"], ctx["EW"] = (
-                                    _staged_device(
-                                        data, folds_key, _make_folds)
-                                )
-                        return ctx
-
-                    extra_args = {}
-                    for name in sorted(specs):
-                        subkey, make = specs[name]
-                        build = lambda m=make: m(extra_ctx())  # noqa: E731
-                        if subkey is None:
-                            # nothing stable to key on (e.g. an unsigned
-                            # fold plan): still hoisted out of the
-                            # per-dispatch jit, just not cached across runs
-                            extra_args[name] = build()
-                            continue
-                        ekey = ("batched_extra", kernel.name, name,
-                                stage_mode) + tuple(subkey)
-                        if packed_mesh is None:
-                            extra_args[name] = _staged_device(
-                                data, ekey, build)
-                        else:
-                            # like the data and the folds: one build, then
-                            # a copy to every chip over ICI
-                            extra_args[name] = _staged_mesh(
-                                data, ekey, ekey, build, None, mesh,
-                                trial_axis,
-                            )
-            # one key for both layers: _aot_key carries everything that
-            # determines the executable (incl. the interpret-mode env var,
-            # which is baked into the closure at build time, and the packed/
-            # staging transfer knobs)
-            cache_key = ("batched",) + _aot_key(
-                kernel, static, X, data.n_classes, plan.n_splits, chunk,
-                hyper_names, stage_mode=stage_mode,
-                # a mesh executable hands back the per-leaf dict (below)
-                packed=None if packed_mesh is None else False,
-            )
-            if packed_mesh is not None:
-                cache_key = cache_key + (_mesh_signature(packed_mesh),)
-            if extra_args:
-                # the staged extras join the executable's input signature
-                cache_key = cache_key + (
-                    "extras",
-                    tuple(
-                        (k, tuple(v.shape), str(v.dtype))
-                        for k, v in sorted(extra_args.items())
-                    ),
-                )
-            with child_span("executor.compile", cache="hit") as csp:
-                fresh_compile = cache_key not in _compiled_cache
-                _cache_count(not fresh_compile)
-                if fresh_compile:
-                    raw = batched_fn
-                    if stage_mode != "f32":
-                        # widen the compressed staged matrix before the fused
-                        # kernel sees it (it expects the f32 design matrix)
-                        raw = _decode_wrap(batched_fn)
-                    if packed_mesh is not None:
-                        # like every mesh executable (_lookup_compiled):
-                        # the per-leaf dict, no cost capture, no AOT blob
-                        compiled = _shard_batched(
-                            raw, packed_mesh, trial_axis, dev_chunk,
-                            hyper_names or ["_pad"], sorted(extra_args or ()),
-                        )
-                        spec = cost = None
-                        csp.attrs["cache"] = "traced"
-                    else:
-                        example = _example_args(
-                            X, y_np, plan.train_w, plan.eval_w, hyper_names,
-                            chunk)
-                        if extra_args:
-                            example[4].update(
-                                {k: _sds(v) for k, v in extra_args.items()}
-                            )
-                        cost = _capture_cost(raw, example)
-                        spec = None
-                        if _packed_enabled():
-                            spec = _pack_spec_of(raw, example)
-                            raw = _pack_wrap(raw)
-                        compiled, csp.attrs["cache"] = aot_jit(
-                            raw, cache_key, example
-                        )
-                    _compiled_cache[cache_key] = (compiled, spec, cost)
-                fn, out_spec, exec_cost = _compiled_cache[cache_key]
-        elif not host_exec:
-            y_d, TW_d, EW_d = _dev_args()
-            X_d = X
-            mem_cap = _memory_chunk_cap(kernel, n, d, static, plan.n_splits, n_dev)
-            chunk = min(max_trials_per_batch, mem_cap, pad_to_multiple(len(idxs), n_dev))
-            chunk = max(n_dev, pad_to_multiple(chunk, n_dev))
-
-        # split-axis chunking (same rationale as _run_chunked's): when even
-        # ONE minimum-size trial batch times all folds blows the memory
-        # budget — Nyström SVC's [n, m] feature matrix per split lane is
-        # the motivating case — run the folds across several dispatches
-        # over a fold-group-sized executable instead of OOMing the device.
-        # Budgets are PER DEVICE: at chunk == n_dev each device holds one
-        # trial's full fold stack, so fold memory does not divide by n_dev.
-        split_groups = None
-        if not host_exec and batched_fn is None:
-            per_split_mb = max(
-                kernel.memory_estimate_mb(n, d, static)
-                if hasattr(kernel, "memory_estimate_mb") else 0.5, 0.5)
-            budget_mb = 0.5 * _backend.device_memory_mb()
-            n_splits = int(plan.n_splits)
-            if chunk == n_dev and per_split_mb * n_splits > budget_mb:
-                sgn = max(1, min(n_splits, int(budget_mb / per_split_mb)))
-                if sgn < n_splits:
-                    split_groups = []
-                    for s0 in range(0, n_splits, sgn):
-                        size = min(sgn, n_splits - s0)
-                        twg = plan.train_w[s0 : s0 + size]
-                        ewg = plan.eval_w[s0 : s0 + size]
-                        if size < sgn:  # pad by repeating; cols dropped later
-                            twg = np.concatenate(
-                                [twg, np.repeat(twg[-1:], sgn - size, 0)])
-                            ewg = np.concatenate(
-                                [ewg, np.repeat(ewg[-1:], sgn - size, 0)])
-                        split_groups.append(
-                            (jnp.asarray(twg), jnp.asarray(ewg), size))
-            if split_groups is not None:
-                TW_g = split_groups[0][0]
-                fn, out_spec, exec_cost, fresh_compile = _get_compiled(
-                    kernel, static_key, static, mesh, trial_axis, data, plan,
-                    chunk, hyper_names, X, y_np,
-                    np.asarray(TW_g), np.asarray(split_groups[0][1]),
-                    n_splits_override=int(TW_g.shape[0]),
-                    stage_mode=stage_mode,
-                )
-            else:
-                fn, out_spec, exec_cost, fresh_compile = _get_compiled(
-                    kernel, static_key, static, mesh, trial_axis, data, plan,
-                    chunk, hyper_names, X, y_np, plan.train_w, plan.eval_w,
-                    stage_mode=stage_mode,
-                )
-
-        if warm_only:
-            # prewarm: executables constructed + tensors staged above —
-            # the cold path a first trial would otherwise pay inline —
-            # but nothing dispatches and no results exist
+        X = _stage_X(data, x_key, host_X, place,
+                     replicate_only=bp.engine == "chunked")
+        if bp.engine == "chunked":
+            # the chunked bucket runs blocking: flush what is queued first,
+            # or its wall would be counted inside the dispatch window
+            run.drain()
+            _run_chunked(run, kernel, bp, X, run.folds(data, place), data,
+                         hypers, idxs, warm_only)
             continue
+        folds = run.folds(data, place)
+        extras = None
+        if bp.engine == "packed":
+            extras = _stage_extras(kernel, bp, data, split_plan, x_key, host_X,
+                                   X, folds)
+        exe, fresh = _bucket_executable(
+            kernel, bp, static_key, data, split_plan, X, extras
+        )
+        # prewarm stops here: executables constructed, tensors staged —
+        # the cold path a first trial would otherwise pay inline
+        if not warm_only:
+            _dispatch(run, bp, exe, fresh, X, folds, extras, hypers, idxs)
 
-        if host_exec:
-            to_dev = put
-        elif single_device:
-            to_dev = jnp.asarray
-        else:
-            # placed trial-sharded straight from the host: jnp.asarray
-            # would land on device 0 and be resharded every dispatch
-            to_dev = functools.partial(
-                jax.device_put, device=NamedSharding(mesh, P(trial_axis))
-            )
-        for start in range(0, len(idxs), chunk):
-            with _dispatch_span(mesh, engine, start // chunk, chunk,
-                                min(chunk, len(idxs) - start),
-                                **dispatch_attrs):
-                batch_idx = idxs[start : start + chunk]
-                T = len(batch_idx)
-                if hyper_names:
-                    hyper_batch = {
-                        k: np.full((chunk,), hypers[batch_idx[-1]][k], np.float32)
-                        for k in hyper_names
-                    }
-                    for j, gi in enumerate(batch_idx):
-                        for k in hyper_names:
-                            hyper_batch[k][j] = hypers[gi][k]
-                else:
-                    hyper_batch = {"_pad": np.zeros((chunk,), np.float32)}
-                hyper_arg = {k: to_dev(v) for k, v in hyper_batch.items()}
-                if extra_args:
-                    hyper_arg = {**hyper_arg, **extra_args}
-
-                t0 = time.perf_counter()
-                if t_first_dispatch is None:
-                    t_first_dispatch = t0
-                if split_groups is not None:
-                    group_outs = []
-                    for gi_, (twg, ewg, size) in enumerate(split_groups):
-                        out_g = fn(X_d, y_d, twg, ewg, hyper_arg)
-                        dispatches += 1
-                        _acc_cost(exec_cost)
-                        if fresh_compile and start == 0 and gi_ == 0:
-                            # attribute the XLA compile to the FIRST group only;
-                            # later groups reuse the executable and their device
-                            # time is steady run time, not compile
-                            out_g = jax.block_until_ready(out_g)
-                            compile_time += time.perf_counter() - t0
-                            observe("tpuml_executor_compile_seconds",
-                                    time.perf_counter() - t0)
-                        if out_spec is not None:
-                            out_g = _Packed(out_g, out_spec)
-                        group_outs.append((out_g, size))
-                    pending.append((group_outs, batch_idx))
-                    continue
-                out = fn(X_d, y_d, TW_d, EW_d, hyper_arg)
-                if fresh_compile and start == 0:
-                    # block only on a fresh executable's first dispatch so its
-                    # XLA compile is attributed; steady-state dispatches queue
-                    out = jax.block_until_ready(out)
-                    compile_time += time.perf_counter() - t0
-                    observe("tpuml_executor_compile_seconds",
-                            time.perf_counter() - t0)
-                if out_spec is not None:
-                    out = _Packed(out, out_spec)
-                if mesh is not None and n_dev > 1:
-                    # collective argmax over the trial-sharded score vector: XLA
-                    # inserts the ICI all-gather/reduce; only two replicated
-                    # scalars come back to host per chunk
-                    bi, bs = _chunk_best(
-                        mesh, trial_axis, chunk, int(plan.n_splits), plan.n_folds
-                    )(out["score"], jnp.int32(T))
-                    pending_best.append((bi, bs, batch_idx))
-                    n_result_devices = max(
-                        n_result_devices, len(out["score"].sharding.device_set)
-                    )
-                pending.append((out, batch_idx))
-                dispatches += 1
-                _acc_cost(exec_cost)
-
-    _drain()
-
-    return TrialRunResult(
-        trial_metrics=[r for r in results if r is not None],
-        compile_time_s=compile_time,
-        run_time_s=run_time,
-        n_dispatches=dispatches,
-        device_best=device_best,
-        n_host_fetches=n_fetches,
-        result_bytes=result_bytes,
-        stage_time_s=_PHASE.stage,
-        fetch_time_s=_PHASE.fetch,
-        model_flops=model_flops if acct and buckets_priced else None,
-        xla_flops=xla_flops if acct and xla_flops > 0 else None,
-        bytes_accessed=xla_bytes if acct and xla_bytes > 0 else None,
-        flops_coverage=(
-            buckets_priced / n_buckets if acct and n_buckets else None
-        ),
-        hbm_peak_bytes=(
-            _backend.hbm_peak_bytes(
-                list(mesh.devices.flat) if mesh is not None else None
-            )
-            if acct else None
-        ),
-        n_result_devices=n_result_devices,
-    )
+    run.drain()
+    return run.result(mesh)
 
 
 def fit_single(
@@ -1595,10 +1566,7 @@ def fit_single(
     only the winner), and per CV fold by the callable-scoring fallback."""
     n, d = data.X.shape
     static_key, hyper = kernel.canonicalize(params)
-    static = kernel.static_from_key(static_key)
-    if hasattr(kernel, "resolve_static"):
-        static = kernel.resolve_static(static, n, d, data.n_classes)
-    static["_n_classes"] = data.n_classes
+    static = _resolved_static(kernel, static_key, n, d, data.n_classes)
 
     if hasattr(kernel, "prepare_data"):
         X = jax.tree_util.tree_map(
@@ -1645,7 +1613,7 @@ def fit_single(
             parts.append(part)  # device arrays: dispatches pipeline
         n_units = int(static.get("n_estimators", 100))
         for p in parts:
-            _prefetch_async(p)
+            prefetch_async(p)
         parts = [jax.tree_util.tree_map(np.asarray, p) for p in parts]
         trees = jax.tree_util.tree_map(
             lambda *xs: np.concatenate(xs, axis=0)[:n_units], *parts
@@ -1727,7 +1695,7 @@ def _chunk_best(mesh, trial_axis: str, chunk: int, n_splits: int, n_folds: int):
     what makes XLA emit the cross-chip collective (all-gather or reduce over
     ICI on TPU meshes). ``n_valid`` masks padding lanes; non-finite scores
     rank last, mirroring _postprocess's diverged-trial rule."""
-    key = ("chunk_best", chunk, n_splits, n_folds, _mesh_signature(mesh))
+    key = ("chunk_best", chunk, n_splits, n_folds) + _mesh_key(mesh)
     if key in _compiled_cache:
         return _compiled_cache[key]
 
@@ -1751,154 +1719,8 @@ def _chunk_best(mesh, trial_axis: str, chunk: int, n_splits: int, n_folds: int):
     return fn
 
 
-def _memory_chunk_cap(kernel, n, d, static, n_splits, n_dev) -> int:
-    """Trials per dispatch bounded by per-device HBM: each in-flight trial
-    holds ~memory_estimate_mb per split concurrently under the split vmap."""
-    per_trial_mb = max(kernel.memory_estimate_mb(n, d, static), 0.5) * max(n_splits, 1)
-    budget_mb = 0.5 * _backend.device_memory_mb() * max(n_dev, 1)
-    return max(n_dev, int(budget_mb / per_trial_mb))
-
-
-def _mesh_signature(mesh):
-    """Stable executable-cache key for a Mesh: axis names/sizes + device
-    ids. ``id(mesh)`` (the previous key) could serve a stale sharded
-    executable if a Mesh was GC'd and a different Mesh landed on the
-    recycled address (VERDICT r2 weak #6)."""
-    if mesh is None:
-        return None
-    return (
-        tuple(mesh.shape.items()),
-        tuple(int(d.id) for d in mesh.devices.flat),
-    )
-
-
-def _get_compiled(*args, **kwargs):
-    """:func:`_lookup_compiled` under an ``executor.compile`` span whose
-    ``cache`` attribute says where the executable came from (``hit``: this
-    process's cache; ``aot``: a disk blob; ``traced``: built here). Returns
-    (fn, pack_spec_or_None, cost_or_None, fresh)."""
-    with child_span("executor.compile") as sp:
-        fn, spec, cost, source = _lookup_compiled(*args, **kwargs)
-        sp.attrs["cache"] = source
-    return fn, spec, cost, source != "hit"
-
-
-def _lookup_compiled(kernel, static_key, static, mesh, trial_axis, data, plan, chunk,
-                     hyper_names, X_proto=None, y=None, TW=None, EW=None,
-                     n_splits_override=None, stage_mode="f32"):
-    """The generic (vmapped) executable of a bucket. Returns (fn,
-    pack_spec_or_None, cost_or_None, source). Single-device
-    executables take the packed-output form (one uint8 result buffer, see
-    _pack_wrap) and carry their XLA cost analysis (captured once, at
-    construction); mesh executables keep the per-leaf dict — their score
-    vector feeds the on-device collective argmax and the cross-process
-    collective fetch — and skip cost capture (sharded lowering would pay a
-    second full trace; the analytical bucket accounting still prices
-    them). A mesh executable built here is partitioned by XLA and so
-    traced on the XLA formulations (:func:`_xla_only`); the one mesh
-    executable that keeps a Pallas kernel is the packed engine's
-    (:func:`_shard_batched`, built in ``_run_trials_impl``), with the same
-    output form."""
-    has_hyper = bool(hyper_names)
-    n_splits_key = n_splits_override or plan.n_splits
-    # a 1-device mesh is compilation-equivalent to no mesh: drop the
-    # NamedShardings so the executable is AOT-exportable and its disk key is
-    # mesh-independent (single chip is the bench/measure environment)
-    n_mesh_dev = int(np.prod(list(mesh.shape.values()))) if mesh is not None else 1
-    if n_mesh_dev == 1:
-        mesh = None
-    x_sig = (
-        tuple(
-            (tuple(a.shape), str(a.dtype))
-            for a in jax.tree_util.tree_leaves(X_proto)
-        )
-        if X_proto is not None else None
-    )
-    cache_key = (
-        kernel.name,
-        # trace-time env knobs (fused-step/curves/... valves) change the
-        # traced program without landing in static — without the salt a
-        # mid-process valve flip would serve a stale executable from this
-        # in-memory cache (the disk _aot_key below already carries it)
-        kernel.trace_salt(),
-        tuple(sorted((k, str(v)) for k, v in static.items())),
-        data.X.shape,
-        x_sig,
-        stage_mode,
-        _packed_enabled(),
-        data.n_classes,
-        n_splits_key,
-        chunk,
-        _mesh_signature(mesh),
-    )
-    if cache_key in _compiled_cache:
-        _cache_count(True)
-        fn, spec, cost = _compiled_cache[cache_key]
-        return fn, spec, cost, "hit"
-    _cache_count(False)
-
-    batched = _make_batched(kernel, static, has_hyper)
-    if stage_mode != "f32":
-        # widen the compressed staged matrix to f32 before the vmapped fits
-        batched = _decode_wrap(batched)
-
-    if mesh is not None:
-        batched = _xla_only(batched)
-        replicated = NamedSharding(mesh, P())
-        trial_sharded = NamedSharding(mesh, P(trial_axis))
-        # 2-D mesh (trials, data): additionally shard the sample dimension of
-        # the dataset arrays across the data axis — XLA inserts the psum/
-        # all-gather collectives inside each trial's fit (batch parallelism
-        # within a trial, trial parallelism across the other axis)
-        data_axis = next((a for a in mesh.shape if a != trial_axis), None)
-        if data_axis is not None and X_proto is not None:
-            # shared with _staged_mesh: the staged placement and these
-            # in_shardings must agree or every dispatch re-shards
-            leaf_sharding = _mesh_leaf_sharding_fn(
-                mesh, data_axis, _data_row_count(data)
-            )
-            X_shardings = jax.tree_util.tree_map(leaf_sharding, X_proto)
-            y_sh = NamedSharding(mesh, P(data_axis))
-            w_sh = NamedSharding(mesh, P(None, data_axis))
-            fn = jax.jit(
-                batched,
-                in_shardings=(X_shardings, y_sh, w_sh, w_sh, trial_sharded),
-                out_shardings=trial_sharded,
-            )
-        else:
-            fn = jax.jit(
-                batched,
-                in_shardings=(replicated, replicated, replicated, replicated, trial_sharded),
-                out_shardings=trial_sharded,
-            )
-        spec = None
-        cost = None
-        source = "traced"
-    else:
-        X_ex = X_proto if X_proto is not None else jax.ShapeDtypeStruct(
-            data.X.shape, jnp.float32
-        )
-        example = _example_args(X_ex, y, TW, EW, hyper_names, chunk)
-        disk_key = ("generic",) + _aot_key(
-            kernel, static, X_ex, data.n_classes, n_splits_key, chunk,
-            hyper_names, stage_mode=stage_mode,
-        )
-        cost = _capture_cost(batched, example)
-        spec = None
-        if _packed_enabled():
-            spec = _pack_spec_of(batched, example)
-            batched = _pack_wrap(batched)
-        fn, source = aot_jit(batched, disk_key, example)
-    _compiled_cache[cache_key] = (fn, spec, cost)
-    return fn, spec, cost, source
-
-
-def _run_chunked(
-    kernel, static, X, y, TW, EW, hypers, idxs, results,
-    plan: SplitPlan, chunk_plan: Dict[str, Any], hyper_names, data,
-    mesh: Optional[Mesh] = None, trial_axis: str = "trials",
-    warm_only: bool = False,
-):
+def _run_chunked(run: _Run, kernel, bp: BucketPlan, X, folds, data, hypers,
+                 idxs, warm_only: bool = False) -> None:
     """Run one bucket through the kernel's chunked-fit protocol.
 
     init -> n_chunks x step -> eval, all vmapped over (trials, splits); the
@@ -1906,16 +1728,17 @@ def _run_chunked(
     per-tree predictions for a forest). Dispatches are NOT synchronized
     between steps — they pipeline on the device queue; only eval's output is
     fetched (packed into one byte buffer on the single-device path, so the
-    whole bucket's scores cross the link as ONE transfer). With ``mesh``,
-    the trial axis of hypers and state is NamedSharded across devices (data
-    replicated) so each chip carries its trial slice through every chunk.
-    Returns (compile_time, run_time, n_dispatches, device_best,
-    n_host_fetches, result_bytes, n_result_devices) — device_best is the
-    collective-argmax winner (submission-order trial index, score) on
-    multi-device meshes with an unsplit fold stack, else None.
+    whole bucket's scores cross the link as ONE transfer). On a mesh the
+    trial axis of hypers and state is NamedSharded across devices (data
+    replicated) so each chip carries its trial slice through every chunk,
+    and a chunk with an unsplit fold stack merges its collective-argmax
+    winner into the run. The bucket runs blocking, chunk by chunk.
     """
+    static, chunk_plan, chunk = bp.static, bp.chunk_plan, bp.chunk
+    hyper_names = bp.hyper_names
+    mesh, trial_axis = bp.placement.mesh, bp.placement.trial_axis
+    y, TW, EW = folds
     n_chunks = int(chunk_plan["n_chunks"])
-    n_dev = int(np.prod(list(mesh.shape.values()))) if mesh is not None else 1
 
     from ..obs.curves import curve_points, curves_enabled
 
@@ -1956,148 +1779,68 @@ def _run_chunked(
     if mesh is not None:
         vinit, vstep, veval = _xla_only(vinit), _xla_only(vstep), _xla_only(veval)
 
-    # trial-chunk size: bounded by BOTH the cross-dispatch state memory and
-    # the kernel's per-trial working-set estimate (histogram buffers etc. —
-    # the same cap the non-chunked path consults)
-    state_mb = 4.0 * data.n_samples * max(data.n_classes, 1) * plan.n_splits / 1e6
-    mem_cap = _memory_chunk_cap(kernel, data.n_samples, data.n_features, static,
-                                plan.n_splits, n_dev)
-    chunk = max(1, min(len(idxs), mem_cap,
-                       int(0.25 * n_dev * _backend.device_memory_mb() / max(state_mb, 1.0)),
-                       64 * n_dev))
-    chunk = max(n_dev, pad_to_multiple(chunk, n_dev))
-
-    # split-axis chunking: the per-trial working set is multiplied by
-    # n_splits inside the split vmap, so when even ONE trial's splits blow
-    # the budget (deep/wide trees at large n), run the folds across several
-    # dispatches instead of dispatching past HBM
-    n_splits = int(plan.n_splits)
-    sg = n_splits
-    per_split_mb = max(kernel.memory_estimate_mb(
-        data.n_samples, data.n_features, static), 0.5)
-    budget_mb = 0.5 * _backend.device_memory_mb()
-    if chunk == 1 and per_split_mb * n_splits > budget_mb:
-        sg = max(1, min(n_splits, int(budget_mb / per_split_mb)))
-
-    split_groups = []
-    for s0 in range(0, n_splits, sg):
-        size = min(sg, n_splits - s0)
-        twg, ewg = TW[s0 : s0 + size], EW[s0 : s0 + size]
-        if size < sg:  # pad by repeating a fold; padded cols dropped below
-            twg = jnp.concatenate([twg, jnp.repeat(twg[-1:], sg - size, 0)])
-            ewg = jnp.concatenate([ewg, jnp.repeat(ewg[-1:], sg - size, 0)])
-        split_groups.append((twg, ewg, size))
-    TW_ex, EW_ex = split_groups[0][0], split_groups[0][1]
-
-    # packed=False: init/step executables never pack (their state stays on
-    # device), so their disk blobs must survive CS230_PACKED_FETCH flips;
-    # only chunk_eval's key (below) carries the live flag
-    base_key_parts = _aot_key(
-        kernel, static, X, data.n_classes, sg, chunk, hyper_names,
-        packed=False,
+    sg = bp.split_width or int(run.split_plan.n_splits)
+    split_groups = _split_groups(TW, EW, sg)
+    base = _aot_key(
+        kernel, static, X, data.n_classes, sg, chunk, hyper_names
     ) + (n_chunks, chunk_plan.get("trees_per_chunk"))
-    cache_tag = ("chunked",) + base_key_parts + (_packed_enabled(),) + (
-        (_mesh_signature(mesh),) if mesh is not None else ()
-    )
-    compile_time = 0.0
-    run_time = 0.0
-    dispatches = 0
-    n_fetches = 0
-    result_bytes = 0
-    device_best = None
-    n_result_devices = 1
-    with child_span("executor.compile", cache="hit") as csp:
-        fresh = cache_tag not in _compiled_cache
-        _cache_count(not fresh)
-        if fresh:
-            # compile_time counts executable construction (trace or AOT
-            # deserialize) only — the first batch's wall time is real chunked
-            # compute and is NOT compile (an earlier version attributed it,
-            # inflating the metric even on full AOT-cache hits). XLA compiles of
-            # freshly traced executables still land in the first batch's
-            # run_time; the persistent compile cache keeps that small.
-            t_build = time.perf_counter()
-            hyper_ex = {
-                k: jax.ShapeDtypeStruct((chunk,), jnp.float32)
-                for k in (hyper_names or ["_pad"])
-            }
-            if mesh is not None:
-                # sharded chunked protocol: trial axis (hypers, state, outputs)
-                # split across the mesh, dataset/fold masks replicated. Mesh
-                # executables are process-local — no AOT export.
-                repl = NamedSharding(mesh, P())
-                tsh = NamedSharding(mesh, P(trial_axis))
-                X_sh = jax.tree_util.tree_map(lambda _: repl, X)
-                h_sh = {k: tsh for k in hyper_ex}
-                state_ex = jax.eval_shape(vinit, X, y, TW_ex, EW_ex, hyper_ex)
-                st_sh = jax.tree_util.tree_map(lambda _: tsh, state_ex)
-                out_ex = jax.eval_shape(veval, X, y, TW_ex, EW_ex, hyper_ex, state_ex)
-                fi = jax.jit(
-                    vinit,
-                    in_shardings=(X_sh, repl, repl, repl, h_sh),
-                    out_shardings=st_sh,
-                )
-                fs = jax.jit(
-                    vstep,
-                    in_shardings=(X_sh, repl, repl, repl, h_sh, repl, st_sh),
-                    out_shardings=st_sh,
-                )
-                fe = jax.jit(
-                    veval,
-                    in_shardings=(X_sh, repl, repl, repl, h_sh, st_sh),
-                    out_shardings=jax.tree_util.tree_map(lambda _: tsh, out_ex),
-                )
-                fe_spec = None
-            else:
-                Xe = jax.tree_util.tree_map(_sds, X)
-                args_ie = (Xe, _sds(y), _sds(TW_ex), _sds(EW_ex), hyper_ex)
-                fi, _ = aot_jit(vinit, ("chunk_init",) + base_key_parts, args_ie)
-                state_ex = jax.eval_shape(vinit, X, y, TW_ex, EW_ex, hyper_ex)
-                args_e = args_ie + (jax.tree_util.tree_map(_sds, state_ex),)
-                fs, _ = aot_jit(
-                    vstep,
-                    ("chunk_step",) + base_key_parts,
-                    args_ie + (jax.ShapeDtypeStruct((), jnp.int32),)
-                    + (jax.tree_util.tree_map(_sds, state_ex),),
-                )
-                # only eval's output crosses to host: pack it (init/step state
-                # stays device-resident across the pipelined dispatches)
-                ev = veval
-                fe_spec = None
-                if _packed_enabled():
-                    fe_spec = _pack_spec_of(veval, args_e)
-                    ev = _pack_wrap(veval)
-                fe, src = aot_jit(
-                    ev,
-                    ("chunk_eval",) + base_key_parts + (_packed_enabled(),),
-                    args_e,
-                )
-            _compiled_cache[cache_tag] = (fi, fs, fe, fe_spec)
-            csp.attrs["cache"] = "traced" if mesh is not None else src
-            compile_time += time.perf_counter() - t_build
-            observe("tpuml_executor_compile_seconds", compile_time)
-    fi, fs, fe, fe_spec = _compiled_cache[cache_tag]
 
+    def parts():
+        args_i = _example_args(X, y, *split_groups[0][:2], hyper_names, chunk)
+        state_ex = jax.eval_shape(vinit, *args_i)
+        args_s = args_i + (jax.ShapeDtypeStruct((), jnp.int32), state_ex)
+        args_e = args_i + (state_ex,)
+        if mesh is None:
+            # only eval's output crosses to the host; no dispatch of the
+            # protocol is priced (the bucket's analytical FLOPs are)
+            return [
+                _Part(vinit, args_i, ("chunk_init",) + base,
+                      to_host=False, priced=False),
+                _Part(vstep, args_s, ("chunk_step",) + base,
+                      to_host=False, priced=False),
+                _Part(veval, args_e, ("chunk_eval",) + base, priced=False),
+            ]
+        repl = NamedSharding(mesh, P())
+        tsh = NamedSharding(mesh, P(trial_axis))
+
+        def trial_sharded(tree):
+            return jax.tree_util.tree_map(lambda _: tsh, tree)
+
+        ins = (jax.tree_util.tree_map(lambda _: repl, X), repl, repl, repl,
+               trial_sharded(args_i[4]))
+        st_sh = trial_sharded(state_ex)
+
+        def jit(ins, outs):
+            return lambda fn: jax.jit(fn, in_shardings=ins, out_shardings=outs)
+
+        return [
+            _Part(vinit, args_i, mesh_jit=jit(ins, st_sh)),
+            _Part(vstep, args_s, mesh_jit=jit(ins + (repl, st_sh), st_sh)),
+            _Part(veval, args_e, mesh_jit=jit(
+                ins + (st_sh,), trial_sharded(jax.eval_shape(veval, *args_e)))),
+        ]
+
+    # compile_time here is executable construction (trace or AOT load)
+    # only: the first batch's wall is real chunked compute. XLA compiles of
+    # freshly traced executables land in the first batch's run_time; the
+    # persistent compile cache keeps that small.
+    t_build = time.perf_counter()
+    ((fi, _, _), (fs, _, _), (fe, fe_spec, _)), fresh = _build_executable(
+        ("chunked",) + base + _mesh_key(mesh), parts
+    )
+    if fresh:
+        dt = time.perf_counter() - t_build
+        run.compile_time += dt
+        observe("tpuml_executor_compile_seconds", dt)
     if warm_only:
-        # prewarm: the init/step/eval executables are constructed (AOT
-        # deserialize or trace) and the staged tensors uploaded; nothing
-        # dispatches
-        return compile_time, 0.0, 0, None, 0, 0, 1
+        return
 
     for start in range(0, len(idxs), chunk):
         batch_idx = idxs[start : start + chunk]
-        if hyper_names:
-            hyper_arg = {
-                k: jnp.asarray(
-                    [hypers[gi][k] for gi in batch_idx]
-                    + [hypers[batch_idx[-1]][k]] * (chunk - len(batch_idx)),
-                    jnp.float32,
-                )
-                for k in hyper_names
-            }
-        else:
-            hyper_arg = {"_pad": jnp.zeros((chunk,), jnp.float32)}
-
+        hyper_arg = {
+            k: jnp.asarray(v) for k, v in
+            _hyper_batch(hypers, batch_idx, hyper_names, chunk).items()
+        }
         t0 = time.perf_counter()
         with _dispatch_span(mesh, "chunked", start // chunk, chunk,
                             len(batch_idx)):
@@ -2123,47 +1866,30 @@ def _run_chunked(
                         mids.append(fe(X, y, twg, ewg, hyper_arg, state))
                 group_outs.append((fe(X, y, twg, ewg, hyper_arg, state), size))
                 group_curves.append(mids)
-                dispatches += len(mids)
+                run.dispatches += len(mids)
         if mesh is not None:
-            n_result_devices = max(
-                n_result_devices,
-                len(group_outs[0][0]["score"].sharding.device_set),
+            score = group_outs[0][0]["score"]
+            run.n_result_devices = max(
+                run.n_result_devices, len(score.sharding.device_set)
             )
-        if mesh is not None and len(split_groups) == 1:
-            # collective argmax on the trial-sharded eval output (see
-            # run_trials' generic path); split-group runs skip it — their
-            # fold means span executables
-            bi, bs = _chunk_best(mesh, trial_axis, chunk, sg, plan.n_folds)(
-                group_outs[0][0]["score"], jnp.int32(len(batch_idx))
-            )
-            pos, score = int(bi), float(bs)
-            n_fetches += 2
-            if pos < len(batch_idx) and np.isfinite(score) and (
-                device_best is None or score > device_best[1]
-            ):
-                device_best = (batch_idx[pos], score)
+            if len(split_groups) == 1:
+                # collective argmax on the trial-sharded eval output, read
+                # at once (the bucket runs blocking); split-group runs skip
+                # it: their fold means span executables
+                bi, bs = _chunk_best(
+                    mesh, trial_axis, chunk, sg, run.split_plan.n_folds
+                )(score, jnp.int32(len(batch_idx)))
+                pos, best = int(bi), float(bs)
+                run.n_fetches += 2
+                if pos < len(batch_idx) and np.isfinite(best):
+                    run.merge_best(batch_idx[pos], best)
         for og, _size in group_outs:
-            _prefetch_async(og)
-        fetched = []
-        for og, size in group_outs:
-            host, nf, nb = _fetch_result(og, fe_spec)
-            n_fetches += nf
-            result_bytes += nb
-            fetched.append((host, size))
-        group_outs = fetched
-        mids_host = []
-        for mids in group_curves:
-            row = []
-            for og in mids:
-                host, nf, nb = _fetch_result(og, fe_spec)
-                n_fetches += nf
-                result_bytes += nb
-                row.append(host)
-            mids_host.append(row)
-        out = {
-            k: np.concatenate([og[k][:, :size] for og, size in group_outs], axis=1)
-            for k in group_outs[0][0]
-        }
+            prefetch_async(og)
+        group_outs = [(run.fetch(og, fe_spec), size) for og, size in group_outs]
+        mids_host = [
+            [run.fetch(og, fe_spec) for og in mids] for mids in group_curves
+        ]
+        out = _join_split_groups(group_outs)
         if curve_stride:
             cs = [
                 np.stack(
@@ -2177,22 +1903,13 @@ def _run_chunked(
             shape2 = out["score"].shape[:2]
             out["curve_stride"] = np.full(shape2, float(curve_stride), np.float32)
             out["curve_steps"] = np.full(shape2, float(n_chunks), np.float32)
-        run_time += time.perf_counter() - t0
-        dispatches += (2 + n_chunks) * len(split_groups)
-
-        for j, gi in enumerate(batch_idx):
-            results[gi] = _postprocess(
-                out, j, plan, kernel.task, static.get("_scoring")
-            )
-
-    return (compile_time, run_time, dispatches, device_best, n_fetches,
-            result_bytes, n_result_devices)
+        run.run_time += time.perf_counter() - t0
+        run.dispatches += (2 + n_chunks) * len(split_groups)
+        run.record(out, batch_idx)
 
 
-def _run_streamed(
-    kernel, static, X_np, y_np, hypers, idxs, results,
-    plan: SplitPlan, hyper_names, data, max_trials_per_batch: int,
-):
+def _run_streamed(run: _Run, kernel, bp: BucketPlan, X_np, data, hypers,
+                  idxs) -> None:
     """Run one bucket through the kernel's out-of-core streaming driver.
 
     The full design matrix never stages: ``kernel.stream_form`` names the
@@ -2206,42 +1923,25 @@ def _run_streamed(
     judges each alone); the block cache keys carry
     ``host_signature()`` + the kernel's trace_salt + the staged form.
 
-    Returns ``(run_time, n_dispatches)``; the consumer's blocked
-    block-wait time lands in ``_PHASE.stage`` like any other staging
-    wall (the hidden share is devprof's ``stream`` phase).
+    The bucket runs blocking; the consumer's blocked block-wait time
+    lands in ``_PHASE.stage`` like any other staging wall (the hidden
+    share is devprof's ``stream`` phase).
     """
-    from ..data import stage_cache as _sc
     from ..data.streaming import (
         RowBlockStreamer, array_block_source, plan_blocks,
     )
 
-    blockable, form_salt = kernel.stream_form(X_np, static)
+    split_plan = run.split_plan
+    blockable, form_salt = kernel.stream_form(X_np, bp.static)
     n = int(blockable.shape[0])
     row_bytes = int(blockable.nbytes // max(n, 1))
     bplan = plan_blocks(n, row_bytes)
-    # prepare_data kernels stream already-compact prepared forms (binned
-    # int codes) — the f32-cast compressor would corrupt them; raw-matrix
-    # kernels reuse the CS230_STAGE_DTYPE link compression per block
-    stage_mode = (
-        "f32" if hasattr(kernel, "prepare_data")
-        else _resolve_stage_mode(_staging_dtype())
-    )
-    if stage_mode == "f32":
-        def to_device(blk):
-            return jnp.asarray(blk)
-    else:
-        def to_device(blk):
-            return jax.tree_util.tree_map(
-                jnp.asarray, _stage_compress(blk, stage_mode)
-            )
-
     base_key = (
         _sc.dataset_fingerprint(data), _sc.host_signature(), "block",
-        kernel.name, kernel.trace_salt(), tuple(form_salt), stage_mode,
-        bplan.rows,
+        kernel.name, kernel.trace_salt(), tuple(form_salt), bplan.rows,
     )
     streamer = RowBlockStreamer(
-        base_key, array_block_source(blockable, bplan), to_device, bplan,
+        base_key, array_block_source(blockable, bplan), jnp.asarray, bplan,
         row_shape=tuple(blockable.shape[1:]),
     )
 
@@ -2249,7 +1949,7 @@ def _run_streamed(
     pad = n_pad - n
 
     def _pad_y():
-        yv = np.asarray(y_np)
+        yv = np.asarray(data.y)
         return jnp.asarray(np.concatenate([yv, np.zeros((pad,), yv.dtype)]))
 
     def _pad_w(W):
@@ -2258,37 +1958,27 @@ def _run_streamed(
             np.concatenate([W, np.zeros((W.shape[0], pad), np.float32)], 1)
         )
 
-    if plan.signature is not None:
+    if split_plan.signature is not None:
         y_d = _staged_device(
-            data, ("stream_folds", plan.signature, n_pad, "y"), _pad_y
+            data, ("stream_folds", split_plan.signature, n_pad, "y"), _pad_y
         )
         TW_d = _staged_device(
-            data, ("stream_folds", plan.signature, n_pad, "tw"),
-            lambda: _pad_w(plan.train_w),
+            data, ("stream_folds", split_plan.signature, n_pad, "tw"),
+            lambda: _pad_w(split_plan.train_w),
         )
         EW_d = _staged_device(
-            data, ("stream_folds", plan.signature, n_pad, "ew"),
-            lambda: _pad_w(plan.eval_w),
+            data, ("stream_folds", split_plan.signature, n_pad, "ew"),
+            lambda: _pad_w(split_plan.eval_w),
         )
     else:
-        y_d, TW_d, EW_d = _pad_y(), _pad_w(plan.train_w), _pad_w(plan.eval_w)
+        y_d, TW_d, EW_d = (
+            _pad_y(), _pad_w(split_plan.train_w), _pad_w(split_plan.eval_w)
+        )
 
-    run_time = 0.0
-    dispatches = 0
-    chunk = min(max_trials_per_batch, len(idxs))
+    chunk = bp.chunk
     for start in range(0, len(idxs), chunk):
         batch_idx = idxs[start : start + chunk]
-        if hyper_names:
-            hyper_batch = {
-                k: np.asarray(
-                    [hypers[gi][k] for gi in batch_idx]
-                    + [hypers[batch_idx[-1]][k]] * (chunk - len(batch_idx)),
-                    np.float32,
-                )
-                for k in hyper_names
-            }
-        else:
-            hyper_batch = {"_pad": np.zeros((chunk,), np.float32)}
+        hyper_batch = _hyper_batch(hypers, batch_idx, bp.hyper_names, chunk)
         t0 = time.perf_counter()
         wait0 = streamer.stats["wait_s"]
         blocks0 = streamer.stats["blocks"]
@@ -2296,18 +1986,15 @@ def _run_streamed(
                             len(batch_idx)):
             score = np.asarray(
                 kernel.stream_scores(
-                    streamer, y_d, TW_d, EW_d, hyper_batch, static, n
+                    streamer, y_d, TW_d, EW_d, hyper_batch, bp.static, n
                 )
             )
         wall = time.perf_counter() - t0
         wait = streamer.stats["wait_s"] - wait0
         _PHASE.stage += wait
-        run_time += max(wall - wait, 0.0)
-        dispatches += streamer.stats["blocks"] - blocks0
-        out = {"score": score}
-        for j, gi in enumerate(batch_idx):
-            results[gi] = _postprocess(out, j, plan, kernel.task, None)
-    return run_time, dispatches
+        run.run_time += max(wall - wait, 0.0)
+        run.dispatches += streamer.stats["blocks"] - blocks0
+        run.record({"score": score}, batch_idx)
 
 
 def _postprocess(out: Dict[str, np.ndarray], j: int, plan: SplitPlan, task: str,

@@ -14,10 +14,8 @@ state survives process boundaries so the steady-state cost, not the cold
 cost, is what jobs pay.
 
 Entries are keyed by the executable identity (kernel/static/shapes/splits/
-chunk — trial_map._aot_key, which also folds in the transfer-layer knobs:
-the packed-output flag and the staging dtype, plus the staged leaves' own
-shape/dtype signature, so bf16/int8-staged and packed/per-leaf executables
-never collide with their f32/dict counterparts), the lowering platform, the
+chunk and the staged leaves' own shape/dtype signature: trial_map._aot_key
+behind the engine's tag), the lowering platform, the
 jax version, and a content fingerprint of this package's compute-path
 sources — a code change invalidates every blob, so a stale cache can never
 resurrect old kernel behavior. A blob that cannot be read back, or a

@@ -389,7 +389,7 @@ def test_packed_fn_staged_extras_bitwise(monkeypatch):
         fold_signature=("test", 1),
     )
     assert set(specs) == {"_logreg_ab", "_logreg_lam_max"}
-    ctx = {"X": X, "y": y, "TW": TW, "EW": EW, "decode": lambda x: x}
+    ctx = {"X": X, "y": y, "TW": TW, "EW": EW}
     extras = {name: make(ctx) for name, (subkey, make) in specs.items()}
     assert extras["_logreg_ab"].dtype == jnp.bfloat16
     assert extras["_logreg_lam_max"].shape == (S,)

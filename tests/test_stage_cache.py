@@ -1,8 +1,7 @@
 """Multi-tenant staged-dataset cache (data/stage_cache.py): single-flight
 uploads, content-fingerprint keying, refcounted LRU eviction under a
-device-memory budget, the CS230_STAGE_CACHE=0 parity valve, the
-CS230_STAGE_DTYPE=auto policy, and the upload-counter contract the
-concurrency benchmark (benchmarks/staging_concurrency.py) relies on."""
+device-memory budget, the CS230_STAGE_CACHE=0 parity valve, and the
+upload-counter contract the concurrency benchmark (benchmarks/staging_concurrency.py) relies on."""
 
 import threading
 import time
@@ -249,37 +248,6 @@ def test_logreg_packed_precomputes_staged_once(monkeypatch):
     assert sc.STAGE_CACHE.stats()["hits"] >= hits_before + 2
     for a, b in zip(first.trial_metrics, second.trial_metrics):
         assert a["mean_cv_score"] == pytest.approx(b["mean_cv_score"])
-
-
-# ---------------- auto staging dtype ----------------
-
-
-def test_auto_stage_dtype_resolution(monkeypatch):
-    monkeypatch.setenv("CS230_STAGE_DTYPE", "auto")
-    monkeypatch.setenv("CS230_STAGE_LINK_MBPS", "5")  # a slow link
-    assert tm._resolve_stage_mode(tm._staging_dtype()) in ("bf16", "f32")
-    try:
-        import ml_dtypes  # noqa: F401
-    except ImportError:
-        pytest.skip("ml_dtypes missing: auto degrades to f32")
-    assert tm._resolve_stage_mode(tm._staging_dtype()) == "bf16"
-    monkeypatch.setenv("CS230_STAGE_LINK_MBPS", "500")  # local-class link
-    assert tm._resolve_stage_mode(tm._staging_dtype()) == "f32"
-
-
-def test_auto_stage_dtype_stages_bf16_on_slow_link(monkeypatch):
-    try:
-        import ml_dtypes  # noqa: F401
-    except ImportError:
-        pytest.skip("ml_dtypes missing")
-    monkeypatch.setenv("CS230_STAGE_DTYPE", "auto")
-    monkeypatch.setenv("CS230_STAGE_LINK_MBPS", "5")
-    run = _run(_data(seed=13))
-    assert run.trial_metrics
-    assert any(
-        "bf16" in k for key in sc.STAGE_CACHE.uploads_by_key()
-        for k in key if isinstance(k, str)
-    )
 
 
 # ---------------- metrics catalog ----------------

@@ -361,11 +361,11 @@ def test_batched_functions_carry_scopes_and_kernel_names(
     ``tpuml.fit``, ``tpuml.eval`` or ``tpuml.pack`` (the op's label), the
     Mosaic kernel under its own name (the op's name). Lowering only: names
     are metadata, and the CPU lowering would drop the kernel's."""
-    from cs230_distributed_machine_learning_tpu.parallel import trial_map
+    from cs230_distributed_machine_learning_tpu.parallel import packing
 
     fn, args = build()
     text = (
-        jax.jit(trial_map._pack_wrap(fn)).trace(*args)
+        jax.jit(packing.pack_wrap(fn)).trace(*args)
         .lower(lowering_platforms=("tpu",)).as_text(debug_info=True)
     )
     names = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, flags=re.M))
@@ -378,7 +378,7 @@ def test_batched_functions_carry_scopes_and_kernel_names(
     assert f'kernel_name = "{kernel_name}"' in call
     # compiled for the chip, the kernel's instruction has the kernel's name
     # (a trace event's name) and the fit's scope in its op_name (its label)
-    compiled = _lower_and_compile(trial_map._pack_wrap(fn), *args)
+    compiled = _lower_and_compile(packing.pack_wrap(fn), *args)
     if compiled is not None:
         (instr,) = {m.group(0) for m in re.finditer(
             rf'%{kernel_name}[\w.]* = [^\n]*custom_call_target="tpu_custom_call"[^\n]*',
