@@ -252,13 +252,24 @@ class LogisticRegressionKernel(ModelKernel):
 
     def batched_trial_block(self, trials_per_device: int, n_splits: int) -> int:
         """Trials a packed weight block (``Tw``) for a device's share of a
-        bucket: the narrowest width the kernel admits that holds the share,
-        ``batched_trial_multiple`` at most. The engine rounds a device's
-        chunk up to it and ``build_batched_fn`` reads the same width back
-        from that chunk."""
+        bucket: the width that holds the share with the fewest packed
+        columns (16, 32, 64 or ``batched_trial_multiple``; each class slab
+        is padded to whole 128-lane vregs, so every width runs). The engine
+        rounds a device's chunk up to it and ``build_batched_fn`` reads the
+        same width back from that chunk."""
         from ..ops.pallas_logreg import packed_trial_block
 
         return packed_trial_block(trials_per_device, n_splits)
+
+    def batched_slab(self, block: int, n_splits: int) -> Dict[str, int]:
+        """What a weight block of ``block`` trials is made of, for the
+        engine's plan and dispatch span: ``slab_lanes`` lanes a class slab,
+        ``slab_pad_lanes`` of them dead columns (zero weights, sample
+        weight 0, dropped at unpack)."""
+        from ..ops.pallas_logreg import slab_lanes
+
+        lanes = slab_lanes(n_splits, block)
+        return {"slab_lanes": lanes, "slab_pad_lanes": lanes - n_splits * block}
 
     def batched_applicable(self, static: Dict[str, Any], n: int, d: int) -> bool:
         if static.get("_method") != "nesterov":
@@ -345,6 +356,7 @@ class LogisticRegressionKernel(ModelKernel):
             fused_step_applicable,
             packed_nesterov_step,
             packed_softmax_grad,
+            slab_lanes,
         )
 
         interpret = _backend.pallas_interpret()
@@ -354,7 +366,10 @@ class LogisticRegressionKernel(ModelKernel):
         lam = geo["lam"]
         steps = int(static.get("_iters", _NESTEROV_STEPS))
         n_wb = chunk // Tw
-        Bblk = S * Tw
+        # a class slab: S*Tw real (split, trial) columns, then dead ones up
+        # to whole vregs (zero weights; scores and curves dropped at unpack)
+        Breal = S * Tw
+        Bblk = slab_lanes(S, Tw)
         NB = c * Bblk
         dp, dpp = geo["dp"], geo["dpp"]
         bm = 256
@@ -373,9 +388,10 @@ class LogisticRegressionKernel(ModelKernel):
         tr_stride = trace_stride(steps) if capture else 1
         tr_used = -(-steps // tr_stride) if capture else 0
 
-        # static column maps: block col j -> (split, trial-in-block)
+        # static column maps: slab col j -> (split, trial-in-block); a dead
+        # column reads the last split's row, like any other valid index
         j = np.arange(Bblk)
-        split_of = j // Tw
+        split_of = (j // Tw).clip(max=S - 1)
         trial_map = (np.arange(n_wb)[:, None] * Tw + (j % Tw)[None, :]).clip(
             max=chunk - 1
         )
@@ -512,13 +528,16 @@ class LogisticRegressionKernel(ModelKernel):
                 den = jnp.maximum(jnp.sum(EW.astype(jnp.float32), axis=1), 1e-12)  # [S]
                 score_b = acc / den[split_of_j][None, :]
             with jax.named_scope("tpuml.pack"):
-                score = score_b.reshape(n_wb, S, Tw).transpose(0, 2, 1).reshape(chunk, S)
+                score = (
+                    score_b[:, :Breal].reshape(n_wb, S, Tw)
+                    .transpose(0, 2, 1).reshape(chunk, S)
+                )
                 out = {"score": score}
                 if capture:
                     # same lane->(trial, split) mapping as score, with the
                     # trace-slot axis carried along as a trailing dim
                     curve = (
-                        tr_out.transpose(1, 2, 0)
+                        tr_out.transpose(1, 2, 0)[:, :Breal]
                         .reshape(n_wb, S, Tw, tr_used)
                         .transpose(0, 2, 1, 3)
                         .reshape(chunk, S, tr_used)

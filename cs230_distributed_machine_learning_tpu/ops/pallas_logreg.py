@@ -14,11 +14,18 @@ streaming row tiles of the shared design matrix A through VMEM. The
 probabilities never touch HBM.
 
 Packing: all trials' weight columns are packed into one matrix with a
-**class-major** column layout, ``col = (a * S + s) * Tw + t`` per
-128-trial block (a = class, s = split, t = trial-in-block). The grouped
-softmax over classes then becomes elementwise ops over ``c`` statically
-sliced ``[bm, S*Tw]`` tiles — no lane shuffles, no padding of the class
-dimension, and the matmul minor dimension is fully lane-packed.
+**class-major** column layout, ``col = a * Bp + s * Tw + t`` per block of
+``Tw`` trials (a = class, s = split, t = trial-in-block), where the class
+slab ``Bp = slab_lanes(S, Tw)`` is ``S * Tw`` rounded up to whole 128-lane
+vregs. The grouped softmax over classes then becomes elementwise ops over
+``c`` statically sliced ``[bm, Bp]`` tiles, each starting on a vreg
+boundary — no lane shuffles, no padding of the class dimension.
+
+Lanes ``[S*Tw, Bp)`` of every slab are **dead columns**: zero weights in,
+sample weight 0 in the kernel (so residual and gradient are exactly 0 and
+the weights stay 0), ``max|G|`` 0, dropped at unpack. Where ``S * Tw`` is
+a multiple of 128 (six splits at 64 and 128 trials) there are none and the
+layout is the dense ``(a * S + s) * Tw + t``.
 
 Replaces (in effect) the per-trial sklearn fit of the reference worker
 (``aws-prod/worker/worker.py:289-349``) for the LogisticRegression family;
@@ -34,10 +41,37 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-#: trials in the widest weight block; the packed block width is ``c * S * Tw``
+#: trials in the widest weight block; the packed block width is ``c * Bp``
 TRIAL_BLOCK = 128
 #: the widths ``packed_trial_block`` chooses among, narrowest first
 TRIAL_BLOCKS = (16, 32, 64, TRIAL_BLOCK)
+
+
+def slab_lanes(S: int, Tw: int) -> int:
+    """Lanes of one class slab (``Bp``): ``S * Tw`` real (split, trial)
+    columns rounded up to whole 128-lane vregs."""
+    return -(-S * Tw // 128) * 128
+
+
+def _sample_weight_slab(wsp_ref, bm: int, S: int, Tw: int):
+    """The per-(sample, split, trial) weight tile ``[bm, Bp]``: split
+    ``s``'s column of ``wsp_ref`` on lanes ``[s*Tw, (s+1)*Tw)``, 0 on a
+    slab's dead lanes. A dense slab concatenates ``Tw``-wide broadcasts
+    (six splits at ``Tw`` 64 and 128: half and whole vregs, the program it
+    always was). In a padded slab (six splits at 16 and 32) such pieces
+    are the relayout the padding is there to avoid: it selects by lane
+    index instead."""
+    Bp = slab_lanes(S, Tw)
+    if Bp == S * Tw:
+        return jnp.concatenate(
+            [jnp.broadcast_to(wsp_ref[:, s : s + 1], (bm, Tw)) for s in range(S)],
+            axis=1,
+        )
+    lane = jax.lax.broadcasted_iota(jnp.int32, (bm, Bp), 1)
+    wexp = jnp.zeros((bm, Bp), jnp.float32)
+    for s in reversed(range(S)):
+        wexp = jnp.where(lane < (s + 1) * Tw, wsp_ref[:, s : s + 1], wexp)
+    return wexp
 
 
 def _tile_softmax_gram(a, W, yv, wsp_ref, acc_ref, *, c: int, S: int, Tw: int):
@@ -48,21 +82,20 @@ def _tile_softmax_gram(a, W, yv, wsp_ref, acc_ref, *, c: int, S: int, Tw: int):
     parity contract), which this single body enforces by construction.
 
     a   [bm, dpp]      bf16  design-matrix row tile (shared by all trials)
-    W   [dpp, NB]      bf16  packed weights operand, NB = c*S*Tw, class-major
+    W   [dpp, NB]      bf16  packed weights operand, NB = c*Bp, class-major
     yv  [bm, 1]        i32   labels for the tile rows
     wsp_ref [bm, S]    f32   per-split {0,1} sample-weight ref
     acc_ref [1, dpp, NB] f32 accumulator block, revisited across row tiles
+
+    ``B`` below is the slab ``Bp``: every slice starts on a vreg boundary.
     """
-    B = S * Tw
+    B = slab_lanes(S, Tw)
     bm = a.shape[0]
     # logits for every (class, split, trial) column: one MXU pass, f32 out
     logits = jnp.dot(a, W, preferred_element_type=jnp.float32)  # [bm, NB]
 
     # per-(sample, split, trial) weight tile, broadcast from the S columns
-    wexp_parts = [
-        jnp.broadcast_to(wsp_ref[:, s : s + 1], (bm, Tw)) for s in range(S)
-    ]
-    wexp = jnp.concatenate(wexp_parts, axis=1)  # [bm, B]
+    wexp = _sample_weight_slab(wsp_ref, bm, S, Tw)  # [bm, B]
 
     # grouped softmax over the c class slices (elementwise; classes are
     # separate [bm, B] tiles, so no cross-lane reductions are needed)
@@ -93,7 +126,7 @@ def _grad_kernel(a_ref, w_ref, y_ref, wsp_ref, g_ref, *, c: int, S: int, Tw: int
     """One (weight-block, row-tile) grid step.
 
     a_ref   [bm, dpp]      bf16  design-matrix row tile (shared by all trials)
-    w_ref   [1, dpp, NB]   bf16  packed weights, NB = c*S*Tw, class-major
+    w_ref   [1, dpp, NB]   bf16  packed weights, NB = c*Bp, class-major
     y_ref   [bm, 1]        i32   labels for the tile rows
     wsp_ref [bm, S]        f32   per-split {0,1} sample weights
     g_ref   [1, dpp, NB]   f32   output: A^T (w (P - Y)), accumulated over row tiles
@@ -114,14 +147,14 @@ def packed_softmax_grad(
     """G3[wb] = A^T @ (w * (softmax(A @ W3[wb]) - Y)) for every packed column.
 
     Ab  [n_pad, dpp]       bf16, n_pad % bm == 0 (pad rows must have w == 0)
-    W3  [n_wb, dpp, NB]    bf16, NB == c*S*Tw, column = (a*S + s)*Tw + t
+    W3  [n_wb, dpp, NB]    bf16, NB == c*Bp, column = a*Bp + s*Tw + t
     y2  [n_pad, 1]         i32
     WSP [n_pad, S]         f32
-    returns G3 [n_wb, dpp, NB] f32
+    returns G3 [n_wb, dpp, NB] f32 (dead columns exactly 0)
     """
     n_pad, dpp = Ab.shape
     n_wb, _, NB = W3.shape
-    assert NB == c * S * Tw, (NB, c, S, Tw)
+    assert NB == c * slab_lanes(S, Tw), (NB, c, S, Tw)
     assert n_pad % bm == 0, (n_pad, bm)
 
     grid = (n_wb, n_pad // bm)
@@ -155,27 +188,22 @@ _FUSED_STEP_VMEM_BYTES = 8 * 1024 * 1024
 _FUSED_STEP_VMEM_LIMIT = 64 * 1024 * 1024
 
 
-def trial_block_admissible(S: int, Tw: int) -> bool:
-    """Whether the packed fit may run ``Tw`` trials a weight block at ``S``
-    splits. The widest block always may; a narrower one only where its
-    class slabs ``[bm, S*Tw]`` fill whole 128-lane vregs. Found on a v5e
-    (5M x 54, 7 classes, 6 splits, 100 steps, one block a fit): 9.53 s at
-    128, 5.24 s at 64, and then *slower*: 5.98 s at 32 and 7.23 s at 16,
-    whose slabs of 192 and 96 lanes start off a vreg boundary. All four
-    gave the same scores and curves to the last bit."""
-    return Tw == TRIAL_BLOCK or (Tw in TRIAL_BLOCKS and (S * Tw) % 128 == 0)
-
-
 def packed_trial_block(trials: int, S: int) -> int:
-    """The narrowest admissible weight block that holds ``trials`` trials
-    at ``S`` splits, ``TRIAL_BLOCK`` at most. Kernel time follows the
-    packed width ``c*S*Tw``, so a share of 16 trials in a block of 128
-    spends seven eighths of the fit on padding lanes."""
-    return next(
-        (Tw for Tw in TRIAL_BLOCKS
-         if Tw >= trials and trial_block_admissible(S, Tw)),
-        TRIAL_BLOCK,
-    )
+    """The weight block for a share of ``trials`` trials at ``S`` splits:
+    the width in ``TRIAL_BLOCKS`` that holds the share with the fewest
+    packed columns ``c * slab_lanes(S, Tw)``, the narrower on a tie,
+    ``TRIAL_BLOCK`` at most. A slab never shrinks as ``Tw`` grows, so that
+    is the narrowest width that holds the share. Kernel time follows the
+    packed columns (v5e, 5M x 54, 7 classes, 6 splits, 100 steps, one
+    block a fit: 9.53 s at 128 = 768 lanes a slab, 5.24 s at 64 = 384,
+    3.80 s at 32 = 256, 2.33 s at 16 = 128), so a share of 16 in a block
+    of 128 would spend seven eighths of the fit on padding lanes. At four
+    splits 16 and 32 both make a slab of 128 and read 2.36 and 2.34 s.
+
+    Every width runs because slabs are padded to whole vregs. Dense slabs
+    off a vreg boundary lost what their columns saved: 5.98 s at 32 (192
+    lanes a slab) and 7.23 s at 16 (96), against 5.24 s at 64."""
+    return next((Tw for Tw in TRIAL_BLOCKS if Tw >= trials), TRIAL_BLOCK)
 
 
 def fused_step_applicable(dpp: int, NB: int, bm: int = 256) -> bool:
@@ -195,12 +223,13 @@ def _fused_step_kernel(
     """One (weight-block, row-tile) grid step of the FULL Nesterov update.
 
     a_ref     [bm, dpp]      bf16  design-matrix row tile (shared by all trials)
-    w_ref     [1, dpp, NB]   f32   W, packed class-major (NB = c*S*Tw)
+    w_ref     [1, dpp, NB]   f32   W, packed class-major (NB = c*Bp)
     wp_ref    [1, dpp, NB]   f32   W_prev
     y_ref     [bm, 1]        i32   labels for the tile rows
     wsp_ref   [bm, S]        f32   per-split {0,1} sample weights
     t_ref     [1, 1]         f32   iteration index t (SMEM scalar)
     done_ref  [1, 1, B]      f32   1.0 where the trial already converged
+                                   (B = Bp, the padded slab, throughout)
     step_ref  [1, 1, B]      f32   per-(split, trial) step size
     cb_ref    [1, 1, B]      f32   per-trial C
     maxit_ref [1, 1, B]      f32   per-trial max_iter
@@ -231,7 +260,7 @@ def _fused_step_kernel(
     traffic on the weights is the same 4 passes.
     """
     i = pl.program_id(1)
-    B = S * Tw
+    B = slab_lanes(S, Tw)
     t = t_ref[0, 0]
     mom = t / (t + 3.0)
 
@@ -284,12 +313,15 @@ def packed_nesterov_step(
     f32 passes to 4 (W/Wp read + W/Wp write).
 
     Ab      [n_pad, dpp]     bf16  (n_pad % bm == 0; pad rows carry w == 0)
-    W3      [n_wb, dpp, NB]  f32   NB == c*S*Tw, column = (a*S + s)*Tw + t
+    W3      [n_wb, dpp, NB]  f32   NB == c*Bp, column = a*Bp + s*Tw + t,
+                                   dead columns (lanes >= S*Tw of a slab) 0
     Wp3     [n_wb, dpp, NB]  f32
     y2      [n_pad, 1]       i32
     WSP     [n_pad, S]       f32
     t       scalar           f32   iteration index (momentum = t/(t+3))
-    done    [n_wb, B]        f32   1.0 freezes the (split, trial) column
+    done    [n_wb, B]        f32   1.0 freezes the (split, trial) column;
+                                   B = Bp here and below, any finite value
+                                   on a dead column
     step_b  [n_wb, B]        f32   per-column step size
     Cb      [n_wb, B]        f32   per-column C
     maxit_b [n_wb, B]        f32   per-column max_iter
@@ -297,11 +329,12 @@ def packed_nesterov_step(
     lam     static float           L2 strength (0 disables the penalty)
 
     Returns ``(W_new, Wp_new, gmax)`` with shapes/dtypes of
-    ``(W3, Wp3, [n_wb, B] f32)``.
+    ``(W3, Wp3, [n_wb, B] f32)``; dead columns come back exactly 0 in all
+    three.
     """
     n_pad, dpp = Ab.shape
     n_wb, _, NB = W3.shape
-    B = S * Tw
+    B = slab_lanes(S, Tw)
     assert NB == c * B, (NB, c, S, Tw)
     assert n_pad % bm == 0, (n_pad, bm)
     n_tiles = n_pad // bm
@@ -354,7 +387,7 @@ def packed_nesterov_step_reference(
     legacy scan body's algebra (models/logistic.py pre-fusion) on the
     same packed layout, for parity tests."""
     n_wb, dpp, NB = W3.shape
-    B = S * Tw
+    B = slab_lanes(S, Tw)
     t = jnp.asarray(t, jnp.float32)
     mom = t / (t + 3.0)
     V = W3 + mom * (W3 - Wp3)
@@ -474,7 +507,7 @@ def packed_softmax_grad_reference(Ab, W3, y2, WSP, *, c: int, S: int, Tw: int = 
     """Pure-XLA reference of the kernel (same packing), for parity tests."""
     n_pad, dpp = Ab.shape
     n_wb, _, NB = W3.shape
-    B = S * Tw
+    B = slab_lanes(S, Tw)
     A = Ab.astype(jnp.float32)
     y = y2[:, 0]
 
@@ -483,7 +516,8 @@ def packed_softmax_grad_reference(Ab, W3, y2, WSP, *, c: int, S: int, Tw: int = 
         L = logits.reshape(n_pad, c, B)
         P = jax.nn.softmax(L, axis=1)
         onehot = jax.nn.one_hot(y, c, dtype=jnp.float32)  # [n, c]
-        wexp = jnp.repeat(WSP, Tw, axis=1)  # [n, B] (split-major blocks)
+        # [n, B]: split-major blocks, 0 on a slab's dead lanes
+        wexp = jnp.pad(jnp.repeat(WSP, Tw, axis=1), ((0, 0), (0, B - S * Tw)))
         R = (P - onehot[:, :, None]) * wexp[:, None, :]
         return jnp.einsum("nd,ncb->dcb", A, R).reshape(dpp, NB)
 

@@ -93,7 +93,9 @@ def _dispatch_span(mesh, engine: str, chunk_no: int, lanes: int,
                    n_trials: int, **attrs):
     """The ``executor.dispatch`` span of one chunk. ``engine`` says which
     of the four forms ran it (``packed``: a kernel's ``build_batched_fn``,
-    with ``block`` trials a weight block and ``blocks`` a device;
+    with ``block`` trials a weight block and ``blocks`` a device, and for a
+    kernel whose block is made of slabs ``slab_lanes`` lanes a slab of
+    which ``slab_pad_lanes`` are dead columns;
     ``generic``: the vmapped fit; ``chunked``; ``streamed``), counted in
     ``tpuml_engine_dispatch_total{engine, mesh}``. ``lanes`` is the chunk
     size the executable was compiled for, so ``lanes_padding`` trial lanes
@@ -713,10 +715,12 @@ class BucketPlan:
 
     ``chunk`` is the trial lanes of one dispatch, all devices together.
     Packed: ``block`` trials a weight block, ``blocks`` blocks and
-    ``dev_chunk`` lanes a device. Generic and chunked: ``mem_cap`` trials
-    the memory budget admits a dispatch, and ``split_width`` folds a
-    dispatch when one trial's whole fold stack passes half a device's
-    memory (None: all folds in one)."""
+    ``dev_chunk`` lanes a device; ``slab_lanes`` and ``slab_pad_lanes``
+    where the kernel says what a block is made of (``batched_slab``: the
+    packed LogReg fit's class slab and its dead lanes). Generic and
+    chunked: ``mem_cap`` trials the memory budget admits a dispatch, and
+    ``split_width`` folds a dispatch when one trial's whole fold stack
+    passes half a device's memory (None: all folds in one)."""
 
     engine: str
     placement: Placement
@@ -726,6 +730,8 @@ class BucketPlan:
     block: Optional[int] = None
     blocks: Optional[int] = None
     dev_chunk: Optional[int] = None
+    slab_lanes: Optional[int] = None
+    slab_pad_lanes: Optional[int] = None
     mem_cap: Optional[int] = None
     split_width: Optional[int] = None
     chunk_plan: Optional[Dict[str, Any]] = None
@@ -876,10 +882,12 @@ def plan_bucket(kernel, static, bucket_hypers, host_X, *, n, d, n_classes,
             chunk=dev_chunk,
         )
         if batched_fn is not None:
+            slab = (kernel.batched_slab(block, n_splits)
+                    if hasattr(kernel, "batched_slab") else {})
             return plan(
                 "packed", place, chunk=dev_chunk * place.n_dev, block=block,
                 blocks=dev_chunk // block, dev_chunk=dev_chunk,
-                batched_fn=batched_fn,
+                batched_fn=batched_fn, **slab,
             )
 
     mem_cap = _memory_chunk_cap(kernel, n, d, static, n_splits, place.n_dev)
@@ -1402,6 +1410,9 @@ def _dispatch(run: _Run, bp: BucketPlan, exe, fresh: bool, X, folds, extras,
     engine, attrs = "generic", {}
     if bp.engine == "packed":
         engine, attrs = "packed", {"block": bp.block, "blocks": bp.blocks}
+        if bp.slab_lanes is not None:
+            attrs.update(slab_lanes=bp.slab_lanes,
+                         slab_pad_lanes=bp.slab_pad_lanes)
     for start in range(0, len(idxs), chunk):
         batch_idx = idxs[start : start + chunk]
         with _dispatch_span(place.mesh, engine, start // chunk, chunk,

@@ -65,22 +65,37 @@ _CASES = [
     # the cell logreg_rows5m.rs128: one weight block of 128, one dispatch
     ("logreg_one_device_128", "LogisticRegression", ROWS_5M, 128, 6, None, {},
      dict(engine="packed", kind="device", chunk=128, block=128, blocks=1,
-          dev_chunk=128)),
-    # 64 trials at six splits: the narrower block is admitted (S*Tw % 128)
+          dev_chunk=128, slab_lanes=768, slab_pad_lanes=0)),
+    # 64 trials at six splits: a block of 64, its slabs three whole vregs
     ("logreg_one_device_64", "LogisticRegression", ROWS_5M, 64, 6, None, {},
      dict(engine="packed", kind="device", chunk=64, block=64, blocks=1,
-          dev_chunk=64)),
-    # the cell logreg_rows5m_mesh4.rs64c: a share of 16 in a block of 64
+          dev_chunk=64, slab_lanes=384, slab_pad_lanes=0)),
+    # the cell logreg_rows5m_mesh4.rs64c: a share of 16 in a block of 16,
+    # each class slab's 96 lanes padded to one vreg
     ("logreg_mesh4_64", "LogisticRegression", ROWS_5M, 64, 6, "1d", {},
-     dict(engine="packed", kind="mesh_1d", chunk=256, block=64, blocks=1,
-          dev_chunk=64)),
+     dict(engine="packed", kind="mesh_1d", chunk=64, block=16, blocks=1,
+          dev_chunk=16, slab_lanes=128, slab_pad_lanes=32)),
+    # 16 trials on one device ride the same block of 16
+    ("logreg_one_device_16", "LogisticRegression", ROWS_5M, 16, 6, None, {},
+     dict(engine="packed", kind="device", chunk=16, block=16, blocks=1,
+          dev_chunk=16, slab_lanes=128, slab_pad_lanes=32)),
+    # four splits: 16 and 32 both make a slab of one vreg, the narrower runs
+    ("logreg_mesh4_64_four_splits", "LogisticRegression", ROWS_5M, 64, 4, "1d",
+     {}, dict(engine="packed", kind="mesh_1d", chunk=64, block=16, blocks=1,
+              dev_chunk=16, slab_lanes=128, slab_pad_lanes=64)),
     # more than a block a device: whole blocks, capped by the kernel
     ("logreg_one_device_300", "LogisticRegression", ROWS_5M, 300, 6, None, {},
      dict(engine="packed", kind="device", chunk=384, block=128, blocks=3,
-          dev_chunk=384)),
+          dev_chunk=384, slab_lanes=768, slab_pad_lanes=0)),
+    # the cell mlp_mnist.rs64: lanes pack per (trial, split), a block is one
+    # trial and there is no slab to report
+    ("mlp_one_device_64", "MLPClassifier", (60_000, 784, 10), 64, 6, None, {},
+     dict(engine="packed", kind="device", chunk=64, block=1, blocks=64,
+          dev_chunk=64, slab_lanes=None, slab_pad_lanes=None)),
     # XLA partitions a (trials, data) mesh: no fused kernel there
     ("logreg_2d_mesh", "LogisticRegression", (10_240, 54, 7), 8, 4, "2d", {},
-     dict(engine="generic", kind="mesh_2d", chunk=8, block=None)),
+     dict(engine="generic", kind="mesh_2d", chunk=8, block=None,
+          slab_lanes=None)),
     # fused paths score by the default metric only
     ("logreg_custom_scorer", "LogisticRegression", (10_240, 54, 7), 8, 4, None,
      {"scoring": "f1_macro"},
@@ -200,12 +215,18 @@ def test_one_builder_builds_all_four_tags(monkeypatch):
     mesh program hands back the per-leaf dict, unpriced, ``traced``."""
     from cs230_distributed_machine_learning_tpu.obs import TRACER, span
 
+    import threading
+
     built = []
     real = trial_map._build_executable
+    here = threading.get_ident()
 
     def recording(key, make_parts):
         entry, fresh = real(key, make_parts)
-        built.append((key, entry, fresh))
+        # an executor thread another test file left running in this worker
+        # may build too (seen once under xdist: an iris job of its own)
+        if threading.get_ident() == here:
+            built.append((key, entry, fresh))
         return entry, fresh
 
     monkeypatch.setattr(trial_map, "_build_executable", recording)

@@ -24,6 +24,7 @@ from cs230_distributed_machine_learning_tpu.ops.pallas_logreg import (
     packed_nesterov_step_reference,
     packed_softmax_grad,
     packed_softmax_grad_reference,
+    slab_lanes,
 )
 from cs230_distributed_machine_learning_tpu.parallel import trial_map
 
@@ -176,15 +177,24 @@ def test_fit_fused_masked_grad_matches_legacy(monkeypatch):
 # ---------------- fused packed Nesterov step (ISSUE 10) ----------------
 
 
+def _dead_columns(c, S, Tw):
+    """[NB] bool: the lanes past ``S*Tw`` of each of the ``c`` class slabs."""
+    return np.tile(np.arange(slab_lanes(S, Tw)) >= S * Tw, c)
+
+
 def _fused_step_inputs(c, S, n_wb=2, n_pad=512, dpp=64, seed=0, Tw=128):
+    """Random inputs on the packed layout: ``B`` is the padded slab, the
+    weights of its dead columns are 0 as the layout's contract says, the
+    per-column vectors carry ordinary values there."""
     rng = np.random.RandomState(seed)
-    B = S * Tw
+    B = slab_lanes(S, Tw)
     NB = c * B
+    live = ~_dead_columns(c, S, Tw)
     Ab = jnp.asarray(rng.randn(n_pad, dpp).astype(np.float32)).astype(
         jnp.bfloat16
     )
-    W = jnp.asarray((rng.randn(n_wb, dpp, NB) * 0.2).astype(np.float32))
-    Wp = jnp.asarray((rng.randn(n_wb, dpp, NB) * 0.2).astype(np.float32))
+    W = jnp.asarray((rng.randn(n_wb, dpp, NB) * 0.2 * live).astype(np.float32))
+    Wp = jnp.asarray((rng.randn(n_wb, dpp, NB) * 0.2 * live).astype(np.float32))
     y2 = jnp.asarray(rng.randint(0, c, (n_pad, 1)).astype(np.int32))
     WSP = jnp.asarray((rng.rand(n_pad, S) > 0.3).astype(np.float32))
     done = jnp.asarray((rng.rand(n_wb, B) > 0.7).astype(np.float32))
@@ -224,12 +234,16 @@ def test_fused_step_kernel_matches_reference_interpret(c, S, lam):
 
 
 @pytest.mark.parametrize("Tw", [16, 32, 64, 128])
-def test_fused_step_kernel_matches_reference_at_every_trial_block(Tw):
+@pytest.mark.parametrize("S", [4, 6, 11])
+def test_fused_step_kernel_matches_reference_at_every_trial_block(S, Tw):
     """The widths ``packed_trial_block`` chooses among, at the benchmark's
-    7 classes x 6 splits: below 128 the class slabs ``[bm, S*Tw]`` are
-    narrower than at the widest block (at 16 and 32 not a whole number of
-    128-lane vregs), and the kernel's column arithmetic must not care."""
-    c, S, lam = 7, 6, 1.0
+    7 classes x 6 splits, at four splits (16 and 32 both fill one vreg a
+    slab) and at eleven (every width padded; three classes, so an
+    interpreted case stays in seconds): each class slab is ``S*Tw`` lanes
+    rounded up to whole 128-lane vregs, the kernel's column arithmetic
+    must agree with the reference's at every width, and a dead column
+    comes back exactly 0."""
+    c, lam = (3 if S == 11 else 7), 1.0
     Ab, W, Wp, y2, WSP, done, step, Cb, maxit, pen, _ = _fused_step_inputs(
         c, S, Tw=Tw
     )
@@ -241,29 +255,112 @@ def test_fused_step_kernel_matches_reference_at_every_trial_block(Tw):
         Ab, W, Wp, y2, WSP, 3.0, done, step, Cb, maxit, pen,
         c=c, S=S, Tw=Tw, lam=lam,
     )
+    B = slab_lanes(S, Tw)
+    assert got[0].shape == W.shape and got[2].shape == (W.shape[0], B)
+    dead = _dead_columns(c, S, Tw)
+    assert dead.sum() == c * (B - S * Tw)
     for name, g, r in zip(("W_new", "Wp_new", "gmax"), got, ref):
         g, r = np.asarray(g), np.asarray(r)
         assert np.abs(g - r).max() / (np.abs(r).max() + 1e-9) < 5e-3, name
+        # weights [n_wb, dpp, NB] and gmax [n_wb, B] both end on a lane axis
+        assert not g[..., dead[: g.shape[-1]]].any(), name
 
 
-def test_trial_block_is_the_narrowest_admissible_width_that_holds_the_share():
+def _kernel_eqns(jaxpr, out):
+    """Every equation of a jaxpr and of the jaxprs in its parameters (the
+    Pallas kernel body, ``pl.when`` branches, inner jits)."""
+    for e in jaxpr.eqns:
+        out.append(e)
+        for v in e.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else [v]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _kernel_eqns(sub, out)
+    return out
+
+
+@pytest.mark.parametrize("Tw", [16, 32, 64, 128])
+def test_fused_step_slices_every_slab_on_a_vreg_boundary(Tw):
+    """What the padding is for, read off the traced kernel: every static
+    slice of a value and every window of a ref over the packed columns
+    starts at a multiple of 128 lanes and is whole vregs wide; a padded
+    slab builds its sample-weight tile without a lane concatenation, a
+    dense one keeps the ``Tw``-wide pieces it always had."""
+    import functools
+
+    import jax
+
+    c, dpp, bm = 7, 64, 256
+    sds = jax.ShapeDtypeStruct
+    for S in (4, 6, 11):
+        B = slab_lanes(S, Tw)
+        W = sds((1, dpp, c * B), jnp.float32)
+        col = sds((1, B), jnp.float32)
+        jaxpr = jax.make_jaxpr(functools.partial(
+            packed_nesterov_step, c=c, S=S, Tw=Tw, bm=bm, lam=1.0,
+        ))(
+            sds((2 * bm, dpp), jnp.bfloat16), W, W, sds((2 * bm, 1), jnp.int32),
+            sds((2 * bm, S), jnp.float32), sds((), jnp.float32),
+            col, col, col, col, sds((dpp, 1), jnp.float32),
+        )
+        windows, lane_concats = [], []
+        for e in _kernel_eqns(jaxpr.jaxpr, []):
+            if not e.invars:  # iota, program_id
+                continue
+            name, shape = e.primitive.name, e.invars[0].aval.shape
+            if name == "slice" and shape[-1] >= 128:
+                start, limit = e.params["start_indices"][-1], e.params["limit_indices"][-1]
+                windows.append((start, limit - start))
+            elif name in ("get", "swap", "addupdate") and shape[-1] >= 128:
+                (idx,) = jax.tree_util.tree_unflatten(
+                    e.params["tree"], e.invars[1 if name == "get" else 2:]
+                )
+                windows.append((idx.indices[-1].start, idx.indices[-1].size))
+            elif name == "concatenate" and e.params["dimension"] == len(shape) - 1:
+                lane_concats.append([v.aval.shape[-1] for v in e.invars])
+        assert len(windows) >= 4 * c, (S, Tw)  # logits, accumulator, epilogue
+        assert all(start % 128 == 0 and size % 128 == 0
+                   for start, size in windows), (S, Tw, windows)
+        if B == S * Tw:
+            assert lane_concats == [[Tw] * S], (S, Tw)
+        else:
+            assert lane_concats == [], (S, Tw)
+
+
+def test_trial_block_holds_the_share_in_the_fewest_padded_columns():
+    """Every width runs (slabs are padded to whole vregs), so the block of
+    a share is the one that holds it with the fewest packed columns
+    ``c * slab_lanes(S, Tw)``, the narrower on a tie; beyond the widest,
+    whole blocks of 128."""
     from cs230_distributed_machine_learning_tpu.ops.pallas_logreg import (
-        TRIAL_BLOCK, TRIAL_BLOCKS, packed_trial_block, trial_block_admissible,
+        TRIAL_BLOCK, TRIAL_BLOCKS, packed_trial_block,
     )
 
-    for S in (1, 2, 4, 6):
-        for trials in (1, 3, 16, 17, 64, 65, 128, 130, 1000):
+    for S in (1, 2, 4, 6, 11):
+        for Tw in TRIAL_BLOCKS:
+            lanes = slab_lanes(S, Tw)
+            assert lanes % 128 == 0 and 0 <= lanes - S * Tw < 128
+        for trials in (1, 3, 16, 17, 32, 33, 64, 65, 128, 130, 1000):
             Tw = packed_trial_block(trials, S)
-            assert trial_block_admissible(S, Tw) and Tw <= TRIAL_BLOCK
+            assert Tw in TRIAL_BLOCKS
             assert Tw >= min(trials, TRIAL_BLOCK)  # it holds the share
-            assert not any(  # and nothing narrower would
-                trial_block_admissible(S, w) for w in TRIAL_BLOCKS
-                if trials <= w < Tw
+            holds = [w for w in TRIAL_BLOCKS if w >= min(trials, TRIAL_BLOCK)]
+            assert slab_lanes(S, Tw) == min(slab_lanes(S, w) for w in holds)
+            assert not any(  # the narrower on a tie
+                slab_lanes(S, w) == slab_lanes(S, Tw) for w in holds if w < Tw
             )
-    # the one-chip benchmark cell's geometry is what it was: 128 trials, one block
+    # lanes a slab at six splits; at four, 16 and 32 both fill one vreg
+    assert [slab_lanes(6, w) for w in TRIAL_BLOCKS] == [128, 256, 384, 768]
+    assert [slab_lanes(4, w) for w in TRIAL_BLOCKS] == [128, 128, 256, 512]
+    # the one-chip benchmark cell's geometry is what it was: 128 trials, one
+    # dense block; the four-chip cell's share of 16 rides a block of 16
     assert packed_trial_block(128, 6) == TRIAL_BLOCK == 128
+    assert packed_trial_block(16, 6) == 16 and packed_trial_block(16, 4) == 16
     kernel = get_kernel("LogisticRegression")
     assert kernel.batched_trial_block(128, 6) == kernel.batched_trial_multiple
+    assert kernel.batched_slab(128, 6) == {"slab_lanes": 768, "slab_pad_lanes": 0}
+    assert kernel.batched_slab(16, 6) == {"slab_lanes": 128, "slab_pad_lanes": 32}
+    assert not hasattr(get_kernel("MLPClassifier"), "batched_slab")
 
 
 def test_fused_step_freezes_done_and_past_max_iter_columns():
@@ -273,7 +370,8 @@ def test_fused_step_freezes_done_and_past_max_iter_columns():
     c, S = 3, 2
     Ab, W, Wp, y2, WSP, _, step, Cb, _, pen, Tw = _fused_step_inputs(c, S)
     n_wb, _, _ = W.shape
-    B = S * Tw
+    B = slab_lanes(S, Tw)
+    assert B == S * Tw  # a dense slab: every column is a real one
     done = jnp.zeros((n_wb, B), jnp.float32).at[:, ::3].set(1.0)
     maxit = jnp.full((n_wb, B), 100.0, jnp.float32).at[:, 1::3].set(5.0)
     t = 5.0  # AT the max_iter boundary: t < maxit is False for the 5.0 cols
@@ -395,6 +493,32 @@ def test_packed_fn_staged_extras_bitwise(monkeypatch):
     assert extras["_logreg_lam_max"].shape == (S,)
     with_extras = np.asarray(fn(X, y, TW, EW, {**hyper, **extras})["score"])
     np.testing.assert_array_equal(with_extras, base)
+
+
+def test_packed_fn_sixteen_trials_alone_and_inside_a_block_of_128(monkeypatch):
+    """The same 16 trials fitted at a chunk of 16 (a block of 16: slabs of
+    48 real lanes padded to 128) and as the first 16 lanes of a chunk of
+    128 (a dense block of 128): a trial's columns never meet another's,
+    dead columns or not, so the scores agree bit for bit. On the chip the
+    ``curve_gmax`` leaves do too (PERF.md, PR 31's width sweep); under the
+    interpreter XLA's CPU matmul blocks its contraction by the operands'
+    width, so the curves are held to a few units in the last place."""
+    n, d, c, S = 700, 5, 3, 3
+    kernel, _, fn128 = _build_packed_fn(monkeypatch, "pallas", n, d, c, S, chunk=128)
+    assert kernel.batched_slab(128, S)["slab_pad_lanes"] == 0
+    X, y, TW, EW, hyper = _packed_fn_inputs(n, d, c, S, 128)
+    wide = fn128(X, y, TW, EW, hyper)
+    _, _, fn16 = _build_packed_fn(monkeypatch, "pallas", n, d, c, S, chunk=16)
+    assert kernel.batched_trial_block(16, S) == 16
+    assert kernel.batched_slab(16, S) == {"slab_lanes": 128, "slab_pad_lanes": 80}
+    alone = fn16(X, y, TW, EW, {k: v[:16] for k, v in hyper.items()})
+    assert alone["score"].shape == (16, S)
+    np.testing.assert_array_equal(
+        np.asarray(alone["score"]), np.asarray(wide["score"][:16])
+    )
+    curve, curve_wide = (np.asarray(o["curve_gmax"][:16]) for o in (alone, wide))
+    assert curve.shape == curve_wide.shape and curve.any()
+    np.testing.assert_allclose(curve, curve_wide, rtol=1e-4, atol=0)
 
 
 def test_packed_fn_legacy_mode_has_no_extras(monkeypatch):

@@ -99,24 +99,25 @@ def test_flagship_packed_fit(tpu_backend, n, chunk):
     )
 
 
-def _admissible_blocks(n_splits=S):
-    """The trial-block widths the packed fit may run at ``n_splits``, by the
-    kernel's own rule (pure Python: safe while a test file is imported)."""
+def _trial_blocks():
+    """The trial-block widths the packed fit chooses among: every one runs,
+    its class slabs padded to whole vregs (a constant: safe while a test
+    file is imported)."""
     from cs230_distributed_machine_learning_tpu.ops.pallas_logreg import (
-        TRIAL_BLOCKS, trial_block_admissible,
+        TRIAL_BLOCKS,
     )
 
-    return [Tw for Tw in TRIAL_BLOCKS if trial_block_admissible(n_splits, Tw)]
+    return list(TRIAL_BLOCKS)
 
 
-@pytest.mark.parametrize("Tw", _admissible_blocks())
+@pytest.mark.parametrize("Tw", _trial_blocks())
 def test_fused_step_kernel(tpu_backend, Tw):
     from cs230_distributed_machine_learning_tpu.ops.pallas_logreg import (
-        packed_nesterov_step,
+        packed_nesterov_step, slab_lanes,
     )
 
     n_wb, dpp, n_pad = 8, 64, 2048
-    B = S * Tw
+    B = slab_lanes(S, Tw)
     W = _sds((n_wb, dpp, C * B), jnp.float32)
     col = _sds((n_wb, B), jnp.float32)
     _lower_and_compile(
@@ -260,7 +261,7 @@ def test_sharded_generic_logreg_four_devices(tpu_backend, data_parallel):
         assert "all-reduce" not in hlo and "all-gather" not in hlo
 
 
-@pytest.mark.parametrize("Tw", _admissible_blocks())
+@pytest.mark.parametrize("Tw", _trial_blocks())
 def test_sharded_packed_logreg_four_devices(tpu_backend, Tw):
     """The four-chip benchmark cell's executable (``logreg_rows5m_mesh4``:
     5M x 54, 7 classes, 6 splits, 100 steps, staged extras handed in) at

@@ -131,10 +131,11 @@ def test_mesh_fit_matches_one_device_and_a_plain_float32_fit(covertype_like, cas
             assert np.abs(c_mesh[j, s] - gmax).max() < 0.03 * gmax[0], (j, s)
 
 
-PACKED_CASES = {  # trials -> trials a weight block, blocks a device, lanes of the chunk
-    "sixteen_a_chip": (64, 32, 1, 128),
-    "ten_dealt_3_3_2_2": (10, 32, 1, 128),
-    "two_blocks_a_chip": (520, 128, 2, 1024),
+PACKED_CASES = {  # trials -> trials a weight block, blocks a device, lanes of the
+    # chunk, lanes of a class slab at the fixture's four splits and its dead ones
+    "sixteen_a_chip": (64, 16, 1, 64, 128, 64),
+    "ten_dealt_3_3_2_2": (10, 16, 1, 64, 128, 64),
+    "two_blocks_a_chip": (520, 128, 2, 1024, 512, 0),
 }
 
 
@@ -147,7 +148,8 @@ def test_packed_fit_on_every_chip_matches_one_device_and_a_plain_float32_fit(
     and whichever device and slot a trial was dealt to."""
     monkeypatch.setenv("CS230_PALLAS_INTERPRET", "1")
     data, plan = covertype_like
-    n_trials, block, blocks, lanes = PACKED_CASES[case]
+    n_trials, block, blocks, lanes, slab, slab_pad = PACKED_CASES[case]
+    assert plan.n_splits * block + slab_pad == slab
     params = _trials(n_trials)
     kernel = get_kernel("LogisticRegression")
     mesh = trial_mesh(jax.devices()[:4])
@@ -162,7 +164,8 @@ def test_packed_fit_on_every_chip_matches_one_device_and_a_plain_float32_fit(
                    if s["name"] == "executor.dispatch"]
     assert dispatch == {"engine": "packed", "block": block, "blocks": blocks, "chunk": 0,
                         "n_devices": 4, "n_trials": n_trials, "lanes": lanes,
-                        "lanes_padding": lanes - n_trials}
+                        "lanes_padding": lanes - n_trials,
+                        "slab_lanes": slab, "slab_pad_lanes": slab_pad}
     assert lanes == 4 * blocks * block  # every device a whole number of blocks
     assert len(on_mesh.trial_metrics) == n_trials  # padding lanes are dropped
     s_mesh, c_mesh = _scores_and_curves(on_mesh)
