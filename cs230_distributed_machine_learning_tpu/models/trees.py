@@ -561,6 +561,10 @@ def _bootstrap_counts(key, w, n):
     caw = jnp.cumsum(active)
     n_active = caw[-1]
     targets = jax.random.randint(key, (n,), 1, jnp.maximum(n_active, 1) + 1)
+    # the draws are made once and then searched: without the barrier XLA
+    # fuses randint's remainder chain into the binary search's loop body
+    # (every probe draws again; 29 s of TPU compile against 6, PR 32)
+    caw, targets = jax.lax.optimization_barrier((caw, targets))
     rows = jnp.searchsorted(caw, targets, side="left")
     counts = jax.ops.segment_sum(
         jnp.ones((n,), jnp.float32), rows, num_segments=n
@@ -610,6 +614,47 @@ class _RandomForestBase(_TreeBase):
                 else jax.lax.Precision.HIGHEST
             ),
         )
+
+    def _tree_group(self, X, S, C, static, keys):
+        """The trees of one group (``keys`` [T, 2]), stacked. A group of one
+        is fitted as it stands: a size-1 vmap over the key makes the
+        bootstrap's ``randint`` a draw with a batched key AND (under the
+        split lanes) a batched bound, which the TPU compiler took 106 s
+        over at a Covertype row count (PR 32; 4.5 s with the key as it is)."""
+        if keys.shape[0] == 1:
+            tree = self._one_tree(X, S, C, static, keys[0])
+            return jax.tree_util.tree_map(lambda a: a[None], tree)
+        return jax.vmap(lambda k: self._one_tree(X, S, C, static, k))(keys)
+
+    def dispatch_attrs(self, static: Dict[str, Any], X) -> Dict[str, Any]:
+        """What the chunked engine's ``executor.dispatch`` span says of one
+        fit's shape: levels, the arena's width, and which histogram form
+        (pallas / matmul / scatter) its levels take. Shapes only."""
+        from ..ops.trees import COARSE_BINS, _resolve_hist_kernel, deep_hist_routes
+
+        xb = X["xb"] if isinstance(X, dict) else X
+        d, n_bins = int(xb.shape[1]), int(static["_n_bins"])
+        integer = self.task == "classification"
+        kk = max(int(static.get("_n_classes", 2)), 2) if integer else 2
+        if static.get("_deep"):
+            ds, nbs = (d,), (n_bins,)
+            if isinstance(X, dict) and "xb_coarse" in X:
+                ds = (int(X["xb_cont"].shape[1]), int(X["xb_coarse"].shape[1]))
+                nbs = (n_bins, COARSE_BINS)
+            levels, width = int(static["_levels"]), int(static["_W"])
+            routes = deep_hist_routes(
+                ds, nbs, levels=levels, width=width, n_bins=n_bins, kk=kk,
+                integer_stats=integer, w_schedule=static.get("_wsched"),
+                nb_schedule=static.get("_nb_sched"))
+        else:
+            levels = int(static["_depth"])
+            width = 2 ** max(levels - 1, 0)
+            routes = {_resolve_hist_kernel(integer, (d,), (n_bins,), kk): levels}
+        return {
+            "levels": levels, "arena_width": width,
+            "hist_route": next(iter(routes)) if len(routes) == 1 else "mixed",
+            "hist_levels_by_route": ",".join(f"{r}:{c}" for r, c in sorted(routes.items())),
+        }
 
     def _tree_group_size(self, n: int, d: int, static: Dict[str, Any]) -> int:
         """Trees fitted CONCURRENTLY per sequential step (an inner vmap
@@ -723,9 +768,7 @@ class _RandomForestBase(_TreeBase):
             i = gi * T + jnp.arange(T)
             t = chunk_idx * g + i
             keys = jax.vmap(lambda tt: jax.random.fold_in(base_key, tt))(t)
-            trees = jax.vmap(
-                lambda k: self._one_tree(X, S, C, static, k)
-            )(keys)
+            trees = self._tree_group(X, S, C, static, keys)
             vals = jax.vmap(
                 lambda tr: self._tree_predict(xb, tr, static)
             )(trees)  # [T, n, k]
@@ -782,7 +825,7 @@ class _RandomForestBase(_TreeBase):
         idx = chunk_idx * g + jnp.arange(G * T)
         keys = jax.vmap(lambda t: jax.random.fold_in(base_key, t))(idx)
         trees = jax.lax.map(
-            jax.vmap(lambda k: self._one_tree(X, S, w, static, k)),
+            lambda ks: self._tree_group(X, S, w, static, ks),
             jax.tree_util.tree_map(
                 lambda a: a.reshape(G, T, *a.shape[1:]), keys
             ),

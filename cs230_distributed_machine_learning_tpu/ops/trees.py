@@ -86,8 +86,13 @@ def _hist_kernel_mode() -> str:
       tiles built in VMEM, accumulator page resident across row tiles;
     - ``scatter`` — the literal bin-and-scatter segment-sum form
       (O(n*d*kk) adds; the fast form without an MXU);
-    - ``auto`` (default) — pallas on TPU for integer stats at eligible
-      shapes, scatter on CPU, matmul otherwise.
+    - ``auto`` (default) — scatter on CPU, matmul otherwise. On the v5e
+      the matmul form (s8 operands for integer stats) beat the Pallas
+      kernel by 3.4 times over a whole Covertype forest search (45.8 s
+      against 157.2 s, 232 405 rows, frontier 1536; PR 32): the kernel
+      rebuilds a row tile's bin one-hot for each of a level's 64-node
+      blocks and feeds the MXU 256-row contractions. It stays behind the
+      valve until it reads each row tile once.
 
     The valve is read at trace time and keyed into every executable cache
     via the tree kernels' ``trace_salt``.
@@ -102,17 +107,21 @@ def _resolve_hist_kernel(integer_stats: bool, ds, n_binss, kk: int) -> str:
         return mode
     if _backend.on_cpu():
         return "scatter"
-    if _backend.auto_pallas():
-        from .pallas_hist import pallas_hist_applicable
-
-        if integer_stats and all(
-            pallas_hist_applicable(d, nb, kk) for d, nb in zip(ds, n_binss)
-        ):
-            return "pallas"
     return "matmul"  # float stats keep the HIGHEST-precision contraction
 
 
 def _level_histogram_multi(local, xbs, SC, n_nodes: int, n_binss,
+                           precision=None, integer_stats: bool = False):
+    """:func:`_level_histogram_forms` under the ``level_histogram`` scope:
+    every form's ops carry that name in a device trace (the Pallas kernel's
+    own name is the same), so a reader finds the histograms whatever
+    computes them."""
+    with jax.named_scope("level_histogram"):
+        return _level_histogram_forms(
+            local, xbs, SC, n_nodes, n_binss, precision, integer_stats)
+
+
+def _level_histogram_forms(local, xbs, SC, n_nodes: int, n_binss,
                            precision=None, integer_stats: bool = False):
     """Feature-grouped level histograms in ONE row scan: a tuple of
     [n_nodes, d_g, nb_g, kk] histograms, one per (xb_g, nb_g) feature group.
@@ -379,13 +388,39 @@ def _use_compact(n: int, n_nodes: int) -> bool:
     )
 
 
-def _split_gain(H, k: int, n_bins: int, min_samples_leaf: float):
+#: a gain under this share of the parent's own score is float32 noise, not
+#: a split (16 ulps). The deep builder snaps it to zero: a pure node's gain
+#: is L^2/C_L + R^2/C_R - P^2/C_P = 0 in exact arithmetic and on the CPU's
+#: IEEE division, but the TPU's divide is a refined reciprocal, x^2/x is not
+#: x to the last bit there, and the few e-7 of the parent it leaves passed
+#: the absolute 1e-7 threshold: one pure node in a hundred was split, each
+#: taking a frontier slot and two arena ids (which key every later node's
+#: feature subset) to no end (PR 32: 98 of 11 526 splits of a 186 000-row
+#: tree, and no tree the CPU's from level 8 on).
+GAIN_NOISE = 2.0 ** -19
+
+
+def _rank_gain(g):
+    """A gain as the deep builder compares it (argmax within a node, the
+    frontier's cut): its float32 with the low 11 mantissa bits cleared.
+    Small nodes hold small integer counts, so many candidates share one
+    exact gain (4/3, 0.8, ...) reached through different roundings; which of
+    them wins then hung on the last bit of a division, and the CPU and the
+    chip grew different trees from the same rows. On 12 bits equal gains
+    compare equal, and position decides, on every backend alike."""
+    bits = jax.lax.bitcast_convert_type(g.astype(jnp.float32), jnp.int32)
+    return jax.lax.bitcast_convert_type(bits & jnp.int32(-2048), jnp.float32)
+
+
+def _split_gain(H, k: int, n_bins: int, min_samples_leaf: float,
+                noise_floor: float = 0.0):
     """Per-(node, feature, bin) split gain from a histogram.
 
     H: [m, d, n_bins, k+1] (stats + count). Returns gain [m, d, n_bins] with
     invalid candidates at -inf. The score is the unified S^2/C proxy (gini /
     variance / Newton gain depending on what S, C carry); identical math to
-    the level-wise builder's inline version.
+    the level-wise builder's inline version. A valid candidate whose gain
+    does not pass ``noise_floor`` times the parent's score reads 0.
     """
     Sh = H[..., :k]
     Ch = jnp.maximum(H[..., k], 0.0)
@@ -408,7 +443,10 @@ def _split_gain(H, k: int, n_bins: int, min_samples_leaf: float):
     valid = (Ccum >= min_samples_leaf) & (Cr >= min_samples_leaf)
     # last bin = degenerate split (empty right)
     valid = valid & (jnp.arange(n_bins)[None, None, :] < n_bins - 1)
-    return jnp.where(valid, gain - parent, -jnp.inf)
+    g = gain - parent
+    if noise_floor:
+        g = jnp.where(g > noise_floor * parent, g, 0.0)
+    return jnp.where(valid, g, -jnp.inf)
 
 
 def _pick_best(gain, n_bins: int):
@@ -854,6 +892,54 @@ def build_tree_streamed(
 #: narrow coarse-histogram group (one-hot/binary columns: 2 codes)
 COARSE_BINS = int(os.environ.get("CS230_COARSE_BINS", "4"))
 
+#: slots of the deep builder's frontier in the narrow early levels (1, 2, 4,
+#: ... nodes): one shape for all of them (see the level plan there)
+FRONTIER_PAD = 64
+
+
+def _deep_schedules(w_schedule, nb_schedule):
+    """The deep builder's width and resolution schedules in force: the
+    kernel's resolved static (production path, in every cache key) unless
+    the env sweep hooks CS230_DEEP_WSCHED=hi:split:lo / CS230_DEEP_NBSCHED=
+    occ:deep are set (keyed via trace_salt)."""
+    def swept(name, default):
+        raw = os.environ.get(name, "")
+        return tuple(int(x) for x in raw.split(":")) if raw else default
+
+    w_schedule = swept("CS230_DEEP_WSCHED", w_schedule)
+    nb_schedule = swept("CS230_DEEP_NBSCHED", nb_schedule)
+    return w_schedule, nb_schedule
+
+
+def deep_hist_routes(ds, nbs, *, levels: int, width: int, n_bins: int, kk: int,
+                     integer_stats: bool, w_schedule=None, nb_schedule=None):
+    """{route: histograms} of one ``build_tree_deep`` fit: which of pallas /
+    matmul / scatter :func:`_resolve_hist_kernel` picks for the root's
+    histogram and for each level's children (``levels`` in all), under the
+    same width and resolution schedules (env sweep hooks included). ``ds``
+    and ``nbs`` are the feature groups' widths and full bin counts. Shapes
+    only: what the trial engine's dispatch span says of a fit it may have
+    loaded as an AOT blob and never traced."""
+    w_schedule, nb_schedule = _deep_schedules(w_schedule, nb_schedule)
+    occ_w, nb_deep = nb_schedule if nb_schedule is not None else (0, n_bins)
+
+    def res_at(cand_w):
+        return n_bins if (occ_w <= 0 or cand_w < occ_w) else nb_deep
+
+    def route(r):
+        return _resolve_hist_kernel(
+            integer_stats, ds, tuple(r if nb == n_bins else nb for nb in nbs), kk)
+
+    r, W = res_at(2), 1
+    routes = {route(r): 1}
+    for level in range(levels - 1):
+        r = min(r, res_at(2 * W))
+        routes[route(r)] = routes.get(route(r), 0) + 1
+        cap = width if w_schedule is None else (
+            w_schedule[0] if level + 1 < w_schedule[1] else w_schedule[2])
+        W = min(2 * W, cap)
+    return routes
+
 
 def build_tree_deep(
     xb,
@@ -887,9 +973,13 @@ def build_tree_deep(
       children's exact best-split gains are known for the cost of one
       histogram;
     - the next frontier = top-``width`` children by their OWN best gain
-      (``lax.top_k``) — true-gain best-first selection, not a proxy; children
-      not selected (budget) or unsplittable (gain <= eps, min_samples_leaf)
-      become leaves;
+      (``lax.top_k`` finds the cut) — true-gain best-first selection, not a
+      proxy — kept in candidate order, equal gains at the cut going to the
+      earlier candidates; children not selected (budget) or unsplittable
+      (gain <= eps, min_samples_leaf) become leaves. Gains are compared on
+      12 bits of mantissa (:func:`_rank_gain`) and one under ``GAIN_NOISE``
+      of the parent's score is none, so the tree does not hang on the last
+      bit of a backend's division (the CPU and the chip grow the same);
     - per-level cost is O(n * width * kk * d * n_bins) MACs regardless of
       depth, all on the MXU; total leaf budget ~ width * levels (~12k at the
       defaults), the regime sklearn's grow-to-purity needs.
@@ -918,9 +1008,12 @@ def build_tree_deep(
     are untouched. Resolution is monotone non-increasing over levels (a
     width-schedule drop never re-raises it).
 
-    Shapes are static: the frontier width at level l is min(2^l, width)
-    (early levels don't pay the full budget), the arena is a fixed
-    ``2*width*levels + 2`` slots, and routing state is one int32 per sample.
+    Shapes are static: the frontier at level l holds min(2^l, width) nodes
+    in the next larger of a few slot counts (``FRONTIER_PAD`` and the width
+    schedule's caps; holes carry id -1), so early levels don't pay the full
+    budget and runs of levels with one shape are one ``lax.scan`` body; the
+    arena is a fixed ``2*width*levels + 2`` slots, and routing state is one
+    int32 per sample.
     Returns {"feat","bin","child" [A+1], "leaf_val" [A+1, k]}; ``child`` is
     the left-child arena id (0 = leaf; right child = left + 1).
     """
@@ -936,9 +1029,7 @@ def build_tree_deep(
     # ``w_schedule`` comes from the kernel's resolved static (production
     # path, in every cache key); env CS230_DEEP_WSCHED is the sweep hook
     # and takes precedence (keyed via trace_salt).
-    sched = os.environ.get("CS230_DEEP_WSCHED", "")
-    if sched:
-        w_schedule = tuple(int(x) for x in sched.split(":"))
+    w_schedule, nb_schedule = _deep_schedules(w_schedule, nb_schedule)
     if w_schedule is not None:
         w_hi, w_split, w_lo = (int(x) for x in w_schedule)
         width_at = lambda lvl: w_hi if lvl < w_split else w_lo  # noqa: E731
@@ -960,7 +1051,6 @@ def build_tree_deep(
     # at that level (-1 id = no node). predict_tree_deep routes with the
     # same compare/matmul forms the fit uses, instead of per-row gathers
     # from the [A+1] arena tables (profiled ~3x slower).
-    lvl_ids, lvl_feat, lvl_bin, lvl_left = [], [], [], []
 
     # feature groups: (xb columns, global feature ids or None, bin count)
     if groups is not None:
@@ -975,10 +1065,6 @@ def build_tree_deep(
     # candidate frontier is narrow, nb_deep once wide; monotone. Applies
     # to groups histogrammed at the full n_bins (the continuous/single
     # group) — the COARSE_BINS group is already minimal.
-    nbsched = os.environ.get("CS230_DEEP_NBSCHED", "")
-    if nbsched:
-        occ_w, nb_deep = (int(x) for x in nbsched.split(":"))
-        nb_schedule = (occ_w, nb_deep)
     if nb_schedule is not None:
         occ_w, nb_deep = (int(x) for x in nb_schedule)
         if nb_deep <= 0 or n_bins % max(nb_deep, 1) or nb_deep > n_bins:
@@ -1034,7 +1120,7 @@ def build_tree_deep(
         best = None
         for Hg, (_, fidg, nbg) in zip(Hs, gspec):
             rg = g_res(r, nbg)
-            g = _split_gain(Hg, k, rg, min_samples_leaf)
+            g = _rank_gain(_split_gain(Hg, k, rg, min_samples_leaf, GAIN_NOISE))
             if allowed is not None:
                 ag = allowed if fidg is None else jnp.take(allowed, fidg, axis=1)
                 g = jnp.where(ag[:, :, None], g, -jnp.inf)
@@ -1055,13 +1141,38 @@ def build_tree_deep(
                 )
         return best
 
-    # root: full histogram + its best split
-    frontier = jnp.zeros((1,), jnp.int32)
-    r_H = res_at(2)
-    H = hist_groups(node, 1, r_H)
-    gain, bf, bb = best_from_hists(H, frontier, r_H)
+    # ---- the level plan: every level's shapes, from the schedules alone ----
+    # w_act[l]: the nodes the budget allows at level l (1, 2, 4, ... up to
+    # the width schedule's cap). The frontier ARRAY of a level is padded to
+    # the next of a few slot counts (:data:`FRONTIER_PAD`, the schedule's
+    # caps), holes carrying id -1 and gain -inf, so that runs of levels share
+    # one shape and ride one ``lax.scan`` body: a Covertype fit's 24 levels
+    # are 9 traced bodies, not 24 (with the prediction walk's scan, a minute
+    # less of the TPU compiler's three a step program, and a third of the
+    # trace: PR 32, whose four-bucket search could not start inside seven
+    # minutes). Holes cost histogram width only in the narrow early levels
+    # (about 8% more frontier slots over a fit).
+    w_act = [1]
+    for level in range(levels - 1):
+        w_act.append(min(2 * w_act[-1], width_at(level + 1)))
+    classes = sorted({min(FRONTIER_PAD, width)}
+                     | {width_at(level) for level in range(levels)})
+    slots = [next(c for c in classes if c >= w) for w in w_act]
+    plan, r_H = [], res_at(2)
+    for level in range(levels - 1):
+        # candidate resolution for this level's 2*w children (monotone
+        # non-increasing); a level whose children outnumber the next
+        # level's budget cuts them by gain
+        r_c = min(r_H, res_at(2 * w_act[level]))
+        plan.append((slots[level], slots[level + 1], r_H, r_c,
+                     2 * w_act[level] > w_act[level + 1]))
+        r_H = r_c
 
-    for level in range(levels):
+    def split_level(carry):
+        """Split the frontier's nodes of positive gain and route their rows
+        to the children. Returns the carry, this level's routing-table rows
+        and the rows' left-child slots (``W_l`` for a row that stays)."""
+        node, n_alloc, feat_a, bin_a, child_a, frontier, gain, bf, bb, H = carry
         W_l = frontier.shape[0]
         do_split = (gain > 1e-7) & (frontier >= 0)
         rank_inc = jnp.cumsum(do_split.astype(jnp.int32))
@@ -1103,27 +1214,28 @@ def build_tree_deep(
         n_alloc = n_alloc + 2 * rank_inc[-1]
 
         pad = width - W_l
-        lvl_ids.append(jnp.pad(
-            jnp.where(do_split, frontier, -1), (0, pad), constant_values=-1))
-        lvl_feat.append(jnp.pad(bf, (0, pad)))
-        lvl_bin.append(jnp.pad(bb, (0, pad)))
-        lvl_left.append(jnp.pad(left_id, (0, pad)))
-
-        if level == levels - 1:
-            break  # children of the last level are leaves
-
-        # children's histograms: left by matmul over parent slots, right by
-        # subtraction (exact for integer stats; float tails are gain-clamped)
+        rows = (
+            jnp.pad(jnp.where(do_split, frontier, -1), (0, pad), constant_values=-1),
+            jnp.pad(bf, (0, pad)), jnp.pad(bb, (0, pad)), jnp.pad(left_id, (0, pad)),
+        )
         local_left = jnp.where(in_split & go_left, slot, W_l)
-        # candidate resolution for this level's 2*W_l children (monotone
-        # non-increasing); parents coarsen by adjacent-bin sums — exact
-        r_c = min(r_H, res_at(2 * W_l))
+        carry = (node, n_alloc, feat_a, bin_a, child_a, frontier, gain, bf, bb, H)
+        return carry, rows, (do_split, left_id, local_left)
+
+    def grow_level(carry, W_next: int, r_H: int, r_c: int, cut_by_gain: bool):
+        """One level but the last: split, histogram the children, and make
+        the next frontier of ``W_next`` slots from them."""
+        carry, rows, (do_split, left_id, local_left) = split_level(carry)
+        node, n_alloc, feat_a, bin_a, child_a, frontier, _, _, _, H = carry
+        W_l = frontier.shape[0]
+        # children's histograms: left by matmul over parent slots, right by
+        # subtraction (exact for integer stats; float tails are gain-clamped);
+        # parents coarsen by adjacent-bin sums — exact
         if r_c != r_H:
             H = tuple(
                 coarsen(h, g_res(r_H, nbg), g_res(r_c, nbg))
                 for h, (_, _, nbg) in zip(H, gspec)
             )
-            r_H = r_c
         H_L = hist_groups(local_left, W_l, r_c)
         cand_H = tuple(
             jnp.concatenate([hl, h - hl], axis=0)  # [2*W_l, d_g, nb_g, k+1]
@@ -1135,14 +1247,72 @@ def build_tree_deep(
         cgain, cbf, cbb = best_from_hists(cand_H, cand_id, r_c)
         cgain = jnp.where(cand_id >= 0, cgain, -jnp.inf)
 
-        W_next = min(2 * W_l, width_at(level + 1))
-        vals, sel = jax.lax.top_k(cgain, W_next)
-        live = vals > -jnp.inf
-        frontier = jnp.where(live, cand_id[sel], -1)
-        gain = vals
-        bf = cbf[sel]
-        bb = cbb[sel]
-        H = tuple(h[sel] for h in cand_H)
+        if 2 * W_l < W_next:
+            # more slots than children: holes at the end
+            more = W_next - 2 * W_l
+            cgain = jnp.pad(cgain, (0, more), constant_values=-jnp.inf)
+            cand_id = jnp.pad(cand_id, (0, more), constant_values=-1)
+            cbf, cbb = jnp.pad(cbf, (0, more)), jnp.pad(cbb, (0, more))
+            cand_H = tuple(
+                jnp.pad(h, ((0, more),) + ((0, 0),) * (h.ndim - 1)) for h in cand_H)
+        if 2 * W_l <= W_next:
+            sel = None
+        else:
+            # the W_next children of the largest gain, KEPT IN CANDIDATE
+            # ORDER (left children in frontier order, then right children):
+            # ids are dealt in frontier order and key each node's feature
+            # subset, so an order by gain would hang every later node on how
+            # near-equal gains happen to round. At the cut, equal gains go to
+            # the earlier candidates (top_k only finds the cut's value).
+            # Where the budget has room for every child the cut is -inf:
+            # the children close ranks over the holes, in the same order.
+            cut = (jax.lax.top_k(cgain, W_next)[0][-1] if cut_by_gain
+                   else jnp.float32(-jnp.inf))
+            above, at = cgain > cut, cgain == cut
+            room = W_next - jnp.sum(above.astype(jnp.int32))
+            keep = above | (at & (jnp.cumsum(at.astype(jnp.int32)) <= room))
+            sel = jnp.nonzero(keep, size=W_next, fill_value=0)[0]
+            cgain, cand_id, cbf, cbb = cgain[sel], cand_id[sel], cbf[sel], cbb[sel]
+            cand_H = tuple(h[sel] for h in cand_H)
+        frontier = jnp.where(cgain > -jnp.inf, cand_id, -1)
+        return (node, n_alloc, feat_a, bin_a, child_a, frontier,
+                cgain, cbf, cbb, cand_H), rows
+
+    # root: full histogram + its best split, in the first level's slots
+    frontier = jnp.zeros((1,), jnp.int32)
+    H = hist_groups(node, 1, res_at(2))
+    gain, bf, bb = best_from_hists(H, frontier, res_at(2))
+    more = slots[0] - 1
+    carry = (
+        node, n_alloc, feat_a, bin_a, child_a,
+        jnp.pad(frontier, (0, more), constant_values=-1),
+        jnp.pad(gain, (0, more), constant_values=-jnp.inf),
+        jnp.pad(bf, (0, more)), jnp.pad(bb, (0, more)),
+        tuple(jnp.pad(h, ((0, more),) + ((0, 0),) * (h.ndim - 1)) for h in H),
+    )
+    tables = []  # per-level routing-table rows, each [levels in the run, width]
+    level = 0
+    while level < levels - 1:
+        sig = plan[level]
+        run = 1
+        if sig[0] == sig[1] and sig[2] == sig[3]:
+            # the levels after this one that share its shapes: one scan body
+            while level + run < levels - 1 and plan[level + run] == sig:
+                run += 1
+        if run == 1:
+            carry, rows = grow_level(carry, *sig[1:])
+            tables.append(tuple(r[None] for r in rows))
+        else:
+            carry, rows = jax.lax.scan(
+                lambda c, _, sig=sig: grow_level(c, *sig[1:]), carry, None, length=run)
+            tables.append(rows)
+        level += run
+    # children of the last level are leaves
+    carry, rows, _ = split_level(carry)
+    tables.append(tuple(r[None] for r in rows))
+    node, _, feat_a, bin_a, child_a = carry[:5]
+    lvl_ids, lvl_feat, lvl_bin, lvl_left = (
+        jnp.concatenate(t) for t in zip(*tables))
 
     leaf_S = jax.ops.segment_sum(S, node, num_segments=A + 1)
     leaf_C = jax.ops.segment_sum(C, node, num_segments=A + 1)
@@ -1153,10 +1323,10 @@ def build_tree_deep(
         "child": child_a,
         "leaf_val": leaf_val,
         "leaf_weight": leaf_C,
-        "level_ids": jnp.stack(lvl_ids),
-        "level_feat": jnp.stack(lvl_feat),
-        "level_bin": jnp.stack(lvl_bin),
-        "level_left": jnp.stack(lvl_left),
+        "level_ids": lvl_ids,
+        "level_feat": lvl_feat,
+        "level_bin": lvl_bin,
+        "level_left": lvl_left,
     }
 
 
@@ -1180,20 +1350,25 @@ def _route_deep_levels(xb, level_ids, level_feat, level_bin, level_left,
     faster: [n, W] compare/one-hot-matmul forms instead of three per-row
     [A+1]-table gathers per level)."""
     n = xb.shape[0]
-    node = jnp.zeros((n,), jnp.int32)
-    for lvl in range(levels):
-        ids = level_ids[lvl]
+
+    def walk(node, table):
+        ids, feat, bins, left = table
         eq = node[:, None] == ids[None, :]  # -1 ids never match (node >= 0)
         in_split = eq.any(1)
-        cols = _col_select(xb, level_feat[lvl], n_bins or 1 << 30)
-        le = cols <= level_bin[lvl][None, :].astype(cols.dtype)
+        cols = _col_select(xb, feat, n_bins or 1 << 30)
+        le = cols <= bins[None, :].astype(cols.dtype)
         go_left = jnp.any(eq & le, axis=1)
         l_i = jnp.dot(
             eq.astype(jnp.float32),
-            level_left[lvl].astype(jnp.float32),
+            left.astype(jnp.float32),
             precision=jax.lax.Precision.HIGHEST,
         ).astype(jnp.int32)
-        node = jnp.where(in_split, l_i + 1 - go_left.astype(jnp.int32), node)
+        return jnp.where(in_split, l_i + 1 - go_left.astype(jnp.int32), node), None
+
+    # one body for every level: the tables are [levels, width] already
+    node, _ = jax.lax.scan(
+        walk, jnp.zeros((n,), jnp.int32),
+        (level_ids[:levels], level_feat[:levels], level_bin[:levels], level_left[:levels]))
     return node
 
 

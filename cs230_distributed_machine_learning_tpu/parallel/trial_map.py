@@ -40,6 +40,7 @@ import functools
 import os
 import threading
 import time
+from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -316,14 +317,24 @@ def _prepared_data(kernel, data, static_key, static):
         try:
             object.__setattr__(data, "_prepared_cache", cache)
         except Exception:  # exotic TrialData subclass: just don't cache
-            return kernel.prepare_data(np.asarray(data.X), static)
+            cache = None
     # trace_salt folds in the resolve-time env knobs (CS230_TREE_DEEP_N,
     # CS230_DEEP_W_FORCE, ...) that change prepare_data output without
     # changing the static bucket key — a knob flip mid-process must miss
     key = (kernel.name, static_key, kernel.trace_salt())
-    if key not in cache:
-        cache[key] = kernel.prepare_data(np.asarray(data.X), static)
-    return cache[key]
+    hit = cache is not None and key in cache
+    # one span a bucket, hits included: on a miss the host bins the whole
+    # table (quantiles, a device round trip for the codes), which is most
+    # of what a forest search's first bucket does before its first dispatch
+    with child_span("executor.prepare", outcome="hit" if hit else "miss",
+                    bytes=0) as sp:
+        if hit:
+            return cache[key]
+        prepared = kernel.prepare_data(np.asarray(data.X), static)
+        sp.attrs["bytes"] = int(_sc._tree_nbytes(prepared))
+    if cache is not None:
+        cache[key] = prepared
+    return prepared
 
 
 #: distinct staged entries kept per dataset — each can be dataset-sized in
@@ -932,7 +943,8 @@ class _Run:
         self.buckets_priced = 0
         self.xla_flops = 0.0
         self.xla_bytes = 0.0
-        #: (out | [(group out, n folds)], batch_idx) awaiting the drain
+        #: (out | [(group out, n folds)], batch_idx[, a last step on the
+        #: fetched result]) awaiting the drain
         self.pending: List[Any] = []
         #: (lane, score, batch_idx) of each chunk's collective argmax
         #: (multi-device mesh only), read at the drain
@@ -1023,7 +1035,7 @@ class _Run:
         # conversion (serial round trips otherwise)
         for bi, bs, _ in self.pending_best:
             prefetch_async((bi, bs))
-        for out, _ in self.pending:
+        for out, *_ in self.pending:
             for og, _size in out if isinstance(out, list) else [(out, None)]:
                 prefetch_async(og.buf if isinstance(og, Packed) else og)
         if self.pending_best:
@@ -1034,13 +1046,15 @@ class _Run:
                     if pos < len(batch_idx) and np.isfinite(score):
                         self.merge_best(batch_idx[pos], score)
             self.pending_best.clear()
-        for out, batch_idx in self.pending:
+        for out, batch_idx, *post in self.pending:
             if isinstance(out, list):
                 out = _join_split_groups(
                     [(self.fetch(og), size) for og, size in out]
                 )
             else:
                 out = self.fetch(out)
+            for step in post:
+                out = step(out)
             self.record(out, batch_idx)
         self.pending.clear()
         if self.t_first_dispatch is not None:
@@ -1141,6 +1155,40 @@ def _build_executable(key, make_parts):
             _compiled_cache[key] = tuple(built)
             sp.attrs["cache"] = source
     return _compiled_cache[key], fresh
+
+
+_compile_pool: Optional[ThreadPoolExecutor] = None
+
+
+def _compile_ahead(key, built, examples) -> Callable[[], tuple]:
+    """Start the backend compiles (or persistent-cache loads) of a freshly
+    built entry's one-device programs on worker threads. The returned
+    ``wait()`` gives the entry's ``(fn, pack_spec, cost)`` triples with the
+    compiled programs in the jitted ones' place, and leaves them in
+    ``_compiled_cache[key]``. ``jax.jit`` compiles at a program's first
+    call, on the caller's thread: a search whose every trial is a bucket
+    of its own (the forests) then compiles bucket after bucket, most of a
+    minute a step program at a Covertype shape, with the host's other cores idle.
+    XLA compiles outside the GIL, so the caller goes on to trace the next
+    bucket meanwhile."""
+    global _compile_pool
+    if _compile_pool is None:
+        _compile_pool = ThreadPoolExecutor(
+            max_workers=4, thread_name_prefix="tpuml-compile")
+    compiling = [
+        _compile_pool.submit(lambda fn=fn, ex=ex: fn.lower(*ex).compile())
+        for (fn, _, _), ex in zip(built, examples)
+    ]
+
+    def wait():
+        done = tuple(
+            (c.result(), spec, cost)
+            for c, (_, spec, cost) in zip(compiling, built)
+        )
+        _compiled_cache[key] = done
+        return done
+
+    return wait
 
 
 def _mesh_key(mesh) -> tuple:
@@ -1509,6 +1557,8 @@ def _run_buckets(kernel, data, split_plan, param_dicts, mesh, trial_axis,
         hypers.append(hyper)
         buckets.setdefault(static_key, []).append(i)
 
+    #: the dispatches of the chunked buckets built so far (off a mesh)
+    chunked: List[Callable[[], None]] = []
     for static_key, idxs in buckets.items():
         static = _resolved_static(kernel, static_key, n, d, data.n_classes,
                                   scoring)
@@ -1540,11 +1590,13 @@ def _run_buckets(kernel, data, split_plan, param_dicts, mesh, trial_axis,
         X = _stage_X(data, x_key, host_X, place,
                      replicate_only=bp.engine == "chunked")
         if bp.engine == "chunked":
-            # the chunked bucket runs blocking: flush what is queued first,
-            # or its wall would be counted inside the dispatch window
-            run.drain()
-            _run_chunked(run, kernel, bp, X, run.folds(data, place), data,
-                         hypers, idxs, warm_only)
+            # built now, dispatched once every bucket is built, fetched at
+            # the drain: a fresh bucket's programs compile on worker
+            # threads while the host prepares and traces the next bucket's
+            dispatch = _run_chunked(run, kernel, bp, X, run.folds(data, place),
+                                    data, hypers, idxs, warm_only)
+            if dispatch is not None:
+                chunked.append(dispatch)
             continue
         folds = run.folds(data, place)
         extras = None
@@ -1559,6 +1611,8 @@ def _run_buckets(kernel, data, split_plan, param_dicts, mesh, trial_axis,
         if not warm_only:
             _dispatch(run, bp, exe, fresh, X, folds, extras, hypers, idxs)
 
+    for dispatch in chunked:
+        dispatch()
     run.drain()
     return run.result(mesh)
 
@@ -1731,7 +1785,7 @@ def _chunk_best(mesh, trial_axis: str, chunk: int, n_splits: int, n_folds: int):
 
 
 def _run_chunked(run: _Run, kernel, bp: BucketPlan, X, folds, data, hypers,
-                 idxs, warm_only: bool = False) -> None:
+                 idxs, warm_only: bool = False) -> Optional[Callable[[], None]]:
     """Run one bucket through the kernel's chunked-fit protocol.
 
     init -> n_chunks x step -> eval, all vmapped over (trials, splits); the
@@ -1743,7 +1797,13 @@ def _run_chunked(run: _Run, kernel, bp: BucketPlan, X, folds, data, hypers,
     trial axis of hypers and state is NamedSharded across devices (data
     replicated) so each chip carries its trial slice through every chunk,
     and a chunk with an unsplit fold stack merges its collective-argmax
-    winner into the run. The bucket runs blocking, chunk by chunk.
+    winner into the run (read at once: on a mesh the bucket blocks). Off a
+    mesh this only BUILDS the bucket and returns its dispatch, which the
+    caller runs once every bucket is built; the result is fetched at the
+    run's drain like every other bucket's. Every trial of a forest search
+    is a bucket of its own: a freshly built bucket's three programs compile
+    on worker threads (:func:`_compile_ahead`) while the host traces the
+    next bucket's, and the dispatch waits for its own compile alone.
     """
     static, chunk_plan, chunk = bp.static, bp.chunk_plan, bp.chunk
     hyper_names = bp.hyper_names
@@ -1796,11 +1856,14 @@ def _run_chunked(run: _Run, kernel, bp: BucketPlan, X, folds, data, hypers,
         kernel, static, X, data.n_classes, sg, chunk, hyper_names
     ) + (n_chunks, chunk_plan.get("trees_per_chunk"))
 
+    examples: List[tuple] = []
+
     def parts():
         args_i = _example_args(X, y, *split_groups[0][:2], hyper_names, chunk)
         state_ex = jax.eval_shape(vinit, *args_i)
         args_s = args_i + (jax.ShapeDtypeStruct((), jnp.int32), state_ex)
         args_e = args_i + (state_ex,)
+        examples.extend((args_i, args_s, args_e))
         if mesh is None:
             # only eval's output crosses to the host; no dispatch of the
             # protocol is priced (the bucket's analytical FLOPs are)
@@ -1836,87 +1899,122 @@ def _run_chunked(run: _Run, kernel, bp: BucketPlan, X, folds, data, hypers,
     # freshly traced executables land in the first batch's run_time; the
     # persistent compile cache keeps that small.
     t_build = time.perf_counter()
-    ((fi, _, _), (fs, _, _), (fe, fe_spec, _)), fresh = _build_executable(
-        ("chunked",) + base + _mesh_key(mesh), parts
-    )
+    key = ("chunked",) + base + _mesh_key(mesh)
+    built, fresh = _build_executable(key, parts)
     if fresh:
         dt = time.perf_counter() - t_build
         run.compile_time += dt
         observe("tpuml_executor_compile_seconds", dt)
     if warm_only:
-        return
+        return None
+    compiled = None
+    if fresh and mesh is None:
+        compiled = _compile_ahead(key, built, examples)
 
-    for start in range(0, len(idxs), chunk):
-        batch_idx = idxs[start : start + chunk]
-        hyper_arg = {
-            k: jnp.asarray(v) for k, v in
-            _hyper_batch(hypers, batch_idx, hyper_names, chunk).items()
-        }
-        t0 = time.perf_counter()
-        with _dispatch_span(mesh, "chunked", start // chunk, chunk,
-                            len(batch_idx)):
-            group_outs = []
-            group_curves = []
-            for twg, ewg, size in split_groups:
-                state = fi(X, y, twg, ewg, hyper_arg)
-                mids = []
-                for ci in range(n_chunks):
-                    state = fs(X, y, twg, ewg, hyper_arg, jnp.int32(ci), state)
-                    if (
-                        curve_stride
-                        and (ci + 1) % curve_stride == 0
-                        and ci < n_chunks - 1
-                    ):
-                        # trial telemetry plane: score-vs-chunk curve via
-                        # strided extra eval dispatches on the existing fe
-                        # executable (the accumulator protocol makes every
-                        # prefix a valid model) — the tree kernels themselves
-                        # are untouched. eval is O(n*k) against the chunk's
-                        # O(n*k*trees) build, so the sampled extra evals stay
-                        # inside the curve overhead gate.
-                        mids.append(fe(X, y, twg, ewg, hyper_arg, state))
-                group_outs.append((fe(X, y, twg, ewg, hyper_arg, state), size))
-                group_curves.append(mids)
-                run.dispatches += len(mids)
-        if mesh is not None:
-            score = group_outs[0][0]["score"]
-            run.n_result_devices = max(
-                run.n_result_devices, len(score.sharding.device_set)
-            )
-            if len(split_groups) == 1:
-                # collective argmax on the trial-sharded eval output, read
-                # at once (the bucket runs blocking); split-group runs skip
-                # it: their fold means span executables
-                bi, bs = _chunk_best(
-                    mesh, trial_axis, chunk, sg, run.split_plan.n_folds
-                )(score, jnp.int32(len(batch_idx)))
-                pos, best = int(bi), float(bs)
-                run.n_fetches += 2
-                if pos < len(batch_idx) and np.isfinite(best):
-                    run.merge_best(batch_idx[pos], best)
-        for og, _size in group_outs:
-            prefetch_async(og)
-        group_outs = [(run.fetch(og, fe_spec), size) for og, size in group_outs]
-        mids_host = [
-            [run.fetch(og, fe_spec) for og in mids] for mids in group_curves
-        ]
-        out = _join_split_groups(group_outs)
-        if curve_stride:
-            cs = [
-                np.stack(
-                    [m["score"][:, :size] for m in row]
-                    + [host["score"][:, :size]],
-                    axis=-1,
+    # what a chunked bucket did, on its dispatch spans: the chunk geometry
+    # and, where the kernel says it (the forests), the shape of one fit
+    shape_attrs = {"n_chunks": n_chunks, "split_lanes": sg}
+    if "trees_per_chunk" in chunk_plan:
+        shape_attrs["trees_per_chunk"] = int(chunk_plan["trees_per_chunk"])
+    if hasattr(kernel, "dispatch_attrs"):
+        shape_attrs.update(kernel.dispatch_attrs(static, X))
+
+    def dispatch() -> None:
+        fns = None
+        for start in range(0, len(idxs), chunk):
+            batch_idx = idxs[start : start + chunk]
+            hyper_arg = {
+                k: jnp.asarray(v) for k, v in
+                _hyper_batch(hypers, batch_idx, hyper_names, chunk).items()
+            }
+            if "hist_levels_by_route" in shape_attrs:
+                # tree levels histogrammed by this batch, by the form they took
+                fits = (len(batch_idx) * int(run.split_plan.n_splits)
+                        * int(static.get("n_estimators", 1)))
+                for part in shape_attrs["hist_levels_by_route"].split(","):
+                    route, count = part.split(":")
+                    counter_inc("tpuml_tree_levels_total", fits * int(count), route=route)
+            if run.t_first_dispatch is None:
+                run.t_first_dispatch = time.perf_counter()
+            with _dispatch_span(mesh, "chunked", start // chunk, chunk,
+                                len(batch_idx), **shape_attrs) as sp:
+                if fns is None:
+                    # a fresh bucket's compile is waited for in its first
+                    # batch's span, where jit's first-call compile landed
+                    fns = compiled() if compiled is not None else built
+                (fi, _, _), (fs, _, _), (fe, fe_spec, _) = fns
+                group_outs = []
+                group_curves = []
+                for twg, ewg, size in split_groups:
+                    state = fi(X, y, twg, ewg, hyper_arg)
+                    mids = []
+                    for ci in range(n_chunks):
+                        state = fs(X, y, twg, ewg, hyper_arg, jnp.int32(ci), state)
+                        if (
+                            curve_stride
+                            and (ci + 1) % curve_stride == 0
+                            and ci < n_chunks - 1
+                        ):
+                            # trial telemetry plane: score-vs-chunk curve via
+                            # strided extra eval dispatches on the existing fe
+                            # executable (the accumulator protocol makes every
+                            # prefix a valid model) — the tree kernels themselves
+                            # are untouched. eval is O(n*k) against the chunk's
+                            # O(n*k*trees) build, so the sampled extra evals stay
+                            # inside the curve overhead gate.
+                            mids.append(fe(X, y, twg, ewg, hyper_arg, state))
+                    group_outs.append((fe(X, y, twg, ewg, hyper_arg, state), size))
+                    group_curves.append(mids)
+                # programs enqueued for the batch: init + steps + evals (the
+                # curve's sampled evals among them) of every split group
+                sp.attrs["dispatches"] = (
+                    (2 + n_chunks) * len(split_groups)
+                    + sum(len(mids) for mids in group_curves)
                 )
-                for (host, size), row in zip(group_outs, mids_host)
-            ]
-            out["curve_score"] = np.concatenate(cs, axis=1)
-            shape2 = out["score"].shape[:2]
-            out["curve_stride"] = np.full(shape2, float(curve_stride), np.float32)
-            out["curve_steps"] = np.full(shape2, float(n_chunks), np.float32)
-        run.run_time += time.perf_counter() - t0
-        run.dispatches += (2 + n_chunks) * len(split_groups)
-        run.record(out, batch_idx)
+                run.dispatches += sp.attrs["dispatches"]
+            if mesh is not None:
+                score = group_outs[0][0]["score"]
+                run.n_result_devices = max(
+                    run.n_result_devices, len(score.sharding.device_set)
+                )
+                if len(split_groups) == 1:
+                    # collective argmax on the trial-sharded eval output, read
+                    # at once (the bucket runs blocking); split-group runs skip
+                    # it: their fold means span executables
+                    bi, bs = _chunk_best(
+                        mesh, trial_axis, chunk, sg, run.split_plan.n_folds
+                    )(score, jnp.int32(len(batch_idx)))
+                    pos, best = int(bi), float(bs)
+                    run.n_fetches += 2
+                    if pos < len(batch_idx) and np.isfinite(best):
+                        run.merge_best(batch_idx[pos], best)
+
+            def with_curve(out, mids=group_curves, sizes=[z for _, z in group_outs]):
+                """The drain's last step for this batch: the sampled evals
+                fetched and laid beside the final score, chunk by chunk."""
+                at, cs = 0, []
+                for row, size in zip(mids, sizes):
+                    last = out["score"][:, at:at + size]
+                    cs.append(np.stack(
+                        [run.fetch(og, fe_spec)["score"][:, :size] for og in row]
+                        + [last], axis=-1))
+                    at += size
+                out["curve_score"] = np.concatenate(cs, axis=1)
+                shape2 = out["score"].shape[:2]
+                out["curve_stride"] = np.full(shape2, float(curve_stride), np.float32)
+                out["curve_steps"] = np.full(shape2, float(n_chunks), np.float32)
+                return out
+
+            run.pending.append((
+                [(Packed(og, fe_spec) if fe_spec is not None else og, size)
+                 for og, size in group_outs],
+                batch_idx, *([with_curve] if curve_stride else []),
+            ))
+
+    if mesh is not None:
+        dispatch()
+        return None
+    return dispatch
 
 
 def _run_streamed(run: _Run, kernel, bp: BucketPlan, X_np, data, hypers,

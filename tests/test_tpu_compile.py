@@ -158,8 +158,11 @@ def test_masked_softmax_grad(tpu_backend, vmapped):
 @pytest.mark.parametrize("n_bins", [17, 32, 64])
 def test_hist_gate_admits_only_what_compiles(tpu_backend, n_bins, n_nodes):
     """Covertype forests, 7 integer stat columns: whatever shape the
-    ``auto`` route's gate admits must get through the compiler. 64 bins
-    needs 18.4 MB of scoped VMEM at a 64-node block against a 16 MB limit."""
+    kernel's gate admits must get through the compiler. 64 bins needs
+    18.4 MB of scoped VMEM at a 64-node block against a 16 MB limit. The
+    ``auto`` route takes the matmul form on a TPU since PR 32 (the kernel
+    lost to it by 3.4 times over a forest search on the v5e) and the
+    kernel runs behind ``CS230_HIST_KERNEL=pallas``."""
     from cs230_distributed_machine_learning_tpu.ops.pallas_hist import (
         level_histogram_pallas, pallas_hist_applicable,
     )
@@ -168,9 +171,8 @@ def test_hist_gate_admits_only_what_compiles(tpu_backend, n_bins, n_nodes):
     )
 
     kk, n = 7, 20_000
-    routed = _resolve_hist_kernel(True, (D,), (n_bins,), kk)
-    assert (routed == "pallas") == pallas_hist_applicable(D, n_bins, kk)
-    if routed != "pallas":
+    assert _resolve_hist_kernel(True, (D,), (n_bins,), kk) == "matmul"
+    if not pallas_hist_applicable(D, n_bins, kk):
         return
 
     def hist(local, xb, SC):
@@ -414,3 +416,96 @@ def test_every_other_pallas_call_has_its_name(tpu_backend, name):
     }[name]()
     text = lowered.lower(lowering_platforms=("tpu",)).as_text()
     assert f'kernel_name = "{name}"' in text
+
+
+# ---------------------------------------------------------------------------
+# the forest cell (rf_covertype.rs4, PR 32): one chunk of the chunked
+# protocol at the cell's shape. The whole step program takes the TPU compiler
+# a minute and more here (three with its 24 levels unrolled, before the deep
+# builder's level plan), so this file lowers it for the TPU and reads the
+# level plan off the trace; the Pallas histogram, which the `auto` route no
+# longer takes, is compiled alone at the shapes the step would give it.
+# ---------------------------------------------------------------------------
+
+_FOREST_PARAMS = {"n_estimators": 4, "max_depth": None, "bootstrap": True,
+                  "random_state": 0, "max_features": "sqrt", "min_samples_leaf": 1}
+#: Covertype's column kinds as prepare_data groups them, and the cell's rows
+#: (two fifths of the source's 581 012)
+_D_CONT, _D_COARSE, _N_FOREST = 10, 44, 232_405
+
+
+def _forest_step():
+    """(vstep, its example arguments, kernel, static, X) as
+    ``trial_map._run_chunked`` builds them for one trial of the cell."""
+    from cs230_distributed_machine_learning_tpu.parallel import trial_map
+
+    kernel = get_kernel("RandomForestClassifier")
+    static_key, _ = kernel.canonicalize(_FOREST_PARAMS)
+    static = trial_map._resolved_static(kernel, static_key, _N_FOREST, D, C)
+    X = {"X": _sds((_N_FOREST, D), jnp.float32), "xb": _sds((_N_FOREST, D), jnp.int32),
+         "edges": _sds((D, 47), jnp.float32),
+         "xb_cont": _sds((_N_FOREST, _D_CONT), jnp.int32), "xb_coarse": _sds((_N_FOREST, _D_COARSE), jnp.int32),
+         "fid_cont": _sds((_D_CONT,), jnp.int32), "fid_coarse": _sds((_D_COARSE,), jnp.int32)}
+    plan = kernel.chunked_plan(static, _N_FOREST, D, C, S, prepared=X)
+
+    def step_b(X, y, TW, EW, hyper, ci, state):
+        return jax.vmap(lambda tw, st: kernel.chunk_step(
+            X, y, tw, {}, static, ci, st, plan))(TW, state)
+
+    vstep = jax.vmap(step_b, in_axes=(None, None, None, None, 0, None, 0))
+    args = (X, _sds((_N_FOREST,), jnp.int32), _sds((S, _N_FOREST), jnp.float32),
+            _sds((S, _N_FOREST), jnp.float32),
+            {"_pad": _sds((1,), jnp.float32)}, _sds((), jnp.int32),
+            _sds((1, S, _N_FOREST, C), jnp.float32))
+    return vstep, args, kernel, static, X, plan
+
+
+def test_forest_chunked_step_lowers_at_the_cell_shape(tpu_backend, monkeypatch):
+    from cs230_distributed_machine_learning_tpu.ops import trees as ops_trees
+
+    vstep, args, kernel, static, X, plan = _forest_step()
+    assert plan == {"n_chunks": 4, "trees_per_chunk": 1}
+    assert (static["_W"], static["_levels"], static["_wsched"]) == (1536, 24, (1536, 17, 512))
+    seen, resolve = [], ops_trees._resolve_hist_kernel
+
+    def spy(integer_stats, ds, n_binss, kk):
+        seen.append((tuple(ds), tuple(n_binss), kk, resolve(integer_stats, ds, n_binss, kk)))
+        return seen[-1][-1]
+
+    monkeypatch.setattr(ops_trees, "_resolve_hist_kernel", spy)
+    text = jax.jit(vstep).trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+    # the level plan: 24 histograms from 9 traced bodies. The root, levels
+    # 0-5 as one scan over 64 slots and level 6 (64 -> 512 slots) at 48 bins;
+    # levels 7, 8, 9 (512 slots, 9 handing 1536 on), levels 10-15 as one scan
+    # over 1536, level 16 (1536 -> 512) and levels 17-22 as one scan over 512
+    # at 16; the coarse group at 4
+    assert seen == ([((_D_CONT, _D_COARSE), (48, 4), C, "matmul")] * 3
+                    + [((_D_CONT, _D_COARSE), (16, 4), C, "matmul")] * 6)
+    assert text.count("stablehlo.while") >= 9 + 3  # a row scan each, and the three level scans
+    assert "level_histogram" in jax.jit(vstep).trace(*args).jaxpr.pretty_print(name_stack=True)
+    # what the dispatch span says of the fit is what the trace resolved
+    monkeypatch.setattr(ops_trees, "_resolve_hist_kernel", resolve)
+    assert kernel.dispatch_attrs(static, X) == {
+        "levels": 24, "arena_width": 1536, "hist_route": "matmul",
+        "hist_levels_by_route": "matmul:24"}
+
+
+@pytest.mark.parametrize("n_nodes,d,n_bins", [
+    (1536, _D_CONT, 16), (1536, _D_COARSE, 4), (64, _D_CONT, 48), (512, _D_CONT, 16)])
+def test_forest_level_histograms_compile_under_six_lanes(tpu_backend, n_nodes, d, n_bins):
+    """The cell's level histograms as the Pallas kernel takes them behind
+    ``CS230_HIST_KERNEL=pallas``: one Mosaic call over the six split lanes
+    (the lane axis becomes a grid axis), at the level plan's slot counts and
+    both resolutions."""
+    from cs230_distributed_machine_learning_tpu.ops.pallas_hist import (
+        level_histogram_pallas, pallas_hist_applicable,
+    )
+
+    assert pallas_hist_applicable(d, n_bins, C)
+
+    def lanes(local, xb, SC):
+        return jax.vmap(lambda lo, sc: level_histogram_pallas(
+            lo, xb, sc, n_nodes, n_bins, integer_stats=True))(local, SC)
+
+    _lower_and_compile(lanes, _sds((S, _N_FOREST), jnp.int32), _sds((_N_FOREST, d), jnp.int32),
+                       _sds((S, _N_FOREST, C), jnp.float32))

@@ -256,6 +256,44 @@ def test_covertype_quarter_rf_parity():
     assert ours > sk - 0.03, (ours, sk)
 
 
+@pytest.mark.parametrize("pad", [1, 4, 64])
+def test_deep_builder_grows_one_tree_whatever_its_frontier_slots(monkeypatch, pad):
+    """The deep builder pads a level's frontier to a few slot counts so
+    that runs of levels share a ``lax.scan`` body (PR 32). Holes carry no
+    node: the arena, the leaves and every prediction are the same tree
+    whatever the padding, the budget's cut by gain included."""
+    import jax
+    import jax.numpy as jnp
+
+    from cs230_distributed_machine_learning_tpu.ops import trees as ot
+
+    rng = np.random.default_rng(11)
+    n, d, k, n_bins, levels = 3000, 12, 4, 32, 12
+    xb = rng.integers(0, n_bins, size=(n, d)).astype(np.int32)
+    xb[:, 4:] = rng.integers(0, 2, size=(n, d - 4))
+    groups = {"xb_cont": jnp.asarray(xb[:, :4]), "xb_coarse": jnp.asarray(xb[:, 4:]),
+              "fid_cont": jnp.arange(4, dtype=jnp.int32),
+              "fid_coarse": jnp.arange(4, d, dtype=jnp.int32)}
+    y = (xb[:, 0] * 3 + xb[:, 5] + rng.integers(0, 9, n)) % k
+    w = rng.integers(0, 4, n).astype(np.float32)
+    S = np.eye(k, dtype=np.float32)[y] * w[:, None]
+
+    def grow(slots):
+        monkeypatch.setattr(ot, "FRONTIER_PAD", slots)
+        tree = jax.jit(lambda xb, S, C: ot.build_tree_deep(
+            xb, S, C, levels=levels, width=32, n_bins=n_bins, min_samples_leaf=2.0,
+            max_features=4, key=jax.random.PRNGKey(5), count_from_stats=True,
+            groups=groups, w_schedule=(32, 8, 16), nb_schedule=(16, 8)))(xb, S, w)
+        leaves = ot.predict_tree_deep(jnp.asarray(xb), tree, levels, n_bins)
+        return {name: np.asarray(v) for name, v in tree.items()}, np.asarray(leaves)
+
+    (want, want_leaves), (got, got_leaves) = grow(2 ** 20), grow(pad)
+    assert (want["child"] > 0).sum() > 100  # the 32- and 16-node budgets both cut
+    for name in ("feat", "bin", "child", "leaf_val", "leaf_weight"):
+        np.testing.assert_array_equal(got[name], want[name], err_msg=name)
+    np.testing.assert_array_equal(got_leaves, want_leaves)
+
+
 def test_gather_free_ops_match_reference_forms():
     """The MXU forms in ops/trees (_route_left, _leaf_sums, _leaf_select,
     triangular-ones prefix sums in _split_gain) must reproduce the gather /
