@@ -6,9 +6,10 @@ onehot(bin)[r, f, b]`` (52% of the per-level budget at the production
 shape, benchmarks/deep_profile.py). The XLA one-hot matmul form
 (``ops/trees.py:_level_histogram_multi``) materializes BOTH 0/1 operands
 in HBM between the elementwise one-hot construction and the dot — the
-``T1 = onehot(node) ⊗ SC`` tensor ([row_chunk, n_nodes*kk], ~1 GB/level
-of write+read traffic per lane at W=1024) is the measured dominant
-memory-traffic term.
+``T1 = onehot(node) ⊗ SC`` tensor ([row_chunk, kk*n_nodes], its columns
+stat-major since PR 33, so it is written once in the layout the dot reads;
+~1 GB/level of write+read traffic per lane at W=1024) is the measured
+dominant memory-traffic term.
 
 Two replacements, selected by the ``CS230_HIST_KERNEL`` valve in
 ops/trees.py:

@@ -80,8 +80,9 @@ _HIST_ROW_CHUNK = 16384
 def _hist_kernel_mode() -> str:
     """CS230_HIST_KERNEL valve over the level-histogram implementations:
 
-    - ``matmul``  — the XLA one-hot matmul contraction below (the
-      pre-PR-6 form; both 0/1 operands materialize in HBM);
+    - ``matmul``  — the XLA one-hot matmul contraction below (both 0/1
+      operands materialize in HBM; the left one's columns are stat-major
+      since PR 33, so nothing re-tiles it on the way to the dot);
     - ``pallas``  — the fused Pallas kernel (ops/pallas_hist.py): one-hot
       tiles built in VMEM, accumulator page resident across row tiles;
     - ``scatter`` — the literal bin-and-scatter segment-sum form
@@ -132,12 +133,29 @@ def _level_histogram_forms(local, xbs, SC, n_nodes: int, n_binss,
     ~10-30x). Rows stream through a lax.scan so peak memory is
     O(row_chunk · (n_nodes·kk + sum d_g·nb_g)) regardless of n.
 
-    The left operand T1 = one_hot(node) ⊗ SC ([row_chunk, n_nodes*kk], the
+    The left operand T1 = one_hot(node) ⊗ SC ([row_chunk, kk*n_nodes], the
     histogram's dominant memory-traffic term at wide frontiers) is built
     ONCE per chunk and contracted against every group's bin one-hot — this
     is why grouped histograms fuse into one scan instead of calling a
     single-group kernel per group (an A/B of the two-scan form measured NO
     win: the duplicated T1 traffic ate the narrower matmuls' savings).
+
+    Its columns are **stat-major** (column ``stat * n_nodes + node``): one
+    [row_chunk, n_nodes] slab a stat, concatenated, which the TPU compiler
+    writes slab by slab straight into the layout the dots read, and the
+    accumulators' rows keep that order until the histograms leave this
+    function. Until PR 33 the columns were node-major (``node * kk + stat``,
+    from a [row_chunk, n_nodes, kk] product reshaped): the compiler put the
+    kk = 7 stats on sublanes, padded to a tile, and re-tiled the whole
+    operand with a ``reshape`` op of its own before the dots, a second write
+    and read of it in every row chunk of every level: 14.5 s of a 46.8 s
+    Covertype forest search on the v5e and 1.1 GB of its peak memory
+    (PERF.md section 6, PR 33; tests/test_tpu_compile.py pins the compiled
+    row loop). The same order built as one rows-minor product,
+    ``(SC.T[:, None, :] * one_hot(node).T[None]).reshape(kk * n_nodes,
+    row_chunk)``, was 1.9 s a search slower and, though bit-equal alone,
+    gave wrong trees inside the compiled step program on the chip (PERF.md,
+    the same section): do not go back to it without that check.
 
     ``integer_stats``: the stat columns are small non-negative integers
     (< 128 — classification one-hots times bootstrap counts, which
@@ -195,7 +213,9 @@ def _level_histogram_forms(local, xbs, SC, n_nodes: int, n_binss,
         lb = jax.lax.dynamic_slice(local, (start,), (rc,))
         SCb = jax.lax.dynamic_slice(SC, (start, 0), (rc, kk)).astype(op_dt)
         N = jax.nn.one_hot(lb, n_nodes, dtype=op_dt)  # [rc, nodes]
-        T1 = (N[:, :, None] * SCb[:, None, :]).reshape(rc, n_nodes * kk)
+        # stat-major: one [rc, nodes] slab a stat, written side by side into
+        # the layout the dots read (the docstring says why)
+        T1 = jnp.concatenate([N * SCb[:, j:j + 1] for j in range(kk)], axis=1)
         out = []
         for H, xb, d, n_bins in zip(Hs, xbs, ds, n_binss):
             xbb = jax.lax.dynamic_slice(xb, (start, 0), (rc, d))
@@ -217,10 +237,10 @@ def _level_histogram_forms(local, xbs, SC, n_nodes: int, n_binss,
     )
     starts = jnp.arange(0, n_pad, rc, dtype=jnp.int32)
     Hs, _ = jax.lax.scan(body, H0, starts)
-    # rows are node-major over kk; cols feature-major over bins
+    # rows are stat-major over nodes; cols feature-major over bins
     return tuple(
-        H.astype(jnp.float32).reshape(n_nodes, kk, d, n_bins).transpose(
-            0, 2, 3, 1
+        H.astype(jnp.float32).reshape(kk, n_nodes, d, n_bins).transpose(
+            1, 2, 3, 0
         )
         for H, d, n_bins in zip(Hs, ds, n_binss)
     )

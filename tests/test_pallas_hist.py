@@ -158,3 +158,70 @@ def test_pallas_hist_applicability_gate():
     assert not pallas_hist_applicable(54, 64, 7)
     assert not pallas_hist_applicable(784, 64, 8)  # MNIST-wide: page too big
     assert not pallas_hist_applicable(10, 512, 8)  # bins over the lane cap
+
+
+# ---------------------------------------------------------------------------
+# The matmul form's stat-major order and its undoing (PR 33). The left
+# operand and the accumulators' rows are ``stat * n_nodes + node``; the
+# result leaves as [n_nodes, d, n_bins, kk] through
+# ``reshape(kk, n_nodes, d, n_bins).transpose(1, 2, 3, 0)``. A wrong undoing
+# still has the right shape, so the scatter form (which never has the order)
+# is the judge, over two feature groups of different bin counts in one call,
+# a row count that is no multiple of the row chunk (the padded rows) and
+# dead rows (id == n_nodes).
+# ---------------------------------------------------------------------------
+
+_N_ROWS, _GROUPS = 1237, ((5, 16), (9, 4))  # (columns, bins) a group
+
+
+def _grouped_case(rng, n_nodes, kk, float_stats, lanes=None):
+    lead = () if lanes is None else (lanes,)
+    local = jnp.asarray(rng.randint(0, n_nodes + 1, lead + (_N_ROWS,)).astype(np.int32))
+    xbs = tuple(jnp.asarray(rng.randint(0, nb, (_N_ROWS, d)).astype(np.int32))
+                for d, nb in _GROUPS)
+    stats = (rng.randn(*lead, _N_ROWS, kk) if float_stats
+             else rng.randint(0, 5, lead + (_N_ROWS, kk)))
+    return local, xbs, jnp.asarray(stats.astype(np.float32))
+
+
+def _grouped(monkeypatch, mode, local, xbs, SC, n_nodes, float_stats):
+    monkeypatch.setenv("CS230_HIST_KERNEL", mode)
+    prec = jax.lax.Precision.HIGHEST if float_stats else None
+    nbs = tuple(nb for _, nb in _GROUPS)
+
+    def one(lo, sc):
+        return T._level_histogram_multi(
+            lo, xbs, sc, n_nodes, nbs, prec, integer_stats=not float_stats)
+
+    # the split lanes as trial_map._run_chunked maps them: node ids and
+    # stats batched, the bin codes shared
+    Hs = one(local, SC) if local.ndim == 1 else jax.vmap(one)(local, SC)
+    return [np.asarray(H) for H in Hs]
+
+
+@pytest.mark.parametrize("float_stats", [False, True], ids=["int", "float"])
+@pytest.mark.parametrize("lanes", [None, 3], ids=["plain", "vmap"])
+@pytest.mark.parametrize("kk", [2, 7])
+@pytest.mark.parametrize("n_nodes", [1, 17, 64])
+def test_matmul_stat_major_order_matches_scatter(monkeypatch, n_nodes, kk, lanes, float_stats):
+    """Bit-equal for integer stats (s8 x s8 -> s32), allclose for float
+    stats (float32 operands, HIGHEST), whatever the frontier and however
+    many stat columns; alone and under ``jax.vmap`` over split lanes, where
+    the lane axis leads the operand and the accumulators and the order's
+    undoing stays per lane."""
+    monkeypatch.setattr(T, "_HIST_ROW_CHUNK", 512)  # 1237 rows: two whole chunks and a padded one
+    lead = () if lanes is None else (lanes,)
+    local, xbs, SC = _grouped_case(np.random.RandomState(n_nodes * 10 + kk), n_nodes, kk, float_stats, lanes)
+    got = _grouped(monkeypatch, "matmul", local, xbs, SC, n_nodes, float_stats)
+    want = _grouped(monkeypatch, "scatter", local, xbs, SC, n_nodes, float_stats)
+    # every live row lands once a column: the stats' totals are the input's
+    totals = np.where((np.asarray(local) < n_nodes)[..., None], np.asarray(SC), 0).sum(axis=-2)
+    for g, w, (d, nb) in zip(got, want, _GROUPS):
+        assert g.shape == lead + (n_nodes, d, nb, kk) and g.dtype == np.float32
+        if float_stats:
+            np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-5 * np.abs(w).max())
+            np.testing.assert_allclose(g.sum(axis=(-4, -2)), np.broadcast_to(totals[..., None, :], lead + (d, kk)),
+                                       rtol=1e-4, atol=1e-3)
+        else:
+            np.testing.assert_array_equal(g, w)
+            np.testing.assert_array_equal(g.sum(axis=(-4, -2)), np.broadcast_to(totals[..., None, :], lead + (d, kk)))

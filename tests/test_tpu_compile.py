@@ -11,6 +11,9 @@ covers that on the chip.
 """
 
 import functools
+import importlib.util
+import json
+import os
 import re
 
 import jax
@@ -509,3 +512,59 @@ def test_forest_level_histograms_compile_under_six_lanes(tpu_backend, n_nodes, d
 
     _lower_and_compile(lanes, _sds((S, _N_FOREST), jnp.int32), _sds((_N_FOREST, d), jnp.int32),
                        _sds((S, _N_FOREST, C), jnp.float32))
+
+
+def _perfbench_forest_cell():
+    """The forest cell as the benchmark's readers see it, and its work file,
+    loaded by path and only read."""
+    root = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "perfbench")
+    spec = importlib.util.spec_from_file_location(
+        "_perfbench_work_forest", os.path.join(root, "work", "RandomForestClassifier.py"))
+    work = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(work)
+    with open(os.path.join(root, "configs", "rf_covertype.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(root, "traffic", "rs4.json")) as f:
+        traffic = json.load(f)
+    return work, {"config": config, "traffic": traffic}
+
+
+@pytest.mark.parametrize("n_nodes,n_bins", [(1536, 16), (512, 16), (64, 48)])
+def test_forest_matmul_histogram_row_loop_has_no_retiling(tpu_backend, n_nodes, n_bins):
+    """The form every histogram of the forest cell runs (``auto`` on a TPU):
+    the XLA s8 matmul under six split lanes, both feature groups in one row
+    scan, at the level plan's slot counts. Its left operand's columns are
+    stat-major (PR 33), so the compiled row loop may hold no op that
+    re-tiles it on the way to the dots: node-major, a ``reshape`` of its own
+    rewrote it in every row chunk of every level (14.5 s of a 46.8 s search,
+    and 2.38 GB of temporaries here at 1536). And the benchmark's histogram
+    readers know the loop by its accumulators (``hist_op_pattern``): the
+    program is held to that pattern here."""
+    from cs230_distributed_machine_learning_tpu.ops import trees as ops_trees
+
+    ds, n_binss = (_D_CONT, _D_COARSE), (n_bins, 4)
+    assert ops_trees._resolve_hist_kernel(True, ds, n_binss, C) == "matmul"
+
+    def lanes(local, xb_cont, xb_coarse, SC):
+        return jax.vmap(lambda lo, sc: ops_trees._level_histogram_multi(
+            lo, (xb_cont, xb_coarse), sc, n_nodes, n_binss, None, True))(local, SC)
+
+    compiled = _lower_and_compile(
+        lanes, _sds((S, _N_FOREST), jnp.int32), _sds((_N_FOREST, _D_CONT), jnp.int32),
+        _sds((_N_FOREST, _D_COARSE), jnp.int32), _sds((S, _N_FOREST, C), jnp.float32))
+    if compiled is None:
+        return  # lowered only: no deviceless topology here
+    rc, cols = ops_trees._HIST_ROW_CHUNK, n_nodes * C
+    # an event's name in a device trace is its instruction without metadata
+    text = [re.sub(r", metadata=\{[^}]*\}", "", line.strip()) for line in compiled.as_text().splitlines()
+            if re.match(r"\s*(?:ROOT )?%\S+ = ", line)]
+    operand = rf"s8\[{S},(?:{rc},{cols}|{cols},{rc})\]"
+    retiled = [t[:160] for t in text if re.match(rf"(?:ROOT )?%\S+ = {operand}\S* (?:reshape|copy|transpose)\(", t)]
+    assert not retiled, retiled
+    assert any(re.search(rf"= {operand}", t) for t in text)  # the operand is there to be looked for
+    work, cell = _perfbench_forest_cell()
+    loops = [t for t in text if re.search(work.hist_op_pattern(cell), t)]
+    assert len(loops) == 1 and loops[0].startswith("%while"), loops
+    assert f"s32[{S},{cols},{_D_CONT * n_bins}]" in loops[0] and f"s32[{S},{cols},{_D_COARSE * 4}]" in loops[0]
+    if n_nodes == 1536:
+        assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
