@@ -372,40 +372,41 @@ class _TreeBase(ModelKernel):
         return out
 
     def memory_estimate_mb(self, n: int, d: int, static: Dict[str, Any]) -> float:
-        """Depth-aware: the dominant working set is the deepest level's
-        histogram [2^(depth-1) nodes, d, bins, k+1] (x3 for H/H_prev/stack
-        buffers) plus the binned dataset — 16x growth from depth 10 to 14
-        must throttle trials-per-dispatch accordingly. Deep (arena) mode is
-        frontier-bounded instead: ~4 histogram-sized buffers of W rows
-        (H, left+right candidates, gathered next-H).
+        """What one (trial, split) lane holds on the device while it fits.
 
-        The complete builder's gather-free routing/leaf forms
-        (ops/trees._route_left/_leaf_sums/_leaf_select) additionally
-        materialize [n, m] one-hot/compare buffers over the FULL row count
-        (m = min(2^level, _LOOKUP_M) columns, several f32/bool operands
-        live at once, not row-chunked) — at large n these dominate the
-        histogram term and must count toward the dispatch throttle. The
-        deep arena routes by O(n) gathers, so only the histogram and
-        dataset terms apply there."""
+        Deep (arena) mode is frontier-bounded: ~4 histogram-sized buffers of
+        W rows (H, left+right candidates, gathered next-H) plus the binned
+        dataset; the deep arena routes by O(n) gathers.
+
+        The complete builder is depth-aware: the deepest level's histogram
+        [2^(depth-1) nodes, d, bins, k+1] (x3 for H/H_prev/H_left) -- 16x
+        growth from depth 10 to 14 must throttle trials-per-dispatch -- plus
+        a dozen per-row vectors over the FULL row count (node ids, the stat
+        columns and their went-left copy, masks, the carried scores): 24 +
+        8 (k+1) bytes a row. Its gather-free routing / leaf forms
+        (ops/trees._route_left/_leaf_sums/_leaf_select) are written as
+        [n, m] one-hot compares and products. The TPU compiler fuses each
+        into its reduction and holds no [n, m] buffer: the boosting step
+        program at 1 000 000 x 28, depth 8, 128 bins compiles to 41-54 MB
+        of temporaries a lane at 12 to 126 lanes, 68 at 6, half of it at
+        half the rows, and a forest's step at a set ``max_depth`` likewise
+        (deviceless v5e compiles, PR 34: tests/test_tpu_compile.py; the
+        estimate that counted 6 n m + 4 n 2^depth bytes for those forms
+        said 2 033 MB a lane there, so one trial's six folds were
+        dispatched in two groups where forty-eight lanes fit). No other
+        compiler was read, so off a TPU those forms still count. Past
+        _LOOKUP_M nodes the builder gathers and segment-sums instead
+        (another 64 bytes a row at depth 10). The binned dataset and the
+        histogram's bin one-hot are one copy for every lane of a dispatch
+        and live in the half of the device the lane budget leaves alone;
+        they are not a lane's. The state a chunked fit carries from step
+        to step is the planner's to count (``plan_bucket``: a copy for
+        each step enqueued ahead), not this estimate's."""
         from ..ops.trees import _LOOKUP_M
+        from ..utils import backend as _backend
 
         n_bins = int(static.get("_n_bins", 128))
         kk = max(int(static.get("_n_classes", 2)), 2) + 1
-        route = 0.0
-        if static.get("_deep"):
-            W = int(static["_W"])
-            hist = 4.0 * W * d * n_bins * kk * 4
-        else:
-            depth = int(static.get("_depth", 8))
-            hist = 3.0 * (2 ** max(depth - 1, 0)) * d * n_bins * kk * 4
-            # routing compare mask [n, m] (f32 cols + 2 bool masks ~6 B) and
-            # the [n, n_leaves] f32 leaf-sum one-hot (~4 B), m capped at
-            # _LOOKUP_M past which the O(n) gather path takes over
-            m_route = min(2 ** max(depth - 1, 0), _LOOKUP_M)
-            # leaf-sum one-hot only exists when n_leaves fits the lookup
-            # form; past _LOOKUP_M the builder switches to segment_sum
-            m_leaf = 2**depth if 2**depth <= _LOOKUP_M else 0
-            route = 6.0 * n * m_route + 4.0 * n * m_leaf
         # forest kernels fit T trees concurrently (_tree_group_size): their
         # per-tree buffers coexist, so the engine's lane throttle must see
         # the multiplied working set
@@ -413,7 +414,23 @@ class _TreeBase(ModelKernel):
             self._tree_group_size(n, d, static)
             if hasattr(self, "_tree_group_size") else 1
         )
-        return max(1.0, (group * (hist + route) + 4.0 * n * d * 2) / 1e6)
+        if static.get("_deep"):
+            W = int(static["_W"])
+            hist = 4.0 * W * d * n_bins * kk * 4
+            return max(1.0, (group * hist + 4.0 * n * d * 2) / 1e6)
+        depth = int(static.get("_depth", 8))
+        hist = 3.0 * (2 ** max(depth - 1, 0)) * d * n_bins * kk * 4
+        rows = (24.0 + 8.0 * kk) * n
+        if 2**depth > _LOOKUP_M:
+            rows += 64.0 * n
+        if not _backend.on_tpu():
+            # the [n, m] routing mask (f32 columns + 2 bool masks, ~6 B a
+            # cell, m capped at _LOOKUP_M) and the [n, n_leaves] f32
+            # leaf-sum one-hot (only where the leaves fit the lookup form)
+            m_route = min(2 ** max(depth - 1, 0), _LOOKUP_M)
+            m_leaf = 2**depth if 2**depth <= _LOOKUP_M else 0
+            rows += (6.0 * m_route + 4.0 * m_leaf) * n
+        return max(1.0, group * (hist + rows) / 1e6)
 
     @staticmethod
     def _hist_cols(static, d, prepared=None):
@@ -1012,6 +1029,12 @@ class RandomForestRegressorKernel(_RandomForestBase):
         return self._forest_leaf_mean(params, xq, static)[:, 0]
 
 
+#: the least hessian a row inside a stage's mask carries into the stage's
+#: histograms and leaf sums; a row outside the mask carries none (the floor
+#: comes before the mask, PR 34)
+BOOST_HESSIAN_FLOOR = 1e-12
+
+
 class _GradientBoostingBase(_TreeBase):
     """Boosting stages are sequential, so the chunked-fit state is the
     raw-score vector F carried across dispatches (chunk_step advances g
@@ -1036,14 +1059,34 @@ class _GradientBoostingBase(_TreeBase):
         return {"n_chunks": int(np.ceil(stages / per_chunk)),
                 "trees_per_chunk": per_chunk}
 
+    def _trees_per_stage(self, static) -> int:
+        """One tree a stage, one a class past two classes."""
+        nc = max(int(static.get("_n_classes", 2)), 2)
+        return nc if (self.task == "classification" and nc > 2) else 1
+
     def macs_estimate(self, n, d, static):
         """Per-stage (grad, hess) histogram trees: k_eff trees of kk=2."""
         stages = int(static.get("n_estimators", 100))
-        nc = max(int(static.get("_n_classes", 2)), 2)
-        k_eff = nc if (self.task == "classification" and nc > 2) else 1
+        k_eff = self._trees_per_stage(static)
         depth = int(static.get("_depth", 3))
         n_bins = int(static.get("_n_bins", 128))
         return float(stages) * k_eff * n * (2 ** max(depth - 1, 0)) * 2 * d * n_bins
+
+    def dispatch_attrs(self, static: Dict[str, Any], X) -> Dict[str, Any]:
+        """What the chunked engine's ``executor.dispatch`` span says of one
+        fit's shape: its sequential stages, and the levels each stage
+        histograms (a complete tree of ``depth`` levels, one a class past
+        two classes) by the form they take (pallas / matmul / scatter),
+        which is what ``tpuml_tree_levels_total{route}`` counts. Shapes
+        only."""
+        from ..ops.trees import _resolve_hist_kernel
+
+        xb = X["xb"] if isinstance(X, dict) else X
+        route = _resolve_hist_kernel(
+            False, (int(xb.shape[1]),), (int(static["_n_bins"]),), 2)
+        levels = int(static["_depth"]) * self._trees_per_stage(static)
+        return {"stages": int(static.get("n_estimators", 100)),
+                "hist_levels_by_route": f"{route}:{levels}"}
 
     def chunk_init(self, X, y, w, hyper, static):
         xb = X["xb"] if isinstance(X, dict) else X
@@ -1068,23 +1111,45 @@ class _GradientBoostingBase(_TreeBase):
         )
 
         scoring = static.get("_scoring")
+        # the learning curve's ``gmax`` channel: a leaf of every eval, laid
+        # out stage by stage by the engine's sampled evals
+        curve = {"curve_gmax": self._residual_max(y, state, w_eval, static)}
         if self.task == "classification":
             if scoring_needs_margin(scoring):
                 # binary F keeps column 0 at zero, so the logit difference
                 # is just F[:, 1] - F[:, 0]
                 return {"score": margin_score(
-                    scoring, y, state[:, 1] - state[:, 0], w_eval)}
+                    scoring, y, state[:, 1] - state[:, 0], w_eval), **curve}
             if scoring_needs_proba(scoring):
                 return {"score": proba_score(
                     scoring, y, jax.nn.softmax(state, axis=-1), w_eval,
-                    static.get("_n_classes", 2))}
+                    static.get("_n_classes", 2)), **curve}
             pred = jnp.argmax(state, axis=-1).astype(jnp.int32)
             return {"score": classification_score(
-                scoring, y, pred, w_eval, static.get("_n_classes", 2))}
+                scoring, y, pred, w_eval, static.get("_n_classes", 2)), **curve}
         return {
             "score": regression_score(scoring, y, state, w_eval),
             "mse": weighted_mse(y, state, w_eval),
+            **curve,
         }
+
+    def _residual_max(self, y, F, w_eval, static):
+        """The largest absolute pseudo-residual over the split's held-out
+        rows: ``|y - p|`` of the carried scores (``|y - F|`` for a
+        regressor), the functional gradient's largest component there. It
+        is first-order in one row's raw score, where a split's accuracy
+        moves only when a row crosses zero: what a reader of the curve (the
+        benchmark's comparison among them) sees the fit's arithmetic by."""
+        if self.task == "classification":
+            c = max(int(static.get("_n_classes", 2)), 2)
+            Y = jax.nn.one_hot(y, c, dtype=jnp.float32)
+            if c > 2:
+                R = jnp.max(jnp.abs(Y - jax.nn.softmax(F, axis=-1)), axis=-1)
+            else:
+                R = jnp.abs(Y[:, 1] - jax.nn.sigmoid(F[:, 1]))
+        else:
+            R = jnp.abs(y.astype(jnp.float32) - F)
+        return jnp.max(jnp.where(w_eval > 0, R, 0.0))
 
     # artifact materialization (trial_map.fit_single chunked branch)
     def fit_chunk(self, X, y, w, hyper, static, chunk_idx, carry, plan):
@@ -1171,18 +1236,22 @@ class GradientBoostingClassifierKernel(_GradientBoostingBase):
         sub_key, feat_key = jax.random.split(key)
         mask = (jax.random.uniform(sub_key, (n,)) < subsample).astype(jnp.float32) * w
         P = jax.nn.softmax(F, axis=-1) if c > 2 else jax.nn.sigmoid(F)
+        # the hessian's floor comes before the mask: a row outside the mask
+        # (another fold's, or not drawn by this stage's subsample) has no
+        # hessian at all. Floored after it (until PR 34) every such row
+        # carried 1e-12 into a cell of every level's histogram.
         if c > 2:
             G = (Y - P) * mask[:, None]
-            H = P * (1.0 - P) * mask[:, None]
+            H = jnp.maximum(P * (1.0 - P), BOOST_HESSIAN_FLOOR) * mask[:, None]
         else:
             G = (Y[:, 1:] - P[:, 1:]) * mask[:, None]
-            H = (P[:, 1:] * (1.0 - P[:, 1:])) * mask[:, None]
+            H = jnp.maximum(P[:, 1:] * (1.0 - P[:, 1:]), BOOST_HESSIAN_FLOOR) * mask[:, None]
 
         def per_class(g, h, k2):
             return build_tree(
                 xb,
                 g[:, None],
-                jnp.maximum(h, 1e-12),
+                h,
                 depth=depth,
                 n_bins=n_bins,
                 min_samples_leaf=static["_msl"],

@@ -731,7 +731,9 @@ class BucketPlan:
     packed LogReg fit's class slab and its dead lanes). Generic and
     chunked: ``mem_cap`` trials the memory budget admits a dispatch, and
     ``split_width`` folds a dispatch when one trial's whole fold stack
-    passes half a device's memory (None: all folds in one)."""
+    passes half a device's memory (None: all folds in one). Chunked:
+    ``steps_ahead`` step programs the host may enqueue ahead of the device
+    (each holds its own copy of the carried state until its consumer ran)."""
 
     engine: str
     placement: Placement
@@ -746,6 +748,7 @@ class BucketPlan:
     mem_cap: Optional[int] = None
     split_width: Optional[int] = None
     chunk_plan: Optional[Dict[str, Any]] = None
+    steps_ahead: Optional[int] = None
     batched_fn: Optional[Callable] = dataclasses.field(
         default=None, compare=False, repr=False
     )
@@ -830,9 +833,16 @@ def plan_bucket(kernel, static, bucket_hypers, host_X, *, n, d, n_classes,
             64 * n_all,
         ))
         chunk = max(n_all, pad_to_multiple(chunk, n_all))
+        # a step's output is a new copy of the state, alive until the next
+        # step and the curve's eval have read it, and the host enqueues far
+        # faster than the device runs: the state's quarter of the device(s)
+        # is what bounds the steps in flight (unbounded, a search of 32
+        # stages held 11 copies and one of 100 would hold 50: PERF.md, PR 34)
+        state_budget_mb = 0.25 * n_all * _backend.device_memory_mb()
+        steps_ahead = max(1, int(state_budget_mb / max(chunk * state_mb, 1.0)) - 1)
         return plan(
             "chunked", place, chunk=chunk, mem_cap=mem_cap,
-            chunk_plan=chunk_plan,
+            chunk_plan=chunk_plan, steps_ahead=steps_ahead,
             split_width=(
                 _split_width(kernel, n, d, static, n_splits)
                 if chunk == 1 else None
@@ -1913,7 +1923,7 @@ def _run_chunked(run: _Run, kernel, bp: BucketPlan, X, folds, data, hypers,
 
     # what a chunked bucket did, on its dispatch spans: the chunk geometry
     # and, where the kernel says it (the forests), the shape of one fit
-    shape_attrs = {"n_chunks": n_chunks, "split_lanes": sg}
+    shape_attrs = {"n_chunks": n_chunks, "split_lanes": sg, "mem_cap": bp.mem_cap}
     if "trees_per_chunk" in chunk_plan:
         shape_attrs["trees_per_chunk"] = int(chunk_plan["trees_per_chunk"])
     if hasattr(kernel, "dispatch_attrs"):
@@ -1948,8 +1958,14 @@ def _run_chunked(run: _Run, kernel, bp: BucketPlan, X, folds, data, hypers,
                 for twg, ewg, size in split_groups:
                     state = fi(X, y, twg, ewg, hyper_arg)
                     mids = []
+                    ahead = collections.deque()
                     for ci in range(n_chunks):
                         state = fs(X, y, twg, ewg, hyper_arg, jnp.int32(ci), state)
+                        if bp.steps_ahead and bp.steps_ahead < n_chunks:
+                            # the plan's bound on the states in flight
+                            ahead.append(state)
+                            if len(ahead) > bp.steps_ahead:
+                                jax.block_until_ready(ahead.popleft())
                         if (
                             curve_stride
                             and (ci + 1) % curve_stride == 0
@@ -1991,24 +2007,35 @@ def _run_chunked(run: _Run, kernel, bp: BucketPlan, X, folds, data, hypers,
 
             def with_curve(out, mids=group_curves, sizes=[z for _, z in group_outs]):
                 """The drain's last step for this batch: the sampled evals
-                fetched and laid beside the final score, chunk by chunk."""
-                at, cs = 0, []
+                fetched and laid beside the final one, chunk by chunk: the
+                score, and each ``curve_<channel>`` leaf the kernel's eval
+                names (boosting: ``gmax``). Stride and steps count trees
+                (stages) where the plan says how many a chunk holds."""
+                named = [k for k in out if k.startswith("curve_")]
+                if not curve_stride:  # no curve: the eval's channel leaves go
+                    return {k: v for k, v in out.items() if k not in named}
+                at, cs = 0, {k: [] for k in ["score"] + named}
                 for row, size in zip(mids, sizes):
-                    last = out["score"][:, at:at + size]
-                    cs.append(np.stack(
-                        [run.fetch(og, fe_spec)["score"][:, :size] for og in row]
-                        + [last], axis=-1))
+                    fetched = [run.fetch(og, fe_spec) for og in row]
+                    for k in cs:
+                        cs[k].append(np.stack(
+                            [f[k][:, :size] for f in fetched]
+                            + [out[k][:, at:at + size]], axis=-1))
                     at += size
-                out["curve_score"] = np.concatenate(cs, axis=1)
+                for k in named:
+                    out[k] = np.concatenate(cs[k], axis=1)
+                out["curve_score"] = np.concatenate(cs["score"], axis=1)
                 shape2 = out["score"].shape[:2]
-                out["curve_stride"] = np.full(shape2, float(curve_stride), np.float32)
-                out["curve_steps"] = np.full(shape2, float(n_chunks), np.float32)
+                unit = int(chunk_plan.get("trees_per_chunk", 1))
+                steps = min(n_chunks * unit, int(static.get("n_estimators", n_chunks * unit)))
+                out["curve_stride"] = np.full(shape2, float(curve_stride * unit), np.float32)
+                out["curve_steps"] = np.full(shape2, float(steps), np.float32)
                 return out
 
             run.pending.append((
                 [(Packed(og, fe_spec) if fe_spec is not None else og, size)
                  for og, size in group_outs],
-                batch_idx, *([with_curve] if curve_stride else []),
+                batch_idx, with_curve,
             ))
 
     if mesh is not None:

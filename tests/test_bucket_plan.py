@@ -155,6 +155,71 @@ def test_plan_forest_takes_the_chunked_protocol(no_device_work):
     assert plan.chunk == 8
 
 
+#: one v5e chip's ``bytes_limit`` in MB: the lane budget is half of it
+V5E_MB = 16_900.0
+#: the benchmark's boosting configuration (gbt_higgs: 1M x 28, binary, depth 8)
+HIGGS_1M = (1_000_000, 28, 2)
+
+#: (case, shape, depth, trials, expected chunk, split width, memory cap, steps ahead)
+_BOOST_CASES = [
+    # the cell gbt_higgs.rs8: eight traced trials are ONE bucket and ride one
+    # dispatch with all six folds, 48 lanes (64.5 MB a lane by the corrected
+    # estimate; the old one said 2 033 MB: one trial, fold groups of 4 and 2);
+    # 0.38 GB of carried scores a step, so a quarter of the chip holds the
+    # step being read and ten enqueued ahead
+    ("higgs_cell_8_trials", HIGGS_1M, 8, 8, 8, None, 21, 10),
+    # a bucket past the cap is cut by it, not by the fold stack; 126 lanes
+    # carry 1.0 GB a step: three ahead
+    ("higgs_cell_30_trials", HIGGS_1M, 8, 30, 21, None, 21, 3),
+    # depth 10 passes the lookup forms (gathers, segment sums) at twice the rows
+    ("two_million_rows_depth_10", (2_000_000, 28, 2), 10, 8, 4, None, 4, 10),
+    # depth 14 at four times the rows: one trial's six folds no longer fit
+    # half a chip, so the folds go in groups of five
+    ("four_million_rows_depth_14", (4_000_000, 28, 2), 14, 8, 1, 5, 1, 21),
+]
+
+
+@pytest.mark.parametrize("shape,depth,n_trials,chunk,split_width,mem_cap,steps_ahead",
+                         [c[1:] for c in _BOOST_CASES], ids=[c[0] for c in _BOOST_CASES])
+def test_plan_boosting_bucket_batches_what_the_chip_holds(
+        no_device_work, shape, depth, n_trials, chunk, split_width, mem_cap, steps_ahead):
+    """The chunked engine's trial batching for a boosted bucket at a chip's
+    memory: learning_rate and subsample are traced, so every trial of the
+    search shares one static key and one plan. The states in flight (one a
+    step enqueued ahead, and the one being read) stay inside a quarter of
+    the chip whatever the stage count."""
+    no_device_work.setattr(trial_map._backend, "device_memory_mb", lambda: V5E_MB)
+    no_device_work.setattr(trial_map._backend, "on_tpu", lambda: True)  # the TPU compiler's lane estimate
+    kernel = get_kernel("GradientBoostingClassifier")
+    params = {"n_estimators": 16, "max_depth": depth, "random_state": 0}
+    keys = {kernel.canonicalize({**params, "learning_rate": lr, "subsample": ss})[0]
+            for lr, ss in ((0.05, 0.5), (0.1, 1.0), (0.5, 0.75))}
+    assert len(keys) == 1  # one bucket whatever the draws
+    _, plan = _plan("GradientBoostingClassifier", shape, n_trials, 6, params=params)
+    assert plan.engine == "chunked" and plan.placement.kind == "device"
+    assert plan.hyper_names == ("learning_rate", "subsample")
+    assert (plan.chunk, plan.split_width, plan.mem_cap) == (chunk, split_width, mem_cap)
+    assert plan.chunk_plan["trees_per_chunk"] * plan.chunk_plan["n_chunks"] >= 16
+    lane_mb = kernel.memory_estimate_mb(shape[0], shape[1], plan.static)
+    assert plan.chunk * (split_width or 6) * lane_mb <= 0.5 * V5E_MB or plan.chunk == 1
+    assert plan.steps_ahead == steps_ahead
+    state_mb = plan.chunk * 6 * shape[0] * 2 * 4 / 1e6  # F [trials, folds, n, 2] float32
+    assert (plan.steps_ahead + 1) * state_mb <= 0.25 * V5E_MB
+
+
+def test_lane_estimate_off_a_tpu_still_counts_the_routing_forms(no_device_work):
+    """Only the TPU compiler was read to fuse the complete builder's [n, m]
+    routing and leaf forms away; any other backend keeps the old term."""
+    kernel = get_kernel("GradientBoostingClassifier")
+    static = {"_depth": 8, "_n_bins": 128, "_n_classes": 2}
+    no_device_work.setattr(trial_map._backend, "on_tpu", lambda: True)
+    on_tpu = kernel.memory_estimate_mb(*HIGGS_1M[:2], static)
+    no_device_work.setattr(trial_map._backend, "on_tpu", lambda: False)
+    elsewhere = kernel.memory_estimate_mb(*HIGGS_1M[:2], static)
+    assert on_tpu == pytest.approx(64.5, abs=0.1)
+    assert elsewhere == pytest.approx(on_tpu + (6 * 128 + 4 * 256) * 1e6 / 1e6, abs=0.1)
+
+
 def test_plan_tiny_bucket_off_the_cpu_runs_on_the_host(no_device_work):
     """On an accelerator backend an iris-sized bucket is not worth one
     device round trip; on the CPU backend there is no host to prefer."""

@@ -514,19 +514,23 @@ def test_forest_level_histograms_compile_under_six_lanes(tpu_backend, n_nodes, d
                        _sds((S, _N_FOREST, C), jnp.float32))
 
 
-def _perfbench_forest_cell():
-    """The forest cell as the benchmark's readers see it, and its work file,
-    loaded by path and only read."""
+def _perfbench_cell(estimator, config, traffic):
+    """A cell as the benchmark's readers see it, and its work file, loaded
+    by path and only read."""
     root = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "perfbench")
     spec = importlib.util.spec_from_file_location(
-        "_perfbench_work_forest", os.path.join(root, "work", "RandomForestClassifier.py"))
+        f"_perfbench_work_{estimator}", os.path.join(root, "work", estimator + ".py"))
     work = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(work)
-    with open(os.path.join(root, "configs", "rf_covertype.json")) as f:
+    with open(os.path.join(root, "configs", config + ".json")) as f:
         config = json.load(f)
-    with open(os.path.join(root, "traffic", "rs4.json")) as f:
+    with open(os.path.join(root, "traffic", traffic + ".json")) as f:
         traffic = json.load(f)
     return work, {"config": config, "traffic": traffic}
+
+
+def _perfbench_forest_cell():
+    return _perfbench_cell("RandomForestClassifier", "rf_covertype", "rs4")
 
 
 @pytest.mark.parametrize("n_nodes,n_bins", [(1536, 16), (512, 16), (64, 48)])
@@ -568,3 +572,77 @@ def test_forest_matmul_histogram_row_loop_has_no_retiling(tpu_backend, n_nodes, 
     assert f"s32[{S},{cols},{_D_CONT * n_bins}]" in loops[0] and f"s32[{S},{cols},{_D_COARSE * 4}]" in loops[0]
     if n_nodes == 1536:
         assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
+# ---------------------------------------------------------------------------
+# The boosted-trees cell (gbt_higgs.rs8, PR 34): the chunked step program of
+# depth-8 GradientBoostingClassifier at 1 000 000 x 28, as one dispatch
+# carries it: every trial's six folds, two stages a step.
+# ---------------------------------------------------------------------------
+
+_N_HIGGS, _D_HIGGS = 1_000_000, 28
+
+
+def _perfbench_boost_cell():
+    return _perfbench_cell("GradientBoostingClassifier", "gbt_higgs", "rs8")
+
+
+def _boost_step(chunk):
+    """(vstep, its example arguments, kernel, static) as
+    ``trial_map._run_chunked`` builds them for ``chunk`` trials of the cell."""
+    from cs230_distributed_machine_learning_tpu.parallel import trial_map
+
+    _, cell = _perfbench_boost_cell()
+    kernel = get_kernel("GradientBoostingClassifier")
+    static_key, hyper = kernel.canonicalize(cell["config"]["estimator"]["params"])
+    static = trial_map._resolved_static(kernel, static_key, _N_HIGGS, _D_HIGGS, 2)
+    plan = kernel.chunked_plan(static, _N_HIGGS, _D_HIGGS, 2, S)
+    X = {"X": _sds((_N_HIGGS, _D_HIGGS), jnp.float32), "xb": _sds((_N_HIGGS, _D_HIGGS), jnp.int32),
+         "edges": _sds((_D_HIGGS, 127), jnp.float32)}
+
+    def step_b(X, y, TW, EW, hyper, ci, state):
+        return jax.vmap(lambda tw, st: kernel.chunk_step(
+            X, y, tw, hyper, static, ci, st, plan))(TW, state)
+
+    vstep = jax.vmap(step_b, in_axes=(None, None, None, None, 0, None, 0))
+    args = (X, _sds((_N_HIGGS,), jnp.int32), _sds((S, _N_HIGGS), jnp.float32),
+            _sds((S, _N_HIGGS), jnp.float32),
+            {k: _sds((chunk,), jnp.float32) for k in sorted(hyper)}, _sds((), jnp.int32),
+            _sds((chunk, S, _N_HIGGS, 2), jnp.float32))
+    return vstep, args, kernel, static, plan
+
+
+@pytest.mark.parametrize("chunk", [1, 8])
+def test_boost_chunked_step_compiles_at_the_cell_shape(tpu_backend, chunk):
+    """One trial's six folds, and the cell's dispatch of all eight trials'
+    (48 lanes). Pinned: the float-stat histograms are eight row loops a
+    stage (the root, then the left children of levels 1-7), each known to
+    the benchmark's readers by its float32 accumulator (``hist_op_pattern``)
+    and by nothing else of the program; the (g, h) operand enters the loops
+    in bfloat16 and the bin one-hot is a predicate, never a float32 matrix
+    (the configuration's ``precision``); the compiled program holds no
+    [n, m] routing buffer, and the lane estimate the engine plans with is
+    within 1.5 times of what a lane takes."""
+    vstep, args, kernel, static, plan = _boost_step(chunk)
+    assert plan["trees_per_chunk"] == 2 and static["_depth"] == 8 and static["_n_bins"] == 128
+    compiled = _lower_and_compile(vstep, *args)
+    if compiled is None:
+        return  # lowered only: no deviceless topology here
+    text = [re.sub(r", metadata=\{[^}]*\}", "", line.strip()) for line in compiled.as_text().splitlines()
+            if re.match(r"\s*(?:ROOT )?%\S+ = ", line)]
+    work, cell = _perfbench_boost_cell()
+    loops = [t for t in text if re.search(work.hist_op_pattern(cell), t)]
+    assert len(loops) == 8 and all(t.startswith("%while") for t in loops), [t[:80] for t in loops]
+    nodes = sorted(int(re.search(rf"f32\[{chunk},{S},1,(\d+),{_D_HIGGS * 128}\]", t).group(1)) for t in loops)
+    assert nodes == [2, 2, 4, 8, 16, 32, 64, 128]  # (g, h) x built nodes: root, then left children
+    rows = -(-_N_HIGGS // 16384) * 16384
+    assert all(f"bf16[{chunk},{S},1,{rows},2]" in t for t in loops[1:])  # rounded once, before the loop
+    assert any(re.search(rf"= pred\[16384,{_D_HIGGS},128\]", t) for t in text)
+    assert not any(re.search(rf"= f32\[16384,{_D_HIGGS * 128}\]", t) for t in text)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    lanes = chunk * S
+    assert temp < lanes * 75e6 + 0.2e9  # 2.03 GB a lane by the estimate before PR 34
+    with pytest.MonkeyPatch.context() as mp:  # the estimate as a TPU process makes it
+        mp.setattr("cs230_distributed_machine_learning_tpu.utils.backend.on_tpu", lambda: True)
+        estimate = kernel.memory_estimate_mb(_N_HIGGS, _D_HIGGS, static) * 1e6
+    assert estimate / 1.5 < temp / lanes < estimate * 1.5, (temp / lanes, estimate)
