@@ -282,7 +282,7 @@ class LogisticRegressionKernel(ModelKernel):
         return _backend.auto_pallas() and n >= 4096
 
     def batched_staged_extras(self, static, n, d, n_classes, n_splits,
-                              fold_signature=None):
+                              fold_signature=None, block=None):
         """Dispatch-invariant device inputs of the packed path, staged by
         the trial engine in the multi-tenant stage cache
         (data/stage_cache.py) and merged into the dispatch ``hyper`` dict
@@ -296,6 +296,12 @@ class LogisticRegressionKernel(ModelKernel):
           (30 matmul round-trips over A), which depends only on (dataset,
           fold weights) — keyed by the fold-plan signature so every chunk
           dispatch after the first is a cache hit.
+        - ``_logreg_occ``: the step kernel's per-(row tile, split)
+          occupancy table (``ops/pallas_logreg.py::tile_occupancy``),
+          which depends only on the fold weights — keyed like the bound.
+          Only where the kernel reads it (``_step_form``: the fused step
+          at a ``block`` of 128 trials); a narrower block, or a caller
+          that names none, stages what it always did.
 
         Returns ``{name: (subkey | None, make)}``; ``make(ctx)`` receives
         ``{"X", "y", "TW", "EW"}`` device args. A ``None``
@@ -306,9 +312,15 @@ class LogisticRegressionKernel(ModelKernel):
             return {}
         if not self.batched_applicable(static, n, d):
             return {}
+        from ..ops.pallas_logreg import tile_occupancy
+
         geo = _packed_geometry(static, n, d, n_classes, n_splits)
         fit_intercept, dp = geo["fit_intercept"], geo["dp"]
-        dpp, n_pad = geo["dpp"], geo["n_pad"]
+        dpp, n_pad, bm = geo["dpp"], geo["n_pad"], geo["bm"]
+
+        def folds_key(name, *geometry):
+            return None if fold_signature is None else (
+                name, fold_signature) + geometry
 
         def pad_a(X):
             A = add_intercept(X, fit_intercept)
@@ -317,25 +329,42 @@ class LogisticRegressionKernel(ModelKernel):
         def make_ab(ctx):
             return jax.jit(lambda X: pad_a(X).astype(jnp.bfloat16))(ctx["X"])
 
+        def pad_tw(TW):
+            return jnp.pad(TW.astype(jnp.float32), ((0, 0), (0, n_pad - n)))
+
         def make_lam_max(ctx):
             def compute(X, TW):
-                A = pad_a(X)
-                TWp = jnp.pad(
-                    TW.astype(jnp.float32), ((0, 0), (0, n_pad - n))
-                )
-                return _packed_lam_max(A, TWp)
+                return _packed_lam_max(pad_a(X), pad_tw(TW))
 
             return jax.jit(compute)(ctx["X"], ctx["TW"])
 
-        return {
+        def make_occ(ctx):
+            return jax.jit(lambda TW: tile_occupancy(pad_tw(TW), bm=bm))(ctx["TW"])
+
+        specs = {
             "_logreg_ab": (("ab", fit_intercept, dpp, n_pad), make_ab),
             "_logreg_lam_max": (
-                None
-                if fold_signature is None
-                else ("lam_max", fold_signature, fit_intercept, dpp, n_pad),
-                make_lam_max,
+                folds_key("lam_max", fit_intercept, dpp, n_pad), make_lam_max,
             ),
         }
+        if block is not None and _step_form(geo, block)[1]:
+            specs["_logreg_occ"] = (folds_key("occ", n_pad, bm), make_occ)
+        return specs
+
+    def dispatch_attrs(self, static, X, extras) -> Dict[str, Any]:
+        """What the packed engine's ``executor.dispatch`` span says of a
+        bucket's staged extras: ``tile_skip_pct``, the share of the step
+        kernel's (row tile, split) column groups that the staged occupancy
+        table marks empty; 0.0 where none is staged (a narrower block, the
+        legacy body: the whole slab on every tile). The splits are counted
+        off the per-split bound staged beside the table."""
+        occ = extras.get("_logreg_occ")
+        if occ is None:
+            return {"tile_skip_pct": 0.0}
+        from ..ops.pallas_logreg import tile_skip_pct
+
+        n_splits = int(extras["_logreg_lam_max"].shape[0])
+        return {"tile_skip_pct": tile_skip_pct(occ, n_splits)}
 
     def build_batched_fn(self, static, n, d, n_classes, n_splits, chunk):
         """Returns fn(X, y, TW, EW, hyper) -> {"score": [chunk, n_splits]}
@@ -353,10 +382,10 @@ class LogisticRegressionKernel(ModelKernel):
             return None
 
         from ..ops.pallas_logreg import (
-            fused_step_applicable,
             packed_nesterov_step,
             packed_softmax_grad,
             slab_lanes,
+            tile_occupancy,
         )
 
         interpret = _backend.pallas_interpret()
@@ -372,16 +401,10 @@ class LogisticRegressionKernel(ModelKernel):
         Bblk = slab_lanes(S, Tw)
         NB = c * Bblk
         dp, dpp = geo["dp"], geo["dpp"]
-        bm = 256
+        bm = geo["bm"]
         rc = geo["rc"]  # eval row-chunk
         n_pad = geo["n_pad"]  # multiple of rc (and of bm)
-        mode = _fused_step_mode()
-        # auto routes through the fused step kernel whenever its weight
-        # blocks fit the VMEM gate; pallas forces it (tiny test shapes);
-        # legacy keeps the pre-fusion scan body as the parity reference
-        use_fused = mode == "pallas" or (
-            mode == "auto" and fused_step_applicable(dpp, NB, bm)
-        )
+        use_fused, skip = _step_form(geo, Tw)
         from ..obs.curves import curves_enabled, trace_stride
 
         capture = curves_enabled()
@@ -452,12 +475,21 @@ class LogisticRegressionKernel(ModelKernel):
 
                 if use_fused:
                     pen_col = pen_row_j[0]  # [dpp, 1]
+                    # staged once per (dataset, fold plan) like the bound,
+                    # else inline; loop-invariant either way
+                    occ = None
+                    if skip:
+                        occ = (
+                            hyper["_logreg_occ"]
+                            if "_logreg_occ" in hyper
+                            else tile_occupancy(TWp, bm=bm)
+                        )
 
                     def body(carry, t):
                         W, Wp, done, tr = carry
                         W, Wp, gmax = packed_nesterov_step(
                             Ab, W, Wp, y2, WSP, t, done.astype(jnp.float32),
-                            step_b, Cb, maxit_b, pen_col,
+                            step_b, Cb, maxit_b, pen_col, occ,
                             c=c, S=S, Tw=Tw, bm=bm, lam=lam,
                             interpret=interpret,
                         )
@@ -600,6 +632,28 @@ def _fused_step_mode() -> str:
     return mode if mode in ("auto", "pallas", "legacy") else "auto"
 
 
+def _step_form(geo, Tw: int):
+    """The step a packed fit at a block of ``Tw`` trials scans, as
+    ``(fused, skip)``: ``auto`` routes through the fused step kernel
+    whenever its weight blocks fit the VMEM gate; ``pallas`` forces it
+    (tiny test shapes); ``legacy`` keeps the pre-fusion scan body as the
+    parity reference. ``skip``: a split's lanes are whole vregs (a block
+    of 128), so the fused kernel takes the occupancy table and leaves out
+    the (row tile, split) column groups it marks empty."""
+    from ..ops.pallas_logreg import (
+        fused_step_applicable,
+        slab_lanes,
+        tile_skip_applicable,
+    )
+
+    mode = _fused_step_mode()
+    NB = geo["c"] * slab_lanes(geo["S"], Tw)
+    fused = mode == "pallas" or (
+        mode == "auto" and fused_step_applicable(geo["dpp"], NB, geo["bm"])
+    )
+    return fused, fused and tile_skip_applicable(geo["S"], Tw)
+
+
 def _packed_geometry(static, n, d, n_classes, n_splits):
     """Shared shape/penalty derivation of the packed path —
     ``build_batched_fn`` and ``batched_staged_extras`` must agree on
@@ -612,6 +666,7 @@ def _packed_geometry(static, n, d, n_classes, n_splits):
     dp = d + (1 if fit_intercept else 0)
     rc = 2048
     return {
+        "bm": 256,  # the kernels' row tile; divides rc, so also n_pad
         "c": c,
         "S": int(n_splits),
         "fit_intercept": fit_intercept,

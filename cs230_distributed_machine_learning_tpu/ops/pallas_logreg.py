@@ -27,6 +27,23 @@ the weights stay 0), ``max|G|`` 0, dropped at unpack. Where ``S * Tw`` is
 a multiple of 128 (six splits at 64 and 128 trials) there are none and the
 layout is the dense ``(a * S + s) * Tw + t``.
 
+Empty column groups. A fold weight is ``{0, 1}`` and masks the residual
+after the logits matmul and the softmax, so a row tile in which split
+``s`` trains on no row pays for split ``s``'s ``c * Tw`` columns and adds
+exact zeros. Unshuffled stratified folds (sklearn's ``cv=k`` default) hold
+runs of rows, so about one CV split in ``k`` is empty in every tile, and a
+padded tail tile is empty for every split. ``tile_occupancy`` packs
+``any(WSP[tile, s] != 0)`` into one int32 a row tile (bit ``s``), and where
+a split's lanes are whole vregs (``tile_skip_applicable``: ``Tw % 128 ==
+0``) ``packed_nesterov_step`` takes that table as a scalar-prefetch operand:
+a tile with an empty split leaves that split's column group out and runs a
+slab one group narrower (still one wide logits dot: a split at a time, in
+dots of 128 columns, lost 54% on the v5e), a tile empty for every split
+does nothing, every other tile runs the whole slab. A skipped group adds
+nothing, which is what it added before: the outputs are bit-equal with and
+without the table. At the narrower widths (16, 32, 64: a split is part of
+a vreg) the table is ignored and the whole-slab body runs.
+
 Replaces (in effect) the per-trial sklearn fit of the reference worker
 (``aws-prod/worker/worker.py:289-349``) for the LogisticRegression family;
 see models/logistic.py for the solver that drives it.
@@ -38,6 +55,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -74,12 +92,51 @@ def _sample_weight_slab(wsp_ref, bm: int, S: int, Tw: int):
     return wexp
 
 
+def _softmax_gram(a, logits, L: int, c: int, yv, wexp, add):
+    """The softmax arithmetic both gradient bodies share, so that they
+    cannot drift: grouped softmax over ``c`` class tiles -> weighted
+    residual -> one Gram dot a class, handed to ``add``.
+
+    a       [bm, dpp]   bf16  design-matrix row tile
+    logits  [bm, c*L]   f32   class-major: class ``a_i`` is the tile
+                              ``[:, a_i*L : (a_i+1)*L]`` (elementwise:
+                              classes are separate tiles, no cross-lane
+                              reductions)
+    yv      [bm, 1]     i32   labels for the tile rows
+    wexp    [bm, L]     f32   sample weight of every column
+    add     (class, [dpp, L] f32) -> None: accumulates the class's Gram tile
+    """
+    m = logits[:, 0:L]
+    for a_i in range(1, c):
+        m = jnp.maximum(m, logits[:, a_i * L : (a_i + 1) * L])
+    es = [jnp.exp(logits[:, a_i * L : (a_i + 1) * L] - m) for a_i in range(c)]
+    den = es[0]
+    for a_i in range(1, c):
+        den = den + es[a_i]
+    rden = 1.0 / den
+
+    # per class: residual tile and its gradient contribution (c small dots
+    # instead of one concat keeps everything statically sliced)
+    for a_i, e in enumerate(es):
+        onehot = (yv == a_i).astype(jnp.float32)  # [bm, 1] broadcasts
+        r = ((e * rden - onehot) * wexp).astype(jnp.bfloat16)  # [bm, L]
+        add(a_i, jax.lax.dot_general(
+            a,
+            r,
+            dimension_numbers=(((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ))  # [dpp, L]
+
+
 def _tile_softmax_gram(a, W, yv, wsp_ref, acc_ref, *, c: int, S: int, Tw: int):
-    """Shared (row-tile x weight-block) gradient body of ``_grad_kernel``
-    and ``_fused_step_kernel``: logits -> grouped softmax -> masked
-    residual -> per-class Gram accumulation into ``acc_ref[0]``. The two
-    kernels MUST run op-for-op identical gradients (the fused-vs-legacy
-    parity contract), which this single body enforces by construction.
+    """Whole-slab (row-tile x weight-block) gradient body of ``_grad_kernel``
+    and of ``_fused_step_kernel`` (every tile where no occupancy table
+    rides along, the tiles where every split trains where one does):
+    logits for every column in one dot -> ``_softmax_gram`` over the ``c``
+    class slabs. ``_narrow_softmax_gram`` computes the same columns less
+    one split's; the two MUST give bit-equal gradients (the
+    fused-vs-legacy parity contract), which tests/test_pallas_logreg.py
+    pins.
 
     a   [bm, dpp]      bf16  design-matrix row tile (shared by all trials)
     W   [dpp, NB]      bf16  packed weights operand, NB = c*Bp, class-major
@@ -90,36 +147,60 @@ def _tile_softmax_gram(a, W, yv, wsp_ref, acc_ref, *, c: int, S: int, Tw: int):
     ``B`` below is the slab ``Bp``: every slice starts on a vreg boundary.
     """
     B = slab_lanes(S, Tw)
-    bm = a.shape[0]
     # logits for every (class, split, trial) column: one MXU pass, f32 out
     logits = jnp.dot(a, W, preferred_element_type=jnp.float32)  # [bm, NB]
-
     # per-(sample, split, trial) weight tile, broadcast from the S columns
-    wexp = _sample_weight_slab(wsp_ref, bm, S, Tw)  # [bm, B]
+    wexp = _sample_weight_slab(wsp_ref, a.shape[0], S, Tw)  # [bm, B]
 
-    # grouped softmax over the c class slices (elementwise; classes are
-    # separate [bm, B] tiles, so no cross-lane reductions are needed)
-    m = logits[:, 0:B]
-    for a_i in range(1, c):
-        m = jnp.maximum(m, logits[:, a_i * B : (a_i + 1) * B])
-    es = [jnp.exp(logits[:, a_i * B : (a_i + 1) * B] - m) for a_i in range(c)]
-    den = es[0]
-    for a_i in range(1, c):
-        den = den + es[a_i]
-    rden = 1.0 / den
-
-    # per class: residual tile and its gradient contribution (7 small dots
-    # instead of one concat keeps everything statically sliced)
-    for a_i in range(c):
-        onehot = (yv == a_i).astype(jnp.float32)  # [bm, 1] broadcasts
-        r = ((es[a_i] * rden - onehot) * wexp).astype(jnp.bfloat16)  # [bm, B]
-        g_a = jax.lax.dot_general(
-            a,
-            r,
-            dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [dpp, B]
+    def add(a_i, g_a):
         acc_ref[0, :, a_i * B : (a_i + 1) * B] += g_a
+
+    _softmax_gram(a, logits, B, c, yv, wexp, add)
+
+
+def _narrow_softmax_gram(a, w_at, yv, wsp_ref, acc_ref, dead, *, c: int, S: int, Tw: int):
+    """``_tile_softmax_gram`` over a slab one split narrower: split
+    ``dead`` (a traced scalar), whose fold weights are all zero in this
+    row tile, is left out, and the other ``S - 1`` splits' column groups
+    (``Tw`` lanes each, whole vregs: ``tile_skip_applicable``) are laid
+    side by side, so the logits stay one wide dot. The dead group's
+    residual is ``(p - y) * 0``: exact zeros whose Gram product adds exact
+    zeros to a float32 accumulator, so leaving its columns untouched gives
+    the same bits; and a column's dot does not depend on which other
+    columns share the matmul.
+
+    w_at  sl -> bf16 ``[dpp, Tw]``: the packed weights operand's columns
+          ``sl`` (a ``pl.ds`` of ``Tw`` lanes)
+    """
+    B = slab_lanes(S, Tw)
+    bm, L = a.shape[0], (S - 1) * Tw
+    # slot k holds split k below the dead one and k + 1 from it on
+    after = [k >= dead for k in range(S - 1)]
+    splits = [k + after[k].astype(jnp.int32) for k in range(S - 1)]
+
+    def cols(a_i, s):
+        return pl.ds(pl.multiple_of(a_i * B + s * Tw, 128), Tw)
+
+    W = jnp.concatenate(
+        [w_at(cols(a_i, s)) for a_i in range(c) for s in splits], axis=1
+    )  # [dpp, c*L]
+    logits = jnp.dot(a, W, preferred_element_type=jnp.float32)  # [bm, c*L]
+    wexp = jnp.concatenate(
+        [
+            jnp.broadcast_to(
+                jnp.where(after[k], wsp_ref[:, k + 1 : k + 2], wsp_ref[:, k : k + 1]),
+                (bm, Tw),
+            )
+            for k in range(S - 1)
+        ],
+        axis=1,
+    )  # [bm, L]
+
+    def add(a_i, g_a):
+        for k, s in enumerate(splits):
+            acc_ref[0, :, cols(a_i, s)] += g_a[:, k * Tw : (k + 1) * Tw]
+
+    _softmax_gram(a, logits, L, c, yv, wexp, add)
 
 
 def _grad_kernel(a_ref, w_ref, y_ref, wsp_ref, g_ref, *, c: int, S: int, Tw: int):
@@ -130,6 +211,9 @@ def _grad_kernel(a_ref, w_ref, y_ref, wsp_ref, g_ref, *, c: int, S: int, Tw: int
     y_ref   [bm, 1]        i32   labels for the tile rows
     wsp_ref [bm, S]        f32   per-split {0,1} sample weights
     g_ref   [1, dpp, NB]   f32   output: A^T (w (P - Y)), accumulated over row tiles
+
+    Always the whole-slab body, no group skipped: the parity reference of
+    ``_fused_step_kernel``'s narrower slabs (bit-equal outputs).
     """
     i = pl.program_id(1)
 
@@ -215,13 +299,48 @@ def fused_step_applicable(dpp: int, NB: int, bm: int = 256) -> bool:
     return 16 * dpp * NB + 2 * bm * dpp <= _FUSED_STEP_VMEM_BYTES
 
 
+def tile_skip_applicable(S: int, Tw: int) -> bool:
+    """Whether ``packed_nesterov_step`` can skip a split's column group:
+    the split's ``Tw`` lanes of a class slab are whole vregs, there is a
+    slab left without it, and the splits fit the bits of one int32.
+    Narrower blocks (a split is part of a vreg) run the whole-slab body."""
+    return Tw % 128 == 0 and 1 < S < 32
+
+
+@functools.partial(jax.jit, static_argnames=("bm",))
+def tile_occupancy(TWp, *, bm: int = 256):
+    """The per-(row tile, split) occupancy table of ``packed_nesterov_step``:
+    int32 ``[n_pad // bm]``, bit ``s`` of entry ``i`` set where any row of
+    tile ``i`` has a non-zero weight in split ``s`` (one word a tile, so
+    that the scalar-prefetch operand stays small in SMEM: 78 KB at 5M
+    rows). It depends on the fold plan alone: made once per (dataset, fold
+    plan) and staged (models/logistic.py).
+
+    TWp [S, n_pad] f32  the fold weights split-major (``WSP.T``),
+                        n_pad % bm == 0, S < 32
+    """
+    S, n_pad = TWp.shape
+    occ = jnp.any(TWp.reshape(S, n_pad // bm, bm) != 0, axis=2)  # [S, n_tiles]
+    return jnp.sum(
+        occ.astype(jnp.int32) << jnp.arange(S, dtype=jnp.int32)[:, None], axis=0
+    )
+
+
+def tile_skip_pct(occ, S: int) -> float:
+    """Share of the (row tile, split) column groups that ``occ`` lets the
+    step kernel skip, in percent. A host read of the table: the device
+    copies it once and the array keeps the copy."""
+    words = np.asarray(occ)
+    return 100.0 * (1.0 - int(np.bitwise_count(words).sum()) / (words.size * S))
+
+
 def _fused_step_kernel(
-    a_ref, w_ref, wp_ref, y_ref, wsp_ref, t_ref, done_ref, step_ref,
-    cb_ref, maxit_ref, pen_ref, wout_ref, wpout_ref, gmax_ref,
-    *, c: int, S: int, Tw: int, lam: float, n_tiles: int
+    *refs, c: int, S: int, Tw: int, lam: float, n_tiles: int, skip: bool
 ):
     """One (weight-block, row-tile) grid step of the FULL Nesterov update.
 
+    occ_ref   [n_tiles]      i32   (``skip`` only: scalar prefetch, SMEM)
+                                   ``tile_occupancy``'s word a row tile
     a_ref     [bm, dpp]      bf16  design-matrix row tile (shared by all trials)
     w_ref     [1, dpp, NB]   f32   W, packed class-major (NB = c*Bp)
     wp_ref    [1, dpp, NB]   f32   W_prev
@@ -252,6 +371,16 @@ def _fused_step_kernel(
     per-trial C scaling + L2 penalty, reduces ``max|G|``, and performs the
     done/max_iter-masked W/Wp writeback.
 
+    With ``skip`` the tile's word ``occ_ref[i]`` picks its body: every
+    split occupied, the whole-slab body (``_tile_softmax_gram``), as in
+    ``_grad_kernel``; none occupied (a padded tail tile), nothing; else
+    ``_narrow_softmax_gram`` without the lowest empty split's column group,
+    which would have added exact zeros (a second empty split of the same
+    tile is computed as before: zeros; unshuffled stratified folds leave
+    one). The look-ahead columns are formed inside the chosen branch.
+    Without ``skip`` every tile runs the whole-slab body. The table is
+    indexed by the row tile alone and shared by the weight blocks.
+
     The outputs are NOT aliased onto the W/Wp inputs. An earlier form
     (``input_output_aliases={1: 0, 2: 1}``) passed interpret-mode parity
     bit for bit and computed wrong weights compiled on a v5e: the
@@ -259,6 +388,9 @@ def _fused_step_kernel(
     is read from. The scan carry ping-pongs two buffers instead; HBM
     traffic on the weights is the same 4 passes.
     """
+    occ_ref, refs = (refs[0], refs[1:]) if skip else (None, refs)
+    (a_ref, w_ref, wp_ref, y_ref, wsp_ref, t_ref, done_ref, step_ref,
+     cb_ref, maxit_ref, pen_ref, wout_ref, wpout_ref, gmax_ref) = refs
     i = pl.program_id(1)
     B = slab_lanes(S, Tw)
     t = t_ref[0, 0]
@@ -268,11 +400,36 @@ def _fused_step_kernel(
     def _init():
         wout_ref[0] = jnp.zeros_like(wout_ref[0])
 
-    # look-ahead iterate, recomputed per tile from the VMEM-resident blocks
-    Vb = (w_ref[0] + mom * (w_ref[0] - wp_ref[0])).astype(jnp.bfloat16)
-    # the one shared gradient body with _grad_kernel (parity by
-    # construction), accumulating into the W_new output block
-    _tile_softmax_gram(a_ref[:], Vb, y_ref[:], wsp_ref, wout_ref, c=c, S=S, Tw=Tw)
+    def look_ahead(sl=slice(None)):
+        # columns of the look-ahead iterate, recomputed per tile from the
+        # VMEM-resident blocks
+        return (
+            w_ref[0, :, sl] + mom * (w_ref[0, :, sl] - wp_ref[0, :, sl])
+        ).astype(jnp.bfloat16)
+
+    def whole_slab():
+        # the tile's gradient, accumulating into the W_new output block
+        Vb = look_ahead()
+        _tile_softmax_gram(a_ref[:], Vb, y_ref[:], wsp_ref, wout_ref, c=c, S=S, Tw=Tw)
+
+    if skip:
+        occ = occ_ref[i]
+        full = (1 << S) - 1
+        pl.when(occ == full)(whole_slab)
+
+        @pl.when(jnp.logical_and(occ != full, occ != 0))
+        def _narrow_slab():
+            # the lowest empty split: as many as the low bits that are all set
+            dead = sum(
+                ((occ & ((2 << s) - 1)) == (2 << s) - 1).astype(jnp.int32)
+                for s in range(S)
+            )
+            _narrow_softmax_gram(
+                a_ref[:], look_ahead, y_ref[:], wsp_ref, wout_ref, dead,
+                c=c, S=S, Tw=Tw,
+            )
+    else:
+        whole_slab()
 
     @pl.when(i == n_tiles - 1)
     def _epilogue():
@@ -299,7 +456,7 @@ def _fused_step_kernel(
     jax.jit, static_argnames=("c", "S", "Tw", "bm", "lam", "interpret")
 )
 def packed_nesterov_step(
-    Ab, W3, Wp3, y2, WSP, t, done, step_b, Cb, maxit_b, pen_col,
+    Ab, W3, Wp3, y2, WSP, t, done, step_b, Cb, maxit_b, pen_col, occ=None,
     *, c: int, S: int, Tw: int = TRIAL_BLOCK, bm: int = 256,
     lam: float = 0.0, interpret: bool = False,
 ):
@@ -326,11 +483,15 @@ def packed_nesterov_step(
     Cb      [n_wb, B]        f32   per-column C
     maxit_b [n_wb, B]        f32   per-column max_iter
     pen_col [dpp, 1]         f32   L2 row mask (0 on intercept + pad rows)
+    occ     [n_pad // bm]    i32   optional ``tile_occupancy(WSP.T, bm=bm)``:
+                                   where ``tile_skip_applicable(S, Tw)`` the
+                                   column groups it marks empty are skipped;
+                                   ignored at the other widths
     lam     static float           L2 strength (0 disables the penalty)
 
     Returns ``(W_new, Wp_new, gmax)`` with shapes/dtypes of
     ``(W3, Wp3, [n_wb, B] f32)``; dead columns come back exactly 0 in all
-    three.
+    three. The three are bit-equal with and without ``occ``.
     """
     n_pad, dpp = Ab.shape
     n_wb, _, NB = W3.shape
@@ -339,33 +500,44 @@ def packed_nesterov_step(
     assert n_pad % bm == 0, (n_pad, bm)
     n_tiles = n_pad // bm
 
+    skip = occ is not None and tile_skip_applicable(S, Tw)
+    prefetch = (occ,) if skip else ()
+
     t2 = jnp.asarray(t, jnp.float32).reshape(1, 1)
     kernel = functools.partial(
-        _fused_step_kernel, c=c, S=S, Tw=Tw, lam=float(lam), n_tiles=n_tiles
+        _fused_step_kernel, c=c, S=S, Tw=Tw, lam=float(lam), n_tiles=n_tiles,
+        skip=skip,
     )
-    col_spec = pl.BlockSpec((1, 1, B), lambda wb, i: (wb, 0, 0))
+    # index maps also receive the scalar-prefetch refs, and ignore them
+    row_tile = lambda wb, i, *_: (i, 0)  # noqa: E731
+    w_block = lambda wb, i, *_: (wb, 0, 0)  # noqa: E731
+    whole = lambda wb, i, *_: (0, 0)  # noqa: E731
+    col_spec = pl.BlockSpec((1, 1, B), w_block)
     cols = [v.reshape(n_wb, 1, B) for v in (done, step_b, Cb, maxit_b)]
     W_new, Wp_new, gmax = pl.pallas_call(
         kernel,
-        grid=(n_wb, n_tiles),
-        in_specs=[
-            pl.BlockSpec((bm, dpp), lambda wb, i: (i, 0)),
-            pl.BlockSpec((1, dpp, NB), lambda wb, i: (wb, 0, 0)),
-            pl.BlockSpec((1, dpp, NB), lambda wb, i: (wb, 0, 0)),
-            pl.BlockSpec((bm, 1), lambda wb, i: (i, 0)),
-            pl.BlockSpec((bm, S), lambda wb, i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda wb, i: (0, 0), memory_space=pltpu.SMEM),
-            col_spec,
-            col_spec,
-            col_spec,
-            col_spec,
-            pl.BlockSpec((dpp, 1), lambda wb, i: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, dpp, NB), lambda wb, i: (wb, 0, 0)),
-            pl.BlockSpec((1, dpp, NB), lambda wb, i: (wb, 0, 0)),
-            col_spec,
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch),
+            grid=(n_wb, n_tiles),
+            in_specs=[
+                pl.BlockSpec((bm, dpp), row_tile),
+                pl.BlockSpec((1, dpp, NB), w_block),
+                pl.BlockSpec((1, dpp, NB), w_block),
+                pl.BlockSpec((bm, 1), row_tile),
+                pl.BlockSpec((bm, S), row_tile),
+                pl.BlockSpec((1, 1), whole, memory_space=pltpu.SMEM),
+                col_spec,
+                col_spec,
+                col_spec,
+                col_spec,
+                pl.BlockSpec((dpp, 1), whole),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, dpp, NB), w_block),
+                pl.BlockSpec((1, dpp, NB), w_block),
+                col_spec,
+            ],
+        ),
         out_shape=[
             jax.ShapeDtypeStruct((n_wb, dpp, NB), jnp.float32),
             jax.ShapeDtypeStruct((n_wb, dpp, NB), jnp.float32),
@@ -375,7 +547,7 @@ def packed_nesterov_step(
         name="packed_nesterov_step",
         **({} if interpret else {"compiler_params": pltpu.CompilerParams(
             vmem_limit_bytes=_FUSED_STEP_VMEM_LIMIT)}),
-    )(Ab, W3, Wp3, y2, WSP, t2, *cols, pen_col)
+    )(*prefetch, Ab, W3, Wp3, y2, WSP, t2, *cols, pen_col)
     return W_new, Wp_new, gmax.reshape(n_wb, B)
 
 

@@ -96,7 +96,9 @@ def _dispatch_span(mesh, engine: str, chunk_no: int, lanes: int,
     of the four forms ran it (``packed``: a kernel's ``build_batched_fn``,
     with ``block`` trials a weight block and ``blocks`` a device, and for a
     kernel whose block is made of slabs ``slab_lanes`` lanes a slab of
-    which ``slab_pad_lanes`` are dead columns;
+    which ``slab_pad_lanes`` are dead columns, and what the kernel's
+    ``dispatch_attrs`` reads off the staged extras: the packed LogReg fit's
+    ``tile_skip_pct``;
     ``generic``: the vmapped fit; ``chunked``; ``streamed``), counted in
     ``tpuml_engine_dispatch_total{engine, mesh}``. ``lanes`` is the chunk
     size the executable was compiled for, so ``lanes_padding`` trial lanes
@@ -1378,6 +1380,7 @@ def _stage_extras(kernel, bp: BucketPlan, data, split_plan: SplitPlan, x_key,
     specs = kernel.batched_staged_extras(
         static=bp.static, n=n, d=d, n_classes=data.n_classes,
         n_splits=split_plan.n_splits, fold_signature=signature,
+        block=bp.block,
     )
     if not specs:
         return None
@@ -1452,8 +1455,8 @@ def _join_split_groups(fetched) -> Dict[str, np.ndarray]:
     }
 
 
-def _dispatch(run: _Run, bp: BucketPlan, exe, fresh: bool, X, folds, extras,
-              hypers, idxs) -> None:
+def _dispatch(run: _Run, kernel, bp: BucketPlan, exe, fresh: bool, X, folds,
+              extras, hypers, idxs) -> None:
     """Enqueue a host, packed or generic bucket, chunk by chunk, without
     blocking (but for a fresh executable's first dispatch); results wait
     in ``run.pending`` for the drain."""
@@ -1471,6 +1474,11 @@ def _dispatch(run: _Run, bp: BucketPlan, exe, fresh: bool, X, folds, extras,
         if bp.slab_lanes is not None:
             attrs.update(slab_lanes=bp.slab_lanes,
                          slab_pad_lanes=bp.slab_pad_lanes)
+        if (run.acct and run.split_plan.signature is not None
+                and hasattr(kernel, "dispatch_attrs")):
+            # read off the staged extras: only where spans are kept, and not
+            # from extras an unsigned plan builds anew for every search
+            attrs.update(kernel.dispatch_attrs(bp.static, X, extras or {}))
     for start in range(0, len(idxs), chunk):
         batch_idx = idxs[start : start + chunk]
         with _dispatch_span(place.mesh, engine, start // chunk, chunk,
@@ -1619,7 +1627,8 @@ def _run_buckets(kernel, data, split_plan, param_dicts, mesh, trial_axis,
         # prewarm stops here: executables constructed, tensors staged —
         # the cold path a first trial would otherwise pay inline
         if not warm_only:
-            _dispatch(run, bp, exe, fresh, X, folds, extras, hypers, idxs)
+            _dispatch(run, kernel, bp, exe, fresh, X, folds, extras, hypers,
+                      idxs)
 
     for dispatch in chunked:
         dispatch()

@@ -6,6 +6,7 @@ vmapped engine path (which is itself parity-tested against sklearn in
 test_search_parity.py).
 """
 
+import dataclasses
 import os
 
 import numpy as np
@@ -415,6 +416,241 @@ def test_fused_step_vmem_gate():
     assert not fused_step_applicable(512, NB, 256)
 
 
+# ---------------- the occupancy table: empty column groups (ISSUE 36) -------
+
+
+def _fold_weights(case, n_pad, S, bm, seed=0):
+    """``WSP [n_pad, S]`` of the four shapes of fold plan: ``runs`` (split 0
+    a random 80%, split k >= 1 holds out the k-th run of rows, as unshuffled
+    stratified folds do: whole tiles empty for one split), ``shuffled`` (every
+    split a random 80%: no empty group), ``tail`` (runs, and the last tile
+    pad rows, weight 0 in every split), ``two_empty`` (runs, and the first
+    tile empty for the last split too: the kernel leaves the lowest empty
+    split out and computes the other's zeros), ``two_blocks`` (runs; the
+    caller takes n_wb = 2)."""
+    rng = np.random.RandomState(seed)
+    w = (rng.rand(n_pad, S) > 0.2).astype(np.float32)
+    if case != "shuffled":
+        w[:, 1:] = 1.0
+        run = n_pad // (S - 1)
+        for k in range(1, S):
+            w[(k - 1) * run : k * run, k] = 0.0
+    if case == "tail":
+        w[n_pad - bm :] = 0.0
+    if case == "two_empty":
+        w[:bm, S - 1] = 0.0
+    return w
+
+
+_FOLD_CASES = ("runs", "shuffled", "tail", "two_empty", "two_blocks")
+
+
+@pytest.mark.parametrize("case", _FOLD_CASES[:4])  # two_blocks: runs' weights
+@pytest.mark.parametrize("S", [4, 6])
+def test_tile_occupancy_is_any_over_the_tile(case, S):
+    from cs230_distributed_machine_learning_tpu.ops.pallas_logreg import (
+        tile_occupancy, tile_skip_pct,
+    )
+
+    bm, n_tiles = 128, 2 * (S - 1) + 1
+    w = _fold_weights(case, n_tiles * bm, S, bm)
+    occ = tile_occupancy(jnp.asarray(w.T), bm=bm)
+    assert occ.shape == (n_tiles,) and occ.dtype == jnp.int32
+    want = (w.reshape(n_tiles, bm, S) != 0).any(axis=1)  # [n_tiles, S]
+    got = (np.asarray(occ)[:, None] >> np.arange(S)) & 1
+    np.testing.assert_array_equal(got, want)
+    assert tile_skip_pct(occ, S) == pytest.approx(100.0 * (1 - want.mean()))
+    if case == "shuffled":
+        assert want.all()
+    else:
+        assert not want[:, 1:].all() and want[:, 0][: n_tiles - 1].all()
+    if case == "tail":
+        assert not want[-1].any()
+
+
+@pytest.mark.parametrize("case", _FOLD_CASES)
+@pytest.mark.parametrize("c", [2, 7])
+@pytest.mark.parametrize("S,bm", [(4, 128), (6, 128), (6, 256)])
+def test_fused_step_with_the_table_is_the_unskipped_body_bit_for_bit(S, bm, c, case):
+    """At a block of 128 ``packed_nesterov_step`` with the occupancy table
+    (a tile with an empty split runs a slab without its column group, a
+    tile empty for all does nothing) against the unskipped whole-slab
+    body, ``W``, ``Wp`` and ``gmax`` bit for bit over three steps: a
+    skipped group would have added exact zeros. At a row tile of 128 and
+    at the shipped one of 256 (the benchmark cell's six splits). This is
+    the interpreter: the compiled kernel is held to the same comparison at
+    the cell's shape by ``perfbench/tools/probe_logreg_skip_parity.py`` on
+    the chip."""
+    from cs230_distributed_machine_learning_tpu.ops.pallas_logreg import (
+        tile_occupancy,
+    )
+
+    n_wb = 2 if case == "two_blocks" else 1
+    n_pad = (2 * (S - 1) + 1) * bm
+    Ab, W, Wp, y2, _, done, step, Cb, maxit, pen, Tw = _fused_step_inputs(
+        c, S, n_wb=n_wb, n_pad=n_pad
+    )
+    w = _fold_weights(case, n_pad, S, bm)
+    WSP = jnp.asarray(w)
+    occ = tile_occupancy(WSP.T, bm=bm)
+    if case != "shuffled":
+        assert (np.asarray(occ) != (1 << S) - 1).any()
+    plain, tabled = (W, Wp), (W, Wp)
+    for t in (0.0, 1.0, 2.0):
+        args = (y2, WSP, t, done, step, Cb, maxit, pen)
+        kw = dict(c=c, S=S, Tw=Tw, bm=bm, lam=1.0, interpret=True)
+        *plain, g0 = packed_nesterov_step(Ab, *plain, *args, **kw)
+        *tabled, g1 = packed_nesterov_step(Ab, *tabled, *args, occ, **kw)
+        for name, a, b in zip(("W", "Wp", "gmax"), (*plain, g0), (*tabled, g1)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b), err_msg=name)
+    assert np.asarray(g0).any() and not np.array_equal(plain[0], W)
+
+
+@pytest.mark.parametrize("Tw", [16, 32, 64, 128])
+def test_only_a_block_of_128_branches_on_the_table(Tw):
+    """The choice of body is made from ``Tw``, a shape: handed the table,
+    the narrower blocks (a split is part of a vreg) trace the whole-slab
+    body, their kernel holding the two ``pl.when`` it always had (first
+    tile, last tile) and no scalar-prefetch operand; a block of 128 adds
+    two (the whole slab, the slab less an empty split) and, without the
+    table, none."""
+    import functools
+
+    import jax
+
+    c, S, dpp, bm, n_pad = 7, 6, 64, 256, 1024
+    sds = jax.ShapeDtypeStruct
+    B = slab_lanes(S, Tw)
+    W = sds((1, dpp, c * B), jnp.float32)
+    col = sds((1, B), jnp.float32)
+    args = (
+        sds((n_pad, dpp), jnp.bfloat16), W, W, sds((n_pad, 1), jnp.int32),
+        sds((n_pad, S), jnp.float32), sds((), jnp.float32),
+        col, col, col, col, sds((dpp, 1), jnp.float32),
+    )
+    step = functools.partial(
+        packed_nesterov_step, c=c, S=S, Tw=Tw, bm=bm, lam=1.0, interpret=True
+    )
+
+    def conds_and_operands(*a):
+        eqns = _kernel_eqns(jax.make_jaxpr(step)(*a).jaxpr, [])
+        (call,) = [e for e in eqns if e.primitive.name == "pallas_call"]
+        return (sum(e.primitive.name == "cond" for e in eqns), len(call.invars))
+
+    table = sds((n_pad // bm,), jnp.int32)
+    assert conds_and_operands(*args) == (2, 11)
+    assert conds_and_operands(*args, table) == (
+        (4, 12) if Tw == 128 else (2, 11)
+    )
+
+
+def test_kernel_tells_the_dispatch_span_the_staged_tables_skip_share(monkeypatch):
+    """The occupancy table is staged only where the step kernel reads it
+    (the fused step at a block of 128), and ``dispatch_attrs`` of the
+    kernel reads its skip share off the staged extras; 0.0 where none is
+    staged (a narrower block, no block named, the legacy body)."""
+    n, d, c, S = 2500, 5, 3, 3
+    kernel, static, fn = _build_packed_fn(monkeypatch, "pallas", n, d, c, S)
+    X, y, _, EW, hyper = _packed_fn_inputs(n, d, c, S, 128)
+    w = _fold_weights("runs", 4096, S, 256)[:n]  # n_pad: 2 eval chunks of 2048
+    TW = jnp.asarray(w.T)
+
+    def specs(**kw):
+        return kernel.batched_staged_extras(
+            static=static, n=n, d=d, n_classes=c, n_splits=S,
+            fold_signature=("runs", 36), **kw,
+        )
+
+    for narrow in ({}, {"block": 16}, {"block": 32}, {"block": 64}):
+        assert set(specs(**narrow)) == {"_logreg_ab", "_logreg_lam_max"}, narrow
+    wide = specs(block=128)
+    assert set(wide) == {"_logreg_ab", "_logreg_lam_max", "_logreg_occ"}
+    assert wide["_logreg_occ"][0] == ("occ", ("runs", 36), 4096, 256)
+    ctx = {"X": X, "y": y, "TW": TW, "EW": EW}
+    extras = {name: make(ctx) for name, (_, make) in wide.items()}
+    tiles = np.pad(w, ((0, 4096 - n), (0, 0))).reshape(16, 256, S)
+    want = 100.0 * (1 - (tiles != 0).any(axis=1).mean())
+    assert 30 < want < 60  # a run a CV split, and the six pad tiles
+    assert kernel.dispatch_attrs(static, X, extras) == {
+        "tile_skip_pct": pytest.approx(want)
+    }
+    del extras["_logreg_occ"]
+    assert kernel.dispatch_attrs(static, X, extras) == {"tile_skip_pct": 0.0}
+    assert kernel.dispatch_attrs(static, X, {}) == {"tile_skip_pct": 0.0}
+    # the staged table and the inline derivation give the same fit
+    base = fn(X, y, TW, EW, hyper)
+    staged = fn(X, y, TW, EW, {**hyper, "_logreg_occ": wide["_logreg_occ"][1](ctx)})
+    for k in ("score", "curve_gmax"):
+        np.testing.assert_array_equal(np.asarray(base[k]), np.asarray(staged[k]))
+    monkeypatch.setenv("CS230_FUSED_STEP", "legacy")
+    assert specs(block=128) == {}
+
+
+def test_dispatch_span_carries_tile_skip_pct_and_the_reader_reads_it(monkeypatch):
+    """Through the engine: a packed LogReg bucket's ``executor.dispatch``
+    span says ``tile_skip_pct`` beside ``slab_lanes``, and the benchmark's
+    reader (``perfbench/layer_metrics/logreg_tile_skip_pct.py``) averages
+    it over the window's searches; a program without the attribute gives
+    the reader nothing."""
+    import importlib.util
+
+    from cs230_distributed_machine_learning_tpu.obs import TRACER
+    from cs230_distributed_machine_learning_tpu.obs.tracing import span
+
+    monkeypatch.setenv("CS230_PALLAS_INTERPRET", "1")
+    data = _toy(n=2500)
+    kernel = get_kernel("LogisticRegression")
+    plan = build_split_plan(data.y, task="classification")  # cv=5, unshuffled
+    orig_resolve = kernel.resolve_static
+    monkeypatch.setattr(
+        kernel, "resolve_static",
+        lambda *a: {**orig_resolve(*a), "_method": "nesterov"},
+    )
+    tiles = np.pad(np.asarray(plan.train_w), ((0, 0), (0, 4096 - 2500)))
+    want = 100.0 * (1 - (tiles.reshape(6, 16, 256) != 0).any(axis=2).mean())
+    assert want > 40  # six pad tiles of sixteen, and a tile or two a CV split
+
+    def dispatch_attrs(job, tid, n_trials, plan=plan):
+        TRACER.bind_job(job, tid)
+        params = [{"C": 1.0, "tol": 1e-4, "max_iter": 3}] * n_trials
+        with span("executor.batch", trace_id=tid):
+            trial_map.run_trials(kernel, data, plan, params)
+        (d,) = [s for s in TRACER.spans_for(tid) if s["name"] == "executor.dispatch"]
+        assert d["attrs"]["engine"] == "packed" and "slab_lanes" in d["attrs"]
+        return d["attrs"]
+
+    narrow = dispatch_attrs("job-skip-36", "t1le5k1p00000036", 3)
+    assert narrow["block"] == 16 and narrow["tile_skip_pct"] == 0.0
+    wide = dispatch_attrs("job-skip-36w", "t1le5k1p000036aa", 65)
+    assert wide["block"] == 128
+    assert wide["tile_skip_pct"] == pytest.approx(want)
+    # an unsigned plan's table is built anew for every search: the engine
+    # does not wait for it on the host, and the span says nothing
+    unsigned = dispatch_attrs("job-skip-36u", "t1le5k1p000036bb", 65,
+                              dataclasses.replace(plan, signature=None))
+    assert unsigned["block"] == 128 and "tile_skip_pct" not in unsigned
+
+    spec = importlib.util.spec_from_file_location(
+        "logreg_tile_skip_pct",
+        os.path.join(os.path.dirname(__file__), "..", "perfbench",
+                     "layer_metrics", "logreg_tile_skip_pct.py"),
+    )
+    reader = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reader)
+    assert reader.read({"searches": [{"job_id": "job-skip-36"}]}) == 0.0
+    assert reader.read({"searches": [{"job_id": "never-traced"}]}) is None
+    for i, share in enumerate((16.0, 17.0)):
+        TRACER.bind_job(f"job-skip-36-{i}", f"t1le5k1p0000036{i}")
+        TRACER.record({
+            "trace_id": f"t1le5k1p0000036{i}", "span_id": "d0", "parent_id": None,
+            "name": "executor.dispatch", "start": 1.79e9, "end": 1.79e9 + 1,
+            "attrs": {"engine": "packed", "tile_skip_pct": share},
+            "process": "pid:1",
+        })
+    two = {"searches": [{"job_id": "job-skip-36-0"}, {"job_id": "job-skip-36-1"}]}
+    assert reader.read(two) == pytest.approx(16.5)
+
+
 def _build_packed_fn(monkeypatch, mode, n, d, c, S, fit_intercept=True,
                      steps=12, chunk=128):
     """kernel.build_batched_fn under a CS230_FUSED_STEP mode, plus matching
@@ -453,17 +689,29 @@ def _packed_fn_inputs(n, d, c, S, chunk, seed=0):
     return X, y, TW, EW, hyper
 
 
+@pytest.mark.parametrize("folds", ["random", "runs"])
 @pytest.mark.parametrize("c,fit_intercept", [(2, True), (7, True), (3, False)])
-def test_packed_fn_fused_matches_legacy_scan_body(monkeypatch, c, fit_intercept):
+def test_packed_fn_fused_matches_legacy_scan_body(monkeypatch, c, fit_intercept, folds):
     """End-to-end packed fn (fit scan + eval) parity: CS230_FUSED_STEP=
     pallas vs legacy, across binary/7-class and fit_intercept on/off,
     with per-trial max_iter below the scan cap (mask edges exercised) and
-    non-multiple n/d padding."""
+    non-multiple n/d padding. At a block of 128 and the shipped row tile
+    of 256 the fused step reads the occupancy table: the pad rows make
+    tiles empty for every split (skipped whole), and ``runs`` (a CV split
+    holds out a run of rows, as unshuffled stratified folds do) makes
+    tiles empty for one, which run the slab less that split's column
+    group; the legacy body computes every column of every tile."""
     n, d, S, chunk = 700, 5, 3, 128
     _, _, fn_legacy = _build_packed_fn(
         monkeypatch, "legacy", n, d, c, S, fit_intercept
     )
     X, y, TW, EW, hyper = _packed_fn_inputs(n, d, c, S, chunk)
+    if folds == "runs":
+        tw = np.ones((S, n), np.float32)
+        tw[0] = np.asarray(TW)[0]
+        tw[1, :256] = 0.0
+        tw[2, 256:512] = 0.0
+        TW = jnp.asarray(tw)
     score_legacy = np.asarray(fn_legacy(X, y, TW, EW, hyper)["score"])
     _, _, fn_fused = _build_packed_fn(
         monkeypatch, "pallas", n, d, c, S, fit_intercept
@@ -484,13 +732,15 @@ def test_packed_fn_staged_extras_bitwise(monkeypatch):
 
     specs = kernel.batched_staged_extras(
         static=static, n=n, d=d, n_classes=c, n_splits=S,
-        fold_signature=("test", 1),
+        fold_signature=("test", 1), block=chunk,
     )
-    assert set(specs) == {"_logreg_ab", "_logreg_lam_max"}
+    assert set(specs) == {"_logreg_ab", "_logreg_lam_max", "_logreg_occ"}
     ctx = {"X": X, "y": y, "TW": TW, "EW": EW}
     extras = {name: make(ctx) for name, (subkey, make) in specs.items()}
     assert extras["_logreg_ab"].dtype == jnp.bfloat16
     assert extras["_logreg_lam_max"].shape == (S,)
+    assert extras["_logreg_occ"].shape == (2048 // 256,)
+    assert extras["_logreg_occ"].dtype == jnp.int32
     with_extras = np.asarray(fn(X, y, TW, EW, {**hyper, **extras})["score"])
     np.testing.assert_array_equal(with_extras, base)
 

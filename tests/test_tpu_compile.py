@@ -113,22 +113,53 @@ def _trial_blocks():
     return list(TRIAL_BLOCKS)
 
 
-@pytest.mark.parametrize("Tw", _trial_blocks())
-def test_fused_step_kernel(tpu_backend, Tw):
+def _fused_step_compiled(Tw, n_wb, n_pad, table):
     from cs230_distributed_machine_learning_tpu.ops.pallas_logreg import (
         packed_nesterov_step, slab_lanes,
     )
 
-    n_wb, dpp, n_pad = 8, 64, 2048
+    dpp, bm = 64, 256
     B = slab_lanes(S, Tw)
     W = _sds((n_wb, dpp, C * B), jnp.float32)
     col = _sds((n_wb, B), jnp.float32)
-    _lower_and_compile(
-        functools.partial(packed_nesterov_step, c=C, S=S, Tw=Tw, lam=1.0),
+    occ = (_sds((n_pad // bm,), jnp.int32),) if table else ()
+    return _lower_and_compile(
+        functools.partial(packed_nesterov_step, c=C, S=S, Tw=Tw, bm=bm, lam=1.0),
         _sds((n_pad, dpp), jnp.bfloat16), W, W, _sds((n_pad, 1), jnp.int32),
         _sds((n_pad, S), jnp.float32), _sds((), jnp.float32),
-        col, col, col, col, _sds((dpp, 1), jnp.float32),
+        col, col, col, col, _sds((dpp, 1), jnp.float32), *occ,
     )
+
+
+@pytest.mark.parametrize("Tw", _trial_blocks())
+def test_fused_step_kernel(tpu_backend, Tw):
+    """Every width with the occupancy table handed in, as the packed fit
+    hands it: a block of 128 takes it as a scalar-prefetch operand and
+    holds the whole-slab body and the slab less an empty split's column
+    group, the narrower blocks ignore it and compile the whole-slab body
+    alone; all inside ``_FUSED_STEP_VMEM_LIMIT``."""
+    from cs230_distributed_machine_learning_tpu.ops.pallas_logreg import (
+        tile_skip_applicable,
+    )
+
+    compiled = _fused_step_compiled(Tw, n_wb=8, n_pad=2048, table=True)
+    assert tile_skip_applicable(S, Tw) == (Tw == 128)
+    if compiled is not None:
+        # the table is an operand of the custom call only where it is read
+        call = re.search(
+            r"packed_nesterov_step\S* = .*?custom-call\(([^)]*)\)",
+            compiled.as_text(),
+        )
+        assert call is not None
+        assert len(call.group(1).split(",")) == (12 if Tw == 128 else 11)
+
+
+@pytest.mark.parametrize("table", [False, True])
+def test_fused_step_kernel_at_the_cell_rows(tpu_backend, table):
+    """The benchmark cell's row tiles (5M rows: 19 536 words of table, 78
+    KB of SMEM) at one block of 128, with the table and without it (the
+    unskipped parity reference of the chip check)."""
+    _fused_step_compiled(128, n_wb=1, n_pad=5_001_216, table=table)
 
 
 @pytest.mark.parametrize("vmapped", [False, True])

@@ -162,10 +162,18 @@ def test_packed_fit_on_every_chip_matches_one_device_and_a_plain_float32_fit(
     assert engines.value(engine="packed", mesh="1d") - packed_before == 1
     (dispatch,) = [s["attrs"] for s in TRACER.spans_for(root.trace_id)
                    if s["name"] == "executor.dispatch"]
+    # a block of 128 skips the (row tile, split) groups the replicated
+    # occupancy table marks empty; the narrower blocks run the whole slab
+    n_pad = -(-data.X.shape[0] // 2048) * 2048
+    tiles = np.pad(np.asarray(plan.train_w), ((0, 0), (0, n_pad - data.X.shape[0])))
+    occupied = (tiles.reshape(plan.n_splits, n_pad // 256, 256) != 0).any(axis=2)
+    skip = 100.0 * (1 - occupied.mean()) if block == 128 else 0.0
     assert dispatch == {"engine": "packed", "block": block, "blocks": blocks, "chunk": 0,
                         "n_devices": 4, "n_trials": n_trials, "lanes": lanes,
                         "lanes_padding": lanes - n_trials,
-                        "slab_lanes": slab, "slab_pad_lanes": slab_pad}
+                        "slab_lanes": slab, "slab_pad_lanes": slab_pad,
+                        "tile_skip_pct": pytest.approx(skip)}
+    assert (skip > 0) == (block == 128)  # the padded tail tiles, at least
     assert lanes == 4 * blocks * block  # every device a whole number of blocks
     assert len(on_mesh.trial_metrics) == n_trials  # padding lanes are dropped
     s_mesh, c_mesh = _scores_and_curves(on_mesh)
