@@ -8,6 +8,7 @@ names: 5 classifiers, 5 regressors, 5 transformers.
 
 from __future__ import annotations
 
+import threading
 from typing import Dict, List
 
 from .base import ModelKernel
@@ -36,22 +37,30 @@ def supported_models() -> List[str]:
 
 
 _populated = False
+_populate_lock = threading.Lock()
 
 
 def _ensure_populated() -> None:
+    """Register every family once. Under a lock, the flag set last: a
+    thread that finds it set finds every family (set before the optional
+    imports, a process's concurrent first jobs could look a family up
+    while another thread was still importing it)."""
     global _populated
     if _populated:
         return
-    from .linear import LinearRegressionKernel, RidgeKernel
-    from .logistic import LogisticRegressionKernel
+    with _populate_lock:
+        if _populated:
+            return
+        from .linear import LinearRegressionKernel, RidgeKernel
+        from .logistic import LogisticRegressionKernel
 
-    register_kernel(LogisticRegressionKernel())
-    register_kernel(LinearRegressionKernel())
-    register_kernel(RidgeKernel())
-    _populated = True
-    # Remaining families land with their modules (see models/):
-    for optional in ("knn", "svm", "trees", "mlp", "transforms", "naive_bayes"):
-        try:
-            __import__(f"{__package__}.{optional}")
-        except ImportError:
-            pass
+        register_kernel(LogisticRegressionKernel())
+        register_kernel(LinearRegressionKernel())
+        register_kernel(RidgeKernel())
+        # Remaining families land with their modules (see models/):
+        for optional in ("knn", "svm", "trees", "mlp", "transforms", "naive_bayes"):
+            try:
+                __import__(f"{__package__}.{optional}")
+            except ImportError:
+                pass
+        _populated = True

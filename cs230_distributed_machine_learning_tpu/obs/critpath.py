@@ -11,6 +11,9 @@ the measured wall [t0, t1] with labeled segments:
     executor.{load_data,split_plan,stage,compile,dispatch,fetch,emit} →
     result.ingest → aggregate
 
+(inside a fetch or a dispatch, ``executor.wait``: the host waiting for the
+device; inside a compile, ``executor.build``: one stage of the build)
+
 The tiling is EXACT by construction: candidate intervals (spans, plus
 intervals derived from recorder events — queue wait before the first
 placement, the lease-reclaim wait of a hung attempt, the gap between a
@@ -62,6 +65,16 @@ _PHASE_NAMES = ("executor.compile", "executor.stage",
                 "executor.dispatch", "executor.fetch")
 _BATCH_CHILD_NAMES = _PHASE_NAMES + (
     "executor.load_data", "executor.split_plan", "executor.emit")
+#: a span one level below a phase that names its share of the phase: the
+#: phase -> (the child's name, the attribute its detail carries). An
+#: ``executor.wait`` inside a fetch or a dispatch is the host waiting (for
+#: the device, ``on`` says for what); an ``executor.build`` inside a
+#: compile is one stage of building the executable
+_PHASE_SHARE = {
+    "executor.fetch": ("executor.wait", "on"),
+    "executor.dispatch": ("executor.wait", "on"),
+    "executor.compile": ("executor.build", "stage"),
+}
 
 
 def _f(v: Any, default: float = 0.0) -> float:
@@ -252,13 +265,21 @@ def critical_path(
                 add(b0, b1, "execute", 6,
                     worker=(s.get("attrs") or {}).get("worker"))
                 batch_windows[s.get("span_id")] = (b0, b1)
+    phase_windows: Dict[Any, Tuple[str, float, float]] = {}
     for s in spans:
         win = batch_windows.get(s.get("parent_id"))
         if s["name"] in _BATCH_CHILD_NAMES and win is not None:
             # a child is a real interval inside its batch; the clamp only
             # cuts the part of a winner's batch that outlived its result
-            add(max(_f(s.get("start")), win[0]),
-                min(_f(s.get("end")), win[1]), s["name"], 7)
+            lo, hi = max(_f(s.get("start")), win[0]), min(_f(s.get("end")), win[1])
+            add(lo, hi, s["name"], 7)
+            phase_windows[s.get("span_id")] = (s["name"], lo, hi)
+    for s in spans:
+        phase, lo, hi = phase_windows.get(s.get("parent_id"), (None, 0.0, 0.0))
+        if _PHASE_SHARE.get(phase, (None,))[0] == s["name"]:
+            key = _PHASE_SHARE[phase][1]
+            add(max(_f(s.get("start")), lo), min(_f(s.get("end")), hi),
+                s["name"], 8, **{key: (s.get("attrs") or {}).get(key)})
 
     # ---- sweep: most-specific candidate wins each elementary slice ----
     bounds = sorted({t0, t1, *(c.start for c in cands),

@@ -210,7 +210,7 @@ class Tracer:
 
     def record(self, span: Dict[str, Any]) -> None:
         """Store one finished span dict (keys: trace_id, span_id, parent_id,
-        name, start, end, attrs, process)."""
+        name, start, end, cpu_s, attrs, process)."""
         tid = span.get("trace_id")
         if not tid:
             return
@@ -423,7 +423,14 @@ def span(
     interval, a profiler annotation (:func:`_annotation`). ``start`` is the
     wall-clock anchor REST consumers stitch processes on; the duration
     comes from ``time.perf_counter()`` (``end = start + elapsed``), so a
-    stepping wall clock cannot stretch or invert a span."""
+    stepping wall clock cannot stretch or invert a span.
+
+    ``cpu_s``, beside ``start`` / ``end`` in the record, is the CPU time of
+    the thread inside the span (``time.thread_time()``): the span's wall
+    less ``cpu_s`` less its children's wall is time the thread was blocked
+    (on the device, a lock, another thread), not computing. (A span opens
+    and closes on one thread: the context variable's token refuses any
+    other.)"""
     if not _enabled():
         _NOOP.attrs.clear()
         yield _NOOP
@@ -436,7 +443,7 @@ def span(
     sid = new_span_id()
     handle = SpanHandle(tid, sid, pid, name, time.time(), dict(attrs))
     token = _CTX.set((tid, sid))
-    t0 = time.perf_counter()
+    t0, c0 = time.perf_counter(), time.thread_time()
     try:
         with _annotation(name):
             yield handle
@@ -444,7 +451,7 @@ def span(
         handle.attrs["error"] = f"{type(e).__name__}: {e}"
         raise
     finally:
-        elapsed = time.perf_counter() - t0
+        elapsed, cpu = time.perf_counter() - t0, time.thread_time() - c0
         _CTX.reset(token)
         t = tracer or active_tracer()
         t.record(
@@ -455,6 +462,7 @@ def span(
                 "name": name,
                 "start": handle.start,
                 "end": handle.start + elapsed,
+                "cpu_s": cpu,
                 "attrs": handle.attrs,
                 "process": process or _process_tag(),
             }

@@ -257,6 +257,9 @@ def _fetch_result(out, spec: Optional[PackSpec]):
             d for leaf in jax.tree_util.tree_leaves(out)
             if isinstance(leaf, jax.Array) for d in leaf.sharding.device_set
         }) or 1
+        # waiting for the fit, apart from moving its bytes
+        with child_span("executor.wait", on="result"):
+            jax.block_until_ready(out)
         if spec is not None:
             buf = np.asarray(jax.device_get(out))
             result = unpack(buf, spec), 1, buf.nbytes
@@ -1001,7 +1004,8 @@ class _Run:
     def await_compile(self, out, t0: float):
         """Block on a fresh executable's first dispatch, so that its XLA
         compile is attributed; steady-state dispatches queue."""
-        out = jax.block_until_ready(out)
+        with child_span("executor.wait", on="first_run"):
+            out = jax.block_until_ready(out)
         dt = time.perf_counter() - t0
         self.compile_time += dt
         observe("tpuml_executor_compile_seconds", dt)
@@ -1029,6 +1033,19 @@ class _Run:
             self.n_result_devices, len(score.sharding.device_set)
         )
 
+    def read_best(self, pending) -> None:
+        """Read the collective argmax of each ``(lane, score, batch_idx)``
+        and merge the winners. On a mesh this is where the host waits for
+        the chunks' fits: the wait is named apart from the reads."""
+        with child_span("executor.fetch", what="argmax", bytes=0):
+            with child_span("executor.wait", on="argmax"):
+                jax.block_until_ready([(bi, bs) for bi, bs, _ in pending])
+            for bi, bs, batch_idx in pending:
+                pos, score = int(bi), float(bs)
+                self.n_fetches += 2  # the argmax's two replicated scalars
+                if pos < len(batch_idx) and np.isfinite(score):
+                    self.merge_best(batch_idx[pos], score)
+
     def fetch(self, out, spec: Optional[PackSpec] = None):
         host, n_fetches, n_bytes = _fetch_result(out, spec)
         self.n_fetches += n_fetches
@@ -1051,12 +1068,7 @@ class _Run:
             for og, _size in out if isinstance(out, list) else [(out, None)]:
                 prefetch_async(og.buf if isinstance(og, Packed) else og)
         if self.pending_best:
-            with child_span("executor.fetch", what="argmax", bytes=0):
-                for bi, bs, batch_idx in self.pending_best:
-                    pos, score = int(bi), float(bs)
-                    self.n_fetches += 2  # the argmax's two replicated scalars
-                    if pos < len(batch_idx) and np.isfinite(score):
-                        self.merge_best(batch_idx[pos], score)
+            self.read_best(self.pending_best)
             self.pending_best.clear()
         for out, batch_idx, *post in self.pending:
             if isinstance(out, list):
@@ -1132,7 +1144,11 @@ def _build_executable(key, make_parts):
     :class:`_Part` that ``make_parts()`` names, from ``_compiled_cache``
     where the key is there (``fresh`` False). The ``executor.compile``
     span's ``cache`` says where the entry came from: ``hit`` (this
-    process's cache), ``aot`` (a disk blob), ``traced`` (built here).
+    process's cache), ``aot`` (a disk blob), ``traced`` (built here). A
+    fresh entry's stages are ``executor.build`` children of it, one a part
+    and stage: ``stage`` = ``cost`` (:func:`_capture_cost`), ``pack_spec``,
+    ``export`` (:func:`aot_jit`; ``source`` = aot / traced) or
+    ``mesh_jit``; what is left of the parent is ``make_parts()`` itself.
 
     The one rule of the result's form: a ONE-DEVICE program whose result
     crosses to the host packs it (one uint8 buffer, one transfer:
@@ -1152,17 +1168,22 @@ def _build_executable(key, make_parts):
             for part in make_parts():
                 fn, spec, cost, source = part.fn, None, None, "traced"
                 if part.mesh_jit is not None:
-                    fn = part.mesh_jit(fn)
+                    with child_span("executor.build", stage="mesh_jit"):
+                        fn = part.mesh_jit(fn)
                 else:
                     if part.priced:
-                        cost = _capture_cost(fn, part.example)
+                        with child_span("executor.build", stage="cost"):
+                            cost = _capture_cost(fn, part.example)
                     if part.to_host:
-                        spec = pack_spec_of(fn, part.example)
-                        fn = pack_wrap(fn)
+                        with child_span("executor.build", stage="pack_spec"):
+                            spec = pack_spec_of(fn, part.example)
+                            fn = pack_wrap(fn)
                     if part.disk_key is None:
                         fn = jax.jit(fn)
                     else:
-                        fn, source = aot_jit(fn, part.disk_key, part.example)
+                        with child_span("executor.build", stage="export") as bsp:
+                            fn, source = aot_jit(fn, part.disk_key, part.example)
+                            bsp.attrs["source"] = source
                 built.append((fn, spec, cost))
             _compiled_cache[key] = tuple(built)
             sp.attrs["cache"] = source
@@ -1193,10 +1214,12 @@ def _compile_ahead(key, built, examples) -> Callable[[], tuple]:
     ]
 
     def wait():
-        done = tuple(
-            (c.result(), spec, cost)
-            for c, (_, spec, cost) in zip(compiling, built)
-        )
+        # for the compiler's worker threads, not for the chip
+        with child_span("executor.wait", on="compile"):
+            done = tuple(
+                (c.result(), spec, cost)
+                for c, (_, spec, cost) in zip(compiling, built)
+            )
         _compiled_cache[key] = done
         return done
 
@@ -1590,13 +1613,17 @@ def _run_buckets(kernel, data, split_plan, param_dicts, mesh, trial_axis,
         else:
             host_X = np.asarray(data.X, np.float32)
             x_key = ("X",)
-        bp = plan_bucket(
-            kernel, static, [hypers[i] for i in idxs], host_X, n=n, d=d,
-            n_classes=data.n_classes, n_splits=split_plan.n_splits,
-            mesh=mesh, trial_axis=trial_axis, scoring=scoring,
-            max_trials_per_batch=max_trials_per_batch,
-        )
-        run.price_bucket(kernel, host_X, n, d, bp.static, len(idxs))
+        # host-only, but not free in a process's first search: a kernel's
+        # fused path imports its Pallas module here
+        with child_span("executor.plan") as sp:
+            bp = plan_bucket(
+                kernel, static, [hypers[i] for i in idxs], host_X, n=n, d=d,
+                n_classes=data.n_classes, n_splits=split_plan.n_splits,
+                mesh=mesh, trial_axis=trial_axis, scoring=scoring,
+                max_trials_per_batch=max_trials_per_batch,
+            )
+            run.price_bucket(kernel, host_X, n, d, bp.static, len(idxs))
+            sp.attrs.update(engine=bp.engine, chunk=bp.chunk)
         place = bp.placement
         if bp.engine == "streamed":
             # nothing to prewarm that is worth a full block pass: the
@@ -1974,7 +2001,8 @@ def _run_chunked(run: _Run, kernel, bp: BucketPlan, X, folds, data, hypers,
                             # the plan's bound on the states in flight
                             ahead.append(state)
                             if len(ahead) > bp.steps_ahead:
-                                jax.block_until_ready(ahead.popleft())
+                                with child_span("executor.wait", on="backpressure"):
+                                    jax.block_until_ready(ahead.popleft())
                         if (
                             curve_stride
                             and (ci + 1) % curve_stride == 0
@@ -2009,10 +2037,7 @@ def _run_chunked(run: _Run, kernel, bp: BucketPlan, X, folds, data, hypers,
                     bi, bs = _chunk_best(
                         mesh, trial_axis, chunk, sg, run.split_plan.n_folds
                     )(score, jnp.int32(len(batch_idx)))
-                    pos, best = int(bi), float(bs)
-                    run.n_fetches += 2
-                    if pos < len(batch_idx) and np.isfinite(best):
-                        run.merge_best(batch_idx[pos], best)
+                    run.read_best([(bi, bs, batch_idx)])
 
             def with_curve(out, mids=group_curves, sizes=[z for _, z in group_outs]):
                 """The drain's last step for this batch: the sampled evals

@@ -464,8 +464,12 @@ class LocalExecutor:
         device_best_pos = (
             run.device_best[0] if run.device_best is not None else None
         )
-        with child_span("executor.emit", n_subtasks=len(idxs)):
+        # the span's three shares (no span a subtask): building the result
+        # dicts, the result callback, the metrics message and its callback
+        build_s = on_result_s = on_metrics_s = 0.0
+        with child_span("executor.emit", n_subtasks=len(idxs)) as emit_sp:
             for j, gi in enumerate(idxs):
+                t_build = time.perf_counter()
                 st = subtasks[gi]
                 result = {
                     "subtask_id": st["subtask_id"],
@@ -495,8 +499,10 @@ class LocalExecutor:
                     result["batch_cost"] = batch_cost
                 results[gi] = result
                 counter_inc("tpuml_subtasks_completed_total")
+                t_result = time.perf_counter()
                 if on_result:
                     on_result(st["subtask_id"], "completed", result)
+                t_metrics = time.perf_counter()
                 if on_metrics:
                     on_metrics(
                         self._metrics_message(
@@ -508,6 +514,11 @@ class LocalExecutor:
                             curve=run.trial_metrics[j].get("curve"),
                         )
                     )
+                build_s += t_result - t_build
+                on_result_s += t_metrics - t_result
+                on_metrics_s += time.perf_counter() - t_metrics
+            emit_sp.attrs.update(build_s=build_s, on_result_s=on_result_s,
+                                 on_metrics_s=on_metrics_s)
 
     def _record_batch_cost(
         self, run, model_type: str, dataset_id: str, batch_size: int,
@@ -916,9 +927,12 @@ def _split_plan(data, y, **kw):
     once per batch. The key's first half is the fingerprint the stage
     cache prefixes to the same masks' device copy, memoised on ``data``."""
     with child_span("executor.split_plan", n_rows=len(y)) as sp:
-        plan, outcome = SPLIT_PLAN_CACHE.get_or_build(
-            dataset_fingerprint(data), y, **kw
-        )
+        # a dataset's first search hashes every byte of it here (memoised
+        # on ``data``): ``fingerprint_s`` says how much of the span that was
+        t0 = time.perf_counter()
+        fingerprint = dataset_fingerprint(data)
+        sp.attrs["fingerprint_s"] = time.perf_counter() - t0
+        plan, outcome = SPLIT_PLAN_CACHE.get_or_build(fingerprint, y, **kw)
         sp.attrs.update(
             n_splits=plan.n_splits, signature=str(plan.signature),
             outcome=outcome,

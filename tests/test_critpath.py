@@ -276,6 +276,37 @@ def test_direct_job_admits_its_batches_by_trace(with_timelines):
     assert r["dominant"][0] == "executor.fetch"
 
 
+@pytest.mark.parametrize("phase, window, child, key, value, inner, share_s", [
+    # the host waiting for the fit, apart from moving its bytes
+    ("executor.fetch", (2.8, 9.0), "executor.wait", "on", "result", (2.81, 8.9), 6.09),
+    # a chunked enqueue held back by the plan's bound on steps in flight
+    ("executor.dispatch", (2.7, 2.8), "executor.wait", "on", "backpressure", (2.72, 2.79), 0.07),
+    # a fresh executable: the cost analysis' own trace inside the build
+    ("executor.compile", (2.6, 2.7), "executor.build", "stage", "cost", (2.61, 2.69), 0.08),
+])
+def test_a_phase_share_is_named_by_the_span_inside_it(
+        phase, window, child, key, value, inner, share_s):
+    """An ``executor.wait`` inside a fetch or a dispatch relabels that part
+    of the phase as waiting (``on`` in the detail), an ``executor.build``
+    inside a compile names the stage; the phase keeps what is left, and a
+    wait or build under any other parent names nothing."""
+    spans, timelines = _direct_scenario()
+    spans = [s for s in spans if s["name"] != phase]
+    spans.append(_span(phase, *window, sid="ph000001", parent="bd000001"))
+    spans.append(_span(child, *inner, parent="ph000001", attrs={key: value}))
+    spans.append(_span(child, 9.05, 9.3, parent="bd000001", attrs={key: "stray"}))
+    r = critical_path("job-1", trace_id="aaaabbbbccccdddd", spans=spans,
+                      timelines=timelines)
+    _assert_tiles(r)
+    assert r["totals"][child] == pytest.approx(share_s, abs=1e-5)
+    assert r["totals"][phase] == pytest.approx(
+        window[1] - window[0] - share_s, abs=1e-5)
+    (seg,) = [s for s in r["segments"] if s["name"] == child]
+    assert seg["detail"] == {key: value}
+    assert r["totals"]["executor.emit"] == pytest.approx(0.4)  # the stray names nothing
+    assert set(r["totals"]) & {"executor.wait", "executor.build"} == {child}
+
+
 def test_scheduled_job_keeps_the_winner_only_rule():
     """A placed job whose result has not named a worker yet admits no
     batch: the direct rule is for jobs that were never placed."""

@@ -28,9 +28,9 @@ from cs230_distributed_machine_learning_tpu.obs import (
 )
 from cs230_distributed_machine_learning_tpu.obs import tracing
 
-ENGINE = ("executor.load_data", "executor.split_plan", "executor.stage",
-          "executor.compile", "executor.dispatch", "executor.fetch",
-          "executor.emit")
+ENGINE = ("executor.load_data", "executor.split_plan", "executor.plan",
+          "executor.stage", "executor.compile", "executor.dispatch",
+          "executor.fetch", "executor.emit")
 
 
 def _search():
@@ -85,6 +85,8 @@ def test_engine_spans_are_real_children_of_the_batch(local_search):
     # attributes the per-layer readers and the docs promise
     assert _one(spans, "executor.split_plan")["attrs"]["n_rows"] == 150
     assert _one(spans, "executor.split_plan")["attrs"]["n_splits"] == 6
+    assert 0 <= _one(spans, "executor.split_plan")["attrs"]["fingerprint_s"] < 0.01  # memoised
+    assert _one(spans, "executor.plan")["attrs"] == {"engine": "generic", "chunk": 4}
     stages = [s["attrs"] for s in spans if s["name"] == "executor.stage"]
     assert {a["what"] for a in stages} >= {"data", "folds"}
     assert all(a["outcome"] == "hit" and a["bytes"] == 0 for a in stages)  # warm
@@ -182,6 +184,176 @@ def test_child_span_needs_an_ambient_trace():
                 assert sp.parent_id == parent.span_id
     kid = _one(t.spans_for("abcd000000000000"), "kid")
     assert kid["attrs"] == {"x": 1}
+
+
+# ---------------- waits, build stages and thread CPU (PR 37) ----------------
+
+
+def _toy_table(n, d=9, n_classes=3, seed=37):
+    from cs230_distributed_machine_learning_tpu.models.base import TrialData
+
+    rng = np.random.RandomState(seed)
+    X = rng.randn(n, d).astype(np.float32)
+    w = rng.randn(d, n_classes).astype(np.float32)
+    y = np.argmax(X @ w + 0.5 * rng.randn(n, n_classes), axis=1).astype(np.int32)
+    return TrialData(X=X, y=y, n_classes=n_classes)
+
+
+#: engine -> (kernel, rows, trials, the ``on`` of a fresh search's waits,
+#: of a warm one's, the chunked plan's steps a dispatch or 0)
+ENGINES = {
+    "packed": ("LogisticRegression", 700,
+               [{"C": c, "tol": 1e-4, "max_iter": 5} for c in (0.1, 1.0, 10.0)],
+               {"first_run", "result"}, {"result"}),
+    "generic_mesh": ("LogisticRegression", 640,
+                     [{"C": c, "tol": 1e-4, "max_iter": 20} for c in (0.1, 0.5, 1.0, 10.0)],
+                     {"first_run", "argmax", "result"}, {"argmax", "result"}),
+    "chunked": ("GradientBoostingClassifier", 400,
+                [{"n_estimators": 12, "max_depth": 3, "random_state": 0}],
+                {"compile", "backpressure", "result"}, {"backpressure", "result"}),
+}
+
+
+def _engine_search(engine, monkeypatch):
+    """``search()`` runs the engine's toy bucket under an ``executor.batch``
+    span of a private tracer and returns (the run, its spans, the chunked
+    plan's steps a dispatch); the first call builds its executable."""
+    import dataclasses
+
+    from cs230_distributed_machine_learning_tpu.models.registry import get_kernel
+    from cs230_distributed_machine_learning_tpu.ops.folds import build_split_plan
+    from cs230_distributed_machine_learning_tpu.parallel import trial_map
+    from cs230_distributed_machine_learning_tpu.parallel.mesh import trial_mesh
+
+    model, n, params, _fresh, _warm = ENGINES[engine]
+    kernel = get_kernel(model)
+    data = _toy_table(n, n_classes=2 if engine == "chunked" else 3)
+    plan = build_split_plan(np.asarray(data.y), task="classification", n_folds=3)
+    kwargs, seen = {}, {"n_chunks": 0}
+    if engine == "packed":
+        monkeypatch.setenv("CS230_PALLAS_INTERPRET", "1")
+        resolve = kernel.resolve_static
+        monkeypatch.setattr(kernel, "resolve_static",
+                            lambda *a: {**resolve(*a), "_method": "nesterov"})
+    elif engine == "generic_mesh":
+        kwargs["mesh"] = trial_mesh(jax.devices()[:4])
+    else:
+        monkeypatch.setenv("CS230_TREE_CHUNK_MACS", "1e6")  # several steps a fit
+        plan_bucket = trial_map.plan_bucket
+
+        def all_but_two_steps_in_flight(*a, **k):
+            bp = plan_bucket(*a, **k)
+            seen["n_chunks"] = int(bp.chunk_plan["n_chunks"])
+            return dataclasses.replace(bp, steps_ahead=seen["n_chunks"] - 2)
+
+        monkeypatch.setattr(trial_map, "plan_bucket", all_but_two_steps_in_flight)
+    monkeypatch.setattr(trial_map, "_compiled_cache", {})
+    tracer = Tracer(journal=False)
+
+    def search():
+        tid = tracing.new_trace_id()
+        with use_tracer(tracer), span("executor.batch", trace_id=tid):
+            run = trial_map.run_trials(kernel, data, plan, params, **kwargs)
+        return run, tracer.spans_for(tid), seen["n_chunks"]
+
+    return search
+
+
+def _interval_inside(inner, outer, eps=1e-3):
+    return outer["start"] - eps <= inner["start"] <= inner["end"] <= outer["end"] + eps
+
+
+@pytest.mark.parametrize("warm", [False, True], ids=["fresh", "warm"])
+@pytest.mark.parametrize("engine", sorted(ENGINES))
+def test_every_engine_names_its_waits_and_its_build_stages(engine, warm, monkeypatch):
+    """Wherever the engine's thread blocks on something else there is one
+    ``executor.wait`` (``on`` says on what) inside the fetch or dispatch
+    span it happens in, a block a span and never a trial a span; a fresh
+    executable's stages are ``executor.build`` children of its
+    ``executor.compile``, a hit records none; every span carries the CPU
+    time of its thread."""
+    search = _engine_search(engine, monkeypatch)
+    run, spans, n_chunks = search()
+    if warm:
+        run, spans, n_chunks = search()
+    assert len(run.trial_metrics) == len(ENGINES[engine][2])
+    by_id = {s["span_id"]: s for s in spans}
+    waits = [s for s in spans if s["name"] == "executor.wait"]
+    assert {s["attrs"]["on"] for s in waits} == ENGINES[engine][4 if warm else 3]
+    assert len(waits) <= n_chunks + 4, [s["attrs"] for s in waits]
+    on = [s["attrs"]["on"] for s in waits]
+    # one wait a blocking fetch, and one a step the plan's bound held back
+    assert on.count("result") == sum(
+        s["name"] == "executor.fetch" and "what" not in s["attrs"] for s in spans)
+    assert on.count("backpressure") == (2 if engine == "chunked" else 0)
+    for s in waits:
+        parent = by_id[s["parent_id"]]
+        assert parent["name"] in ("executor.fetch", "executor.dispatch")
+        assert _interval_inside(s, parent), (s["attrs"], parent["name"])
+    (argmax,) = [s for s in waits if s["attrs"]["on"] == "argmax"] or [None]
+    if argmax is not None:
+        assert by_id[argmax["parent_id"]]["attrs"]["what"] == "argmax"
+    # the build stages: under a fresh executable's compile span only
+    builds = [s for s in spans if s["name"] == "executor.build"]
+    compiles = [s for s in spans if s["name"] == "executor.compile"]
+    assert {c["attrs"]["cache"] for c in compiles} == ({"hit"} if warm else {"traced"})
+    assert bool(builds) == (not warm)
+    for s in builds:
+        parent = by_id[s["parent_id"]]
+        assert parent["name"] == "executor.compile" and parent["attrs"]["cache"] != "hit"
+        assert _interval_inside(s, parent)
+    stages = [s["attrs"]["stage"] for s in builds]
+    if not warm:
+        assert stages == {
+            "packed": ["cost", "pack_spec", "export"],
+            "generic_mesh": ["mesh_jit"],
+            "chunked": ["export", "export", "pack_spec", "export"],
+        }[engine]
+        assert all(s["attrs"]["source"] == "traced" for s in builds
+                   if s["attrs"]["stage"] == "export")
+    # thread CPU: on every span, never more than the wall it was spent in
+    for s in spans:
+        assert np.isfinite(s["cpu_s"]) and 0 <= s["cpu_s"] <= s["end"] - s["start"] + 1e-3, s
+
+
+@pytest.mark.parametrize("engine", sorted(ENGINES))
+def test_valve_off_records_no_wait_and_returns_the_same_results(engine, monkeypatch):
+    search = _engine_search(engine, monkeypatch)
+    on, spans, _ = search()
+    assert any(s["name"] == "executor.wait" for s in spans)
+    monkeypatch.setenv("CS230_OBS", "0")
+    off, spans, _ = search()
+    assert spans == []
+    assert len(off.trial_metrics) == len(on.trial_metrics)
+    for a, b in zip(on.trial_metrics, off.trial_metrics):
+        assert (a["accuracy"], a["cv_scores"]) == (b["accuracy"], b["cv_scores"])
+
+
+def test_cpu_time_tells_a_span_that_computes_from_one_that_waits():
+    """``cpu_s`` is the thread's CPU time inside the span: wall less
+    ``cpu_s`` (less the children's wall) is time the thread was blocked."""
+    t = Tracer(journal=False)
+    with span("waits", trace_id="cpu0000000000000", tracer=t):
+        time.sleep(0.05)
+    with span("computes", trace_id="cpu0000000000000", tracer=t):
+        end = time.thread_time() + 0.05  # of CPU, however loaded the machine is
+        while time.thread_time() < end:
+            pass
+    waits, computes = t.spans_for("cpu0000000000000")
+    assert waits["end"] - waits["start"] >= 0.05 and 0 <= waits["cpu_s"] < 0.02
+    assert 0.05 <= computes["cpu_s"] <= computes["end"] - computes["start"] + 1e-3
+
+
+def test_emit_span_says_where_its_time_went(local_search):
+    """``executor.emit``'s three shares are attributes, not a span a
+    subtask: building the result dicts, the result callback, the metrics
+    message and its callback."""
+    _manager, spans = local_search
+    emit = _one(spans, "executor.emit")
+    shares = [emit["attrs"][k] for k in ("build_s", "on_result_s", "on_metrics_s")]
+    assert all(np.isfinite(v) and v >= 0 for v in shares)
+    assert 0 < sum(shares) <= emit["end"] - emit["start"] + 1e-3
+    assert not any(s["parent_id"] == emit["span_id"] for s in spans)
 
 
 class _CountingAnnotation:
