@@ -42,6 +42,8 @@ from ..ops.trees import (
     bin_data,
     build_tree,
     build_tree_deep,
+    build_tree_with_leaves,
+    leaf_values,
     predict_tree,
     predict_tree_deep,
     quantile_bins,
@@ -889,7 +891,7 @@ class _RandomForestBase(_TreeBase):
         row range so the multinomial stream matches exactly. Prediction
         for the fitting rows reuses the builder's final node ids — a
         resident leaf lookup, no extra pass."""
-        from ..ops.trees import _LOOKUP_M, _leaf_select, build_tree_streamed
+        from ..ops.trees import build_tree_streamed
 
         c = max(int(static["_n_classes"]), 2)
         n_splits = int(TW.shape[0])
@@ -901,7 +903,6 @@ class _RandomForestBase(_TreeBase):
         n_trees = int(static.get("n_estimators", 100))
         base_key = jax.random.PRNGKey(static["_seed"])
         n_internal = 2**depth - 1
-        n_leaves = 2**depth
 
         def stream_pass(fn, carry, *consts):
             for _i, start, blk in streamer.iter_blocks():
@@ -940,12 +941,7 @@ class _RandomForestBase(_TreeBase):
                     precision=jax.lax.Precision.DEFAULT,
                     count_from_stats=True,
                 )
-                leaf_local = node - n_internal
-                if n_leaves <= _LOOKUP_M:
-                    vals = _leaf_select(leaf_local, tree["leaf_val"], n_leaves)
-                else:
-                    vals = tree["leaf_val"][leaf_local]
-                acc = acc + vals
+                acc = acc + leaf_values(node - n_internal, tree["leaf_val"])
             mean = acc / float(n_trees)
             pred = jnp.argmax(mean, axis=-1).astype(jnp.int32)
             ew = EW[s].astype(jnp.float32)
@@ -1086,7 +1082,10 @@ class _GradientBoostingBase(_TreeBase):
             False, (int(xb.shape[1]),), (int(static["_n_bins"]),), 2)
         levels = int(static["_depth"]) * self._trees_per_stage(static)
         return {"stages": int(static.get("n_estimators", 100)),
-                "hist_levels_by_route": f"{route}:{levels}"}
+                "hist_levels_by_route": f"{route}:{levels}",
+                # routing passes over the table a stage: one a level a tree,
+                # the builder's own; the update is read off its leaf ids
+                "route_levels": levels}
 
     def chunk_init(self, X, y, w, hyper, static):
         xb = X["xb"] if isinstance(X, dict) else X
@@ -1248,7 +1247,10 @@ class GradientBoostingClassifierKernel(_GradientBoostingBase):
             H = jnp.maximum(P[:, 1:] * (1.0 - P[:, 1:]), BOOST_HESSIAN_FLOOR) * mask[:, None]
 
         def per_class(g, h, k2):
-            return build_tree(
+            # (tree, leaf of every row): the builder has routed the whole
+            # table, rows outside the mask too, so the stage reads its
+            # update off those ids and walks the finished tree no second time
+            return build_tree_with_leaves(
                 xb,
                 g[:, None],
                 h,
@@ -1267,12 +1269,8 @@ class GradientBoostingClassifierKernel(_GradientBoostingBase):
 
         kdim = G.shape[1]
         keys = jax.random.split(feat_key, kdim)
-        trees = jax.vmap(per_class, in_axes=(1, 1, 0))(G, H, keys)
-
-        def upd(tree):
-            return predict_tree(xb, tree, depth, n_bins)[:, 0]
-
-        delta = jax.vmap(upd)(trees).T  # [n, kdim]
+        trees, leaves = jax.vmap(per_class, in_axes=(1, 1, 0))(G, H, keys)
+        delta = jax.vmap(leaf_values)(leaves, trees["leaf_val"])[:, :, 0].T  # [n, kdim]
         if c > 2:
             F = F + lr * leaf_scale * delta
         else:
@@ -1357,7 +1355,7 @@ class GradientBoostingRegressorKernel(_GradientBoostingBase):
         sub_key, feat_key = jax.random.split(key)
         mask = (jax.random.uniform(sub_key, (n,)) < subsample).astype(jnp.float32) * w
         g = (y.astype(jnp.float32) - F) * mask
-        tree = build_tree(
+        tree, leaf_local = build_tree_with_leaves(
             xb,
             g[:, None],
             mask,
@@ -1367,7 +1365,7 @@ class GradientBoostingRegressorKernel(_GradientBoostingBase):
             max_features=static["_mf"] if static["_mf"] < xb.shape[1] else None,
             key=feat_key,
         )
-        F = F + lr * predict_tree(xb, tree, depth, n_bins)[:, 0]
+        F = F + lr * leaf_values(leaf_local, tree["leaf_val"])[:, 0]
         return F, tree
 
     def fit(self, X, y, w, hyper: Dict[str, Any], static: Dict[str, Any]):

@@ -541,6 +541,17 @@ def _leaf_select(leaf_local, V, n_leaves: int):
     return jnp.dot(oh, V, precision=jax.lax.Precision.HIGHEST)
 
 
+def leaf_values(leaf_local, leaf_val):
+    """``leaf_val[leaf_local]``: each row's leaf value from its leaf id,
+    gather-free up to ``_LOOKUP_M`` leaves. The one place that choice is
+    made: ``predict_tree`` (after its walk), the streamed forests and the
+    boosting stages (from the builder's own ids) all read values here."""
+    n_leaves = leaf_val.shape[0]
+    if n_leaves <= _LOOKUP_M:
+        return _leaf_select(leaf_local, leaf_val, n_leaves)
+    return leaf_val[leaf_local]
+
+
 def _feature_subset_allowed(node_ids, key, max_features: Optional[int], d: int):
     """[m, d] bool mask of each node's random feature subset (or None when
     all features are allowed), keyed by arena node id (fold_in) so chunked/
@@ -597,7 +608,7 @@ def _hist_with_count(local, xb, SC, n_nodes, n_bins, precision, k,
     )[0]
 
 
-def build_tree(
+def build_tree_with_leaves(
     xb,
     S,
     C,
@@ -609,14 +620,20 @@ def build_tree(
     key=None,
     precision=jax.lax.Precision.HIGHEST,
     count_from_stats: bool = False,
-) -> Dict[str, jnp.ndarray]:
-    """Fit one tree.
+):
+    """Fit one tree, and say where every row of ``xb`` ended.
 
     xb: [n, d] int32 bin codes. S: [n, k] per-sample weighted target stats
     (already multiplied by sample weight). C: [n] per-sample weights
     (counts for RF, hessians for boosting; 0 = sample not in this fit).
-    Returns {"split_feat" [2^depth-1], "split_bin" [2^depth-1],
-    "leaf_val" [2^depth, k]}.
+    Returns ``(tree, leaf_local)``: ``tree`` is {"split_feat" [2^depth-1],
+    "split_bin" [2^depth-1], "leaf_val" [2^depth, k], "leaf_weight"
+    [2^depth]}; ``leaf_local`` [n] int32 is each row's leaf, rows of weight
+    0 included (routing reads bin codes only), and is what
+    ``_route(xb, split_feat, split_bin, depth, n_bins)`` returns, to the
+    bit: a caller that wants the tree's values on its own training table
+    reads them with ``leaf_values`` and walks nothing again. The ids stay
+    out of the tree dict: trees are stacked into artifacts.
 
     precision: matmul precision for the histogram contraction. HIGHEST
     (default) for float-valued stats (boosting gradients); integer-valued
@@ -694,12 +711,35 @@ def build_tree(
         Sl = jax.ops.segment_sum(S, leaf_local, num_segments=n_leaves)
         Cl = jax.ops.segment_sum(C, leaf_local, num_segments=n_leaves)
     leaf_val = Sl / jnp.maximum(Cl, _EPS)[:, None]
-    return {
+    tree = {
         "split_feat": split_feat,
         "split_bin": split_bin,
         "leaf_val": leaf_val,
         "leaf_weight": Cl,
     }
+    return tree, leaf_local
+
+
+def build_tree(
+    xb,
+    S,
+    C,
+    *,
+    depth: int,
+    n_bins: int,
+    min_samples_leaf: float = 1.0,
+    max_features: Optional[int] = None,
+    key=None,
+    precision=jax.lax.Precision.HIGHEST,
+    count_from_stats: bool = False,
+) -> Dict[str, jnp.ndarray]:
+    """``build_tree_with_leaves``'s tree alone, for callers that score
+    other rows than they fitted on."""
+    return build_tree_with_leaves(
+        xb, S, C, depth=depth, n_bins=n_bins, min_samples_leaf=min_samples_leaf,
+        max_features=max_features, key=key, precision=precision,
+        count_from_stats=count_from_stats,
+    )[0]
 
 
 # ---------------- out-of-core streamed builder ----------------
@@ -1429,7 +1469,4 @@ def predict_tree(xb, tree, depth: int, n_bins: int = 0):
     """Leaf values for each row of binned query data. ``n_bins`` (when
     known) lets the gather-free router use the fast bf16 column select."""
     leaf = _route(xb, tree["split_feat"], tree["split_bin"], depth, n_bins)
-    n_leaves = 2**depth
-    if n_leaves <= _LOOKUP_M:
-        return _leaf_select(leaf, tree["leaf_val"], n_leaves)
-    return tree["leaf_val"][leaf]
+    return leaf_values(leaf, tree["leaf_val"])

@@ -177,7 +177,7 @@ def test_each_control_reads_not_correct(toy, control):
 
 def test_a_boosted_bucket_says_its_stages_and_its_levels_by_route(toy):
     attrs = toy["kernel"].dispatch_attrs(toy["static"], toy["Xd"])
-    assert attrs == {"stages": STAGES, "hist_levels_by_route": f"scatter:{DEPTH}"}
+    assert attrs == {"stages": STAGES, "hist_levels_by_route": f"scatter:{DEPTH}", "route_levels": DEPTH}
 
 
 def test_the_reference_draws_the_programs_row_masks_and_rounds_to_the_grids(toy):

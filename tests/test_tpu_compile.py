@@ -651,8 +651,11 @@ def test_boost_chunked_step_compiles_at_the_cell_shape(tpu_backend, chunk):
     the benchmark's readers by its float32 accumulator (``hist_op_pattern``)
     and by nothing else of the program; the (g, h) operand enters the loops
     in bfloat16 and the bin one-hot is a predicate, never a float32 matrix
-    (the configuration's ``precision``); the compiled program holds no
-    [n, m] routing buffer, and the lane estimate the engine plans with is
+    (the configuration's ``precision``); a stage routes the table once, the
+    builder's own walk (PR 38: the stage reads its update off the builder's
+    leaf ids; until then ``predict_tree`` walked the finished tree again and
+    the program held every routing fusion twice); the compiled program holds
+    no [n, m] routing buffer, and the lane estimate the engine plans with is
     within 1.5 times of what a lane takes."""
     vstep, args, kernel, static, plan = _boost_step(chunk)
     assert plan["trees_per_chunk"] == 2 and static["_depth"] == 8 and static["_n_bins"] == 128
@@ -670,6 +673,18 @@ def test_boost_chunked_step_compiles_at_the_cell_shape(tpu_backend, chunk):
     assert all(f"bf16[{chunk},{S},1,{rows},2]" in t for t in loops[1:])  # rounded once, before the loop
     assert any(re.search(rf"= pred\[16384,{_D_HIGGS},128\]", t) for t in text)
     assert not any(re.search(rf"= f32\[16384,{_D_HIGGS * 128}\]", t) for t in text)
+    # a level's go-left is one fusion, whose computation contracts the [n, 28]
+    # bin codes with the level's [28, m] feature one-hot (``_col_select``): an
+    # instruction sits in one computation and a fused computation has one
+    # caller, so the contractions count the fusions
+    shape = {m.group(1): m.group(2) for t in text if (m := re.match(r"(?:ROOT )?(%\S+) = (\S+)", t))}
+    routed = []
+    for t in text:
+        dot = re.match(rf"(?:ROOT )?%\S+ = f32\[{_N_HIGGS},[\d,]+\]\S* convolution\(%\S+, (%[^\s)]+)\)", t)
+        hot = dot and re.match(rf"pred\[(?:{chunk},)?{S},{_D_HIGGS}(?:,(\d+))?\]", shape.get(dot.group(1), ""))
+        if hot:
+            routed.append(int(hot.group(1) or 1))
+    assert sorted(routed) == [1, 2, 4, 8, 16, 32, 64, 128], routed  # the root's compare, then m = 2 ... 128
     temp = compiled.memory_analysis().temp_size_in_bytes
     lanes = chunk * S
     assert temp < lanes * 75e6 + 0.2e9  # 2.03 GB a lane by the estimate before PR 34

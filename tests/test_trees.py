@@ -435,3 +435,87 @@ def test_covertype_tree_grid_best_params_match():
         sk_scores[ne] = float(np.mean(cross_val_score(est, Xf, yf, cv=3)))
     sk_pick = max(sk_scores, key=sk_scores.get)
     assert ours_pick == sk_pick, (ours_pick, sk_pick, sk_scores)
+
+
+@pytest.mark.parametrize("depth,max_features", [(3, None), (3, 5), (8, None), (8, 5), (10, None)])
+def test_builder_hands_back_the_leaf_of_every_row(depth, max_features):
+    """``build_tree_with_leaves``'s ids are the ones a walk of the finished
+    tree gives (``_route``), for every row, rows of weight 0 included: a
+    boosting stage reads its update off them instead of walking again
+    (PR 38). Depth 8 is the boosting cell's (nodes that do not split pass
+    every row left); depth 10 is past ``_LOOKUP_M``, where routing gathers
+    and ``leaf_values`` indexes. ``build_tree`` is the same tree alone."""
+    import jax
+    import jax.numpy as jnp
+
+    from cs230_distributed_machine_learning_tpu.ops import trees as ot
+
+    rng = np.random.default_rng(38 + depth)
+    n, d, nb = 3000, 8, 32
+    xb = jnp.asarray(rng.integers(0, nb, (n, d)), jnp.int32)
+    w = jnp.asarray(rng.random(n) < 0.8, jnp.float32)  # a fifth of the rows are not in the fit
+    g = jnp.asarray(rng.normal(size=(n, 1)), jnp.float32) * w[:, None]
+    kw = dict(depth=depth, n_bins=nb, max_features=max_features, key=jax.random.PRNGKey(3))
+    tree, leaf = ot.build_tree_with_leaves(xb, g, w, **kw)
+    assert leaf.shape == (n,) and leaf.dtype == jnp.int32
+    walked = ot._route(xb, tree["split_feat"], tree["split_bin"], depth, nb)
+    np.testing.assert_array_equal(np.asarray(leaf), np.asarray(walked))
+    assert len(np.unique(np.asarray(leaf)[np.asarray(w) == 0])) > 1  # unweighted rows are routed too
+    np.testing.assert_array_equal(
+        np.asarray(ot.leaf_values(leaf, tree["leaf_val"])), np.asarray(ot.predict_tree(xb, tree, depth, nb)))
+    alone = ot.build_tree(xb, g, w, **kw)
+    assert sorted(alone) == sorted(tree) == ["leaf_val", "leaf_weight", "split_bin", "split_feat"]
+    for name in tree:
+        np.testing.assert_array_equal(np.asarray(alone[name]), np.asarray(tree[name]))
+
+
+@pytest.mark.parametrize("name,n_classes", [
+    ("GradientBoostingClassifier", 2), ("GradientBoostingClassifier", 3), ("GradientBoostingRegressor", 0)])
+def test_boosting_stage_updates_scores_as_a_walk_of_its_trees_would(name, n_classes):
+    """F after two stages (the chunked step's scan) is, bit for bit, the
+    parent's form: ``F + lr * predict_tree(xb, tree, ...)`` from the trees
+    the stages return, on rows outside the stage's mask as on those inside.
+    And the stacked trees carry no per-row leaf ids."""
+    import jax
+    import jax.numpy as jnp
+
+    from cs230_distributed_machine_learning_tpu.ops.trees import predict_tree
+    from cs230_distributed_machine_learning_tpu.parallel import trial_map
+
+    rng = np.random.default_rng(38)
+    n, d, depth = 1500, 8, 4
+    X = rng.normal(size=(n, d)).astype(np.float32)
+    score = X[:, 0] + X[:, 1] * X[:, 2] + 0.3 * rng.normal(size=n)
+    if n_classes:
+        y = np.digitize(score, np.quantile(score, np.arange(1, n_classes) / n_classes)).astype(np.int32)
+    else:
+        y = score.astype(np.float32)
+    kernel = get_kernel(name)
+    static_key, hyper = kernel.canonicalize(
+        {"n_estimators": 2, "max_depth": depth, "learning_rate": 0.3, "subsample": 0.7, "max_features": 5})
+    static = trial_map._resolved_static(kernel, static_key, n, d, n_classes)
+    Xd = jax.tree_util.tree_map(jnp.asarray, kernel.prepare_data(X, static))
+    xb, yd = Xd["xb"], jnp.asarray(y)
+    w = jnp.asarray(rng.random(n) < 0.8, jnp.float32)  # another fold's rows carry weight 0
+    hyper = {k: jnp.float32(v) for k, v in hyper.items()}
+    F0 = kernel.chunk_init(Xd, yd, w, hyper, static)
+    F, trees = jax.jit(lambda F0: kernel.fit_chunk(
+        Xd, yd, w, hyper, static, jnp.int32(0), F0, {"trees_per_chunk": 2}))(F0)
+    assert all(leaf.shape[0] == 2 and n not in leaf.shape for leaf in jax.tree_util.tree_leaves(trees))
+
+    lr, n_bins = hyper["learning_rate"], static["_n_bins"]
+
+    @jax.jit  # as the stage is: a compiled multiply-add may round once where two eager ops round twice
+    def walked(F, stage):
+        if n_classes == 0:
+            return F + lr * predict_tree(xb, stage, depth, n_bins)[:, 0]
+        delta = jax.vmap(lambda tree: predict_tree(xb, tree, depth, n_bins)[:, 0])(stage).T
+        if n_classes > 2:
+            return F + lr * ((n_classes - 1) / n_classes) * delta
+        return F.at[:, 1].add(lr * delta[:, 0])
+
+    want = F0
+    for t in range(2):
+        want = walked(want, jax.tree_util.tree_map(lambda a: a[t], trees))
+    assert float(jnp.max(jnp.abs(F - F0))) > 0.01  # the stages moved the scores
+    assert np.array_equal(np.asarray(F), np.asarray(want))
