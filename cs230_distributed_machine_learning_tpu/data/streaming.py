@@ -56,6 +56,7 @@ flight-recorder event per pass, and devprof's ``stream`` phase
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import os
 import threading
@@ -65,7 +66,7 @@ from typing import Any, Callable, Iterable, Iterator, List, Optional, Tuple
 
 import numpy as np
 
-from ..obs import counter_inc, record_event
+from ..obs import child_span, counter_inc, record_event
 from ..utils.logging import get_logger
 from .stage_cache import STAGE_CACHE, _tree_nbytes, budget_bytes
 
@@ -84,6 +85,12 @@ _BLOCK_BUDGET_FRACTION = 8
 #: would exceed this fraction of the stage budget (past it, one dataset
 #: crowds out every other tenant even when it technically fits)
 _AUTO_BUDGET_FRACTION = 0.5
+
+#: the transient device bytes of ONE block while the kernel computes on it
+#: (every lane's intermediates: logits, residuals) get this multiple of the
+#: stage budget. At the default budget (0.4 of the device) the resident
+#: blocks, the double-buffered pair and one block's work stay within 0.7
+_WORK_BUDGET_FRACTION = 0.75
 
 
 def stream_mode() -> str:
@@ -141,10 +148,20 @@ class BlockPlan:
         return range(self.n_blocks)
 
 
-def plan_blocks(n: int, row_bytes: int, rows: Optional[int] = None) -> BlockPlan:
+def work_budget_bytes() -> int:
+    """Device bytes one block's computation may hold beside the cache."""
+    return int(_WORK_BUDGET_FRACTION * budget_bytes())
+
+
+def plan_blocks(n: int, row_bytes: int, rows: Optional[int] = None, *,
+                work_row_bytes: int = 0) -> BlockPlan:
     """Tile ``n`` rows of ``row_bytes`` bytes each into uniform blocks.
     ``CS230_STREAM_BLOCK_ROWS`` (or the ``rows`` argument) pins the block
-    height; the default targets ``budget_bytes() / 8`` per block."""
+    height. The default is the lower of two heights: the block's own bytes
+    at ``budget_bytes() / 8``, and ``work_row_bytes`` (what one row of the
+    block costs while the kernel computes on it: all its lanes'
+    intermediates together) at ``work_budget_bytes()``, so a block shrinks
+    as the lanes grow."""
     if rows is None:
         env = os.environ.get("CS230_STREAM_BLOCK_ROWS")
         if env:
@@ -154,7 +171,10 @@ def plan_blocks(n: int, row_bytes: int, rows: Optional[int] = None) -> BlockPlan
                 rows = None
     if rows is None:
         target = max(budget_bytes() // _BLOCK_BUDGET_FRACTION, 1)
-        rows = max(_MIN_BLOCK_ROWS, int(target // max(int(row_bytes), 1)))
+        rows = int(target // max(int(row_bytes), 1))
+        if work_row_bytes > 0:
+            rows = min(rows, int(work_budget_bytes() // int(work_row_bytes)))
+        rows = max(_MIN_BLOCK_ROWS, rows)
     rows = max(1, min(int(rows), max(int(n), 1)))
     n_blocks = max(1, -(-int(n) // rows))
     return BlockPlan(n=int(n), rows=rows, n_blocks=n_blocks)
@@ -247,6 +267,9 @@ class RowBlockStreamer:
             "bytes": 0,        # bytes uploaded (post-compression)
             "upload_s": 0.0,   # upload wall on the worker (misses only)
             "wait_s": 0.0,     # consumer blocked waiting for a block
+            "hits": 0,         # blocks the cache already held
+            "dispatch_s": 0.0,  # consumer's wall on each block (its enqueue)
+            "device_wait_s": 0.0,  # blocked on the device (:meth:`wait`)
         }
 
     def block_key(self, i: int) -> tuple:
@@ -289,8 +312,8 @@ class RowBlockStreamer:
         )
         pending: "collections.deque" = collections.deque()
         pos = 0
-        blocks = uploads = nbytes = 0
-        upload_s = wait_s = 0.0
+        blocks = uploads = nbytes = hits = 0
+        upload_s = wait_s = dispatch_s = 0.0
 
         def submit():
             nonlocal pos
@@ -318,11 +341,16 @@ class RowBlockStreamer:
                     uploads += 1
                     nbytes += up_bytes
                     upload_s += up_wall
+                else:
+                    hits += 1
+                    counter_inc("tpuml_stream_cache_hits_total")
                 counter_inc("tpuml_stream_blocks_total")
+                t0 = time.perf_counter()
                 try:
                     yield i, self.plan.start(i), val
                 finally:
                     # the consumer advanced: this block is evictable again
+                    dispatch_s += time.perf_counter() - t0
                     self._cache.release(key)
         finally:
             # abandoned pass / worker error: drop refs the prefetcher took
@@ -337,9 +365,11 @@ class RowBlockStreamer:
                 self._cache.release(key)
             if ex is not None:
                 ex.shutdown(wait=True)
-            self._finish_pass(blocks, uploads, nbytes, upload_s, wait_s)
+            self._finish_pass(blocks, uploads, nbytes, upload_s, wait_s,
+                              hits, dispatch_s)
 
-    def _finish_pass(self, blocks, uploads, nbytes, upload_s, wait_s):
+    def _finish_pass(self, blocks, uploads, nbytes, upload_s, wait_s,
+                     hits, dispatch_s):
         if blocks == 0:
             return
         with self._stats_lock:
@@ -349,6 +379,8 @@ class RowBlockStreamer:
             self.stats["bytes"] += nbytes
             self.stats["upload_s"] += upload_s
             self.stats["wait_s"] += wait_s
+            self.stats["hits"] += hits
+            self.stats["dispatch_s"] += dispatch_s
         hidden_s = max(upload_s - wait_s, 0.0)
         counter_inc("tpuml_stream_passes_total")
         if nbytes:
@@ -376,6 +408,45 @@ class RowBlockStreamer:
             ),
             double_buffer=self._db,
         )
+
+    # ---------------- spans ----------------
+
+    @contextlib.contextmanager
+    def pass_span(self, kind: str):
+        """The ``stream.pass`` span of one pass over the block set, opened
+        by the kernel's driver around its ``iter_blocks`` loop: ``kind``
+        (the driver's name for the pass: ``power`` / ``step`` / ``eval`` for
+        the LogReg solver), and on exit ``blocks``, ``uploaded_bytes``,
+        ``cache_hits``, ``wait_s`` (blocked on a block that was not ready)
+        and ``dispatch_s`` (the driver's wall on the blocks: enqueueing
+        their programs)."""
+        with self._stats_lock:
+            before = dict(self.stats)
+        with child_span("stream.pass", kind=kind) as sp:
+            yield sp
+            with self._stats_lock:
+                now = dict(self.stats)
+            sp.attrs.update(
+                blocks=now["blocks"] - before["blocks"],
+                uploaded_bytes=now["bytes"] - before["bytes"],
+                cache_hits=now["hits"] - before["hits"],
+                wait_s=now["wait_s"] - before["wait_s"],
+                dispatch_s=now["dispatch_s"] - before["dispatch_s"],
+            )
+
+    def wait(self, value):
+        """Block until ``value`` is computed, as an ``executor.wait
+        on=result`` span: the driver's per-step sync and final read wait
+        for the device, and ``stats["device_wait_s"]`` sums them."""
+        import jax
+
+        with child_span("executor.wait", on="result"):
+            t0 = time.perf_counter()
+            value = jax.block_until_ready(value)
+            waited = time.perf_counter() - t0
+        with self._stats_lock:
+            self.stats["device_wait_s"] += waited
+        return value
 
     # ---------------- derived stats ----------------
 

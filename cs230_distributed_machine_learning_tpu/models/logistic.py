@@ -183,6 +183,16 @@ class LogisticRegressionKernel(ModelKernel):
         plus a salt naming the form in block cache keys."""
         return np.asarray(X_np, np.float32), ("raw", "f32")
 
+    def stream_lane_row_bytes(self, static: Dict[str, Any]) -> int:
+        """Engine hook: device bytes one row of a block costs one (trial,
+        split) lane while ``grad_block`` computes on it, the block plan's
+        ``work_row_bytes`` a lane: the float32 logits and the bfloat16
+        residual of each class, and the row's float32 max, sum and scale.
+        The compiled v5e program holds 58 of this rule's 72 at ten classes
+        (tests/test_tpu_compile.py)."""
+        c = max(int(static["_n_classes"]), 2)
+        return c * (4 + 2) + 3 * 4
+
     def stream_scores(self, streamer, y_pad, TW, EW, hyper_batch, static, n):
         """Block-accumulated Nesterov over a RowBlockStreamer: one pass
         per solver iteration (plus 31 Lipschitz passes and one eval
@@ -190,7 +200,10 @@ class LogisticRegressionKernel(ModelKernel):
         ``weighted_accuracy`` composition restructured so no array of
         ``n`` rows is ever device-resident. Pad rows carry zero sample
         weight, so every block-sum matches the single-shot value up to
-        f32 summation order (the parity tests/test_streaming.py pins)."""
+        f32 summation order (the parity tests/test_streaming.py pins).
+        Returns the packed path's leaves on the device, for the engine to
+        fetch: ``score`` [T, S] and, with curves on, ``curve_gmax`` [T, S,
+        slots], ``curve_stride`` and ``curve_steps``."""
         return _stream_nesterov_scores(
             streamer, y_pad, TW, EW, hyper_batch, static, n
         )
@@ -906,10 +919,36 @@ def _nesterov(A, w, W0, grad_fn, C, lam, max_iter, tol, steps=_NESTEROV_STEPS,
 _STREAM_FN_CACHE: Dict[Any, Any] = {}
 
 
+class _BuiltAtFirstCall:
+    """A jitted block program compiled at its first call for the shapes it
+    is given, from that call's own arguments, in an ``executor.compile``
+    span (``engine=streamed``) whose ``executor.build stage=compile`` child
+    holds the backend's compile or persistent-cache load, as every other
+    engine's executables are built; later calls with those shapes run the
+    executable. ``fn`` is the jitted function."""
+
+    def __init__(self, fn):
+        self.fn, self._exes = fn, {}
+
+    def __call__(self, *args):
+        key = tuple(None if a is None else (a.shape, a.dtype) for a in args)
+        exe = self._exes.get(key)
+        if exe is None:
+            from ..obs import child_span
+
+            with child_span("executor.compile", engine="streamed",
+                            cache="traced"):
+                with child_span("executor.build", stage="compile"):
+                    exe = self._exes[key] = self.fn.lower(*args).compile()
+        return exe(*args)
+
+
 def _stream_fns(rows, d, c, S, T, fit_intercept, lam):
-    """Jitted per-block / per-iteration pieces of the streamed Nesterov
-    solver, cached on geometry: the engine re-enters stream_scores once
-    per trial chunk and every repeat chunk re-dispatches these."""
+    """Per-block / per-iteration pieces of the streamed Nesterov solver,
+    ``(power_block, power_norm, extrapolate, grad_block, update,
+    eval_block)``, each built at its first call (:class:`_BuiltAtFirstCall`)
+    and cached on geometry: the engine re-enters stream_scores once per
+    trial chunk and every repeat chunk re-dispatches these."""
     key = (rows, d, c, S, T, bool(fit_intercept), float(lam))
     fns = _STREAM_FN_CACHE.get(key)
     if fns is not None:
@@ -969,11 +1008,15 @@ def _stream_fns(rows, d, c, S, T, fit_intercept, lam):
         )
 
     @jax.jit
-    def update(W, Wp, V, G_raw, t, done, lam_max, C, max_iter, tol):
+    def update(W, Wp, V, G_raw, t, done, lam_max, C, max_iter, tol, tr, slot):
         # _nesterov's scan body, batched over (trial, split) lanes, with
-        # the cross-block gradient sum supplied instead of grad_fn(V)
+        # the cross-block gradient sum supplied instead of grad_fn(V); the
+        # learning curve's slot ``slot`` of ``tr`` (None: capture off) takes
+        # this step's gmax, last sample of a stride window winning
         G = C[:, None, None, None] * G_raw + lam * pen_mask[None, None] * V
         gmax = jnp.max(jnp.abs(G), axis=(2, 3))           # [T, S]
+        if tr is not None:
+            tr = tr.at[slot].set(gmax)
         L = 0.5 * C[:, None] * lam_max[None, :] + lam + 1e-6
         step = (1.0 / L)[:, :, None, None]
         active = jnp.logical_and(t < max_iter[:, None], jnp.logical_not(done))
@@ -982,7 +1025,7 @@ def _stream_fns(rows, d, c, S, T, fit_intercept, lam):
         Wp_new = jnp.where(a4, W, Wp)
         done = jnp.logical_or(done, gmax < tol[:, None])
         idle = jnp.logical_or(done, (t + 1.0) >= max_iter[:, None])
-        return W_new, Wp_new, done, jnp.all(idle)
+        return W_new, Wp_new, done, jnp.all(idle), tr
 
     @jax.jit
     def eval_block(blk, acc, W, y_pad, EW, start):
@@ -995,12 +1038,15 @@ def _stream_fns(rows, d, c, S, T, fit_intercept, lam):
         hit = (jnp.argmax(Z, axis=-1) == yb[None, None, :]).astype(jnp.float32)
         return acc + jnp.einsum("sr,tsr->ts", ewb, hit)
 
-    fns = (power_block, power_norm, extrapolate, grad_block, update, eval_block)
+    fns = tuple(_BuiltAtFirstCall(f) for f in (
+        power_block, power_norm, extrapolate, grad_block, update, eval_block))
     _STREAM_FN_CACHE[key] = fns
     return fns
 
 
 def _stream_nesterov_scores(streamer, y_pad, TW, EW, hyper_batch, static, n):
+    from ..obs.curves import curves_enabled, trace_stride
+
     n_classes = int(static["_n_classes"])
     c = max(n_classes, 2)
     fit_intercept = bool(static.get("fit_intercept", True))
@@ -1017,6 +1063,8 @@ def _stream_nesterov_scores(streamer, y_pad, TW, EW, hyper_batch, static, n):
     dp = d + (1 if fit_intercept else 0)
     steps = int(static.get("_iters", _NESTEROV_STEPS))
 
+    stride = trace_stride(steps)
+    slots = -(-steps // stride) if curves_enabled() else None
     power_block, power_norm, extrapolate, grad_block, update, eval_block = (
         _stream_fns(rows, d, c, S, T, fit_intercept, lam)
     )
@@ -1027,32 +1075,50 @@ def _stream_nesterov_scores(streamer, y_pad, TW, EW, hyper_batch, static, n):
     u = jnp.zeros((S, dp), jnp.float32)
     for it in range(31):
         u = jnp.zeros((S, dp), jnp.float32)
-        for _i, start, blk in streamer.iter_blocks():
-            u = power_block(blk, u, v, TW, jnp.asarray(start, jnp.int32))
+        with streamer.pass_span("power"):
+            for _i, start, blk in streamer.iter_blocks():
+                u = power_block(blk, u, v, TW, jnp.asarray(start, jnp.int32))
         if it < 30:
             v = power_norm(u)
     lam_max = jnp.sum(v * u, axis=1)                      # [S]
 
+    # the learning curve at the packed path's slots (trace_stride): slot
+    # t // stride holds the gmax of the last step of its window
+    tr = None if slots is None else jnp.zeros((slots, T, S), jnp.float32)
     W = jnp.zeros((T, S, dp, c), jnp.float32)
     Wp = W
     done = jnp.zeros((T, S), bool)
+    ran = 0
     for t in range(steps):
         tf = jnp.asarray(t, jnp.float32)
         V = extrapolate(W, Wp, tf)
         G = jnp.zeros((T, S, dp, c), jnp.float32)
-        for _i, start, blk in streamer.iter_blocks():
-            G = grad_block(blk, G, V, y_pad, TW, jnp.asarray(start, jnp.int32))
-        W, Wp, done, idle = update(
-            W, Wp, V, G, tf, done, lam_max, C, max_iter, tol
+        with streamer.pass_span("step"):
+            for _i, start, blk in streamer.iter_blocks():
+                G = grad_block(blk, G, V, y_pad, TW,
+                               jnp.asarray(start, jnp.int32))
+        W, Wp, done, idle, tr = update(
+            W, Wp, V, G, tf, done, lam_max, C, max_iter, tol, tr,
+            jnp.asarray(t // stride, jnp.int32),
         )
+        ran = t + 1
         # host-visible early exit: once every (trial, split) lane is
         # converged or past its max_iter, the remaining scan steps would
         # be masked no-ops — each costing a full pass over the blocks
-        if bool(idle):
+        if bool(streamer.wait(idle)):
             break
 
     acc = jnp.zeros((T, S), jnp.float32)
-    for _i, start, blk in streamer.iter_blocks():
-        acc = eval_block(blk, acc, W, y_pad, EW, jnp.asarray(start, jnp.int32))
+    with streamer.pass_span("eval"):
+        for _i, start, blk in streamer.iter_blocks():
+            acc = eval_block(blk, acc, W, y_pad, EW,
+                             jnp.asarray(start, jnp.int32))
     den = jnp.maximum(jnp.sum(EW.astype(jnp.float32), axis=1), 1e-12)
-    return np.asarray(acc / den[None, :], np.float32)
+    out = {"score": acc / den[None, :]}
+    if tr is not None:
+        # the packed path's leaves: [T, S, slots] and per-lane stride and
+        # steps (the steps run: an early exit leaves the later slots out)
+        out["curve_gmax"] = jnp.transpose(tr, (1, 2, 0))
+        out["curve_stride"] = jnp.full((T, S), float(stride), jnp.float32)
+        out["curve_steps"] = jnp.full((T, S), float(ran), jnp.float32)
+    return out

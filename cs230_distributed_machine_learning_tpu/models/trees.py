@@ -905,10 +905,11 @@ class _RandomForestBase(_TreeBase):
         n_internal = 2**depth - 1
 
         def stream_pass(fn, carry, *consts):
-            for _i, start, blk in streamer.iter_blocks():
-                carry = fn(
-                    carry, *consts, blk, jnp.asarray(start, jnp.int32),
-                )
+            with streamer.pass_span("level"):
+                for _i, start, blk in streamer.iter_blocks():
+                    carry = fn(
+                        carry, *consts, blk, jnp.asarray(start, jnp.int32),
+                    )
             return carry
 
         y1 = jax.nn.one_hot(y_pad, c, dtype=jnp.float32)       # [n_pad, c]
@@ -946,7 +947,8 @@ class _RandomForestBase(_TreeBase):
             pred = jnp.argmax(mean, axis=-1).astype(jnp.int32)
             ew = EW[s].astype(jnp.float32)
             num = jnp.sum((pred == y_pad).astype(jnp.float32) * ew)
-            scores[s] = float(num / jnp.maximum(jnp.sum(ew), 1e-12))
+            scores[s] = float(streamer.wait(
+                num / jnp.maximum(jnp.sum(ew), 1e-12)))
         # trials in one bucket share an identical static config (RF hypers
         # are static), so every trial of the chunk gets the same row
         n_t = len(next(iter(hyper_batch.values()))) if hyper_batch else 1

@@ -366,6 +366,11 @@ def register_catalog() -> None:
         "Complete passes over a streamed dataset's block set",
     )
     c(
+        "tpuml_stream_cache_hits_total",
+        "Row blocks a streaming pass found already staged in the cache "
+        "(no upload)",
+    )
+    c(
         "tpuml_mesh_reshards_total",
         "Fleet mesh-generation bumps, labeled by reason "
         "(join|death|evict|unsubscribe)",

@@ -2093,9 +2093,15 @@ def _run_streamed(run: _Run, kernel, bp: BucketPlan, X_np, data, hypers,
     judges each alone); the block cache keys carry
     ``host_signature()`` + the kernel's trace_salt + the staged form.
 
-    The bucket runs blocking; the consumer's blocked block-wait time
-    lands in ``_PHASE.stage`` like any other staging wall (the hidden
-    share is devprof's ``stream`` phase).
+    The block height counts the lanes (``plan_blocks``'s
+    ``work_row_bytes``: the kernel's ``stream_lane_row_bytes`` times the
+    chunk's trials x splits), so a block's intermediates fit beside the
+    cached blocks. The bucket runs blocking; the consumer's blocked
+    block-wait time lands in ``_PHASE.stage`` like any other staging wall
+    (the hidden share is devprof's ``stream`` phase), and only the driver's
+    waits for the device (``streamer.wait``: ``executor.wait on=result``
+    spans) are ``run_time``. Each pass over the blocks is a ``stream.pass``
+    span under the chunk's ``executor.dispatch``.
     """
     from ..data.streaming import (
         RowBlockStreamer, array_block_source, plan_blocks,
@@ -2105,7 +2111,11 @@ def _run_streamed(run: _Run, kernel, bp: BucketPlan, X_np, data, hypers,
     blockable, form_salt = kernel.stream_form(X_np, bp.static)
     n = int(blockable.shape[0])
     row_bytes = int(blockable.nbytes // max(n, 1))
-    bplan = plan_blocks(n, row_bytes)
+    # every chunk is padded to ``bp.chunk`` trials: the lanes a pass computes
+    lanes = bp.chunk * int(split_plan.n_splits)
+    lane_row_bytes = (kernel.stream_lane_row_bytes(bp.static)
+                      if hasattr(kernel, "stream_lane_row_bytes") else 0)
+    bplan = plan_blocks(n, row_bytes, work_row_bytes=lanes * lane_row_bytes)
     base_key = (
         _sc.dataset_fingerprint(data), _sc.host_signature(), "block",
         kernel.name, kernel.trace_salt(), tuple(form_salt), bplan.rows,
@@ -2149,22 +2159,25 @@ def _run_streamed(run: _Run, kernel, bp: BucketPlan, X_np, data, hypers,
     for start in range(0, len(idxs), chunk):
         batch_idx = idxs[start : start + chunk]
         hyper_batch = _hyper_batch(hypers, batch_idx, bp.hyper_names, chunk)
-        t0 = time.perf_counter()
-        wait0 = streamer.stats["wait_s"]
-        blocks0 = streamer.stats["blocks"]
+        before = dict(streamer.stats)
         with _dispatch_span(None, "streamed", start // chunk, chunk,
-                            len(batch_idx)):
-            score = np.asarray(
-                kernel.stream_scores(
-                    streamer, y_d, TW_d, EW_d, hyper_batch, bp.static, n
-                )
+                            len(batch_idx), block_rows=bplan.rows,
+                            n_blocks=bplan.n_blocks,
+                            split_lanes=int(split_plan.n_splits)):
+            out = kernel.stream_scores(
+                streamer, y_d, TW_d, EW_d, hyper_batch, bp.static, n
             )
-        wall = time.perf_counter() - t0
-        wait = streamer.stats["wait_s"] - wait0
-        _PHASE.stage += wait
-        run.run_time += max(wall - wait, 0.0)
-        run.dispatches += streamer.stats["blocks"] - blocks0
-        run.record({"score": score}, batch_idx)
+        with child_span("executor.fetch") as sp:
+            out = jax.device_get(streamer.wait(
+                out if isinstance(out, dict) else {"score": out}))
+            sp.attrs.update(n_devices=1, bytes=sum(
+                int(v.nbytes) for v in out.values()))
+        _PHASE.stage += streamer.stats["wait_s"] - before["wait_s"]
+        run.run_time += (streamer.stats["device_wait_s"]
+                         - before["device_wait_s"])
+        run.dispatches += streamer.stats["blocks"] - before["blocks"]
+        run.record({k: np.asarray(v, np.float32) for k, v in out.items()},
+                   batch_idx)
 
 
 def _postprocess(out: Dict[str, np.ndarray], j: int, plan: SplitPlan, task: str,

@@ -232,10 +232,13 @@ def test_logreg_packed_precomputes_staged_once(monkeypatch, n_trials, staged):
     params = [{"C": c, "max_iter": 15} for c in np.geomspace(0.1, 1.0, n_trials)]
 
     def extra_uploads():
+        # this dataset's entries only: a job another test left running in
+        # the process (a prewarm, an agent) may stage its own extras here
+        fp = sc.dataset_fingerprint(data)
         return {
             k: v
             for k, v in sc.STAGE_CACHE.uploads_by_key().items()
-            if "batched_extra" in str(k)
+            if "batched_extra" in str(k) and k[0] == fp
         }
 
     first = tm.run_trials(kernel, data, plan, params)

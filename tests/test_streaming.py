@@ -482,3 +482,148 @@ def test_csv_chunked_ingest_round_trip(tmp_path):
     assert np.allclose(
         src.fetch(tail)[: plan.size(tail)], ref[plan.start(tail):], atol=1e-6
     )
+
+
+# ---------------- the block height counts the lanes (PR 40) ----------------
+
+#: the cell logreg_mnist8m.rs32: 1.6M rows of 784 float32 pixels, 10
+#: classes, 32 trials x 6 splits, on a v5e (bytes_limit about 16.9 GB, the
+#: stage budget 0.4 of it)
+_CELL_N, _CELL_ROW_BYTES, _CELL_LANES, _V5E_BUDGET_MB = 1_600_000, 784 * 4, 32 * 6, 0.4 * 16.9e3
+
+
+def _lane_row_bytes(n_classes=10):
+    kern = get_kernel("LogisticRegression")
+    return kern.stream_lane_row_bytes({"_n_classes": n_classes})
+
+
+def test_block_plan_counts_the_lanes_intermediates(monkeypatch):
+    """At the cell's shape the block's own eighth of the budget binds and
+    the planned intermediates of its 192 lanes fit the work budget; past it
+    the lanes decide, and a block shrinks as they grow."""
+    monkeypatch.delenv("CS230_STREAM_BLOCK_ROWS", raising=False)
+    monkeypatch.setenv("CS230_STAGE_CACHE_MB", str(_V5E_BUDGET_MB))
+    lane = _lane_row_bytes()
+    assert lane == 10 * 6 + 12
+    plan = st.plan_blocks(_CELL_N, _CELL_ROW_BYTES, work_row_bytes=_CELL_LANES * lane)
+    assert plan.rows == int(_V5E_BUDGET_MB * 1e6 // 8 // _CELL_ROW_BYTES) and plan.n_blocks == 6
+    assert plan.rows * _CELL_LANES * lane <= st.work_budget_bytes()
+    assert plan.n_blocks * plan.rows * _CELL_ROW_BYTES <= sc.budget_bytes()  # the cache holds it
+    rows = [st.plan_blocks(_CELL_N, _CELL_ROW_BYTES, work_row_bytes=lanes * lane).rows
+            for lanes in (192, 384, 768, 1536)]
+    assert rows[0] > rows[1] > rows[2] > rows[3]
+    for lanes, r in zip((384, 768, 1536), rows[1:]):
+        assert r == st.work_budget_bytes() // (lanes * lane)
+    # without lanes (a kernel that names none) the height is the block's alone
+    assert st.plan_blocks(_CELL_N, _CELL_ROW_BYTES).rows == rows[0]
+
+
+def test_streamed_engine_sizes_its_blocks_by_the_chunks_lanes(monkeypatch):
+    """``_run_streamed`` hands the kernel's lane bytes x the chunk's trials
+    x splits to the plan: a tight stage budget makes the lanes bind, and
+    the dispatch span says the height it chose."""
+    from cs230_distributed_machine_learning_tpu.obs import TRACER
+    from cs230_distributed_machine_learning_tpu.obs.tracing import span
+
+    monkeypatch.setenv("CS230_STREAM", "force")
+    monkeypatch.delenv("CS230_STREAM_BLOCK_ROWS", raising=False)
+    monkeypatch.setenv("CS230_STAGE_CACHE_MB", "8")
+    data = _logreg_data(n=4000, d=128, c=10)
+    plan = build_split_plan(np.asarray(data.y), task="classification", n_folds=2)
+    kern = get_kernel("LogisticRegression")
+    params = [{"C": 10.0 ** -k, "max_iter": 2} for k in range(16)]
+    with span("test.stream") as root:
+        run_trials(kern, data, plan, params)
+    said = [s["attrs"] for s in TRACER.spans_for(root.trace_id) if s["name"] == "executor.dispatch"]
+    lanes = 16 * plan.n_splits
+    want = st.plan_blocks(4000, 128 * 4, work_row_bytes=lanes * _lane_row_bytes())
+    assert want.rows < st.plan_blocks(4000, 128 * 4).rows  # the lanes bind here
+    assert said[0]["block_rows"] == want.rows and said[0]["n_blocks"] == want.n_blocks == 3
+
+
+def test_streamed_passes_are_spans_and_the_curve_comes_back(monkeypatch):
+    """One ``stream.pass`` a pass (31 power, a step each, one eval) under the
+    chunk's ``executor.dispatch`` (engine=streamed with the block plan), a
+    device wait a step, each block program's compile at its first call, the
+    fetch after; the cache-hit
+    counter; ``run_time`` the device waits alone; and the learning curve at
+    the packed path's slots, its steps those the solver ran."""
+    from cs230_distributed_machine_learning_tpu.obs import TRACER
+    from cs230_distributed_machine_learning_tpu.obs.tracing import span
+
+    from cs230_distributed_machine_learning_tpu.models import logistic
+
+    monkeypatch.setattr(logistic, "_STREAM_FN_CACHE", {})  # the programs built afresh
+    monkeypatch.setenv("CS230_STREAM", "force")
+    monkeypatch.setenv("CS230_STREAM_BLOCK_ROWS", "512")
+    data = _logreg_data()
+    plan = build_split_plan(np.asarray(data.y), task="classification", n_folds=2)
+    kern = get_kernel("LogisticRegression")
+    params = [{"C": 1.0, "max_iter": 20}, {"C": 0.1, "max_iter": 12}]
+    hits0 = REGISTRY.counter("tpuml_stream_cache_hits_total").value()
+    with span("test.stream") as root:
+        out = run_trials(kern, data, plan, params)
+    spans = TRACER.spans_for(root.trace_id)
+    (disp,) = [s for s in spans if s["name"] == "executor.dispatch"]
+    assert disp["attrs"]["engine"] == "streamed"
+    assert {k: disp["attrs"][k] for k in ("block_rows", "n_blocks", "split_lanes", "n_trials")} == {
+        "block_rows": 512, "n_blocks": 3, "split_lanes": 3, "n_trials": 2}
+    kids = [s for s in spans if s["parent_id"] == disp["span_id"]]
+    passes = [s for s in kids if s["name"] == "stream.pass"]
+    kinds = [p["attrs"]["kind"] for p in passes]
+    assert kinds == ["power"] * 31 + ["step"] * 20 + ["eval"]
+    assert all(p["attrs"]["blocks"] == 3 for p in passes)
+    # the first pass uploads the three blocks, every later one hits them
+    assert passes[0]["attrs"]["uploaded_bytes"] == 3 * 512 * 128 * 4
+    assert passes[0]["attrs"]["cache_hits"] == 0
+    assert all(p["attrs"]["cache_hits"] == 3 and p["attrs"]["uploaded_bytes"] == 0 for p in passes[1:])
+    assert REGISTRY.counter("tpuml_stream_cache_hits_total").value() - hits0 == 3 * (len(passes) - 1)
+    assert all(p["attrs"]["wait_s"] >= 0 and p["attrs"]["dispatch_s"] > 0 for p in passes)
+    waits = [s for s in kids if s["name"] == "executor.wait"]
+    assert len(waits) == 20 and {w["attrs"]["on"] for w in waits} == {"result"}
+    (fetch,) = [s for s in spans if s["name"] == "executor.fetch"]  # after the dispatch
+    assert fetch["start"] >= disp["end"]
+    assert fetch["attrs"]["bytes"] > 0
+    # each of the six block programs compiled once, at its first call, inside the dispatch
+    compiles = [s for s in spans if s["name"] == "executor.compile"]
+    assert len(compiles) == 6 and all(
+        s["attrs"] == {"engine": "streamed", "cache": "traced"} and disp["start"] <= s["start"]
+        and s["end"] <= disp["end"] for s in compiles)
+    assert all(any(b["parent_id"] == s["span_id"] and b["attrs"]["stage"] == "compile"
+                   for b in spans if b["name"] == "executor.build") for s in compiles)
+    fetch_wait = [s for s in spans if s["parent_id"] == fetch["span_id"] and s["name"] == "executor.wait"]
+    device_waits = sum(s["end"] - s["start"] for s in waits + fetch_wait)
+    # the waits inside their spans: never more than the spans, less only by
+    # the spans' own bookkeeping
+    assert 0.8 * device_waits - 0.01 <= out.run_time_s <= device_waits
+    # the curve: stride 1 at 20 steps, a slot a step, the trial's own rows
+    for m, p in zip(out.trial_metrics, params):
+        curve = m["curve"]
+        assert curve["stride"] == 1 and curve["steps"] == 20
+        assert len(curve["gmax"]) == plan.n_splits
+        assert all(len(row) == 20 and all(v is not None and v > 0 for v in row) for row in curve["gmax"])
+        assert curve["tail"][1:] == m["cv_scores"]
+
+
+def test_streamed_curve_stops_where_the_solver_stopped(monkeypatch):
+    """Every lane idle before the step cap: the solver leaves its loop and
+    the curve keeps only the slots it reached."""
+    monkeypatch.setenv("CS230_STREAM", "force")
+    monkeypatch.setenv("CS230_STREAM_BLOCK_ROWS", "512")
+    data = _logreg_data()
+    plan = build_split_plan(np.asarray(data.y), task="classification", n_folds=2)
+    kern = get_kernel("LogisticRegression")
+    # a huge tol: every lane is done after its first step
+    out = run_trials(kern, data, plan, [{"C": 1.0, "max_iter": 20, "tol": 1e9}])
+    curve = out.trial_metrics[0]["curve"]
+    assert curve["steps"] == 1 and all(len(row) == 1 for row in curve["gmax"])
+
+
+def test_streamed_curves_off_is_scores_alone(monkeypatch):
+    monkeypatch.setenv("CS230_STREAM", "force")
+    monkeypatch.setenv("CS230_STREAM_BLOCK_ROWS", "512")
+    monkeypatch.setenv("CS230_CURVES", "0")
+    data = _logreg_data()
+    plan = build_split_plan(np.asarray(data.y), task="classification", n_folds=2)
+    out = run_trials(get_kernel("LogisticRegression"), data, plan, [{"C": 1.0, "max_iter": 5}])
+    assert "curve" not in out.trial_metrics[0] and len(out.trial_metrics[0]["cv_scores"]) == 2

@@ -692,3 +692,40 @@ def test_boost_chunked_step_compiles_at_the_cell_shape(tpu_backend, chunk):
         mp.setattr("cs230_distributed_machine_learning_tpu.utils.backend.on_tpu", lambda: True)
         estimate = kernel.memory_estimate_mb(_N_HIGGS, _D_HIGGS, static) * 1e6
     assert estimate / 1.5 < temp / lanes < estimate * 1.5, (temp / lanes, estimate)
+
+
+#: the streamed cell logreg_mnist8m.rs32 (PR 40): 1.6M rows of 784 pixels,
+#: 10 classes, 32 trials x 6 splits, a v5e stage budget of 0.4 x 16.9 GB
+_N_MNIST8M, _D_MNIST8M, _C_MNIST8M, _T_MNIST8M = 1_600_000, 784, 10, 32
+
+
+def test_streamed_grad_block_fits_beside_the_cached_blocks(tpu_backend, monkeypatch):
+    """The streamed LogReg gradient at the cell's block and lanes. Pinned:
+    the block plan (lanes counted) gives six blocks; the compiled program
+    keeps every [T, S, c, rows] intermediate rows-minor, so no class axis of
+    10 is padded to 128 lanes (58 bytes a row a lane, against the plan's
+    72), and its scratch and arguments beside the six cached blocks are
+    under the chip."""
+    from cs230_distributed_machine_learning_tpu.data import streaming
+    from cs230_distributed_machine_learning_tpu.models import logistic
+
+    monkeypatch.delenv("CS230_STREAM_BLOCK_ROWS", raising=False)
+    monkeypatch.setenv("CS230_STAGE_CACHE_MB", str(0.4 * 16.9e3))
+    kernel = get_kernel("LogisticRegression")
+    lane = kernel.stream_lane_row_bytes({"_n_classes": _C_MNIST8M})
+    lanes = _T_MNIST8M * S
+    plan = streaming.plan_blocks(_N_MNIST8M, _D_MNIST8M * 4, work_row_bytes=lanes * lane)
+    assert plan.n_blocks == 6
+    rows, dp = plan.rows, _D_MNIST8M + 1
+    grad_block = logistic._stream_fns(rows, _D_MNIST8M, _C_MNIST8M, S, _T_MNIST8M, True, 1.0)[3].fn
+    w4 = _sds((_T_MNIST8M, S, dp, _C_MNIST8M), jnp.float32)
+    compiled = _lower_and_compile(
+        grad_block, _sds((rows, _D_MNIST8M), jnp.float32), w4, w4, _sds((plan.n_pad,), jnp.int32),
+        _sds((S, plan.n_pad), jnp.float32), _sds((), jnp.int32))
+    if compiled is None:
+        return  # lowered only: no deviceless topology here
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes <= rows * lanes * lane  # the plan's count is an upper bound
+    assert mem.temp_size_in_bytes / (rows * lanes * _C_MNIST8M) < 6.5  # no 128-lane padding of c
+    cached = plan.n_pad * _D_MNIST8M * 4
+    assert cached + mem.temp_size_in_bytes + mem.argument_size_in_bytes < 15.5e9
