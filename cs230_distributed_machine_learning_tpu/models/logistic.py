@@ -193,6 +193,15 @@ class LogisticRegressionKernel(ModelKernel):
         c = max(int(static["_n_classes"]), 2)
         return c * (4 + 2) + 3 * 4
 
+    def stream_dispatch_attrs(self, static, d: int, trials: int,
+                              n_splits: int) -> Dict[str, Any]:
+        """Engine hook: what the streamed ``executor.dispatch`` span says of
+        a chunk of ``trials`` trials over ``d`` features: ``grad_kernel``,
+        the form that computes the step pass's block gradient
+        (:func:`stream_grad_kernel`)."""
+        c = max(int(static["_n_classes"]), 2)
+        return {"grad_kernel": stream_grad_kernel(d, c, n_splits, trials)}
+
     def stream_scores(self, streamer, y_pad, TW, EW, hyper_batch, static, n):
         """Block-accumulated Nesterov over a RowBlockStreamer: one pass
         per solver iteration (plus 31 Lipschitz passes and one eval
@@ -943,13 +952,51 @@ class _BuiltAtFirstCall:
         return exe(*args)
 
 
+def _grad_block_xla(A, G, V, yb, wb, c):
+    """``grad_block``'s XLA form, and the reference of the Pallas one: the
+    fused masked-gradient formulation of _make_masked_grad_fn restricted to
+    one block ``A`` (the design rows, intercept column included), bf16
+    matmul inputs, f32 accumulation. Pad rows have wb == 0, so both their
+    softmax term and their label term vanish exactly."""
+    Z = jnp.einsum(
+        "rd,tsdc->tsrc",
+        A.astype(jnp.bfloat16), V.astype(jnp.bfloat16),
+        preferred_element_type=jnp.float32,
+    )
+    e = jnp.exp(Z - jnp.max(Z, axis=-1, keepdims=True))
+    scale = wb[None] / jnp.sum(e, axis=-1)            # [T, S, rows]
+    Yb = jax.nn.one_hot(yb, c, dtype=jnp.float32)
+    WY = wb[:, :, None] * Yb[None]                    # [S, rows, c]
+    R = e * scale[..., None] - WY[None]
+    return G + jnp.einsum(
+        "rd,tsrc->tsdc",
+        A.astype(jnp.bfloat16), R.astype(jnp.bfloat16),
+        preferred_element_type=jnp.float32,
+    )
+
+
+def stream_grad_kernel(d, c, S, T) -> str:
+    """Which form computes the streamed solver's block gradient at a
+    geometry: ``pallas`` (``ops/pallas_logreg.py::streamed_softmax_grad``)
+    where the Pallas path runs (a TPU, or the interpreter) and the whole
+    column block fits the kernel's VMEM, else ``xla`` (``_grad_block_xla``).
+    The streamed ``executor.dispatch`` span says which ran."""
+    from ..ops.pallas_logreg import stream_grad_applicable
+
+    if not (_backend.pallas_interpret() or _backend.auto_pallas()):
+        return "xla"
+    return "pallas" if stream_grad_applicable(d, c, S, T) else "xla"
+
+
 def _stream_fns(rows, d, c, S, T, fit_intercept, lam):
     """Per-block / per-iteration pieces of the streamed Nesterov solver,
     ``(power_block, power_norm, extrapolate, grad_block, update,
     eval_block)``, each built at its first call (:class:`_BuiltAtFirstCall`)
-    and cached on geometry: the engine re-enters stream_scores once per
-    trial chunk and every repeat chunk re-dispatches these."""
-    key = (rows, d, c, S, T, bool(fit_intercept), float(lam))
+    and cached on geometry and on the gradient's form
+    (:func:`stream_grad_kernel`): the engine re-enters stream_scores once
+    per trial chunk and every repeat chunk re-dispatches these."""
+    grad_kernel = stream_grad_kernel(d, c, S, T)
+    key = (rows, d, c, S, T, bool(fit_intercept), float(lam), grad_kernel)
     fns = _STREAM_FN_CACHE.get(key)
     if fns is not None:
         return fns
@@ -984,28 +1031,16 @@ def _stream_fns(rows, d, c, S, T, fit_intercept, lam):
 
     @jax.jit
     def grad_block(blk, G, V, y_pad, TW, start):
-        # the fused masked-gradient formulation of _make_masked_grad_fn,
-        # restricted to one block: bf16 matmul inputs, f32 accumulation.
-        # Pad rows have wb == 0, so both their softmax term and their
-        # label term vanish exactly.
-        A = design(blk)
         yb = jax.lax.dynamic_slice(y_pad, (start,), (rows,))
         wb = jax.lax.dynamic_slice(TW, (0, start), (S, rows))
-        Z = jnp.einsum(
-            "rd,tsdc->tsrc",
-            A.astype(jnp.bfloat16), V.astype(jnp.bfloat16),
-            preferred_element_type=jnp.float32,
-        )
-        e = jnp.exp(Z - jnp.max(Z, axis=-1, keepdims=True))
-        scale = wb[None] / jnp.sum(e, axis=-1)            # [T, S, rows]
-        Yb = jax.nn.one_hot(yb, c, dtype=jnp.float32)
-        WY = wb[:, :, None] * Yb[None]                    # [S, rows, c]
-        R = e * scale[..., None] - WY[None]
-        return G + jnp.einsum(
-            "rd,tsrc->tsdc",
-            A.astype(jnp.bfloat16), R.astype(jnp.bfloat16),
-            preferred_element_type=jnp.float32,
-        )
+        if grad_kernel == "pallas":
+            from ..ops.pallas_logreg import streamed_softmax_grad
+
+            return G + streamed_softmax_grad(
+                blk, V, yb, wb, c=c, fit_intercept=bool(fit_intercept),
+                interpret=_backend.pallas_interpret(),
+            )
+        return _grad_block_xla(design(blk), G, V, yb, wb, c)
 
     @jax.jit
     def update(W, Wp, V, G_raw, t, done, lam_max, C, max_iter, tol, tr, slot):

@@ -44,6 +44,12 @@ nothing, which is what it added before: the outputs are bit-equal with and
 without the table. At the narrower widths (16, 32, 64: a split is part of
 a vreg) the table is ignored and the whole-slab body runs.
 
+The streamed solver's block gradient (``streamed_softmax_grad``, the end
+of this file) is the same arithmetic on another grid: one cached float32
+row block a call, read as the device holds it, with the class groups on
+the sublane axis of a transposed logits tile. It shares only the per-tile
+softmax (``_class_residuals``) with the packed kernels.
+
 Replaces (in effect) the per-trial sklearn fit of the reference worker
 (``aws-prod/worker/worker.py:289-349``) for the LogisticRegression family;
 see models/logistic.py for the solver that drives it.
@@ -92,34 +98,50 @@ def _sample_weight_slab(wsp_ref, bm: int, S: int, Tw: int):
     return wexp
 
 
-def _softmax_gram(a, logits, L: int, c: int, yv, wexp, add):
-    """The softmax arithmetic both gradient bodies share, so that they
-    cannot drift: grouped softmax over ``c`` class tiles -> weighted
-    residual -> one Gram dot a class, handed to ``add``.
+def _class_residuals(z, c: int, onehot, wexp):
+    """The per-tile softmax arithmetic every LogReg gradient kernel shares,
+    so that they cannot drift: grouped softmax over ``c`` class tiles ->
+    the weighted residual ``(p - y) * w`` of each class, in bfloat16 (the
+    Gram dot's operand). A generator: class ``a_i``'s residual is formed
+    when the caller asks for it, so a caller that dots each one before
+    asking for the next holds one residual tile at a time.
 
-    a       [bm, dpp]   bf16  design-matrix row tile
-    logits  [bm, c*L]   f32   class-major: class ``a_i`` is the tile
-                              ``[:, a_i*L : (a_i+1)*L]`` (elementwise:
-                              classes are separate tiles, no cross-lane
-                              reductions)
-    yv      [bm, 1]     i32   labels for the tile rows
-    wexp    [bm, L]     f32   sample weight of every column
-    add     (class, [dpp, L] f32) -> None: accumulates the class's Gram tile
+    z       class -> f32 tile of that class's logits (elementwise across
+            classes: each class is its own tile, no cross-lane reductions)
+    onehot  class -> f32 ``y == class``, broadcastable against a tile
+    wexp    f32 sample weight of every element of a tile
+    yields  ``(class, residual tile)``
     """
-    m = logits[:, 0:L]
+    m = z(0)
     for a_i in range(1, c):
-        m = jnp.maximum(m, logits[:, a_i * L : (a_i + 1) * L])
-    es = [jnp.exp(logits[:, a_i * L : (a_i + 1) * L] - m) for a_i in range(c)]
+        m = jnp.maximum(m, z(a_i))
+    es = [jnp.exp(z(a_i) - m) for a_i in range(c)]
     den = es[0]
     for a_i in range(1, c):
         den = den + es[a_i]
     rden = 1.0 / den
+    for a_i, e in enumerate(es):
+        y = onehot(a_i)
+        yield a_i, ((e * rden - y) * wexp).astype(jnp.bfloat16)
 
+
+def _softmax_gram(a, logits, L: int, c: int, yv, wexp, add):
+    """The packed gradient bodies' tile: ``_class_residuals`` over the
+    ``c`` class slabs -> one Gram dot a class, handed to ``add``.
+
+    a       [bm, dpp]   bf16  design-matrix row tile
+    logits  [bm, c*L]   f32   class-major: class ``a_i`` is the tile
+                              ``[:, a_i*L : (a_i+1)*L]``
+    yv      [bm, 1]     i32   labels for the tile rows
+    wexp    [bm, L]     f32   sample weight of every column
+    add     (class, [dpp, L] f32) -> None: accumulates the class's Gram tile
+    """
     # per class: residual tile and its gradient contribution (c small dots
     # instead of one concat keeps everything statically sliced)
-    for a_i, e in enumerate(es):
-        onehot = (yv == a_i).astype(jnp.float32)  # [bm, 1] broadcasts
-        r = ((e * rden - onehot) * wexp).astype(jnp.bfloat16)  # [bm, L]
+    for a_i, r in _class_residuals(
+        lambda k: logits[:, k * L : (k + 1) * L], c,
+        lambda k: (yv == k).astype(jnp.float32), wexp,  # [bm, 1] broadcasts
+    ):
         add(a_i, jax.lax.dot_general(
             a,
             r,
@@ -694,3 +716,182 @@ def packed_softmax_grad_reference(Ab, W3, y2, WSP, *, c: int, S: int, Tw: int = 
         return jnp.einsum("nd,ncb->dcb", A, R).reshape(dpp, NB)
 
     return jax.vmap(one_block)(W3)
+
+
+# ---------------- the streamed solver's block gradient ----------------
+#
+# The streamed Nesterov solver (models/logistic.py::_stream_fns) makes one
+# pass over the cached float32 row blocks a step, and a block's gradient is
+# one call of the kernel below: the logits of every (trial, split, class)
+# column, the grouped softmax, the weighted residual and A'R, row tile by
+# row tile, with the logits never leaving VMEM. It reads the block as the
+# stage cache holds it ([rows, d] float32, cast to bf16 a tile) and adds
+# the intercept itself, so no XLA op copies or casts the block.
+#
+# Layout: transposed, so that the class groups lie on the sublane axis.
+# The logits tile is Z' = V' A_tile' ([c*Lp, bm]); class ``a``'s group is
+# rows ``[a*Lp, (a+1)*Lp)``, inside it row ``s*T + t`` is (trial t, split
+# s), and ``Lp`` is S*T rounded up to a bf16 sublane tile (16): no column
+# is padded to a 128-lane slab, and the [S, bm] tile of the rows-minor fold
+# weights and the [1, bm] labels broadcast down the rows untransposed.
+# The gradient is G' += R' A_tile ([c*Lp, d]).
+
+#: scoped-VMEM limit the streamed block gradient asks of the compiler
+#: (v5e: 128 MiB physical, 16 MiB default): the row tiles' buffers, the
+#: residual scratch and the tile's temporaries; XLA places V' and G' in
+#: VMEM beside them itself
+_STREAM_GRAD_VMEM_LIMIT = 64 * 1024 * 1024
+#: what ``stream_grad_applicable`` admits by ``stream_grad_vmem_bytes``:
+#: three quarters of the v5e's VMEM. The deviceless v5e compile at the
+#: streamed cell's block (269 451 x 784, 10 classes, six splits, a row tile
+#: of 512) held up to 128 trials (an estimate of 123 MB) and ran out of
+#: VMEM at 192 (183 MB)
+_STREAM_GRAD_VMEM_BYTES = 96 * 1024 * 1024
+#: the streamed kernel's row tile: one block-step of logreg_mnist8m.rs32
+#: took 11.81 ms at 256, 10.38 at 512 and 10.34 at 1024 on a v5e (XLA's
+#: form 19.29)
+STREAM_GRAD_ROWS = 512
+
+
+def stream_class_rows(S: int, T: int) -> int:
+    """Rows of one class group of the streamed kernel's transposed logits
+    (``Lp``): the ``S * T`` (split, trial) lanes rounded up to 16, a bf16
+    sublane tile, so that every group starts on a tile boundary."""
+    return -(-S * T // 16) * 16
+
+
+def stream_grad_vmem_bytes(d: int, c: int, S: int, T: int,
+                           bm: int = STREAM_GRAD_ROWS) -> int:
+    """VMEM the streamed block gradient holds at a geometry: V' (bf16) and
+    the G' accumulator (f32), lane-padded, the bias column and the
+    intercept's partial sums, the float32 row tile twice (the pipeline's
+    two buffers), and a tile's residual scratch beside four float32
+    [c*Lp, bm] temporaries (logits, exponentials, their sum and scale)."""
+    N = c * stream_class_rows(S, T)
+    dl, d8 = -(-d // 128) * 128, -(-d // 8) * 8
+    return (N * dl * (2 + 4) + 2 * N * 128 * 4 + 2 * d8 * bm * 4
+            + N * bm * (2 + 4 * 4))
+
+
+def stream_grad_applicable(d: int, c: int, S: int, T: int,
+                           bm: int = STREAM_GRAD_ROWS) -> bool:
+    """Whether a geometry's whole column block fits the kernel's VMEM: V'
+    and G' are resident for the whole block, so more lanes or a wider table
+    take the XLA form instead."""
+    return stream_grad_vmem_bytes(d, c, S, T, bm) <= _STREAM_GRAD_VMEM_BYTES
+
+
+def _stream_grad_kernel(at_ref, vt_ref, b_ref, y_ref, tw_ref, g_ref, gb_ref, r_scr,
+                        *, rows: int, c: int, S: int, T: int, intercept: bool):
+    """One row tile of a block's gradient, accumulated over the grid.
+
+    at_ref  [d, bm]     f32   the block's row tile, transposed (the cached
+                              block's own layout on the device); the ragged
+                              last tile's columns past ``rows`` are garbage
+    vt_ref  [N, d]      bf16  V', N = c*Lp, row a*Lp + s*T + t
+    b_ref   [N, 1]      f32   the intercept row of V (bf16-rounded)
+    y_ref   [1, bm]     i32   labels of the tile's rows
+    tw_ref  [S, bm]     f32   fold weights of the tile's rows
+    g_ref   [N, d]      f32   OUT G' = R' A, revisited across row tiles
+    gb_ref  [N, 128]    f32   OUT the intercept row of G, as 128 partial
+                              sums a column (``intercept`` only)
+    r_scr   [N, bm]     bf16  the tile's residual R'
+
+    Rows past ``rows`` are zeroed in A *and* in the weights (garbage times
+    zero can be NaN); their labels are left as they are, since a weight of
+    zero takes the label term out. Lanes ``[S*T, Lp)`` of a group have zero
+    columns in V' and weight 0, so their residual is exactly 0.
+    """
+    i = pl.program_id(0)
+    bm = at_ref.shape[1]
+    Lp = vt_ref.shape[0] // c
+
+    @pl.when(i == 0)
+    def _init():
+        g_ref[...] = jnp.zeros_like(g_ref)
+        gb_ref[...] = jnp.zeros_like(gb_ref)
+
+    keep = jax.lax.broadcasted_iota(jnp.int32, (1, bm), 1) < rows - i * bm
+    at = jnp.where(keep, at_ref[...], 0.0).astype(jnp.bfloat16)
+    tw = jnp.where(keep, tw_ref[...], 0.0)
+    y = y_ref[...]
+    zt = jnp.dot(vt_ref[...], at, preferred_element_type=jnp.float32)  # [N, bm]
+    if intercept:
+        zt = zt + b_ref[...]
+    # split s's weights on rows [s*T, (s+1)*T) of a group, 0 from S*T on
+    row = jax.lax.broadcasted_iota(jnp.int32, (Lp, bm), 0)
+    wexp = jnp.zeros((Lp, bm), jnp.float32)
+    for s in reversed(range(S)):
+        wexp = jnp.where(row < (s + 1) * T, tw[s : s + 1, :], wexp)
+    for a_i, r in _class_residuals(
+        lambda k: zt[k * Lp : (k + 1) * Lp], c,
+        lambda k: (y == k).astype(jnp.float32), wexp,
+    ):
+        r_scr[a_i * Lp : (a_i + 1) * Lp, :] = r
+        if intercept:
+            rf = r.astype(jnp.float32)
+            part = rf[:, 0:128]
+            for k in range(1, bm // 128):
+                part = part + rf[:, k * 128 : (k + 1) * 128]
+            gb_ref[a_i * Lp : (a_i + 1) * Lp, :] += part
+    g_ref[...] += jax.lax.dot_general(
+        r_scr[...], at, dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )  # [N, d]
+
+
+@functools.partial(
+    jax.jit, static_argnames=("c", "fit_intercept", "bm", "interpret")
+)
+def streamed_softmax_grad(blk, V, yb, wb, *, c: int, fit_intercept: bool,
+                          bm: int = STREAM_GRAD_ROWS, interpret: bool = False):
+    """One row block's gradient ``A' (w * (softmax(A V) - Y))`` for every
+    (trial, split) lane, ``A`` the block with the intercept column where
+    ``fit_intercept``: bf16 operands into float32 accumulators, float32
+    softmax and weights.
+
+    blk [rows, d]         f32  the cached block, passed to the kernel as it
+                               is, transposed: on a TPU the device keeps a
+                               [rows, d] array rows-minor, so ``blk.T`` is
+                               a bitcast (any height: the ragged last row
+                               tile is masked in the kernel, not padded here)
+    V   [T, S, dp, c]     f32  the look-ahead iterate, dp = d + intercept
+    yb  [rows]            i32  the block's labels
+    wb  [S, rows]         f32  the block's fold weights (0 on pad rows)
+    returns [T, S, dp, c] f32
+    """
+    rows, d = blk.shape
+    T, S, dp, _ = V.shape
+    L, Lp = S * T, stream_class_rows(S, T)
+    N = c * Lp
+    Vt = jnp.transpose(V, (3, 1, 0, 2)).reshape(c, L, dp)
+    Vt = jnp.pad(Vt, ((0, 0), (0, Lp - L), (0, 0))).reshape(N, dp).astype(jnp.bfloat16)
+    bias = (Vt[:, d:] if fit_intercept else jnp.zeros((N, 1))).astype(jnp.float32)
+    whole = lambda i: (0, 0)  # noqa: E731
+    lanes = lambda i: (0, i)  # noqa: E731
+    gt, gb = pl.pallas_call(
+        functools.partial(_stream_grad_kernel, rows=rows, c=c, S=S, T=T,
+                          intercept=bool(fit_intercept)),
+        grid=(pl.cdiv(rows, bm),),
+        in_specs=[
+            pl.BlockSpec((d, bm), lanes),
+            pl.BlockSpec((N, d), whole),
+            pl.BlockSpec((N, 1), whole),
+            pl.BlockSpec((1, bm), lanes),
+            pl.BlockSpec((S, bm), lanes),
+        ],
+        out_specs=[pl.BlockSpec((N, d), whole), pl.BlockSpec((N, 128), whole)],
+        out_shape=[
+            jax.ShapeDtypeStruct((N, d), jnp.float32),
+            jax.ShapeDtypeStruct((N, 128), jnp.float32),
+        ],
+        scratch_shapes=[pltpu.VMEM((N, bm), jnp.bfloat16)],
+        interpret=interpret,
+        name="streamed_softmax_grad",
+        **({} if interpret else {"compiler_params": pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_STREAM_GRAD_VMEM_LIMIT)}),
+    )(blk.T, Vt[:, :d], bias, yb.reshape(1, rows), wb)
+    cols = [gt] + ([jnp.sum(gb, axis=1, keepdims=True)] if fit_intercept else [])
+    G = jnp.concatenate(cols, axis=1).reshape(c, Lp, dp)[:, :L]
+    return jnp.transpose(G.reshape(c, S, T, dp), (2, 1, 3, 0))

@@ -2101,7 +2101,9 @@ def _run_streamed(run: _Run, kernel, bp: BucketPlan, X_np, data, hypers,
     (the hidden share is devprof's ``stream`` phase), and only the driver's
     waits for the device (``streamer.wait``: ``executor.wait on=result``
     spans) are ``run_time``. Each pass over the blocks is a ``stream.pass``
-    span under the chunk's ``executor.dispatch``.
+    span under the chunk's ``executor.dispatch``, which also says what the
+    kernel's ``stream_dispatch_attrs`` does (the LogReg fit's
+    ``grad_kernel``).
     """
     from ..data.streaming import (
         RowBlockStreamer, array_block_source, plan_blocks,
@@ -2156,6 +2158,9 @@ def _run_streamed(run: _Run, kernel, bp: BucketPlan, X_np, data, hypers,
         )
 
     chunk = bp.chunk
+    form_attrs = (kernel.stream_dispatch_attrs(
+        bp.static, int(blockable.shape[1]), chunk, int(split_plan.n_splits))
+        if hasattr(kernel, "stream_dispatch_attrs") else {})
     for start in range(0, len(idxs), chunk):
         batch_idx = idxs[start : start + chunk]
         hyper_batch = _hyper_batch(hypers, batch_idx, bp.hyper_names, chunk)
@@ -2163,7 +2168,8 @@ def _run_streamed(run: _Run, kernel, bp: BucketPlan, X_np, data, hypers,
         with _dispatch_span(None, "streamed", start // chunk, chunk,
                             len(batch_idx), block_rows=bplan.rows,
                             n_blocks=bplan.n_blocks,
-                            split_lanes=int(split_plan.n_splits)):
+                            split_lanes=int(split_plan.n_splits),
+                            **form_attrs):
             out = kernel.stream_scores(
                 streamer, y_d, TW_d, EW_d, hyper_batch, bp.static, n
             )

@@ -844,3 +844,72 @@ def test_packed_path_pads_partial_chunks(monkeypatch):
     assert len(out.trial_metrics) == 5
     for m in out.trial_metrics:
         assert 0.0 <= m["mean_cv_score"] <= 1.0
+
+
+# ---------------- the streamed solver's block gradient ----------------
+
+# (rows, d, c, S, T, fit_intercept, bm): blocks whose height is not a
+# multiple of the row tile (the interpreter fills the ragged tail's
+# out-of-range rows with NaN, as garbage the kernel must mask), c = 2 and
+# 10, T*S = 12, 9 and 25 (multiples of neither 8 nor 128)
+_STREAM_CASES = [
+    (300, 40, 10, 6, 2, True, 128),
+    (517, 33, 2, 3, 3, False, 256),
+    (1000, 64, 10, 5, 5, True, 512),
+    (256, 24, 3, 2, 8, True, 128),  # a whole number of tiles
+]
+
+
+@pytest.mark.parametrize("case", _STREAM_CASES, ids=[str(c) for c in _STREAM_CASES])
+def test_streamed_grad_kernel_matches_the_xla_block_gradient(case):
+    """``streamed_softmax_grad`` against ``grad_block``'s XLA form, added to
+    a non-zero G: the same bf16-operand arithmetic, so equal to within
+    bf16 rounding of the residual. The block's last rows are pad rows (zero
+    data, zero fold weight, as ``pad_rows`` makes them) and a share of the
+    others have weight 0 in a split; the result is NaN-free."""
+    from cs230_distributed_machine_learning_tpu.models.base import add_intercept
+    from cs230_distributed_machine_learning_tpu.models.logistic import _grad_block_xla
+    from cs230_distributed_machine_learning_tpu.ops.pallas_logreg import streamed_softmax_grad
+
+    rows, d, c, S, T, fit_intercept, bm = case
+    rng = np.random.default_rng(rows + d)
+    blk = rng.normal(size=(rows, d)).astype(np.float32)
+    blk[-7:] = 0.0
+    V = (0.3 * rng.normal(size=(T, S, d + fit_intercept, c))).astype(np.float32)
+    yb = rng.integers(0, c, rows).astype(np.int32)
+    wb = (rng.random((S, rows)) < 0.8).astype(np.float32)
+    wb[:, -7:] = 0.0
+    G0 = rng.normal(size=V.shape).astype(np.float32)
+    got = np.asarray(G0 + streamed_softmax_grad(
+        jnp.asarray(blk), jnp.asarray(V), jnp.asarray(yb), jnp.asarray(wb),
+        c=c, fit_intercept=fit_intercept, bm=bm, interpret=True))
+    ref = np.asarray(_grad_block_xla(
+        add_intercept(jnp.asarray(blk), fit_intercept), jnp.asarray(G0),
+        jnp.asarray(V), jnp.asarray(yb), jnp.asarray(wb), c))
+    assert np.isfinite(got).all()
+    assert np.abs(got - ref).max() <= 2e-3 * np.abs(ref - G0).max()
+
+
+def test_streamed_grad_form_follows_the_backend_and_the_vmem_gate(monkeypatch):
+    """``pallas`` where a TPU (or the interpreter) runs Pallas and the whole
+    column block fits the kernel's VMEM, ``xla`` elsewhere: the streamed
+    cell's 32 trials x 6 splits x 10 classes at 784 features fit, four
+    times the trials do not."""
+    import jax
+
+    from cs230_distributed_machine_learning_tpu.models import logistic
+    from cs230_distributed_machine_learning_tpu.ops.pallas_logreg import (
+        _STREAM_GRAD_VMEM_BYTES, stream_grad_vmem_bytes,
+    )
+
+    monkeypatch.delenv("CS230_PALLAS_INTERPRET", raising=False)
+    assert logistic.stream_grad_kernel(784, 10, 6, 32) == "xla"  # the CPU
+    monkeypatch.setenv("CS230_PALLAS_INTERPRET", "1")
+    assert logistic.stream_grad_kernel(784, 10, 6, 32) == "pallas"
+    monkeypatch.delenv("CS230_PALLAS_INTERPRET")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert logistic.stream_grad_kernel(784, 10, 6, 32) == "pallas"
+    assert stream_grad_vmem_bytes(784, 10, 6, 128) > _STREAM_GRAD_VMEM_BYTES
+    assert logistic.stream_grad_kernel(784, 10, 6, 128) == "xla"
+    kern = get_kernel("LogisticRegression")
+    assert kern.stream_dispatch_attrs({"_n_classes": 10}, 784, 32, 6) == {"grad_kernel": "pallas"}

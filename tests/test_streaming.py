@@ -98,11 +98,18 @@ def test_host_block_set_partitions_disjointly():
 # ---------------- engine parity: LogReg (float accumulation) ----------------
 
 
-def test_logreg_streamed_engine_parity(monkeypatch):
+@pytest.mark.parametrize("grad_kernel,block_rows", [("xla", 512), ("pallas", 700)])
+def test_logreg_streamed_engine_parity(monkeypatch, grad_kernel, block_rows):
     """CS230_STREAM=force matches the legacy single-shot engine path on an
     n that is NOT a multiple of the block height (pad rows carry zero
     weight). Float gradient block sums reorder f32 additions, so parity
-    is to tolerance — the integer-stat tree test below is the bitwise one."""
+    is to tolerance — the integer-stat tree test below is the bitwise one.
+    The step pass's block gradient in both forms: XLA's (the CPU) and the
+    Pallas kernel (in the interpreter), on blocks of 700 rows, so that each
+    ends in a ragged row tile of 188."""
+    from cs230_distributed_machine_learning_tpu.obs import TRACER
+    from cs230_distributed_machine_learning_tpu.obs.tracing import span
+
     data = _logreg_data()
     plan = build_split_plan(np.asarray(data.y), task="classification", n_folds=2)
     kern = get_kernel("LogisticRegression")
@@ -115,17 +122,22 @@ def test_logreg_streamed_engine_parity(monkeypatch):
     monkeypatch.setenv("CS230_STREAM", "0")
     legacy = run_trials(kern, data, plan, params)
     monkeypatch.setenv("CS230_STREAM", "force")
-    monkeypatch.setenv("CS230_STREAM_BLOCK_ROWS", "512")
-    streamed = run_trials(kern, data, plan, params)
+    monkeypatch.setenv("CS230_STREAM_BLOCK_ROWS", str(block_rows))
+    if grad_kernel == "pallas":
+        monkeypatch.setenv("CS230_PALLAS_INTERPRET", "1")
+    with span("test.stream") as root:
+        streamed = run_trials(kern, data, plan, params)
+    (disp,) = [s for s in TRACER.spans_for(root.trace_id) if s["name"] == "executor.dispatch"]
+    assert disp["attrs"]["grad_kernel"] == grad_kernel
 
-    assert 1500 % 512 != 0
+    assert 1500 % block_rows != 0
     for (a0, cv0), (a1, cv1) in zip(_scores(legacy), _scores(streamed)):
         assert abs(a0 - a1) < 2e-3
         assert np.allclose(cv0, cv1, atol=2e-3)
     # the streamed bucket dispatched per block, not once
     assert streamed.n_dispatches > legacy.n_dispatches
     block_keys = [k for k in sc.STAGE_CACHE.uploads_by_key() if "block" in k]
-    assert len(block_keys) == 3  # ceil(1500 / 512)
+    assert len(block_keys) == 3  # ceil(1500 / block_rows)
 
 
 def test_stream_off_is_legacy_bit_for_bit(monkeypatch):
@@ -566,8 +578,8 @@ def test_streamed_passes_are_spans_and_the_curve_comes_back(monkeypatch):
     spans = TRACER.spans_for(root.trace_id)
     (disp,) = [s for s in spans if s["name"] == "executor.dispatch"]
     assert disp["attrs"]["engine"] == "streamed"
-    assert {k: disp["attrs"][k] for k in ("block_rows", "n_blocks", "split_lanes", "n_trials")} == {
-        "block_rows": 512, "n_blocks": 3, "split_lanes": 3, "n_trials": 2}
+    assert {k: disp["attrs"][k] for k in ("block_rows", "n_blocks", "split_lanes", "n_trials", "grad_kernel")} == {
+        "block_rows": 512, "n_blocks": 3, "split_lanes": 3, "n_trials": 2, "grad_kernel": "xla"}
     kids = [s for s in spans if s["parent_id"] == disp["span_id"]]
     passes = [s for s in kids if s["name"] == "stream.pass"]
     kinds = [p["attrs"]["kind"] for p in passes]

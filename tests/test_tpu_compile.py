@@ -699,13 +699,10 @@ def test_boost_chunked_step_compiles_at_the_cell_shape(tpu_backend, chunk):
 _N_MNIST8M, _D_MNIST8M, _C_MNIST8M, _T_MNIST8M = 1_600_000, 784, 10, 32
 
 
-def test_streamed_grad_block_fits_beside_the_cached_blocks(tpu_backend, monkeypatch):
-    """The streamed LogReg gradient at the cell's block and lanes. Pinned:
-    the block plan (lanes counted) gives six blocks; the compiled program
-    keeps every [T, S, c, rows] intermediate rows-minor, so no class axis of
-    10 is padded to 128 lanes (58 bytes a row a lane, against the plan's
-    72), and its scratch and arguments beside the six cached blocks are
-    under the chip."""
+def _streamed_grad_block(monkeypatch):
+    """(the compiled step-pass block program at the cell's block and lanes,
+    or None where only lowering runs here; the block plan). The plan, lanes
+    counted, gives six blocks."""
     from cs230_distributed_machine_learning_tpu.data import streaming
     from cs230_distributed_machine_learning_tpu.models import logistic
 
@@ -713,8 +710,7 @@ def test_streamed_grad_block_fits_beside_the_cached_blocks(tpu_backend, monkeypa
     monkeypatch.setenv("CS230_STAGE_CACHE_MB", str(0.4 * 16.9e3))
     kernel = get_kernel("LogisticRegression")
     lane = kernel.stream_lane_row_bytes({"_n_classes": _C_MNIST8M})
-    lanes = _T_MNIST8M * S
-    plan = streaming.plan_blocks(_N_MNIST8M, _D_MNIST8M * 4, work_row_bytes=lanes * lane)
+    plan = streaming.plan_blocks(_N_MNIST8M, _D_MNIST8M * 4, work_row_bytes=_T_MNIST8M * S * lane)
     assert plan.n_blocks == 6
     rows, dp = plan.rows, _D_MNIST8M + 1
     grad_block = logistic._stream_fns(rows, _D_MNIST8M, _C_MNIST8M, S, _T_MNIST8M, True, 1.0)[3].fn
@@ -722,10 +718,62 @@ def test_streamed_grad_block_fits_beside_the_cached_blocks(tpu_backend, monkeypa
     compiled = _lower_and_compile(
         grad_block, _sds((rows, _D_MNIST8M), jnp.float32), w4, w4, _sds((plan.n_pad,), jnp.int32),
         _sds((S, plan.n_pad), jnp.float32), _sds((), jnp.int32))
+    return compiled, plan
+
+
+def test_streamed_grad_block_fits_beside_the_cached_blocks(tpu_backend, monkeypatch):
+    """The streamed LogReg gradient's XLA form (where the kernel's column
+    block does not fit its VMEM) at the cell's block and lanes. Pinned: the
+    compiled program keeps every [T, S, c, rows] intermediate rows-minor,
+    so no class axis of 10 is padded to 128 lanes (58 bytes a row a lane,
+    against the plan's 72), and its scratch and arguments beside the six
+    cached blocks are under the chip."""
+    from cs230_distributed_machine_learning_tpu.models import logistic
+
+    monkeypatch.setattr(logistic, "stream_grad_kernel", lambda *geometry: "xla")
+    compiled, plan = _streamed_grad_block(monkeypatch)
     if compiled is None:
         return  # lowered only: no deviceless topology here
+    rows, lanes = plan.rows, _T_MNIST8M * S
+    lane = get_kernel("LogisticRegression").stream_lane_row_bytes({"_n_classes": _C_MNIST8M})
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes <= rows * lanes * lane  # the plan's count is an upper bound
     assert mem.temp_size_in_bytes / (rows * lanes * _C_MNIST8M) < 6.5  # no 128-lane padding of c
     cached = plan.n_pad * _D_MNIST8M * 4
     assert cached + mem.temp_size_in_bytes + mem.argument_size_in_bytes < 15.5e9
+
+
+def test_streamed_grad_kernel_compiles_at_the_cell_shape(tpu_backend, monkeypatch):
+    """The form the streamed cell runs on a TPU: one Pallas kernel a block
+    (``streamed_softmax_grad``). Pinned: it compiles inside its scoped-VMEM
+    limit at the cell's block (269 451 x 784 float32, 32 trials x 6 splits x
+    10 classes); the custom call takes the cached block itself, so its HLO
+    text carries the block height the roofline's reader finds the solver's
+    ops by (``stream_roofline.block_pattern``), and no op copies or casts
+    the block (the device holds it rows-minor, and the kernel reads it
+    transposed: a bitcast); the scratch is a few MB, where XLA's form keeps
+    3.02 GB of logits."""
+    from cs230_distributed_machine_learning_tpu.models import logistic
+    from cs230_distributed_machine_learning_tpu.ops.pallas_logreg import (
+        _STREAM_GRAD_VMEM_BYTES, stream_grad_vmem_bytes,
+    )
+
+    assert logistic.stream_grad_kernel(_D_MNIST8M, _C_MNIST8M, S, _T_MNIST8M) == "pallas"
+    assert stream_grad_vmem_bytes(_D_MNIST8M, _C_MNIST8M, S, _T_MNIST8M) <= _STREAM_GRAD_VMEM_BYTES
+    compiled, plan = _streamed_grad_block(monkeypatch)
+    if compiled is None:
+        return  # lowered only: no deviceless topology here
+    rows = plan.rows
+    root = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "perfbench")
+    spec = importlib.util.spec_from_file_location(
+        "_perfbench_stream_roofline", os.path.join(root, "layer_metrics", "stream_roofline.py"))
+    reader = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reader)
+    text = [line.strip() for line in compiled.as_text().splitlines()
+            if re.match(r"\s*(?:ROOT )?%\S+ = ", line)]
+    (call,) = [t for t in text if "tpu_custom_call" in t]
+    assert call.startswith("%streamed_softmax_grad")
+    assert re.search(reader.block_pattern(rows), call)
+    assert not any(re.match(rf"(?:ROOT )?%\S+ = \w+\[(?:{rows},{_D_MNIST8M}|{_D_MNIST8M},{rows})\]\S* (?:copy|convert|fusion)", t)
+                   for t in text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 64e6
