@@ -22,9 +22,19 @@ Multiclass SVC follows sklearn's one-vs-one scheme: c(c-1)/2 binary
 machines fit with per-pair weight masks (more masked fits — free under
 vmap), votes aggregated with sklearn's tie-breaking (first max).
 
-Hypers: ``C`` (traced), ``epsilon`` for SVR (traced), ``gamma`` traced when
-numeric; "scale"/"auto" resolve per-fit from the masked data like sklearn.
-``kernel`` ("rbf" | "linear" | "poly") is static.
+Hypers: ``C`` (traced), ``epsilon`` for SVR (traced). ``gamma`` is static:
+a numeric value is part of the bucket's key, so every value a search draws
+is an executable of its own; "scale"/"auto" resolve per fit from the masked
+data like sklearn (one executable). ``kernel`` ("rbf" | "linear" | "poly")
+is static.
+
+What a fit ran is handed back with it: the exact dual's ``steps`` (the
+ascent's iterations, a KKT stop or the cap; the Nyström solve's fixed
+count) ride the scores as the ``ascent_steps`` leaf, and the engine's
+dispatch reads them (``SVCKernel.dispatch_result``) into the span and the
+``tpuml_svm_*`` counters. ``fit_curve`` also hands back the exact dual's
+learning curve: the dual objective's linear term, the sum of all
+machines' coefficients, after each step.
 
 Above ``_MAX_N`` samples the exact [n, n] Gram matrix is dropped for a
 **Nyström primal** solve (round-1 verdict #5 — the 30k gate previously
@@ -49,10 +59,19 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs import counter_inc
 from .base import ModelKernel
 
 _PG_STEPS = int(os.environ.get("CS230_SVM_PG_STEPS", "600"))
 _MAX_N = 30_000
+#: slots of the exact dual's learning curve over the step cap: the
+#: coefficients' sum moves smoothly, 24 slots of 25 steps follow it at 600
+_CURVE_SLOTS = 24
+
+
+def _pg_steps() -> int:
+    """Step cap of the exact dual's ascent (``CS230_SVM_PG_STEPS``)."""
+    return int(os.environ.get("CS230_SVM_PG_STEPS", _PG_STEPS))
 
 
 def _nystrom_steps() -> int:
@@ -246,34 +265,59 @@ def _project_box_hyperplane(a_raw, t, lo, hi, iters: int = 30):
 
 
 def _fista_ascent(qmatvec, project, lin, x0, eta, steps: int, tol: float,
-                  scale, diag):
+                  diag, stride=None):
     """Shared FISTA loop (single and multi-machine duals): maximize
     lin.x - 0.5 x'Qx - 0.5 diag||x||^2 over the projection set, with the
-    KKT displacement stop — a fixed point of project(x + eta*grad) IS a
-    KKT point, so the loop exits when the iterate stops moving (relative
-    to ``scale``, the box size)."""
+    KKT stop — a fixed point of project(x + eta*grad) IS a KKT point, so
+    the loop exits when a step's displacement over its step size,
+    max|x_new - x| / eta (the projected gradient's largest entry), falls
+    to ``tol``: a KKT violation in the dual gradient's own units, whose
+    linear term is 1, as libsvm's ``tol`` is. (A displacement held to the
+    box size instead stops at the first step wherever eta < tol * C: eta
+    is 1 / lambda_max, which falls as the rows grow, and at 24 000
+    training rows every C above about 0.5 stopped there with its
+    coefficients a step from zero.) Returns ``(x, steps ran)``, and with a
+    ``stride`` the learning curve as a third element: the objective's
+    linear term lin.x after each step, summed over every column, one slot
+    a ``stride`` steps (the slot's last step), the slots after the stop
+    holding the last value. It is read beside the step's displacement from
+    the same iterate: the whole objective at the extrapolated point,
+    0.5 * sum(y * (lin + g)), needs y and the gradient at once, which on a
+    v5e moved the projection's operands out of VMEM and cost 16% of a
+    search; this costs none that a search shows."""
     def cond(carry):
-        x, x_prev, tk, k, res = carry
-        live = res > tol * scale if tol > 0 else jnp.bool_(True)
+        x, x_prev, tk, k, res = carry[:5]
+        live = res > tol if tol > 0 else jnp.bool_(True)
         return (k < steps) & live
 
     def body(carry):
-        x, x_prev, tk, k, _ = carry
+        x, x_prev, tk, k, _ = carry[:5]
         t_next = 0.5 * (1.0 + jnp.sqrt(1.0 + 4.0 * tk * tk))
         y = x + ((tk - 1.0) / t_next) * (x - x_prev)
         g = lin - qmatvec(y) - diag * y
         x_new = project(y + eta * g)
-        res = jnp.max(jnp.abs(x_new - x))
-        return (x_new, x, t_next, k + 1, res)
+        res = jnp.max(jnp.abs(x_new - x) / eta)
+        out = (x_new, x, t_next, k + 1, res)
+        if stride is None:
+            return out
+        return out + (carry[5].at[k // stride].set(jnp.sum(lin * x_new)),)
 
     carry = (x0, x0, jnp.float32(1.0), jnp.int32(0), jnp.float32(jnp.inf))
-    return jax.lax.while_loop(cond, body, carry)[0]
+    if stride is None:
+        x, _, _, k, _ = jax.lax.while_loop(cond, body, carry)
+        return x, k
+    slots = -(-steps // stride)
+    x, _, _, k, _, curve = jax.lax.while_loop(
+        cond, body, carry + (jnp.zeros((slots,), jnp.float32),))
+    last = (k - 1) // stride
+    return x, k, jnp.where(jnp.arange(slots) > last, curve[last], curve)
 
 
 def _kkt_tol() -> float:
-    """Early-stop tolerance for the dual ascent: iterate-displacement
-    residual relative to the box scale (C). 0 disables the stop (fixed
-    step count — the pre-r5 behavior, and the A/B baseline)."""
+    """Early-stop tolerance for the dual ascent: the largest entry of the
+    projected gradient, a step's displacement over its step size, in the
+    dual gradient's units (libsvm's default ``tol``, 1e-3). 0 disables the
+    stop (fixed step count — the pre-r5 behavior, and the A/B baseline)."""
     return float(os.environ.get("CS230_SVM_KKT_TOL", "1e-3"))
 
 
@@ -291,15 +335,14 @@ def _constrained_dual_ascent(Q, lin, t, lo, hi, steps=None, diag=0.0):
     t-sequence extrapolation (accelerated projected gradient on the
     equivalent convex minimization) gets O(sqrt(kappa)) at identical
     per-step cost — the step is still one [n,n] matvec, the fit's
-    HBM-bound term. The while_loop stops once the projected-iterate
-    displacement falls below ``_kkt_tol() x box scale`` (a stationarity
-    certificate for the projection operator: a fixed point of
-    P_C(a + eta*grad) IS a KKT point), so easy (large-C-margin or
-    small-subset OvO) machines stop in tens of iterations instead of
-    burning the full budget. vmapped lanes run until the SLOWEST lane
-    converges — still bounded by ``steps``."""
+    HBM-bound term. The while_loop stops once the projected gradient's
+    largest entry (a step's displacement over its step size) falls to
+    ``_kkt_tol()`` (a stationarity certificate for the projection
+    operator: a fixed point of P_C(a + eta*grad) IS a KKT point), so easy
+    machines stop early instead of burning the full budget. vmapped lanes
+    run until the SLOWEST lane converges — still bounded by ``steps``."""
     if steps is None:
-        steps = int(os.environ.get("CS230_SVM_PG_STEPS", _PG_STEPS))
+        steps = _pg_steps()
 
     # the ascent is HBM-bound, not FLOP-bound: the [n, n] kernel operand
     # streams from memory on every step (~540 MB x 600 steps per OvO pair
@@ -318,13 +361,15 @@ def _constrained_dual_ascent(Q, lin, t, lo, hi, steps=None, diag=0.0):
         eta=_lipschitz_eta(Q),
         steps=steps,
         tol=_kkt_tol(),
-        scale=jnp.maximum(jnp.max(hi - lo), 1e-12),
         diag=diag,
-    )
+    )[0]
 
 
-def _constrained_dual_ascent_multi(Kb, lin, TS, hi, steps=None, diag=0.0):
-    """ALL OvO machines of one fit in ONE ascent: A [n, P] dual columns.
+def _constrained_dual_ascent_multi(Kb, lin, TS, hi, steps=None, diag=0.0,
+                                   stride=None):
+    """ALL OvO machines of one fit in ONE ascent: A [n, P] dual columns,
+    and the steps the ascent ran (and with a ``stride`` its curve:
+    :func:`_fista_ascent`).
 
     The per-pair form (vmap over ``_constrained_dual_ascent``) re-streams
     the SAME [n, n] kernel operand once per machine per iteration — at
@@ -337,7 +382,7 @@ def _constrained_dual_ascent_multi(Kb, lin, TS, hi, steps=None, diag=0.0):
     and the KKT displacement stop carry over; the loop exits when the
     SLOWEST machine converges."""
     if steps is None:
-        steps = int(os.environ.get("CS230_SVM_PG_STEPS", _PG_STEPS))
+        steps = _pg_steps()
     tol = _kkt_tol()
 
     def qmatvec(V):  # [n, P] -> [n, P], f32 accumulation
@@ -367,9 +412,17 @@ def _constrained_dual_ascent_multi(Kb, lin, TS, hi, steps=None, diag=0.0):
         eta=(1.0 / lam_max)[None, :],
         steps=steps,
         tol=tol,
-        scale=jnp.maximum(jnp.max(hi), 1e-12),
         diag=diag,
+        stride=stride,
     )
+
+
+def _curve_stride(steps: int) -> int:
+    """Steps a slot of the exact dual's curve: ``_CURVE_SLOTS`` slots over
+    the step cap (fewer where ``CS230_CURVE_POINTS`` asks for fewer)."""
+    from ..obs.curves import trace_stride
+
+    return max(trace_stride(steps), -(-steps // _CURVE_SLOTS))
 
 
 class SVCKernel(ModelKernel):
@@ -381,13 +434,18 @@ class SVCKernel(ModelKernel):
     def trace_salt(self):
         """Solver knobs read from env at trace time (module docstring) —
         they change the compiled program, so they must key the AOT cache
-        (a knob flip must not load the pre-knob executable)."""
+        (a knob flip must not load the pre-knob executable). CS230_CURVES
+        joins them: with capture on, the exact dual's loop carries its
+        curve and the program hands it back."""
+        from ..obs.curves import curves_salt
+
         return (
-            int(os.environ.get("CS230_SVM_PG_STEPS", _PG_STEPS)),
+            _pg_steps(),
             _nystrom_steps(),
             _kmeans_iters(),
             os.environ.get("CS230_SVM_NYSTROM_M", ""),
             os.environ.get("CS230_SVM_KKT_TOL", ""),
+            curves_salt(),
         )
 
     def resolve_static(self, static: Dict[str, Any], n: int, d: int, n_classes: int):
@@ -430,13 +488,26 @@ class SVCKernel(ModelKernel):
         return Z, landmarks, inv_sqrt, lam_max
 
     def fit(self, X, y, w, hyper: Dict[str, Any], static: Dict[str, Any]):
+        return self._fit(X, y, w, hyper, static, trace=False)[0]
+
+    def fit_curve(self, X, y, w, hyper: Dict[str, Any], static: Dict[str, Any]):
+        """Capture hook (docs/OBSERVABILITY.md "Trial telemetry plane"): the
+        same fit, and the exact dual's learning curve ``{"gmax": [slots],
+        "stride", "steps"}``: the sum of the coefficients of all machines
+        after each step, the dual objective's linear term
+        (``_fista_ascent``), named ``gmax`` as the other families' curve
+        channel is. The Nyström primal hands back no curve."""
+        return self._fit(X, y, w, hyper, static, trace=True)
+
+    def _fit(self, X, y, w, hyper: Dict[str, Any], static: Dict[str, Any],
+             trace: bool):
         X = X.astype(jnp.float32)
         w = w.astype(jnp.float32)
         c = max(int(static["_n_classes"]), 2)
         C = jnp.asarray(hyper["C"], jnp.float32)
         gamma = self._gamma(X, w, static)
         if static.get("_nystrom"):
-            return self._fit_nystrom(X, y, w, C, gamma, static, c)
+            return self._fit_nystrom(X, y, w, C, gamma, static, c), {}
         K = _gram(X, X, static["kernel"], gamma, static.get("degree", 3), static.get("coef0", 0.0))
         # bf16 kernel operand for the ascent's matvec stream (the fit's
         # HBM-bound term, see _constrained_dual_ascent) — RBF only, whose
@@ -461,18 +532,31 @@ class SVCKernel(ModelKernel):
              & (w > 0)[:, None]).astype(jnp.float32)  # [n, P]
         T = jnp.where(y[:, None] == pa[None, :], 1.0, -1.0)
         TS = T * S
-        A = _constrained_dual_ascent_multi(Kb, S, TS, C * S, diag=1e-6)
+        steps = _pg_steps()
+        stride = _curve_stride(steps) if trace else None
+        A, ran, *curve = _constrained_dual_ascent_multi(
+            Kb, S, TS, C * S, steps=steps, diag=1e-6, stride=stride)
         # KKT intercepts: average t_i - (margin) over FREE support vectors
-        # (0 < alpha < C) per machine; fall back to all SVs
-        F = jnp.matmul(K, A * TS, preferred_element_type=jnp.float32)
+        # (0 < alpha < C) per machine; fall back to all SVs. The margins'
+        # product is float32 at HIGHEST: the default takes one bfloat16
+        # pass on a TPU, and the compiler then keeps only the bf16 Gram
+        F = jnp.matmul(K, A * TS, precision=jax.lax.Precision.HIGHEST,
+                       preferred_element_type=jnp.float32)
         free = S * (A > 1e-6 * C) * (A < C * (1.0 - 1e-6))
         anyv = S * (A > 1e-6 * C)
         use = jnp.where(jnp.sum(free, axis=0) > 0.5, free, anyv)
         b = jnp.sum(use * (T - F), axis=0) / jnp.maximum(
             jnp.sum(use, axis=0), 1e-6
         )
-        return {"X": X, "dual": (A * TS).T, "intercept": b, "gamma": gamma,
-                "pairs_a": pa, "pairs_b": pb}
+        params = {"X": X, "dual": (A * TS).T, "intercept": b, "gamma": gamma,
+                  "pairs_a": pa, "pairs_b": pb, "steps": ran}
+        if not trace:
+            return params, {}
+        return params, {
+            "gmax": curve[0],
+            "stride": jnp.asarray(float(stride), jnp.float32),
+            "steps": jnp.asarray(float(steps), jnp.float32),
+        }
 
     def _fit_nystrom(self, X, y, w, C, gamma, static, c):
         """Primal squared-hinge OvO machines on Nyström features."""
@@ -500,6 +584,7 @@ class SVCKernel(ModelKernel):
             "gamma": gamma,
             "pairs_a": pa,
             "pairs_b": pb,
+            "steps": jnp.int32(_nystrom_steps()),
         }
 
     def _pair_decisions(self, params, X, static: Dict[str, Any]):
@@ -525,6 +610,38 @@ class SVCKernel(ModelKernel):
         # artifacts fitted before the KKT-intercept form: K+1 bias
         return dec + jnp.sum(params["dual"], axis=1)[None, :]
 
+    def evaluate(self, params, X, y, w, static: Dict[str, Any]) -> Dict[str, Any]:
+        """The score, and the steps the fit's solver ran as the
+        ``ascent_steps`` leaf, which the engine's dispatch reads
+        (:meth:`dispatch_result`)."""
+        out = dict(super().evaluate(params, X, y, w, static))
+        out["ascent_steps"] = jnp.asarray(params["steps"], jnp.int32)
+        return out
+
+    def dispatch_result(self, static: Dict[str, Any], n: int, out,
+                        n_trials: int) -> Dict[str, Any]:
+        """What a dispatch ran, from its fetched result (``ascent_steps``
+        [lanes, splits], of which the first ``n_trials`` lanes are real):
+        the attributes of its ``executor.dispatch`` span and the
+        ``tpuml_svm_*`` counters. ``svm.gram_bytes`` is the operand
+        one ascent step streams a lane (the bfloat16 [n, n] Gram of the
+        exact dual, the float32 [n, m+1] Nyström features), so a lane
+        streams ``steps`` times it."""
+        steps = np.asarray(out["ascent_steps"], np.int64)[:n_trials]
+        nystrom = bool(static.get("_nystrom"))
+        c = max(int(static["_n_classes"]), 2)
+        if nystrom:
+            gram_bytes = 4 * n * (int(static["_m"]) + 1)
+        else:
+            gram_bytes = n * n * (2 if static["kernel"] == "rbf" else 4)
+        total = int(steps.sum())
+        counter_inc("tpuml_svm_ascent_steps_total", total,
+                    solver="nystrom" if nystrom else "exact")
+        counter_inc("tpuml_svm_gram_stream_bytes_total", float(total) * gram_bytes)
+        return {"svm.solver": "nystrom" if nystrom else "exact",
+                "svm.machines": c * (c - 1) // 2, "svm.gram_bytes": gram_bytes,
+                "svm.steps_max": int(steps.max(initial=0)), "svm.steps_sum": total}
+
     def predict(self, params, X, static: Dict[str, Any]):
         c = max(int(static["_n_classes"]), 2)
         dec = self._pair_decisions(params, X, static)
@@ -545,10 +662,12 @@ class SVCKernel(ModelKernel):
             return jnp.asarray(static["_gamma_value"], jnp.float32)
         if static.get("_gamma_mode") == "auto":
             return jnp.asarray(1.0 / X.shape[1], jnp.float32)
+        # sklearn's "scale": 1 / (n_features * X.var()) over the training
+        # rows, the variance of all their entries about ONE mean
         w = w.astype(jnp.float32)
-        wsum = jnp.maximum(jnp.sum(w), 1.0)
-        mean = jnp.sum(X * w[:, None], 0) / wsum
-        var = jnp.sum(w[:, None] * (X - mean) ** 2) / (wsum * X.shape[1])
+        cells = jnp.maximum(jnp.sum(w), 1.0) * X.shape[1]
+        mean = jnp.sum(X * w[:, None]) / cells
+        var = jnp.sum(w[:, None] * (X - mean) ** 2) / cells
         return 1.0 / jnp.maximum(X.shape[1] * var, 1e-12)
 
     def memory_estimate_mb(self, n, d, static):

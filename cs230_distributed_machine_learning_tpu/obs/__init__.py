@@ -371,6 +371,16 @@ def register_catalog() -> None:
         "(no upload)",
     )
     c(
+        "tpuml_svm_ascent_steps_total",
+        "Solver steps the SVC fits of the engine's dispatches ran, summed "
+        "over their lanes, by solver (exact|nystrom)",
+    )
+    c(
+        "tpuml_svm_gram_stream_bytes_total",
+        "Bytes of the kernel operand the SVC solvers streamed: each lane's "
+        "steps x the operand one step reads",
+    )
+    c(
         "tpuml_mesh_reshards_total",
         "Fleet mesh-generation bumps, labeled by reason "
         "(join|death|evict|unsubscribe)",

@@ -1502,10 +1502,14 @@ def _dispatch(run: _Run, kernel, bp: BucketPlan, exe, fresh: bool, X, folds,
             # read off the staged extras: only where spans are kept, and not
             # from extras an unsigned plan builds anew for every search
             attrs.update(kernel.dispatch_attrs(bp.static, X, extras or {}))
+    # a kernel whose dispatch says what it ran (the SVC solvers' steps) is
+    # waited for inside the span that carries it: a chunk's fetch, one
+    # round trip a chunk, precedes the next chunk's enqueue
+    said = getattr(kernel, "dispatch_result", None)
     for start in range(0, len(idxs), chunk):
         batch_idx = idxs[start : start + chunk]
         with _dispatch_span(place.mesh, engine, start // chunk, chunk,
-                            len(batch_idx), **attrs):
+                            len(batch_idx), **attrs) as sp:
             hyper_arg = {
                 k: to_dev(v) for k, v in
                 _hyper_batch(hypers, batch_idx, bp.hyper_names, chunk).items()
@@ -1524,6 +1528,14 @@ def _dispatch(run: _Run, kernel, bp: BucketPlan, exe, fresh: bool, X, folds,
                     out = run.await_compile(out, t0)
                 run.dispatched(cost)
                 outs.append((Packed(out, spec) if spec is not None else out, size))
+            if said is not None and place.n_dev == 1:
+                with child_span("executor.wait", on="result"):
+                    jax.block_until_ready([o.buf if isinstance(o, Packed) else o
+                                           for o, _ in outs])
+                out = _join_split_groups([(run.fetch(o), size) for o, size in outs])
+                sp.attrs.update(said(bp.static, int(X.shape[0]), out, len(batch_idx)))
+                run.record(out, batch_idx)
+                continue
             if bp.split_width:
                 run.pending.append((outs, batch_idx))
                 continue
