@@ -381,6 +381,11 @@ def register_catalog() -> None:
         "steps x the operand one step reads",
     )
     c(
+        "tpuml_svm_exact_lanes_total",
+        "Exact-dual SVC lanes of the engine's dispatches, by the rows their "
+        "Gram holds (train: the split's training rows; table: every row)",
+    )
+    c(
         "tpuml_mesh_reshards_total",
         "Fleet mesh-generation bumps, labeled by reason "
         "(join|death|evict|unsubscribe)",

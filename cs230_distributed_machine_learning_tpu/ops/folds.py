@@ -56,6 +56,11 @@ class SplitPlan:
     def n_samples(self) -> int:
         return self.train_w.shape[1]
 
+    @property
+    def fit_rows(self) -> int:
+        """The most rows any split trains on."""
+        return int(np.count_nonzero(self.train_w > 0, axis=1).max(initial=0))
+
 
 def plan_signature(
     n: int, *, task: str, n_folds: int, test_size: float, random_state

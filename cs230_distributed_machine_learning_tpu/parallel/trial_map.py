@@ -759,7 +759,8 @@ class BucketPlan:
     )
 
 
-def _resolved_static(kernel, static_key, n, d, n_classes, scoring=None):
+def _resolved_static(kernel, static_key, n, d, n_classes, scoring=None,
+                     split_plan: Optional[SplitPlan] = None):
     """A bucket's static config before its hypers are known."""
     static = kernel.static_from_key(static_key)
     if hasattr(kernel, "resolve_static"):
@@ -769,6 +770,10 @@ def _resolved_static(kernel, static_key, n, d, n_classes, scoring=None):
         # only non-default scorers join the key: default jobs keep their
         # (already disk-cached) executables byte-identical
         static["_scoring"] = scoring
+    if split_plan is not None and hasattr(kernel, "with_fit_rows"):
+        # a kernel that fits on its split's training rows alone sizes its
+        # arrays by the most rows a split trains on (a host fact of the plan)
+        static = kernel.with_fit_rows(static, n, split_plan.fit_rows)
     return static
 
 
@@ -1614,7 +1619,7 @@ def _run_buckets(kernel, data, split_plan, param_dicts, mesh, trial_axis,
     chunked: List[Callable[[], None]] = []
     for static_key, idxs in buckets.items():
         static = _resolved_static(kernel, static_key, n, d, data.n_classes,
-                                  scoring)
+                                  scoring, split_plan)
         # bucket-level data prep (feature binning for trees): computed
         # once, shared by every trial and split, cached across jobs on the
         # TrialData. Without it every bucket stages the same [n, d] matrix:

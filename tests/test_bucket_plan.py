@@ -349,3 +349,97 @@ def test_one_builder_builds_all_four_tags(monkeypatch):
                 if s["name"] == "executor.compile"]
     assert len(compiles) == 5
     assert {s["attrs"]["cache"] for s in compiles} == {"traced"}
+
+
+# ---- the exact-dual SVC's training rows (the cell svc_mnist30k.rs4svc) ----
+
+#: the cell's table: 30 000 x 784, 10 classes, a holdout of 0.2 and five folds
+SVC_CELL = (30_000, 784, 10)
+
+
+def _svc_cell_plan():
+    n = SVC_CELL[0]
+    return build_split_plan(np.arange(n) % SVC_CELL[2], task="classification",
+                            n_folds=5, test_size=0.2, random_state=42)
+
+
+@pytest.mark.parametrize("device_mb", [16.0e3, 16.9e3])
+def test_svc_bucket_carries_its_plans_fit_rows_at_the_cells_geometry(
+        no_device_work, device_mb):
+    """Every split of the cell trains on 24 000 rows: the bucket's static
+    says so, and the plan keeps one trial and one split a dispatch."""
+    n, d, c = SVC_CELL
+    split_plan = _svc_cell_plan()
+    assert split_plan.fit_rows == 24_000
+    kernel = get_kernel("SVC")
+    static_key, hyper = kernel.canonicalize({"kernel": "rbf", "gamma": "scale", "C": 1.0})
+    static = trial_map._resolved_static(kernel, static_key, n, d, c, None, split_plan)
+    assert static == {**trial_map._resolved_static(kernel, static_key, n, d, c),
+                      "_fit_rows": 24_000}
+    assert kernel.memory_estimate_mb(n, d, static) < kernel.memory_estimate_mb(
+        n, d, {k: v for k, v in static.items() if k != "_fit_rows"})
+    no_device_work.setattr(trial_map._backend, "device_memory_mb", lambda: device_mb)
+    plan = trial_map.plan_bucket(
+        kernel, static, [hyper] * 4, np.broadcast_to(np.float32(0), (n, d)),
+        n=n, d=d, n_classes=c, n_splits=split_plan.n_splits)
+    assert (plan.engine, plan.chunk, plan.split_width) == ("generic", 1, 1)
+    assert plan.static["_fit_rows"] == 24_000
+
+
+@pytest.mark.parametrize("name,params", [
+    ("LogisticRegression", {"C": 1.0}),
+    ("MLPClassifier", {"hidden_layer_sizes": [8]}),
+    ("RandomForestClassifier", {"n_estimators": 4}),
+    ("GradientBoostingClassifier", {"n_estimators": 4}),
+    ("SVR", {"C": 1.0}),
+])
+def test_other_families_statics_and_keys_do_not_see_the_plan(name, params):
+    """Only a kernel that asks for the plan's fit rows gets them: every other
+    family's static and executable key are what they were without it."""
+    rng = np.random.RandomState(0)
+    X = rng.randn(300, 6).astype(np.float32)
+    y = rng.randint(0, 3, 300)
+    kernel = get_kernel(name)
+    c = 3 if kernel.task == "classification" else 0
+    split_plan = build_split_plan(y if c else X[:, 0], task=kernel.task,
+                                  n_folds=5, test_size=0.2, random_state=42)
+    assert split_plan.fit_rows < 300
+    static_key, hyper = kernel.canonicalize(params)
+    without = trial_map._resolved_static(kernel, static_key, 300, 6, c)
+    with_plan = trial_map._resolved_static(kernel, static_key, 300, 6, c, None, split_plan)
+    assert with_plan == without and "_fit_rows" not in with_plan
+    names = sorted(hyper)
+    assert (trial_map._aot_key(kernel, with_plan, X, c, 6, 8, names)
+            == trial_map._aot_key(kernel, without, X, c, 6, 8, names))
+
+
+def test_svc_prewarm_builds_the_executable_its_search_runs():
+    """A ``warm_only`` run of an SVC bucket reaches the key, ``_fit_rows``
+    included, that the search after it hits."""
+    from cs230_distributed_machine_learning_tpu.obs import REGISTRY
+
+    rng = np.random.RandomState(1)
+    X = rng.randn(120, 4).astype(np.float32)
+    y = rng.randint(0, 3, 120).astype(np.int32)
+    data = TrialData(X=X, y=y, n_classes=3)
+    split_plan = build_split_plan(y, task="classification", n_folds=2,
+                                  test_size=0.2, random_state=42)
+    params = [{"kernel": "rbf", "gamma": "scale", "C": C} for C in (0.5, 5.0)]
+    kernel = get_kernel("SVC")
+    saved = dict(trial_map._compiled_cache)
+    trial_map._compiled_cache.clear()
+    try:
+        warm = trial_map.run_trials(kernel, data, split_plan, params, warm_only=True)
+        assert warm.n_dispatches == 0 and not warm.trial_metrics
+        (key,) = trial_map._compiled_cache
+        assert ("_fit_rows", str(split_plan.fit_rows)) in key[2]
+        hits = REGISTRY.counter("tpuml_executable_cache_hits_total").value()
+        misses = REGISTRY.counter("tpuml_executable_cache_misses_total").value()
+        out = trial_map.run_trials(kernel, data, split_plan, params)
+        assert REGISTRY.counter("tpuml_executable_cache_hits_total").value() > hits
+        assert REGISTRY.counter("tpuml_executable_cache_misses_total").value() == misses
+        assert list(trial_map._compiled_cache) == [key]
+        assert all(0.0 <= m["accuracy"] <= 1.0 for m in out.trial_metrics)
+    finally:
+        trial_map._compiled_cache.clear()
+        trial_map._compiled_cache.update(saved)

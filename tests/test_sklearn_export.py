@@ -209,3 +209,46 @@ def test_export_roundtrips_sklearn_pickle():
     a = est.predict(_XQ.astype(np.float64))
     b = est2.predict(_XQ.astype(np.float64))
     assert np.array_equal(a, b)
+
+
+def test_export_svc_fitted_on_its_training_rows():
+    """A fit over the split's training rows (``_fit_rows``, the plan's
+    largest split: this split's rows and pad rows of weight 0) exports to an
+    sklearn SVC that predicts what the program does; its dispatch says the
+    rows and the Gram it streamed, and counts the lane as ``train``."""
+    import jax.numpy as jnp
+
+    from cs230_distributed_machine_learning_tpu.obs import REGISTRY
+    from cs230_distributed_machine_learning_tpu.parallel import trial_map
+
+    data = _data("cls3")
+    kernel = get_kernel("SVC")
+    # folds train on 240 rows, the holdout on 225
+    plan = build_split_plan(np.asarray(data.y), task="classification", n_folds=5,
+                            test_size=0.25, random_state=42)
+    params = {"C": 1.0}
+    static_key, hyper = kernel.canonicalize(params)
+    static = trial_map._resolved_static(kernel, static_key, 300, 6, 3, None, plan)
+    rows = static["_fit_rows"]
+    assert rows == plan.fit_rows < 300
+    w = plan.train_w[0]
+    assert int(w.sum()) < rows  # the holdout split is padded
+    fitted = kernel.fit(jnp.asarray(data.X), jnp.asarray(data.y), jnp.asarray(w),
+                        {k: jnp.asarray(v, jnp.float32) for k, v in hyper.items()}, static)
+    fitted = {k: np.asarray(v) for k, v in fitted.items()}
+    assert fitted["X"].shape == (rows, 6) and fitted["dual"].shape == (3, rows)
+    artifact = {"model_type": "SVC", "parameters": params, "static": static,
+                "fitted_params": fitted}
+    _assert_parity(artifact, kernel)
+
+    lanes = REGISTRY.counter("tpuml_svm_exact_lanes_total")
+    before = {r: lanes.value(rows=r) for r in ("train", "table")}
+    steps = np.full((1, 2), 600, np.int32)
+    said = kernel.dispatch_result(static, 300, {"ascent_steps": steps}, 1)
+    assert said["svm.fit_rows"] == rows and said["svm.gram_bytes"] == rows * rows * 2
+    assert said["svm.steps_sum"] == 1200 and said["svm.solver"] == "exact"
+    assert lanes.value(rows="train") == before["train"] + 2
+    table = {k: v for k, v in static.items() if k != "_fit_rows"}
+    said = kernel.dispatch_result(table, 300, {"ascent_steps": steps}, 1)
+    assert said["svm.fit_rows"] == 300 and said["svm.gram_bytes"] == 300 * 300 * 2
+    assert lanes.value(rows="table") == before["table"] + 2

@@ -287,3 +287,91 @@ def test_svc_scores_and_curves_match_the_plain_reference(svc_cell, monkeypatch, 
     limits.pop("failed_trials")
     correct, table = compare.judge(numbers, limits)
     assert correct, table
+
+
+# ---- the exact dual over a split's training rows only ----
+#
+# With ``_fit_rows`` in its static (the trial engine hands it the most rows
+# a split of the plan trains on) the exact dual gathers each split's training
+# rows and fits over them alone. The rows a split masks added exact zeros to
+# every product, so the compacted fit is the masked full-table fit up to
+# summation order: the same predictions, the same step size, and duals and
+# curves within bfloat16's rounding of the ascent's Gram operand.
+
+#: bfloat16's machine epsilon: the ascent streams its Gram in bfloat16, so
+#: a reordered sum may differ from another by that much of the box C
+BF16_EPS = 2.0 ** -7
+
+
+def _fit_capturing_eta(monkeypatch, kernel, X, y, w, C, static):
+    """``fit_curve`` and the step sizes its ascent ran with."""
+    import cs230_distributed_machine_learning_tpu.models.svm as svm_mod
+
+    seen, ascent = [], svm_mod._fista_ascent
+
+    def spy(*a, **kw):
+        seen.append(np.asarray(kw["eta"]))
+        return ascent(*a, **kw)
+
+    monkeypatch.setattr(svm_mod, "_fista_ascent", spy)
+    fitted, curve = kernel.fit_curve(jnp.asarray(X), jnp.asarray(y), jnp.asarray(w),
+                                     {"C": jnp.float32(C)}, static)
+    return fitted, curve, seen[0]
+
+
+def test_svc_fit_on_the_training_rows_matches_the_masked_table_fit(monkeypatch):
+    from sklearn.datasets import make_classification
+
+    from cs230_distributed_machine_learning_tpu.ops.folds import SplitPlan
+    from cs230_distributed_machine_learning_tpu.parallel import trial_map
+
+    X, y = make_classification(n_samples=240, n_features=8, n_informative=5,
+                               n_classes=4, random_state=3)
+    X, y = X.astype(np.float32), y.astype(np.int32)
+    rng = np.random.RandomState(0)
+    # two splits of unequal size: the smaller is padded up to the larger
+    TW = np.stack([rng.rand(240) < 0.75, rng.rand(240) < 0.6]).astype(np.float32)
+    plan = SplitPlan(train_w=TW, eval_w=1.0 - TW, n_folds=1)
+    counts = TW.sum(axis=1).astype(int)
+    assert plan.fit_rows == counts.max() and counts.min() < counts.max()
+    kernel = get_kernel("SVC")
+    key = kernel.canonicalize({"kernel": "rbf", "gamma": "scale"})[0]
+    table = trial_map._resolved_static(kernel, key, 240, 8, 4)
+    rows = trial_map._resolved_static(kernel, key, 240, 8, 4, None, plan)
+    assert "_fit_rows" not in table and rows == {**table, "_fit_rows": plan.fit_rows}
+    C = 3.0
+    for w, count in zip(TW, counts):
+        full, full_curve, full_eta = _fit_capturing_eta(monkeypatch, kernel, X, y, w, C, table)
+        part, part_curve, part_eta = _fit_capturing_eta(monkeypatch, kernel, X, y, w, C, rows)
+        assert full["X"].shape == (240, 8) and full["dual"].shape == (6, 240)
+        assert part["X"].shape == (plan.fit_rows, 8) and part["dual"].shape == (6, plan.fit_rows)
+        # the training rows in table order, then the pad rows with no weight
+        train = np.flatnonzero(w)
+        np.testing.assert_array_equal(np.asarray(part["X"])[:count], X[train])
+        assert not np.asarray(part["dual"])[:, count:].any()
+        np.testing.assert_allclose(part_eta, full_eta, rtol=1e-5)
+        np.testing.assert_array_equal(np.asarray(kernel.predict(part, jnp.asarray(X), rows)),
+                                      np.asarray(kernel.predict(full, jnp.asarray(X), table)))
+        np.testing.assert_allclose(np.asarray(part["dual"])[:, :count],
+                                   np.asarray(full["dual"])[:, train], atol=BF16_EPS * C)
+        np.testing.assert_allclose(np.asarray(part_curve["gmax"]), np.asarray(full_curve["gmax"]),
+                                   rtol=BF16_EPS)
+        assert int(part["steps"]) == int(full["steps"])
+
+
+def test_svc_without_the_fit_rows_static_fits_the_whole_table():
+    """A direct fit, a refit and a plan whose splits train on every row take
+    the table's Gram; so does the Nyström primal past the exact gate."""
+    X, y = load_iris(return_X_y=True)
+    X, y = X.astype(np.float32), y.astype(np.int32)
+    kernel = get_kernel("SVC")
+    w = np.ones(150, np.float32)
+    w[::3] = 0.0
+    fitted, static = _fit(kernel, X, y, {"C": 1.0}, 3)
+    assert "_fit_rows" not in static and fitted["X"].shape == (150, 4)
+    assert kernel.with_fit_rows(static, 150, 150) is static
+    assert kernel.with_fit_rows(static, 150, 0) is static
+    assert kernel.with_fit_rows({**static, "_nystrom": True}, 150, 100).get("_fit_rows") is None
+    assert kernel.with_fit_rows(static, 150, 100)["_fit_rows"] == 100
+    # SVR keeps the masked table Gram: it asks for no fit rows
+    assert not hasattr(get_kernel("SVR"), "with_fit_rows")

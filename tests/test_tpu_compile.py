@@ -777,3 +777,33 @@ def test_streamed_grad_kernel_compiles_at_the_cell_shape(tpu_backend, monkeypatc
     assert not any(re.match(rf"(?:ROOT )?%\S+ = \w+\[(?:{rows},{_D_MNIST8M}|{_D_MNIST8M},{rows})\]\S* (?:copy|convert|fusion)", t)
                    for t in text)
     assert compiled.memory_analysis().temp_size_in_bytes < 64e6
+
+
+def test_svc_lane_program_holds_the_training_rows_gram(tpu_backend):
+    """The SVC cell's lane program (one trial, one split, 30 000 x 784, 10
+    classes; every split trains on 24 000 rows) as the engine builds it,
+    ``_fit_rows`` from the cell's fold plan. Pinned: no array is the
+    table's [30000, 30000] Gram, the ascent's loop carries its [24000, 45]
+    coefficients in VMEM (``S(1)``: out of it, the bisection's 30 passes a
+    step go to HBM), and the scratch is the float32 and
+    bfloat16 [24000, 24000] Grams (3.56 GB; 5.43 GB over the table's rows)."""
+    from cs230_distributed_machine_learning_tpu.ops.folds import build_split_plan
+    from cs230_distributed_machine_learning_tpu.parallel import trial_map
+
+    n, d, c = 30_000, 784, 10
+    split_plan = build_split_plan(np.arange(n) % c, task="classification", n_folds=5,
+                                  test_size=0.2, random_state=42)
+    kernel = get_kernel("SVC")
+    key = kernel.canonicalize({"kernel": "rbf", "gamma": "scale"})[0]
+    static = trial_map._resolved_static(kernel, key, n, d, c, None, split_plan)
+    assert static["_fit_rows"] == 24_000
+    compiled = _lower_and_compile(
+        trial_map._make_batched(kernel, static, True), _sds((n, d), jnp.float32),
+        _sds((n,), jnp.int32), _sds((1, n), jnp.float32), _sds((1, n), jnp.float32),
+        {"C": _sds((1,), jnp.float32)})
+    if compiled is None:
+        return  # lowered only: no deviceless topology here
+    text = compiled.as_text()
+    assert "[1,30000,30000]" not in text and "bf16[1,24000,24000]" in text
+    assert re.search(r"f32\[1,1,24000,45\]\{[^}]*S\(1\)\}", text)
+    assert 3.0e9 < compiled.memory_analysis().temp_size_in_bytes < 4.0e9
