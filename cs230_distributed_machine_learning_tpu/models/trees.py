@@ -568,9 +568,16 @@ class _TreeBase(ModelKernel):
 def _bootstrap_counts(key, w, n):
     """Exact bootstrap: n draws with replacement from rows where w>0.
 
-    Uniform-over-active-rows multinomial via inverse-CDF searchsorted —
-    O(n log n), unlike jax.random.categorical whose gumbel matrix is
-    [draws, categories] = n x n (54 GB at Covertype scale).
+    Each draw is a rank ``k`` in [1, n_active]; the row it picks is the
+    k-th active row. The draws are counted in rank space (one scatter of n
+    ones into ``H``) and expanded to rows with one gather: ``caw``, the
+    active rows' running count, is one-to-one on the active rows (it rises
+    by one at each), so active row r is the row of rank ``caw[r]`` and its
+    count is ``H[caw[r] - 1]``; an inactive row gets 0. The counts are the
+    integers an inverse-CDF search of ``caw`` for each draw would give, with
+    no log2(n) rounds of random gathers, and O(n) memory, unlike
+    jax.random.categorical whose gumbel matrix is [draws, categories] =
+    n x n (54 GB at Covertype scale).
 
     Counts are capped at 127 so classification histograms can ride the s8
     MXU path (ops/trees integer_stats). The cap is unreachable in
@@ -580,14 +587,14 @@ def _bootstrap_counts(key, w, n):
     caw = jnp.cumsum(active)
     n_active = caw[-1]
     targets = jax.random.randint(key, (n,), 1, jnp.maximum(n_active, 1) + 1)
-    # the draws are made once and then searched: without the barrier XLA
-    # fuses randint's remainder chain into the binary search's loop body
-    # (every probe draws again; 29 s of TPU compile against 6, PR 32)
+    # the draws are made once, as an array, before they are counted: XLA
+    # otherwise fuses randint's remainder chain into its consumers, and in
+    # a loop body draws again every iteration (29 s of TPU compile against 6)
     caw, targets = jax.lax.optimization_barrier((caw, targets))
-    rows = jnp.searchsorted(caw, targets, side="left")
-    counts = jax.ops.segment_sum(
-        jnp.ones((n,), jnp.float32), rows, num_segments=n
+    H = jax.ops.segment_sum(
+        jnp.ones((n,), jnp.float32), targets - 1, num_segments=n
     )
+    counts = jnp.where(active > 0, H[jnp.maximum(caw - 1, 0)], 0.0)
     return jnp.minimum(counts, 127.0)
 
 
